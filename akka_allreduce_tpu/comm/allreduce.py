@@ -153,9 +153,13 @@ def grouped_tree_psum(grads, specs, axis_names: Axes, wire_dtype=None):
 
     Each leaf is summed over the mesh axes its spec does NOT shard over
     (replicated leaves over all axes; TP/EP/PP-sharded leaves only over the
-    remaining ones). Leaves are grouped by reduce-axes and flattened into ONE
-    buffer per group, so the step issues one collective per distinct
-    sharding class — never one psum per parameter leaf. ``wire_dtype``
+    remaining ones). Leaves are grouped by reduce-axes and each group is
+    summed by ONE ``psum`` over all its leaves: JAX emits an all-reduce per
+    leaf and XLA's all-reduce combiner merges them into a few large ones
+    (18 for the 404M flagship's step on four chips) — the bucketing is the
+    compiler's, with no hand-built staging buffer. Only the int8 ring still
+    flattens a group into one buffer (it segments by position).
+    ``wire_dtype``
     (e.g. ``jnp.bfloat16``) casts each group's payload for the collective,
     halving ICI/DCN bytes — or the string ``"int8"``, which runs each
     group through the explicit int8 ring (quarter-width hops with
@@ -196,23 +200,28 @@ def grouped_tree_psum(grads, specs, axis_names: Axes, wire_dtype=None):
             for i in idxs:
                 out[i] = leaves[i]
             continue
+        if not int8:
+            # the leaves as they are, no flat staging buffer:
+            # concatenating 286M-404M gradient elements cost a copy in and
+            # a copy out, and libtpu 0.0.34 laid the MoE's flat buffer out
+            # as f32[N/8, 8] after its (d_model, 8) router leaf — 16x lane
+            # padding, 18.3 GB, refused on a 16 GB v5e (PR 21)
+            group = [leaves[i] for i in idxs]
+            if wire_dtype is not None:
+                group = [g.astype(wire_dtype) for g in group]
+            for i, total in zip(idxs, lax.psum(group, reduce_over)):
+                out[i] = total.astype(leaves[i].dtype)
+            continue
+        # the int8 ring segments ONE flat buffer; its hop decompression
+        # accumulates in f32, so run the whole schedule there and hand
+        # back the leaf dtype
         flat = jnp.concatenate([leaves[i].reshape(-1) for i in idxs])
-        if int8:
-            # the ring's hop decompression accumulates in f32; run the
-            # whole schedule there and hand back the leaf dtype (the
-            # bf16-psum branch below makes the same round trip)
-            total = flat.astype(jnp.float32)
-            for ax in reduce_over:
-                total = ring_allreduce_sum(
-                    total, ax, lax.axis_size(ax), compress="int8"
-                )
-            total = total.astype(flat.dtype)
-        elif wire_dtype is not None and flat.dtype != wire_dtype:
-            total = lax.psum(
-                flat.astype(wire_dtype), reduce_over
-            ).astype(flat.dtype)
-        else:
-            total = lax.psum(flat, reduce_over)
+        total = flat.astype(jnp.float32)
+        for ax in reduce_over:
+            total = ring_allreduce_sum(
+                total, ax, lax.axis_size(ax), compress="int8"
+            )
+        total = total.astype(flat.dtype)
         offset = 0
         for i in idxs:
             n = leaves[i].size

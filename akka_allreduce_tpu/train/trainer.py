@@ -971,8 +971,8 @@ class DPTrainer:
         ``sampler`` is a traced ``(key, batch_size) -> (x, y)`` (e.g.
         ``SyntheticClassification.device_sampler``); each device draws its own
         batch shard per step, so no host->device transfer happens inside the
-        loop — the data-loader discipline for tunneled/remote chips where a
-        per-step host round trip costs more than the step itself.
+        loop — the data-loader discipline for steps short enough that a
+        per-step host round trip would cost more than the step itself.
 
         ``fetch_metrics=False`` returns the raw ``(losses, counts)`` device
         arrays instead of a metrics list — for benchmarks that must keep the

@@ -5,11 +5,7 @@ from akka_allreduce_tpu.utils.metrics import (  # noqa: F401
     RoundMetrics,
 )
 from akka_allreduce_tpu.utils.compile_cache import (  # noqa: F401
-    CompileCacheHandle,
-    enable_persistent_compile_cache,
-)
-from akka_allreduce_tpu.utils.platform import (  # noqa: F401
-    respect_env_platform,
+    enable_compile_cache,
 )
 from akka_allreduce_tpu.utils.verify import (  # noqa: F401
     assert_replica_consistent,

@@ -1,93 +1,46 @@
-"""Persistent XLA compilation cache for elastic re-mesh (VERDICT r4 #7).
+"""Where JAX's persistent compilation cache lives: one rule, no options.
 
-An elastic membership change rebuilds the trainer over the new mesh: new
-closures, new ``jax.jit`` objects, so the IN-PROCESS jit cache cannot
-help — every re-mesh pays a full XLA compile even when a node rejoins at
-a mesh size the process has already compiled for (config 5 measured
-9.3–12.3 s per transformer-family re-mesh, recompile-dominated). JAX's
-persistent compilation cache keys on the HLO fingerprint instead, which
-IS identical when the same program recurs at the same mesh size — so
-with it enabled, the second drop (or any rejoin to a previous size)
-loads the executable from disk instead of recompiling.
+- ``JAX_COMPILATION_CACHE_DIR`` set in the environment: JAX already reads
+  it at import, so this module sets NO directory — whoever placed the cache
+  (the chip tool, an operator) owns the path.
+- unset: the cache goes to :data:`DEFAULT_DIR`, ``<checkout>/.jax_cache``
+  (listed in ``.gitignore``). The path is fixed — never a temp dir, a pid
+  or a timestamp — because the directory is part of what an entry is
+  keyed on: a cache that moves never hits.
 
-Opt-in via ``--compile-cache [DIR]`` on the training CLIs and measured
-by ``bench-suite``'s config-5 tier (cold vs warm cycle latencies).
+JAX's two entry thresholds (minimum compile time / entry size) stay at
+their defaults: the programs worth keeping between processes are the
+multi-second trainer steps, which clear them, and a cache-everything
+override is what ROADMAP Design 1's abort was tied to.
 
-``enable_persistent_compile_cache`` mutates GLOBAL ``jax.config`` state;
-it returns a :class:`CompileCacheHandle` so scoped users (bench-suite
-config 5, tests) can put the three flags back in a ``finally`` — the
-round-5 regression was exactly this leak: the cache-everything
-thresholds left live crashed an unrelated elastic test later in the
-same pytest process.
+Called by the PROCESS entry points only (``python -m akka_allreduce_tpu``
+for the training/bench commands, ``bench.py``, ``chip_smoke.py``) before
+their first compile — never at package import and never by the tests'
+conftest: tier-1 runs without a persistent cache.
 """
 
 from __future__ import annotations
 
 import os
-import tempfile
 
+ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
 
-class CompileCacheHandle:
-    """Restore handle for the jax.config flags the enable call replaced.
-
-    ``str(handle)`` / ``handle.directory`` is the cache directory in use
-    (process-lifetime callers just print it); ``restore()`` — idempotent,
-    also run by ``with``-block exit — puts ``jax_compilation_cache_dir``
-    and both persistent-cache thresholds back to their prior values.
-    """
-
-    def __init__(self, directory: str, previous: dict) -> None:
-        self.directory = directory
-        self._previous = previous
-        self._restored = False
-
-    def restore(self) -> None:
-        if self._restored:
-            return
-        self._restored = True
-        import jax
-
-        for name, value in self._previous.items():
-            jax.config.update(name, value)
-
-    def __enter__(self) -> "CompileCacheHandle":
-        return self
-
-    def __exit__(self, *exc) -> None:
-        self.restore()
-
-    def __str__(self) -> str:
-        return self.directory
-
-    def __fspath__(self) -> str:
-        return self.directory
-
-
-_FLAGS = (
-    "jax_compilation_cache_dir",
-    "jax_persistent_cache_min_entry_size_bytes",
-    "jax_persistent_cache_min_compile_time_secs",
+#: ``<checkout>/.jax_cache`` — beside the package, inside the checkout
+DEFAULT_DIR = os.path.join(
+    os.path.dirname(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    ),
+    ".jax_cache",
 )
 
 
-def enable_persistent_compile_cache(
-    directory: str | None = None,
-) -> CompileCacheHandle:
-    """Point JAX's persistent compilation cache at ``directory`` (created
-    if missing; a shared temp-dir default otherwise) and drop the entry
-    thresholds so even small re-mesh programs are cached. Safe to call
-    more than once; returns a :class:`CompileCacheHandle` whose
-    ``restore()`` undoes all three config updates."""
+def enable_compile_cache() -> str:
+    """Apply the rule above; returns the cache directory in force."""
+    from_env = os.environ.get(ENV_VAR)
+    if from_env:
+        return from_env
     import jax
 
-    directory = directory or os.path.join(
-        tempfile.gettempdir(), "akka_allreduce_tpu_xla_cache"
-    )
-    os.makedirs(directory, exist_ok=True)
-    previous = {name: getattr(jax.config, name) for name in _FLAGS}
-    jax.config.update("jax_compilation_cache_dir", directory)
-    # default thresholds skip sub-second / small programs — exactly the
-    # size class the elastic demo's trainers compile to; cache everything
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    return CompileCacheHandle(directory, previous)
+    os.makedirs(DEFAULT_DIR, exist_ok=True)
+    jax.config.update("jax_compilation_cache_dir", DEFAULT_DIR)
+    return DEFAULT_DIR

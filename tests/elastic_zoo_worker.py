@@ -1,10 +1,7 @@
 """Workload-resilience scenario worker (RESILIENCE.md "Tier 7").
 
 Runs the ElasticTrainer edge scenarios that need a REAL jax mesh in an
-interpreter of their own — with the ``_jax_compat`` shims opted in, so the
-same scenarios execute on this container's jax as on a modern one (the
-in-process tier-1 suite must NOT import the shims: they are process-global
-and would change the documented skew baseline's failure shapes).
+interpreter of their own (an 8-device virtual CPU mesh, forced below).
 
 Invoked by tests/test_chaos_train.py (and test_soak.py) as::
 
@@ -27,8 +24,6 @@ os.environ["XLA_FLAGS"] = (
     + " --xla_force_host_platform_device_count=8"
 ).strip()
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-import akka_allreduce_tpu._jax_compat  # noqa: E402,F401  (operator opt-in)
 
 import jax  # noqa: E402
 import numpy as np  # noqa: E402

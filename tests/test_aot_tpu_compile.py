@@ -1,0 +1,218 @@
+"""Real-width compiles for a DESCRIBED ``v5e:2x2`` — no chip attached.
+
+The TPU compiler is installed beside the CPU backend and compiles for a
+topology that is described, not attached (``/opt/skills/guides/
+on-chip-measurement`` section 2). Interpret-mode tests cannot see what it
+refuses: a slice not aligned to the tiling, a kernel over its VMEM budget, a
+program over the chip's 16 GB. These cases keep the main path's kernels and
+the flagship step compiling at the sizes ``chip_smoke.py`` runs them, at no
+chip time. A compile that passes is not a chip run.
+
+Nothing executes and no array can live on a described device, so every
+case lowers ``jax.ShapeDtypeStruct``s. Code that asks
+``jax.default_backend()`` would still see the CPU and take its CPU branch
+(interpret-mode kernels, blockwise attention); the ``as_tpu`` fixture steers
+it HERE, in the test, not through an option of the program.
+"""
+
+from __future__ import annotations
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs to /tmp
+
+import jax
+import jax.numpy as jnp
+import optax
+import pytest
+from jax.sharding import NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as P
+
+HBM_BYTES = 16e9  # one v5e chip
+
+FLOATS_PER_DEVICE = 64 * 1024 * 1024  # BASELINE config 2, chip_smoke's size
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"cannot describe a v5e:2x2 topology here: {e!r}")
+
+
+@pytest.fixture
+def as_tpu(monkeypatch):
+    """Compile what the chip would run: ``jax.default_backend()`` answers
+    "tpu" for the package's dispatch gates, and the persistent compile cache
+    is off (such a compile could be written to it but never read back)."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    compilation_cache.reset_cache()
+
+
+def _reduce_kernels(topo, monkeypatch):
+    """The fused threshold-reduce + elastic-average at 8 x 8M f32."""
+    from akka_allreduce_tpu.ops import elastic_average_step, masked_average
+
+    one = SingleDeviceSharding(topo.devices[0])
+    x = jax.ShapeDtypeStruct((8, 8 * 1024 * 1024), jnp.float32, sharding=one)
+    v = jax.ShapeDtypeStruct((8,), jnp.float32, sharding=one)
+
+    def fn(x, v):
+        return elastic_average_step(x, v, 0.125), masked_average(x, v)
+
+    return jax.jit(fn).lower(x, v).compile(), 2
+
+
+def _pallas_ring(compress):
+    def build(topo, monkeypatch):
+        from akka_allreduce_tpu.comm.allreduce import build_threshold_allreduce
+        from akka_allreduce_tpu.parallel import line_mesh
+
+        mesh = line_mesh(devices=topo.devices)
+        sh = NamedSharding(mesh, P("line"))
+        xs = jax.ShapeDtypeStruct(
+            (4, FLOATS_PER_DEVICE), jnp.float32, sharding=sh
+        )
+        valid = jax.ShapeDtypeStruct((4,), jnp.float32, sharding=sh)
+        fn = build_threshold_allreduce(
+            mesh, schedule="pallas_ring", compress=compress
+        )
+        return fn.lower(xs, valid).compile(), 1
+
+    return build
+
+
+def _flash_attention(topo, monkeypatch):
+    """``local_attention``'s kernel branch at B8 H16 T2048 D128, fwd+bwd."""
+    from akka_allreduce_tpu.ops.local_attention import local_attention
+
+    one = SingleDeviceSharding(topo.devices[0])
+    qkv = jax.ShapeDtypeStruct((8, 2048, 16, 128), jnp.bfloat16, sharding=one)
+
+    def loss(q, k, v):
+        out = local_attention(q, k, v, causal=True)
+        return out.astype(jnp.float32).sum()
+
+    grad = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
+    return grad.lower(qkv, qkv, qkv).compile(), 3  # fwd, dkv, dq kernels
+
+
+def _grouped_psum(topo, monkeypatch):
+    """``grouped_tree_psum`` over gradients shaped like the 268M-param MoE's
+    (d1024, 8 experts, 4 layers): when it staged them through one flat
+    buffer, libtpu 0.0.34 laid that buffer out as f32[N/8, 8] after the
+    (d_model, 8) router leaf — 16x lane padding, over 16 GB, refused
+    (``bench-mfu --workload moe`` on the chip, PR 21)."""
+    from akka_allreduce_tpu.comm.allreduce import grouped_tree_psum
+
+    mesh = jax.make_mesh((4,), ("data",), devices=topo.devices)
+    layer = {
+        "experts_in": (8, 1024, 4096), "experts_out": (8, 4096, 1024),
+        "router": (1024, 8), "bias": (1024,),
+    }
+    shapes = {f"{i}/{k}": s for i in range(4) for k, s in layer.items()}
+    specs = {k: P() for k in shapes}
+    grads = {
+        k: jax.ShapeDtypeStruct(
+            s, jnp.float32, sharding=NamedSharding(mesh, P())
+        )
+        for k, s in shapes.items()
+    }
+
+    def sync(grads):
+        local = jax.tree.map(
+            lambda g: jax.lax.pcast(g, ("data",), to="varying"), grads
+        )
+        return grouped_tree_psum(local, specs, ("data",))
+
+    fn = jax.jit(
+        jax.shard_map(sync, mesh=mesh, in_specs=(specs,), out_specs=specs)
+    )
+    return fn.lower(grads).compile(), 0  # collectives only, no kernel
+
+
+class _ShapeOnlyLM:
+    """``TransformerLM`` whose ``init`` returns shapes: a described device
+    cannot hold the 404M parameters, and the step only needs their avals."""
+
+    def __init__(self, **kw):
+        from akka_allreduce_tpu.models.transformer import TransformerLM
+
+        self._model = TransformerLM(**kw)
+        self.apply = self._model.apply
+
+    def init(self, *args):
+        return jax.eval_shape(self._model.init, *args)
+
+
+def _lm_step(topo, monkeypatch):
+    """The flagship ``LongContextTrainer`` step (d2048 x 8L x seq2048 x B8,
+    bf16, no remat) on one described chip."""
+    from akka_allreduce_tpu.parallel import data_seq_mesh
+    from akka_allreduce_tpu.train import LongContextTrainer
+
+    # the trainer places its state with device_put; shapes stay where they are
+    monkeypatch.setattr(jax, "device_put", lambda x, *a, **k: x)
+    adam = optax.adam(3e-3)
+    mesh = data_seq_mesh(1, 1, devices=topo.devices[:1])
+    t = LongContextTrainer(
+        mesh, model_cls=_ShapeOnlyLM, vocab=256, d_model=2048, n_heads=16,
+        n_layers=8, seq_len=2048, compute_dtype=jnp.bfloat16,
+        optimizer=optax.GradientTransformation(
+            lambda p: jax.eval_shape(adam.init, p), adam.update
+        ),
+    )
+    assert 400e6 < t.param_count < 410e6
+    assert not t._check_vma  # the flash gate relaxed it: the TPU branch
+
+    def sds(tree, specs):
+        return jax.tree.map(
+            lambda leaf, s: jax.ShapeDtypeStruct(
+                leaf.shape, leaf.dtype, sharding=NamedSharding(mesh, s)
+            ),
+            tree, specs,
+        )
+
+    tokens = jax.ShapeDtypeStruct(
+        (8, 2048), jnp.int32, sharding=t._data_sharding
+    )
+    valid = jax.ShapeDtypeStruct(
+        (1,), jnp.float32, sharding=t._valid_sharding
+    )
+    compiled = t._step.lower(
+        sds(t.params, t._param_specs), sds(t.opt_state, t._opt_specs),
+        tokens, tokens, valid,
+    ).compile()
+    return compiled, 3 * 8  # three flash kernels per layer
+
+
+CASES = {
+    "reduce_kernels_8x8M_f32": _reduce_kernels,
+    "pallas_ring_4dev_64M_f32": _pallas_ring(None),
+    "pallas_ring_4dev_64M_bf16": _pallas_ring("bf16"),
+    "pallas_ring_4dev_64M_int8": _pallas_ring("int8"),
+    "flash_attention_b8_h16_t2048_d128": _flash_attention,
+    "grouped_psum_moe_shaped_grads_4dev": _grouped_psum,
+    "flagship_lm_step": _lm_step,
+}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_compiles_for_described_v5e(case, topo, as_tpu, monkeypatch):
+    compiled, min_kernels = CASES[case](topo, monkeypatch)
+    # compiled mode, not the interpreter: the Mosaic kernels are in the text
+    assert compiled.as_text().count("tpu_custom_call") >= min_kernels
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < HBM_BYTES

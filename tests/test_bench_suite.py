@@ -12,36 +12,16 @@ def test_config1_local_engine_record():
     assert rec["throughput_mbs"] > 0
 
 
-def test_compile_cache_enable_is_scoped(tmp_path):
-    """Regression for the round-5 two-test crash pair: enabling the
-    persistent compile cache mutates GLOBAL jax.config (cache dir + both
-    cache-everything thresholds); the handle must restore all three so a
-    bench-suite run cannot poison later tests in the same process."""
-    import jax
-
-    from akka_allreduce_tpu.utils import enable_persistent_compile_cache
-
-    flags = (
-        "jax_compilation_cache_dir",
-        "jax_persistent_cache_min_entry_size_bytes",
-        "jax_persistent_cache_min_compile_time_secs",
-    )
-    before = tuple(getattr(jax.config, f) for f in flags)
-    with enable_persistent_compile_cache(str(tmp_path / "cache")) as handle:
-        assert jax.config.jax_compilation_cache_dir == handle.directory
-        assert jax.config.jax_persistent_cache_min_entry_size_bytes == -1
-        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
-    assert tuple(getattr(jax.config, f) for f in flags) == before
-    handle.restore()  # idempotent
-
-
 def test_config5_dropout_recovery_record():
-    rec = bench_suite.config5_dropout_recovery(size=20_000)
-    # the config-5 cache enable must not leak past the call (the crash-pair
-    # regression): the cache dir is back to its pre-call value
     import jax
 
-    assert jax.config.jax_compilation_cache_dir != rec["compile_cache"]
+    before = jax.config.jax_compilation_cache_dir
+    rec = bench_suite.config5_dropout_recovery(size=20_000)
+    # config 5 owns no cache directory (the round-5 crash pair came from the
+    # one it used to enable): it names whatever cache the process has —
+    # none under the tests — and leaves the setting alone
+    assert rec["compile_cache"] == before
+    assert jax.config.jax_compilation_cache_dir == before
     assert rec["config"] == 5
     # th=0.75 of 4 workers with one fully dropped: all rounds complete
     assert rec["rounds_completed"] == 10

@@ -1,8 +1,6 @@
 """Tier 7 — workload resilience (RESILIENCE.md, ISSUE 14).
 
-Two layers of evidence, both in real subprocesses so the scenarios run
-with the ``_jax_compat`` shims opted in (process-global — they must NOT
-be imported into the tier-1 interpreter):
+Two layers of evidence, both in real subprocesses:
 
 - the ``chaos-train`` drill's fastest (dp) arm: a real master + 3
   ``chaos-train-node`` processes, each driving an ElasticTrainer-wrapped

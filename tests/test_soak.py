@@ -164,8 +164,7 @@ def test_composed_trainer_soak(tmp_path):
 def test_soak_remesh_split_forced_vs_detected():
     """`soak --chaos`'s scripted leader_failover re-mesh counts as FORCED,
     detector churn as DETECTED (ISSUE 14 satellite) — run in its own
-    interpreter with the _jax_compat shims opted in (the scenario needs a
-    real FSDP mesh; the tier-1 interpreter must not import the shims)."""
+    interpreter (the scenario needs a real FSDP mesh)."""
     import os
     import subprocess
     import sys
