@@ -136,7 +136,10 @@ chaos-train:
 	  --uring --intra-chunk 1048576 --congestion \
 	  --out-dir chaos_train_run
 
+# the driver's form: six loadfile workers (~7 min on 8 cores; serially the
+# files sum to ~40 min). tests/conftest.py gives every test a 300 s limit.
 test:
-	JAX_PLATFORMS=cpu $(PYTHON) -m pytest tests/ -q -m 'not slow'
+	JAX_PLATFORMS=cpu ALLOW_MULTIPLE_LIBTPU_LOAD=1 $(PYTHON) -m pytest tests/ \
+	  -q -m 'not slow' -p xdist -n 6 --dist loadfile -p no:cacheprovider
 
 tier1: lint test

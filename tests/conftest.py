@@ -10,7 +10,10 @@ interpret mode (ops/_platform.py), and no persistent compile cache.
 """
 
 import os
+import signal
 import sys
+
+import pytest
 
 # Force, don't setdefault: the suite runs on the CPU whatever the caller's
 # environment says, and the subprocesses tests spawn inherit this.
@@ -29,3 +32,33 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
+
+# One limit for every test: a hang costs its worker this long and shows as
+# one named failure, never the whole run's clock. The slowest test takes 85 s
+# with seven files running beside it (ISSUE 25's reading).
+DEFAULT_LIMIT_S = 300
+
+
+@pytest.hookimpl(wrapper=True)
+def pytest_runtest_call(item):
+    """Arm SIGALRM around the test body; ``@pytest.mark.limit(seconds)``
+    overrides the default. Tests run in the main thread of each xdist
+    worker, so the handler's TimeoutError lands in the test and unwinds
+    asyncio.run / subprocess.run / time.sleep alike. (Python runs the
+    handler between bytecodes: a thread stuck inside native code that
+    never returns is still the run's own clock's to end.)"""
+    if not hasattr(signal, "SIGALRM"):
+        return (yield)
+    mark = item.get_closest_marker("limit")
+    n = int(mark.args[0]) if mark else DEFAULT_LIMIT_S
+
+    def on_alarm(signum, frame):
+        raise TimeoutError(f"{item.nodeid} ran over its {n} s limit")
+
+    old = signal.signal(signal.SIGALRM, on_alarm)
+    signal.alarm(n)
+    try:
+        return (yield)
+    finally:
+        signal.alarm(0)
+        signal.signal(signal.SIGALRM, old)

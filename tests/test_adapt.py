@@ -571,13 +571,22 @@ def test_int8_nonfinite_inputs_saturate_and_count():
 def test_chaos_adapt_drill_subprocess(tmp_path):
     """The fixed-seed drill at reduced budgets: degrade within K rounds of
     the staged straggler, bounded transitions, restore after heal, EF
-    error budget — the same binary `make chaos-adapt` gates on."""
+    error budget — the same binary `make chaos-adapt` gates on.
+
+    The payload is 4x the drill's default: at 65536 floats a round takes
+    ~3 ms, so the controller's degrade bar (8 rounds of lag) is a 30 ms
+    stall of any node and the whole script spans under a second — on a
+    busy machine real slowness made real extra transitions (11 of 20 runs
+    failed beside a loop of pytest start-ups; ISSUE 25). At 262144 floats
+    a round takes ~25 ms, the script ~3 s, and 20 of 20 passed there with
+    exactly 2 degrades and 2 restores each."""
     proc = subprocess.run(
         [
             sys.executable, "-m", "akka_allreduce_tpu", "chaos-adapt",
             "--seed", "1234", "--out-dir", str(tmp_path / "run"),
             "--straggle-at", "15", "--heal-at", "80",
             "--post-rounds", "15", "--phase-timeout", "120",
+            "--size", "262144", "--chunk", "32768",
         ],
         env={**os.environ, "JAX_PLATFORMS": "cpu"},
         capture_output=True, text=True, timeout=280,

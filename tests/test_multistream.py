@@ -561,8 +561,11 @@ def test_streams1_wire_byte_identical_to_legacy():
     async def run():
         captured = bytearray()
         done = asyncio.Event()
+        handled = asyncio.Event()
+        accepted = []
 
         async def sink(reader, writer):
+            accepted.append(writer)
             while True:
                 chunk = await reader.read(1 << 16)
                 if not chunk:
@@ -570,6 +573,11 @@ def test_streams1_wire_byte_identical_to_legacy():
                 captured.extend(chunk)
                 if len(captured) >= expected_len:
                     done.set()
+            # Python >= 3.12: Server.wait_closed() waits for every accepted
+            # connection, so the handler closes its own (control/remote.py
+            # does the same before its wait_closed)
+            writer.close()
+            handled.set()
 
         server = await asyncio.start_server(sink, "127.0.0.1", 0)
         host, port = server.sockets[0].getsockname()[:2]
@@ -589,7 +597,15 @@ def test_streams1_wire_byte_identical_to_legacy():
         finally:
             await tx.stop()
             server.close()
-            await server.wait_closed()
+        # the guard, BEFORE wait_closed so a dropped close fails here with
+        # a message instead of waiting on the connection until the limit
+        await asyncio.wait_for(handled.wait(), 10.0)
+        still_open = [w for w in accepted if not w.is_closing()]
+        assert accepted and not still_open, (
+            f"sink() left {len(still_open)} of {len(accepted)} accepted "
+            "connection(s) open: wait_closed() would wait on them forever"
+        )
+        await server.wait_closed()
 
     asyncio.run(run())
 

@@ -153,12 +153,23 @@ def test_composed_trainer_soak(tmp_path):
     assert report.checkpoint_saves >= 2
     assert np.isfinite(report.final_loss)
     assert report.final_loss < report.first_loss
-    # the metrics JSONL carries one line per step plus the summary
+    # the metrics JSONL carries one record per step, then the obs registry's
+    # snapshot (utils/metrics.py log_snapshot), then the summary
     import json
 
     lines = (tmp_path / "soak.jsonl").read_text().strip().splitlines()
-    assert len(lines) == 36 + 1
-    assert "summary" in json.loads(lines[-1])
+
+    def kind(rec):
+        if {"step", "loss", "ms"} <= rec.keys():
+            return "step"
+        if rec.get("kind") == "metrics_snapshot":
+            return "metrics_snapshot"
+        if "summary" in rec:
+            return "summary"
+        return f"unknown record with keys {sorted(rec)}"
+
+    kinds = [kind(json.loads(ln)) for ln in lines]
+    assert kinds == ["step"] * 36 + ["metrics_snapshot", "summary"], kinds
 
 
 def test_soak_remesh_split_forced_vs_detected():
