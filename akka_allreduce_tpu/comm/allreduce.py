@@ -2,9 +2,16 @@
 
 Semantics (the reference's, recast in SPMD — SURVEY.md §3 "Collective semantics"):
 every device contributes ``(payload, valid)`` where ``valid`` is 1.0 for a live
-contributor and 0.0 for a straggler/dropout whose data must not count. One fused
-collective computes ``sum = psum(payload * valid)`` and ``count = psum(valid)``;
-consumers divide sum by count to get the partial average. This reproduces the
+contributor and 0.0 for a straggler/dropout whose data must not count. The
+round computes ``sum = psum(masked payload)`` and ``count = psum(valid)``;
+consumers divide sum by count to get the partial average. Where the mask is
+applied depends on where the code stands, not on an option: inside a
+trainer's step (:func:`masked_psum`) it is ``payload * valid``, which XLA
+fuses into whatever is still producing the gradient; at the host-facing entry
+(:func:`build_threshold_allreduce`) the payload is a buffer already in HBM,
+and a whole-payload mask is applied in place (:func:`mask_zero_inplace`: no
+traffic on a live device, a write-only pass of zeros on a masked one, so a
+masked device's NaN or Inf cannot reach the sum). This reproduces the
 reference's ``ReduceBlock.count`` normalization without leaving XLA, and the
 validity mask may be per *bucket* (the ``max_chunk_size`` granularity), matching
 the reference's per-chunk contribution counting.
@@ -43,7 +50,13 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
+from akka_allreduce_tpu.obs import metrics as obs_metrics
 from akka_allreduce_tpu.parallel.mesh import LINE_AXIS
+
+# which mask path each host-facing program was traced with (the mask's rank
+# decides, once per program, so these count programs and not rounds)
+_MASK_INPLACE_BUILDS = obs_metrics.counter("comm.mask_inplace_builds")
+_MASK_MULTIPLY_BUILDS = obs_metrics.counter("comm.mask_multiply_builds")
 
 Axes = tuple[str, ...]
 
@@ -74,6 +87,55 @@ def _num_buckets(data_size: int, bucket_size: int | None) -> int:
 # --------------------------------------------------------------------------
 
 
+def _multiply_mask(
+    x: jax.Array, mask: jax.Array, bucket_size: int | None
+) -> jax.Array:
+    """``x`` times a scalar mask, or times a per-bucket mask ``(n_buckets,)``
+    bucket by bucket (buckets need not align to anything: pad, multiply,
+    slice)."""
+    if bucket_size is None:
+        return x * mask
+    n_buckets = _num_buckets(x.shape[0], bucket_size)
+    if mask.shape != (n_buckets,):
+        raise ValueError(
+            f"per-bucket mask must have shape ({n_buckets},), got {mask.shape}"
+        )
+    pad = n_buckets * bucket_size - x.shape[0]
+    xp = jnp.pad(x, (0, pad)).reshape(n_buckets, bucket_size)
+    return (xp * mask[:, None]).reshape(-1)[: x.shape[0]]
+
+
+def mask_zero_inplace(x: jax.Array, valid: jax.Array) -> jax.Array:
+    """``x`` where the scalar ``valid`` is non-zero, zeros where it is 0 — in
+    ``x``'s own buffer, for a payload that already lies in HBM.
+
+    ``x * valid`` reads and rewrites the whole payload to leave it as it was
+    (a live contributor) or to make it zeros (a straggler): 3.27 ms for 1 GiB
+    on a v5e, on every device of every round (PERF.md, PR 26). This is a loop
+    of no trip or one, whose one trip fills the buffer with zeros: a ``while``
+    carries its state in place, so a device whose ``valid`` is non-zero moves
+    no memory and a masked one writes its payload once and reads nothing
+    (1.65 ms). ``lax.cond`` would say the same and does not do: XLA copies
+    the payload in front of a conditional, the multiply's traffic again
+    (ISSUE 26). Where the caller does not donate ``x``, XLA copies it in
+    front of the loop, which costs what the multiply did.
+
+    Equal to ``x * valid`` for a 0/1 ``valid`` on finite payloads (but for
+    the sign of a zero). Where they differ, this is what a mask means: a
+    masked payload that holds NaN or Inf comes out as zeros (``0 * nan`` is
+    ``nan``), and a ``valid`` that is neither 0 nor 1 leaves the payload as
+    it is instead of scaling it.
+
+    Not for a trainer's step: there ``x`` is a gradient that a fusion is
+    still producing, XLA fuses the multiply into that producer for nothing,
+    and a loop would force the gradient out to HBM first.
+    """
+    with jax.named_scope("mask_zero_inplace"):
+        return lax.fori_loop(
+            0, (valid == 0).astype(jnp.int32), lambda _, x: jnp.zeros_like(x), x
+        )
+
+
 def masked_psum(
     x: jax.Array,
     valid: jax.Array,
@@ -99,18 +161,7 @@ def masked_psum(
       expansion is the caller's choice via :func:`expand_counts`).
     """
     valid = jnp.asarray(valid, dtype=jnp.float32)
-    mask = valid.astype(x.dtype)
-    if bucket_size is None:
-        masked = x * mask
-    else:
-        n_buckets = _num_buckets(x.shape[0], bucket_size)
-        if valid.shape != (n_buckets,):
-            raise ValueError(
-                f"per-bucket mask must have shape ({n_buckets},), got {valid.shape}"
-            )
-        pad = n_buckets * bucket_size - x.shape[0]
-        xp = jnp.pad(x, (0, pad)).reshape(n_buckets, bucket_size)
-        masked = (xp * mask[:, None]).reshape(-1)[: x.shape[0]]
+    masked = _multiply_mask(x, valid.astype(x.dtype), bucket_size)
     if wire_dtype is not None and masked.dtype != wire_dtype:
         total = lax.psum(masked.astype(wire_dtype), axis_names).astype(x.dtype)
     else:
@@ -501,30 +552,23 @@ def expand_counts(
     return jnp.repeat(count, bucket_size)[:data_size]
 
 
-def _staged_masked_psum(
-    x: jax.Array,
+def _staged_psum(
+    masked: jax.Array,
     valid: jax.Array,
     axis_names: Axes,
-    bucket_size: int | None,
     wire_dtype=None,
 ) -> tuple[jax.Array, jax.Array]:
     """Butterfly: reduce one grid axis at a time (dim-0 sink feeds dim-1 source,
-    SURVEY.md §4.3). Numerically equals the fused psum; structurally it is the
-    reference's staged grid round and lets each stage ride a different ICI axis.
+    SURVEY.md §4.3) — the masked payload and, beside it, the mask itself.
+    Numerically equals the fused psum; structurally it is the reference's
+    staged grid round and lets each stage ride a different ICI axis.
     ``wire_dtype`` (e.g. bf16) compresses each stage's collective payload;
     counts always ride float32 (see :func:`masked_psum`)."""
+    total = masked
     count = jnp.asarray(valid, dtype=jnp.float32)
-    mask = count.astype(x.dtype)
-    if bucket_size is not None:
-        n_buckets = _num_buckets(x.shape[0], bucket_size)
-        pad = n_buckets * bucket_size - x.shape[0]
-        xp = jnp.pad(x, (0, pad)).reshape(n_buckets, bucket_size)
-        total = (xp * mask[:, None]).reshape(-1)[: x.shape[0]]
-    else:
-        total = x * mask
     for name in axis_names:
         if wire_dtype is not None and total.dtype != wire_dtype:
-            total = lax.psum(total.astype(wire_dtype), name).astype(x.dtype)
+            total = lax.psum(total.astype(wire_dtype), name).astype(masked.dtype)
         else:
             total = lax.psum(total, name)
         count = lax.psum(count, name)
@@ -833,6 +877,20 @@ def build_threshold_allreduce(
     of ``axes``; ``valid`` is ``(n_devices,)`` (whole-payload mask) or
     ``(n_devices, n_buckets)`` (per-chunk mask). Outputs are replicated.
 
+    The mask's rank picks how it is applied, on every schedule. A
+    whole-payload mask (with ``bucket_size`` or without: only the count is
+    expanded) goes through :func:`mask_zero_inplace`: with ``donate=True`` a
+    live device's payload is not touched and a masked device's is overwritten
+    with zeros, so a masked device may hold NaN or Inf and the sum stays
+    finite. ``valid`` is 0.0 or 1.0 (module docstring); a value that is
+    neither cannot raise, being traced: the payload is zeroed on
+    ``valid == 0`` and left alone otherwise, ``count`` stays ``psum(valid)``,
+    so a fractional weight no longer scales the payload here. A per-bucket
+    mask multiplies, bucket by bucket, as :func:`masked_psum` does (and there
+    a weight still scales, and ``0 * nan`` is still ``nan``). With
+    ``donate=False`` the caller's buffer is left as it was: XLA copies it in
+    front of the in-place mask, which costs what the multiply did.
+
     ``compress`` trades precision for wire bytes on bandwidth-bound syncs:
     ``"bf16"`` runs the psum/butterfly collective in bfloat16 (or bf16 ring
     hops), halving ICI/DCN traffic; ``"int8"`` (ring only — a summed int8
@@ -863,25 +921,22 @@ def build_threshold_allreduce(
 
     spec_in = P(axis_names if len(axis_names) > 1 else axis_names[0])
 
+    wire_dtype = jnp.bfloat16 if compress else None
+
     def kernel(xs, valid):
         x = xs.reshape(xs.shape[-1])  # (1, data) block -> (data,)
         data_size = x.shape[0]
         if valid.ndim > 1:  # (1, n_buckets) block -> per-bucket mask
+            if bucket_size is None:
+                raise ValueError("per-bucket valid mask requires bucket_size")
             v = valid.reshape(valid.shape[1:])
-        else:  # (1,) block -> whole-payload scalar mask
+            _MASK_MULTIPLY_BUILDS.inc()
+            masked = _multiply_mask(x, v.astype(x.dtype), bucket_size)
+        else:  # (1,) block -> whole-payload scalar mask, bucket_size or not
             v = valid.reshape(())
-        if bucket_size is not None and v.ndim == 0:
-            v = jnp.full((_num_buckets(data_size, bucket_size),), v)
-        if bucket_size is None and v.ndim != 0:
-            raise ValueError("per-bucket valid mask requires bucket_size")
+            _MASK_INPLACE_BUILDS.inc()
+            masked = mask_zero_inplace(x, v)
         if schedule in ("ring", "pallas_ring"):
-            if v.ndim == 0:
-                vx = x * v
-            else:
-                n_buckets = _num_buckets(data_size, bucket_size)
-                pad = n_buckets * bucket_size - data_size
-                xp = jnp.pad(x, (0, pad)).reshape(n_buckets, bucket_size)
-                vx = (xp * v[:, None]).reshape(-1)[:data_size]
             if schedule == "pallas_ring":
                 from akka_allreduce_tpu.ops.ring import (
                     _DEF_SEG_ROWS,
@@ -897,7 +952,7 @@ def build_threshold_allreduce(
                     else _DEF_SEG_ROWS
                 )
                 total = pallas_ring_allreduce_sum(
-                    vx, axis_names[0], n_devices, seg_rows=seg_rows,
+                    masked, axis_names[0], n_devices, seg_rows=seg_rows,
                     compress=compress,
                     # decide interpret mode by the MESH's platform, not the
                     # process default backend: with the TPU plugin loaded a
@@ -906,22 +961,22 @@ def build_threshold_allreduce(
                 )
             else:
                 total = ring_allreduce_sum(
-                    vx, axis_names[0], n_devices, compress=compress
+                    masked, axis_names[0], n_devices, compress=compress
                 )
             count = lax.psum(jnp.asarray(v, x.dtype), axis_names)
         elif schedule == "butterfly":
-            total, count = _staged_masked_psum(
-                x, v, axis_names, bucket_size,
-                wire_dtype=jnp.bfloat16 if compress else None,
-            )
+            total, count = _staged_psum(masked, v, axis_names, wire_dtype)
         else:
-            total, count = masked_psum(
-                x,
-                v,
-                axis_names,
-                bucket_size=bucket_size,
-                wire_dtype=jnp.bfloat16 if compress else None,
+            # through the module's `masked_psum`, so whatever stands in that
+            # name's place wraps this schedule's payload collective too; the
+            # payload is masked already, so its weight here is the constant 1
+            # (XLA folds the multiply away: tests/test_aot_tpu_compile.py) and
+            # the count is the real mask's
+            total, _ = masked_psum(
+                masked, jnp.ones((), jnp.float32), axis_names,
+                wire_dtype=wire_dtype,
             )
+            count = lax.psum(jnp.asarray(v, jnp.float32), axis_names)
         return total, expand_counts(count, data_size, bucket_size)
 
     mapped = jax.shard_map(
@@ -951,6 +1006,11 @@ def threshold_allreduce(
     ``xs``: ``(n_devices, data)`` (host or device). ``valid``: None (all
     contribute), ``(n_devices,)``, or ``(n_devices, n_buckets)``.
     ``compress``: None | "bf16" | "int8" — see :func:`build_threshold_allreduce`.
+
+    Never donates ``xs``, so the caller's array stays readable and unchanged;
+    the in-place mask then works on XLA's copy of it (no worse than the
+    multiply it replaced). A round loop that owns its payloads builds the
+    function once with ``donate=True``.
     """
     axis_names = _normalize_axes(mesh, axes)
     key = (mesh, axis_names, bucket_size, schedule, compress)

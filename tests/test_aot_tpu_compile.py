@@ -216,3 +216,40 @@ def test_compiles_for_described_v5e(case, topo, as_tpu, monkeypatch):
     assert compiled.as_text().count("tpu_custom_call") >= min_kernels
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < HBM_BYTES
+
+
+CELL_FLOATS = 268_435_456  # benchmarks/configs/threshold_allreduce_256m.json
+
+
+def test_the_cells_allreduce_masks_its_payload_in_place(topo, as_tpu):
+    """The program of ``allreduce_256m_mask1`` (psum schedule, 1 GiB of f32 a
+    device, donated): the whole-payload mask is ``mask_zero_inplace``'s loop
+    on the parameter's own buffer, and nothing in the optimised HLO reads and
+    rewrites the payload around it — no payload-sized ``copy`` (what
+    ``lax.cond`` brings), no ``select``, and no payload-sized ``multiply``
+    (the parent's ``x * mask``, and the constant 1 the psum schedule hands
+    ``masked_psum``, which XLA has to fold), alone or inside a fusion."""
+    import re
+
+    from akka_allreduce_tpu.comm.allreduce import build_threshold_allreduce
+    from akka_allreduce_tpu.parallel import line_mesh
+
+    mesh = line_mesh(devices=topo.devices)
+    sh = NamedSharding(mesh, P("line"))
+    xs = jax.ShapeDtypeStruct((4, CELL_FLOATS), jnp.float32, sharding=sh)
+    valid = jax.ShapeDtypeStruct((4,), jnp.float32, sharding=sh)
+    compiled = build_threshold_allreduce(mesh).lower(xs, valid).compile()
+    text = compiled.as_text()
+    payload_ops = re.findall(
+        rf"= f32\[(?:1,)?{CELL_FLOATS}\]\S* ([\w-]+)\(", text
+    )
+    moves_memory = sorted(
+        op for op in payload_ops
+        if op not in ("parameter", "bitcast", "get-tuple-element")
+    )
+    # the zero fill inside the loop, the collective, the count's fill
+    assert moves_memory == ["all-reduce", "broadcast", "broadcast"]
+    assert len(re.findall(r" while\(", text)) == 1
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == 4 * CELL_FLOATS  # donated, and aliased
+    assert mem.temp_size_in_bytes < 1 << 20  # no second payload anywhere

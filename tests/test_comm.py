@@ -147,6 +147,152 @@ class TestThresholdAllreduce:
         assert (np.asarray(counts) == 2).all()
 
 
+SCHEDULE_MESH = {
+    "psum": "line8", "ring": "line8", "pallas_ring": "line8",
+    "butterfly": "grid24",
+}
+# the Pallas ring's interpreter runs in test time only with a small staging
+# size (bucket_size sizes it); the mask given with it stays a scalar
+SCHEDULE_KW = {"pallas_ring": {"bucket_size": 1024}}
+MASKS = {
+    "all_valid": np.ones(8, np.float32),
+    "one_masked": np.array([1, 1, 1, 1, 1, 0, 1, 1], np.float32),
+    "all_masked": np.zeros(8, np.float32),
+}
+
+
+def exact_payload(n, d, seed=0):
+    """Whole numbers: every order of f32 additions gives the same bits, so
+    each schedule can be held to ``==`` against numpy."""
+    return np.random.default_rng(seed).integers(
+        -1000, 1000, (n, d)
+    ).astype(np.float32)
+
+
+def host_entry(mesh, xs, valid, **kw):
+    """``build_threshold_allreduce`` as a host loop drives it: sharded
+    arguments in, the replicated pair out."""
+    from jax.sharding import NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from akka_allreduce_tpu.comm import build_threshold_allreduce
+
+    names = mesh.axis_names
+    sh = NamedSharding(mesh, P(names if len(names) > 1 else names[0]))
+    fn = build_threshold_allreduce(mesh, **kw)
+    xs_dev = jax.device_put(xs, sh)
+    total, count = fn(xs_dev, jax.device_put(valid, sh))
+    return np.asarray(total), np.asarray(count), xs_dev
+
+
+def mask_builds():
+    """(in place, multiply): the host entry's programs traced so far."""
+    from akka_allreduce_tpu.obs import metrics
+
+    snap = metrics.REGISTRY.snapshot()
+    return snap["comm.mask_inplace_builds"], snap["comm.mask_multiply_builds"]
+
+
+class TestHostEntryMasksInPlace:
+    """The host-facing entry applies a whole-payload mask without a multiply
+    (``mask_zero_inplace``): the answers are the multiply's, bit for bit,
+    wherever the multiply's were right."""
+
+    @pytest.mark.parametrize("mask", sorted(MASKS))
+    @pytest.mark.parametrize("schedule", sorted(SCHEDULE_MESH))
+    def test_equals_the_plain_masked_sum(self, schedule, mask, request):
+        mesh = request.getfixturevalue(SCHEDULE_MESH[schedule])
+        xs, valid = exact_payload(8, 2048 + 24), MASKS[mask]
+        total, count, _ = host_entry(
+            mesh, xs, valid, schedule=schedule, donate=False,
+            **SCHEDULE_KW.get(schedule, {}),
+        )
+        want = (xs * valid[:, None]).sum(0)
+        assert np.array_equal(total, want)
+        assert np.array_equal(count, np.full(xs.shape[1], valid.sum()))
+
+    @pytest.mark.parametrize("schedule", sorted(SCHEDULE_MESH))
+    def test_a_masked_devices_nan_and_inf_do_not_reach_the_sum(
+        self, schedule, request
+    ):
+        """What ``0 * nan`` never gave: a straggler's data does not count."""
+        mesh = request.getfixturevalue(SCHEDULE_MESH[schedule])
+        xs, valid = exact_payload(8, 1024, seed=1), MASKS["one_masked"]
+        clean = (xs * valid[:, None]).sum(0)
+        xs[5, ::3], xs[5, 1::3], xs[5, 2::3] = np.nan, np.inf, -np.inf
+        total, count, _ = host_entry(
+            mesh, xs, valid, schedule=schedule, donate=False,
+            **SCHEDULE_KW.get(schedule, {}),
+        )
+        assert np.array_equal(total, clean)
+        assert (count == 7).all()
+
+    def test_without_donation_the_callers_array_is_unchanged(self, line8):
+        xs, valid = exact_payload(8, 4096, seed=2), MASKS["one_masked"]
+        total, _, xs_dev = host_entry(line8, xs, valid, donate=False)
+        assert not xs_dev.is_deleted()
+        assert np.array_equal(np.asarray(xs_dev), xs)  # row 5 not zeroed
+        assert np.array_equal(total, (xs * valid[:, None]).sum(0))
+
+    @pytest.mark.parametrize("schedule", ["psum", "ring"])
+    def test_a_per_bucket_mask_still_multiplies(self, line8, schedule):
+        """Decided by the mask's rank: buckets need not align to a tile, and
+        a per-bucket weight still scales its bucket."""
+        xs = exact_payload(8, 100, seed=3)
+        valid = np.ones((8, 4), np.float32)
+        valid[np.arange(8), np.arange(8) % 4] = 0.0
+        valid[0, 1] = 0.5  # a weight: exact on whole numbers
+        in_place, multiply = mask_builds()
+        total, count, _ = host_entry(
+            line8, xs, valid, schedule=schedule, bucket_size=30, donate=False
+        )
+        per_element = np.repeat(valid, 30, axis=1)[:, :100]
+        assert np.array_equal(total, (xs * per_element).sum(0))
+        assert np.array_equal(count, per_element.sum(0))
+        assert mask_builds() == (in_place, multiply + 1)
+
+    def test_a_scalar_mask_with_bucket_size_is_still_a_scalar_mask(self, line8):
+        xs, valid = exact_payload(8, 100, seed=4), MASKS["one_masked"]
+        in_place, multiply = mask_builds()
+        total, count, _ = host_entry(
+            line8, xs, valid, bucket_size=30, donate=False
+        )
+        assert np.array_equal(total, (xs * valid[:, None]).sum(0))
+        assert count.shape == (100,) and (count == 7).all()
+        assert mask_builds() == (in_place + 1, multiply)
+
+    def test_a_weight_that_is_not_0_or_1_counts_but_does_not_scale(self, line8):
+        """Outside the contract (``valid`` is 0.0 or 1.0) and stated in the
+        docstring: the payload is zeroed on ``valid == 0`` and left alone
+        otherwise, ``count`` stays ``psum(valid)``."""
+        xs = exact_payload(8, 512, seed=5)
+        valid = np.array([1, 0.5, 1, 0, 1, 1, 2, 1], np.float32)
+        total, count, _ = host_entry(line8, xs, valid, donate=False)
+        assert np.array_equal(total, (xs * (valid != 0)[:, None]).sum(0))
+        assert (count == valid.sum()).all()
+
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16", "int32"])
+    @pytest.mark.parametrize("shape", [(1000,), (3, 7)], ids=["flat", "2d"])
+    def test_mask_zero_inplace_alone(self, dtype, shape):
+        """Any payload: zeros on ``valid == 0``, the payload itself (NaN and
+        all) otherwise, under ``jit`` with the payload donated."""
+        import jax.numpy as jnp
+
+        from akka_allreduce_tpu.comm.allreduce import mask_zero_inplace
+
+        x = np.arange(1, np.prod(shape) + 1).reshape(shape).astype(dtype)
+        if dtype != "int32":
+            x.flat[5] = np.nan
+        fn = jax.jit(mask_zero_inplace, donate_argnums=(0,))
+        for v, want in ((0.0, np.zeros_like(x)), (1.0, x), (0.5, x)):
+            out = fn(jnp.asarray(x), jnp.float32(v))
+            assert out.dtype == x.dtype and out.shape == x.shape
+            assert np.array_equal(
+                np.asarray(out).astype("float32"), want.astype("float32"),
+                equal_nan=True,
+            )
+
+
 class TestBandwidthHarness:
     def test_measure_reports_and_logs(self, line8):
         logger = MetricsLogger()
