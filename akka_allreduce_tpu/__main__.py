@@ -1970,6 +1970,13 @@ def _cmd_train_moe(argv: list[str]) -> int:
         help="sample batches ON DEVICE inside one jitted chain (no host "
         "I/O per step)",
     )
+    p.add_argument(
+        "--config", default=None, metavar="JSON",
+        help="build the model from an lfm2_moe config.json (conv/attention "
+        "hybrid, gated experts, dropless sigmoid top-k routing over the "
+        "experts it says are held here) in place of the size flags; its "
+        "\"program\" group, if any, gives compute_dtype",
+    )
     _add_sharded_compress_flag(p)
     args = p.parse_args(argv)
 
@@ -1980,6 +1987,8 @@ def _cmd_train_moe(argv: list[str]) -> int:
     from akka_allreduce_tpu.parallel import data_seq_model_mesh
     from akka_allreduce_tpu.train import MoETrainer
 
+    if args.config:
+        return _train_moe_from_config(args)
     devs = jax.devices()
     dp = args.dp or max(1, len(devs) // (args.ep * args.sp))
     if args.sp > 1:
@@ -2066,6 +2075,60 @@ def _cmd_train_moe(argv: list[str]) -> int:
         f"{_mfu_note(perf)}; "
         f"loss {hist[0].loss:.4f} -> {hist[-1].loss:.4f} "
         f"(aux {hist[-1].aux_loss:.3f}, dropped {hist[-1].dropped:.1%})"
+    )
+    return 0
+
+
+def _train_moe_from_config(args) -> int:
+    """``train-moe --config``: a configuration-built decoder through the
+    same ``MoETrainer``; dp over all devices, no expert exchange (the
+    configuration says which experts are held here)."""
+    import json
+    import time
+
+    import jax
+    import jax.numpy as jnp
+
+    from akka_allreduce_tpu.models import data
+    from akka_allreduce_tpu.models.hybrid_decoder import HybridDecoderLM
+    from akka_allreduce_tpu.train import MoETrainer
+
+    if args.ep != 1 or args.sp != 1 or args.device_data:
+        raise SystemExit("--config runs with --ep 1 --sp 1 and host batches")
+    with open(args.config, encoding="utf-8") as f:
+        cfg = json.load(f)
+    dtype = jnp.dtype(cfg.get("program", {}).get("compute_dtype", "float32"))
+    model = HybridDecoderLM.from_config(cfg, compute_dtype=dtype)
+    devs = jax.devices()
+    dp = args.dp or len(devs)
+    trainer = MoETrainer(
+        jax.make_mesh((dp,), ("data",), devices=devs[:dp]),
+        model=model, vocab=model.vocab, seq_len=args.seq_len,
+        learning_rate=args.lr, compress=args.compress, overlap=args.overlap,
+        mu_dtype=jnp.bfloat16 if args.mu_bf16 else None,
+    )
+    print(
+        f"MoE params: {trainer.param_count / 1e6:.2f}M (experts "
+        f"{model.held_first}-{model.held_first + model.held_count - 1} of "
+        f"{model.num_experts} held, top-{model.experts_per_token}), "
+        f"layers {'/'.join(k[:4] for k in model.layer_types)}, "
+        f"mesh dp={trainer.dp}"
+    )
+    if args.steps <= 0:
+        return 0
+    ds = data.lm_copy_task(args.seq_len, vocab=model.vocab)
+    t0 = time.perf_counter()
+    hist = [
+        trainer.train_step(x, y) for x, y in ds.batches(args.batch, args.steps)
+    ]
+    dt = time.perf_counter() - t0
+    rows = hist[-1].expert_rows
+    print(
+        f"moe: {args.steps} steps on {trainer.n_devices} devices in "
+        f"{dt:.2f}s ({dt / args.steps * 1e3:.1f} ms/step); "
+        f"loss {hist[0].loss:.4f} -> {hist[-1].loss:.4f} "
+        f"(dropped {hist[-1].dropped:.1%}; rows per held expert, last "
+        f"step, fullest layer: {rows[rows.sum(axis=1).argmax()].astype(int).tolist()})"
     )
     return 0
 

@@ -286,3 +286,217 @@ def moe_dispatch_compute(
     else:
         y = jnp.einsum("tec,ecd->td", combine.astype(x.dtype), ys)
     return y, aux, route_idx.dropped
+
+
+# -- dropless routing over a held subset of the experts -----------------------
+#
+# The published form of sigmoid-scored top-k routing with NO capacity: every
+# assignment to an expert this device holds is computed, whatever the
+# imbalance. The layer is told which contiguous range of the experts it
+# holds, routes over ALL of them, and computes its own experts' part of the
+# result; what the absent experts would add is left out (with ep == 1 there
+# is no exchange and nothing stands in for the absent devices).
+
+
+class HeldRoute(NamedTuple):
+    """A routing decision laid out for the held experts' grouped products."""
+
+    selected: jax.Array  # (T, k) int32 expert ids over all E, best first
+    weights: jax.Array  # (T, k) float32, normalised over all k selected
+    order: jax.Array  # (T*k,) int32: sorted row -> flat (token, choice)
+    inverse: jax.Array  # (T*k,) int32: flat (token, choice) -> sorted row
+    group_sizes: jax.Array  # (H+1,) int32 rows per held expert; last: absent
+
+
+def sigmoid_topk_route(
+    logits: jax.Array,
+    select_bias: jax.Array | None,
+    k: int,
+    *,
+    renormalise: bool = True,
+    scale: float = 1.0,
+) -> tuple[jax.Array, jax.Array]:
+    """``p = sigmoid(logits)``; the ``k`` experts with the largest
+    ``p + select_bias`` are selected (ties: the lower index), and weighed by
+    ``p`` alone: the bias picks but does not weigh. Returns
+    ``(selected, weights)``, each (T, k); everything float32."""
+    p = jax.nn.sigmoid(logits.astype(jnp.float32))
+    score = p if select_bias is None else p + select_bias.astype(jnp.float32)
+    _, selected = lax.top_k(score, k)
+    w = jnp.take_along_axis(p, selected, axis=-1)
+    if renormalise:
+        w = w / (w.sum(axis=-1, keepdims=True) + 1e-6)
+    return selected.astype(jnp.int32), w * scale
+
+
+def held_route(
+    selected: jax.Array, weights: jax.Array, held_first: int, held_count: int
+) -> HeldRoute:
+    """Sort the (token, choice) assignments expert-major: the rows of held
+    expert 0 first, then 1, ..., and the assignments to absent experts
+    last. The row buffer is all T*k assignments — the worst case, every
+    token choosing only held experts — so nothing can overflow."""
+    local = selected.reshape(-1) - held_first
+    key = jnp.where((local >= 0) & (local < held_count), local, held_count)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    inverse = jnp.argsort(order).astype(jnp.int32)
+    # a histogram by comparison: a scatter-add of T*k ones serialises on a TPU
+    sizes = (key[:, None] == jnp.arange(held_count + 1)).sum(axis=0, dtype=jnp.int32)
+    return HeldRoute(selected, weights, order, inverse, sizes)
+
+
+@jax.custom_vjp
+def permute_rows(rows: jax.Array, perm: jax.Array, inverse: jax.Array):
+    """``rows[perm]`` for a permutation whose inverse is known: the
+    transpose is the gather by ``inverse``, not a scatter-add."""
+    return jnp.take(rows, perm, axis=0)
+
+
+def _permute_fwd(rows, perm, inverse):
+    return jnp.take(rows, perm, axis=0), (perm, inverse)
+
+
+def _permute_bwd(res, g):
+    perm, inverse = res
+    return jnp.take(g, inverse, axis=0), None, None
+
+
+permute_rows.defvjp(_permute_fwd, _permute_bwd)
+
+#: (rows, contracted, out) tiles of the megablox kernels: of those swept on
+#: the v5e at the LFM2 cell's shapes (PERF.md, PR 28) the fastest with an
+#: eighth of the buffer filled, and within 8 % of the fastest when it is full
+GMM_TILING = (512, 512, 512)
+TGMM_TILING = (512, 512, 512)
+
+
+def _tiling(m: int, want: tuple[int, int, int]) -> tuple[int, int, int]:
+    tm = next((t for t in (want[0], 256, 128) if t <= want[0] and m % t == 0), None)
+    if tm is None:
+        raise ValueError(
+            f"the grouped-product kernel tiles its rows by 128: got {m} rows"
+        )
+    return (tm,) + tuple(want[1:])
+
+
+def _gmm(lhs, rhs, group_sizes, interpret):
+    """``interpret``: off the chip the Pallas kernels run interpreted."""
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+
+    return gmm(
+        lhs, rhs.astype(lhs.dtype), group_sizes, lhs.dtype,
+        _tiling(lhs.shape[0], GMM_TILING), interpret=interpret,
+    )
+
+
+_gmm_vjp = jax.custom_vjp(_gmm, nondiff_argnums=(3,))
+
+
+def _gmm_fwd(lhs, rhs, group_sizes, interpret):
+    return _gmm(lhs, rhs, group_sizes, interpret), (lhs, rhs, group_sizes)
+
+
+def _gmm_bwd(interpret, res, g):
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm, tgmm
+
+    lhs, rhs, group_sizes = res
+    m = lhs.shape[0]
+    d_lhs = gmm(
+        g, rhs.astype(lhs.dtype), group_sizes, lhs.dtype,
+        _tiling(m, GMM_TILING), transpose_rhs=True, interpret=interpret,
+    )
+    d_rhs = tgmm(
+        lhs.swapaxes(0, 1), g, group_sizes, jnp.float32,
+        _tiling(m, TGMM_TILING), num_actual_groups=rhs.shape[0],
+        interpret=interpret,
+    )
+    return d_lhs, d_rhs.astype(rhs.dtype), None
+
+
+_gmm_vjp.defvjp(_gmm_fwd, _gmm_bwd)
+
+
+def grouped_matmul(
+    lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array, *,
+    impl: str = "auto",
+) -> jax.Array:
+    """``lhs`` (M, K) times ``rhs`` (H, K, N), rows ``[offset_h, offset_h +
+    group_sizes[h])`` against ``rhs[h]``; ``group_sizes`` has H+1 entries and
+    the rows of its last group (no expert here) come out zero. Output in
+    ``lhs``'s dtype, accumulated in float32; ``rhs`` is cast to it.
+
+    ``impl``: ``"gmm"`` is the megablox Pallas kernel, whose grid is sized
+    from ``group_sizes`` at run time, so its device time follows the rows
+    really routed, with ``tgmm`` for the weights' gradient;
+    ``"ragged_dot"`` is ``lax.ragged_dot``; ``"auto"`` takes the kernel on a
+    TPU and ``ragged_dot`` elsewhere (where the kernel runs interpreted).
+    """
+    from akka_allreduce_tpu.ops._platform import interpret_default
+
+    off_chip = interpret_default(lhs)
+    if impl == "auto":
+        impl = "ragged_dot" if off_chip else "gmm"
+    if impl == "gmm":
+        return _gmm_vjp(lhs, rhs, group_sizes, off_chip)
+    if impl != "ragged_dot":
+        raise ValueError(f"unknown grouped-product {impl=}")
+    return lax.ragged_dot(
+        lhs, rhs.astype(lhs.dtype), group_sizes[: rhs.shape[0]],
+        preferred_element_type=lhs.dtype,
+    )
+
+
+def moe_dropless_held(
+    x: jax.Array,
+    router_w: jax.Array,
+    select_bias: jax.Array | None,
+    w1: jax.Array,
+    w3: jax.Array,
+    w2: jax.Array,
+    *,
+    k: int,
+    held_first: int = 0,
+    renormalise: bool = True,
+    scale: float = 1.0,
+    impl: str = "auto",
+) -> tuple[jax.Array, HeldRoute, jax.Array]:
+    """``x`` (T, d) through the gated experts this device holds.
+
+    ``router_w`` is (d, E) over ALL experts; ``w1``/``w3`` (H, d, f) and
+    ``w2`` (H, f, d) are experts ``held_first .. held_first + H - 1``. Each
+    token's result is ``sum over its selected AND held experts of
+    w_e * W2_e(silu(W1_e x) * W3_e x)``, ``w`` normalised over all ``k``
+    selected. Returns ``(y, route, dropped)``: ``dropped`` counts held
+    assignments beyond the row buffer as a share of all held assignments —
+    0 by construction, the buffer being every assignment there is.
+    """
+    t, d = x.shape
+    held = w1.shape[0]
+    with jax.named_scope("moe_route"):
+        # the router is float32 end to end: on a TPU a default-precision
+        # f32 product would round its operands to bf16
+        logits = jnp.matmul(
+            x.astype(jnp.float32), router_w.astype(jnp.float32),
+            precision=lax.Precision.HIGHEST,
+        )
+        selected, weights = sigmoid_topk_route(
+            logits, select_bias, k, renormalise=renormalise, scale=scale
+        )
+        route = held_route(selected, weights, held_first, held)
+        buffer_rows = t * k
+        routed_here = route.group_sizes[:held].sum()
+        dropped = jnp.maximum(routed_here - buffer_rows, 0) / jnp.maximum(
+            routed_here, 1
+        ).astype(jnp.float32)
+    with jax.named_scope("moe_experts"):
+        rows = jnp.broadcast_to(x[:, None, :], (t, k, d)).reshape(t * k, d)
+        xs = permute_rows(rows, route.order, route.inverse)
+        gate = grouped_matmul(xs, w1, route.group_sizes, impl=impl)
+        up = grouped_matmul(xs, w3, route.group_sizes, impl=impl)
+        ys = grouped_matmul(
+            jax.nn.silu(gate) * up, w2, route.group_sizes, impl=impl
+        )
+    with jax.named_scope("moe_combine"):
+        back = permute_rows(ys, route.inverse, route.order).reshape(t, k, d)
+        y = (back * weights[..., None].astype(back.dtype)).sum(axis=1)
+    return y, route, dropped

@@ -33,8 +33,12 @@ class MoEStepMetrics:
     loss: float  # masked per-token cross-entropy (aux not included)
     aux_loss: float  # Switch load-balancing loss (global weighted mean)
     dropped: float  # fraction of routing ASSIGNMENTS past expert capacity
-    # (denominator k*T under top-k) — the capacity_factor tuning knob
+    # (denominator k*T under top-k) — the capacity_factor tuning knob; 0 by
+    # construction for a dropless model
     contributors: float  # contributing DP replica rows
+    # rows each held expert received, (expert layers, held experts) — only
+    # from a model that reports them (``model=``), else None
+    expert_rows: np.ndarray | None = None
 
 
 class MoETrainer:
@@ -54,6 +58,13 @@ class MoETrainer:
       seq_len: GLOBAL per-sample sequence length (divisible by the seq
         axis size when present).
       aux_coef: weight of the Switch load-balancing loss.
+      model: a built module to train in place of the ``MoETransformerLM``
+        the size arguments describe — ``apply(variables, tokens) -> (logits,
+        aux, dropped, expert_rows)`` (``models.hybrid_decoder``: dropless
+        routing over a held subset of the experts, no auxiliary loss). It
+        runs without an expert exchange, so the mesh is (data,) only.
+      params: with ``model``, its variables (seeded weights handed in);
+        left out, ``model.init`` runs jitted from ``seed``.
     """
 
     def __init__(
@@ -79,6 +90,8 @@ class MoETrainer:
         compress: str | None = None,
         overlap: bool = False,
         dispatch_impl: str = "auto",
+        model=None,
+        params=None,
     ) -> None:
         from akka_allreduce_tpu.models.transformer import (
             MoETransformerLM,
@@ -122,22 +135,31 @@ class MoETrainer:
         self.seq_len = seq_len
         self.vocab = vocab
         self.aux_coef = aux_coef
-        self.model = MoETransformerLM(
-            vocab=vocab,
-            d_model=d_model,
-            n_heads=n_heads,
-            n_kv_heads=n_kv_heads,
-            n_layers=n_layers,
-            n_experts=n_experts,
-            capacity_factor=capacity_factor,
-            compute_dtype=compute_dtype,
-            expert_axis=self.expert_axis if self.ep > 1 else None,
-            ep_size=self.ep,
-            router_topk=router_topk,
-            seq_axis=self.seq_axis if self.sp > 1 else None,
-            seq_impl=seq_impl,
-            dispatch_impl=dispatch_impl,
-        )
+        if model is not None:
+            if self.ep > 1 or self.sp > 1:
+                raise ValueError(
+                    "a model handed in holds its own subset of the experts "
+                    "and runs no exchange: give it a (data,) mesh, got "
+                    f"{dict(mesh.shape)}"
+                )
+            self.model = model
+        else:
+            self.model = MoETransformerLM(
+                vocab=vocab,
+                d_model=d_model,
+                n_heads=n_heads,
+                n_kv_heads=n_kv_heads,
+                n_layers=n_layers,
+                n_experts=n_experts,
+                capacity_factor=capacity_factor,
+                compute_dtype=compute_dtype,
+                expert_axis=self.expert_axis if self.ep > 1 else None,
+                ep_size=self.ep,
+                router_topk=router_topk,
+                seq_axis=self.seq_axis if self.sp > 1 else None,
+                seq_impl=seq_impl,
+                dispatch_impl=dispatch_impl,
+            )
         # mu_dtype=bfloat16 halves the first-moment read+write traffic of
         # the adam update — the LARGEST single cost of a single-chip MoE
         # step, because the optimizer touches ALL E experts' params every
@@ -145,20 +167,27 @@ class MoETrainer:
         # BENCHMARKS.md round 4); nu (the variance) stays f32
         self.tx = optimizer or optax.adam(learning_rate, mu_dtype=mu_dtype)
 
-        # full-shape init (ep=1 twin); shard_map in_specs slice expert leaves
-        init_model = MoETransformerLM(
-            vocab=vocab,
-            d_model=d_model,
-            n_heads=n_heads,
-            n_kv_heads=n_kv_heads,
-            n_layers=n_layers,
-            n_experts=n_experts,
-            capacity_factor=capacity_factor,
-            compute_dtype=compute_dtype,
-            router_topk=router_topk,
-        )
         tokens0 = jnp.zeros((1, seq_len // self.sp), jnp.int32)
-        self.params = init_model.init(jax.random.PRNGKey(seed), tokens0)
+        if model is not None:
+            self.params = (
+                params if params is not None
+                else jax.jit(model.init)(jax.random.PRNGKey(seed), tokens0)
+            )
+        else:
+            # full-shape init (ep=1 twin); shard_map in_specs slice expert
+            # leaves
+            init_model = MoETransformerLM(
+                vocab=vocab,
+                d_model=d_model,
+                n_heads=n_heads,
+                n_kv_heads=n_kv_heads,
+                n_layers=n_layers,
+                n_experts=n_experts,
+                capacity_factor=capacity_factor,
+                compute_dtype=compute_dtype,
+                router_topk=router_topk,
+            )
+            self.params = init_model.init(jax.random.PRNGKey(seed), tokens0)
         self.opt_state = self.tx.init(self.params)
         self.param_count = int(
             sum(np.prod(p.shape) for p in jax.tree.leaves(self.params))
@@ -200,7 +229,12 @@ class MoETrainer:
         self._valid_sharding = NamedSharding(mesh, P(self.data_axis))
         data_axis = self.data_axis
         vary_axes = tuple(n for n in axis_names if n != data_axis)
-        model_apply = self.model.apply
+        n_rows = 0 if model is None else 1  # outputs past (logits, aux, dropped)
+
+        def model_apply(p, x):
+            logits, aux, *stats = self.model.apply(p, x)
+            return logits, aux, tuple(stats)  # (dropped[, expert_rows])
+
         tx = self.tx
         aux_coef = self.aux_coef
         param_specs = self._param_specs
@@ -215,14 +249,14 @@ class MoETrainer:
             denom = jnp.maximum(lax.psum(v * tokens_local, axis_names), 1.0)
 
             def masked_loss(p):
-                logits, aux, dropped = model_apply(p, x)
+                logits, aux, stats = model_apply(p, x)
                 ce = optax.softmax_cross_entropy_with_integer_labels(
                     logits, y
                 ).sum()
                 # aux is a per-device mean: weight by local tokens so the
                 # global sum / denom is its masked token-weighted mean
                 total = (ce + aux_coef * aux * tokens_local) * v / denom
-                return total, (ce, aux, dropped)
+                return total, (ce, aux, stats)
 
             if overlap:
                 # per-leaf in-backward collectives (SURVEY.md §8.4): the
@@ -233,14 +267,14 @@ class MoETrainer:
                 )
 
                 def unmasked_loss(ps):
-                    logits, aux, dropped = model_apply(ps, x)
+                    logits, aux, stats = model_apply(ps, x)
                     ce = optax.softmax_cross_entropy_with_integer_labels(
                         logits, y
                     ).sum()
                     total = (ce + aux_coef * aux * tokens_local) / denom
-                    return total, (ce, aux, dropped)
+                    return total, (ce, aux, stats)
 
-                (_, (ce, aux, dropped)), gavg = overlap_value_and_grad(
+                (_, (ce, aux, stats)), gavg = overlap_value_and_grad(
                     unmasked_loss, params, param_specs, axis_names, v,
                     has_aux=True, wire_dtype=wire_dtype,
                 )
@@ -252,7 +286,7 @@ class MoETrainer:
                     compressed_value_and_grad,
                 )
 
-                (_, (ce, aux, dropped)), gavg = compressed_value_and_grad(
+                (_, (ce, aux, stats)), gavg = compressed_value_and_grad(
                     masked_loss, params, param_specs, axis_names,
                     has_aux=True,
                     wire_dtype=compress,
@@ -266,11 +300,12 @@ class MoETrainer:
                     compressed_value_and_grad,
                 )
 
-                (_, (ce, aux, dropped)), gavg = compressed_value_and_grad(
+                (_, (ce, aux, stats)), gavg = compressed_value_and_grad(
                     masked_loss, params, param_specs, axis_names,
                     has_aux=True,
                     wire_dtype=None,
                 )
+            dropped, *rows = stats
             loss_avg = lax.psum(ce * v / denom, axis_names)
             aux_avg = lax.psum(aux * tokens_local * v / denom, axis_names)
             dropped_avg = lax.psum(
@@ -282,12 +317,22 @@ class MoETrainer:
             return (
                 new_params, new_opt, loss_avg, aux_avg, dropped_avg,
                 contributors,
+                # rows per held expert, summed over the contributing replicas
+                *(lax.psum(r * v, axis_names) for r in rows),
             )
 
         from akka_allreduce_tpu.ops.local_attention import flash_vma_relax
 
-        self._check_vma = not overlap and compress != "int8" and not flash_vma_relax(
-            seq_len, d_model // n_heads, sp=self.sp, seq_impl=seq_impl
+        head_dim = getattr(model, "head_dim", None) or d_model // n_heads
+        self._check_vma = (
+            not overlap
+            and compress != "int8"
+            and not flash_vma_relax(
+                seq_len, head_dim, sp=self.sp, seq_impl=seq_impl
+            )
+            # a handed-in model's grouped products are a Pallas kernel on
+            # the chip, whose outputs carry no varying-axes annotation
+            and not (model is not None and jax.default_backend() == "tpu")
         )
         mapped = jax.shard_map(
             step,
@@ -299,7 +344,10 @@ class MoETrainer:
                 batch_spec,
                 P(self.data_axis),
             ),
-            out_specs=(self._param_specs, self._opt_specs, P(), P(), P(), P()),
+            out_specs=(
+                self._param_specs, self._opt_specs, P(), P(), P(), P(),
+                *(P(),) * n_rows,
+            ),
             # off when the overlap custom_vjp erases varying-axes typing OR
             # the flash kernel can dispatch (outputs carry no vma —
             # ops.local_attention.flash_vma_relax, LongContext's discipline)
@@ -347,16 +395,19 @@ class MoETrainer:
             seq_len=self.seq_len, dp=1,  # row divisibility checked above
         )
         vd = place_mask(valid_arr, self._valid_sharding)
-        self.params, self.opt_state, loss, aux, dropped, cnt = self._step(
+        self.params, self.opt_state, *metrics = self._step(
             self.params, self.opt_state, xd, yd, vd
         )
         self.step_num += 1
+        # one fetch for all of the step's metrics, not one sync each
+        loss, aux, dropped, cnt, *rows = jax.device_get(metrics)
         return MoEStepMetrics(
             step=self.step_num,
             loss=float(loss),
             aux_loss=float(aux),
             dropped=float(dropped),
             contributors=float(cnt),
+            expert_rows=rows[0] if rows else None,
         )
 
     def train(self, batches: Iterable) -> list[MoEStepMetrics]:
@@ -393,7 +444,7 @@ class MoETrainer:
                     y = lax.dynamic_slice_in_dim(
                         y, s * t_local, t_local, axis=1
                     )
-                p, o, loss, aux, dropped, cnt = raw_step(p, o, x, y, valid)
+                p, o, loss, aux, dropped, cnt, *_ = raw_step(p, o, x, y, valid)
                 return (p, o), (loss, aux, dropped, cnt)
 
             (params, opt_state), outs = lax.scan(

@@ -198,6 +198,37 @@ def _lm_step(topo, monkeypatch):
     return compiled, 3 * 8  # three flash kernels per layer
 
 
+def _lfm2_moe_step(topo, monkeypatch):
+    """The ``MoETrainer`` step of the benchmark's ``lfm2_ep8_train_b1_t8192``
+    cell (conv/attention hybrid, 8 of 64 experts held, 1 x 8192 tokens,
+    bf16, no remat) on one described chip: a later change that makes it too
+    large for the chip fails here before it fails the cell."""
+    import importlib.util
+    import json
+    import os.path
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(__file__)), "benchmarks")
+
+    def load(*parts):
+        with open(os.path.join(bench, *parts), encoding="utf-8") as f:
+            return json.load(f)
+
+    spec = importlib.util.spec_from_file_location(
+        "bench_runner_moe_train", os.path.join(bench, "runners", "moe_train.py")
+    )
+    monkeypatch.syspath_prepend(bench)  # the runner imports the harness
+    runner = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(runner)
+    t, lowered = runner.lower_step_on_shapes(
+        load("configs", "lfm2_24b_a2b_ep8_d5.json"),
+        load("traffic", "closed_b1_t8192.json"), topo.devices[0],
+    )
+    assert 486.0e6 < t.param_count < 486.2e6
+    assert not t._check_vma  # flash and the grouped-product kernels: the TPU branch
+    # three flash kernels, and nine grouped products in each expert layer
+    return lowered.compile(), 3 + 9 * 4
+
+
 CASES = {
     "reduce_kernels_8x8M_f32": _reduce_kernels,
     "pallas_ring_4dev_64M_f32": _pallas_ring(None),
@@ -206,6 +237,7 @@ CASES = {
     "flash_attention_b8_h16_t2048_d128": _flash_attention,
     "grouped_psum_moe_shaped_grads_4dev": _grouped_psum,
     "flagship_lm_step": _lm_step,
+    "lfm2_moe_cell_step": _lfm2_moe_step,
 }
 
 
