@@ -2123,12 +2123,14 @@ def _train_moe_from_config(args) -> int:
     ]
     dt = time.perf_counter() - t0
     rows = hist[-1].expert_rows
+    fullest = rows.sum(axis=1).argmax()
     print(
         f"moe: {args.steps} steps on {trainer.n_devices} devices in "
         f"{dt:.2f}s ({dt / args.steps * 1e3:.1f} ms/step); "
         f"loss {hist[0].loss:.4f} -> {hist[-1].loss:.4f} "
         f"(dropped {hist[-1].dropped:.1%}; rows per held expert, last "
-        f"step, fullest layer: {rows[rows.sum(axis=1).argmax()].astype(int).tolist()})"
+        f"step, fullest layer: {rows[fullest].astype(int).tolist()} in a "
+        f"row buffer of {int(hist[-1].buffer_rows[fullest])})"
     )
     return 0
 

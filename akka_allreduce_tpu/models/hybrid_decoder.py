@@ -125,14 +125,16 @@ class HeldExperts(nn.Module):
             renormalise=self.renormalise, scale=self.scale,
         )
         self.sow("intermediates", "selected", route.selected)
-        return y.reshape(x.shape), route.group_sizes[:h], dropped
+        return y.reshape(x.shape), route.group_sizes[:h], dropped, route.buffer_rows
 
 
 class HybridDecoderLM(nn.Module):
-    """``tokens -> (logits, aux, dropped, expert_rows)``: float32 logits,
-    ``aux`` always 0 (no auxiliary loss), ``dropped`` the mean of the expert
-    layers' (0 by construction) and ``expert_rows`` (expert layers,
-    held_count) float32 counts — the tuple ``MoETrainer`` takes."""
+    """``tokens -> (logits, aux, dropped, expert_rows, buffer_rows)``:
+    float32 logits, ``aux`` always 0 (no auxiliary loss), ``dropped`` the
+    mean of the expert layers' (0 by construction), ``expert_rows`` (expert
+    layers, held_count) float32 counts and ``buffer_rows`` (expert layers,)
+    the rows of the row buffer each layer took for them — the tuple
+    ``MoETrainer`` takes."""
 
     vocab: int
     d_model: int
@@ -199,7 +201,7 @@ class HybridDecoderLM(nn.Module):
             epsilon=self.norm_eps, dtype=dt, name=name
         )
         x = nn.Embed(self.vocab, self.d_model, dtype=dt, name="embed")(tokens)
-        rows, dropped = [], []
+        rows, dropped, buffers = [], [], []
         for i, kind in enumerate(self.layer_types):
             pre = f"layers_{i}_"
             h = norm(pre + "op_norm")(x)
@@ -217,7 +219,7 @@ class HybridDecoderLM(nn.Module):
             if i < self.num_dense_layers:
                 y = GatedMLP(self.intermediate_size, dt, name=pre + "mlp")(h)
             else:
-                y, r, dr = HeldExperts(
+                y, r, dr, taken = HeldExperts(
                     self.num_experts, self.experts_per_token,
                     self.moe_intermediate_size, self.held_first, self.held_count,
                     self.use_select_bias, self.renormalise, self.routed_scale,
@@ -225,6 +227,7 @@ class HybridDecoderLM(nn.Module):
                 )(h)
                 rows.append(r)
                 dropped.append(dr)
+                buffers.append(taken)
             x = x + y
         x = norm("final_norm")(x)
         head = self.param(
@@ -241,4 +244,6 @@ class HybridDecoderLM(nn.Module):
             sum(dropped, jnp.float32(0.0)) / n,
             jnp.stack(rows).astype(jnp.float32) if rows
             else jnp.zeros((0, self.held_count), jnp.float32),
+            jnp.stack(buffers).astype(jnp.float32) if buffers
+            else jnp.zeros((0,), jnp.float32),
         )

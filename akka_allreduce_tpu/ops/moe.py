@@ -23,11 +23,14 @@ the oracle the EP path is tested against.
 
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from akka_allreduce_tpu.ops._platform import interpret_default
 
 
 class RouteResult(NamedTuple):
@@ -306,6 +309,8 @@ class HeldRoute(NamedTuple):
     order: jax.Array  # (T*k,) int32: sorted row -> flat (token, choice)
     inverse: jax.Array  # (T*k,) int32: flat (token, choice) -> sorted row
     group_sizes: jax.Array  # (H+1,) int32 rows per held expert; last: absent
+    rung: jax.Array  # () int32: which of the ladder's row buffers holds them
+    buffer_rows: jax.Array  # () int32: that buffer's rows
 
 
 def sigmoid_topk_route(
@@ -329,43 +334,50 @@ def sigmoid_topk_route(
     return selected.astype(jnp.int32), w * scale
 
 
+def row_rungs(rows: int, held: int, experts: int) -> tuple[int, ...]:
+    """The row-buffer sizes, smallest first, for ``rows`` (token, choice)
+    assignments routed over ``experts`` of which ``held`` are here. The
+    first rung is the load under uniform routing, ``rows * held / experts``,
+    and a quarter more (the count scatters around its mean from layer to
+    layer), rounded up to the grouped-product kernels' row tile at this
+    buffer; the last is ``rows``, the worst case — every token choosing
+    only held experts — so nothing can overflow. Two rungs and none between
+    them: every rung is a body of the expert layer the step program carries,
+    2.1 s of each start's set-up at the LFM2 cell (PERF.md, PR 29). A device
+    that holds every expert gets the last rung alone."""
+    tile = next((t for t in (GMM_TILING[0], 256, 128) if rows % t == 0), None)
+    if tile is None:  # no kernel tiles such a buffer: one size
+        return (rows,)
+    first = -(-5 * rows * held // (4 * experts * tile)) * tile
+    return (first, rows) if first < rows else (rows,)
+
+
 def held_route(
-    selected: jax.Array, weights: jax.Array, held_first: int, held_count: int
+    selected: jax.Array, weights: jax.Array, held_first: int, held_count: int,
+    rungs: tuple[int, ...],
 ) -> HeldRoute:
     """Sort the (token, choice) assignments expert-major: the rows of held
     expert 0 first, then 1, ..., and the assignments to absent experts
-    last. The row buffer is all T*k assignments — the worst case, every
-    token choosing only held experts — so nothing can overflow."""
+    last; and pick the smallest of ``rungs`` (:func:`row_rungs`) that holds
+    the rows of the held experts."""
     local = selected.reshape(-1) - held_first
     key = jnp.where((local >= 0) & (local < held_count), local, held_count)
     order = jnp.argsort(key, stable=True).astype(jnp.int32)
     inverse = jnp.argsort(order).astype(jnp.int32)
     # a histogram by comparison: a scatter-add of T*k ones serialises on a TPU
     sizes = (key[:, None] == jnp.arange(held_count + 1)).sum(axis=0, dtype=jnp.int32)
-    return HeldRoute(selected, weights, order, inverse, sizes)
+    routed = sizes[:held_count].sum()
+    rung = (routed > jnp.asarray(rungs[:-1], jnp.int32)).sum(dtype=jnp.int32)
+    return HeldRoute(
+        selected, weights, order, inverse, sizes, rung,
+        jnp.asarray(rungs, jnp.int32)[rung],
+    )
 
-
-@jax.custom_vjp
-def permute_rows(rows: jax.Array, perm: jax.Array, inverse: jax.Array):
-    """``rows[perm]`` for a permutation whose inverse is known: the
-    transpose is the gather by ``inverse``, not a scatter-add."""
-    return jnp.take(rows, perm, axis=0)
-
-
-def _permute_fwd(rows, perm, inverse):
-    return jnp.take(rows, perm, axis=0), (perm, inverse)
-
-
-def _permute_bwd(res, g):
-    perm, inverse = res
-    return jnp.take(g, inverse, axis=0), None, None
-
-
-permute_rows.defvjp(_permute_fwd, _permute_bwd)
 
 #: (rows, contracted, out) tiles of the megablox kernels: of those swept on
-#: the v5e at the LFM2 cell's shapes (PERF.md, PR 28) the fastest with an
-#: eighth of the buffer filled, and within 8 % of the fastest when it is full
+#: the v5e at the LFM2 cell's shapes the fastest with an eighth of the whole
+#: buffer filled, within 8 % of the fastest when it is full (PERF.md, PR 28),
+#: and the fastest at the cell's first rung of 5,120 rows (PERF.md, PR 29)
 GMM_TILING = (512, 512, 512)
 TGMM_TILING = (512, 512, 512)
 
@@ -379,41 +391,76 @@ def _tiling(m: int, want: tuple[int, int, int]) -> tuple[int, int, int]:
     return (tm,) + tuple(want[1:])
 
 
-def _gmm(lhs, rhs, group_sizes, interpret):
-    """``interpret``: off the chip the Pallas kernels run interpreted."""
+def _product(lhs, rhs, group_sizes, impl, transpose_rhs=False):
+    """Rows of group ``h`` times ``rhs[h]`` (its transpose on request), for
+    ``impl`` "gmm" or "ragged_dot"; the rows of the last group of
+    ``group_sizes`` (no expert here) come out zero."""
+    rhs = rhs.astype(lhs.dtype)
+    if impl == "ragged_dot":
+        sizes = group_sizes[: rhs.shape[0]]
+        out = lax.ragged_dot(
+            lhs, rhs.swapaxes(1, 2) if transpose_rhs else rhs, sizes,
+            preferred_element_type=lhs.dtype,
+        )
+        # on a TPU the rows past the last group come back unwritten (NaN
+        # among them; PERF.md, PR 29), on the CPU zero
+        rows = lax.broadcasted_iota(jnp.int32, (lhs.shape[0], 1), 0)
+        return jnp.where(rows < sizes.sum(), out, 0)
     from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
 
+    # off the chip the Pallas kernels run interpreted
     return gmm(
-        lhs, rhs.astype(lhs.dtype), group_sizes, lhs.dtype,
-        _tiling(lhs.shape[0], GMM_TILING), interpret=interpret,
+        lhs, rhs, group_sizes, lhs.dtype, _tiling(lhs.shape[0], GMM_TILING),
+        transpose_rhs=transpose_rhs, interpret=interpret_default(lhs),
     )
 
 
-_gmm_vjp = jax.custom_vjp(_gmm, nondiff_argnums=(3,))
+def _weight_gradient(lhs, g, group_sizes, groups: int, impl):
+    """``lhs[rows of h].T @ g[rows of h]`` for each of ``groups`` experts:
+    (M, K), (M, N) -> (groups, K, N) float32."""
+    if impl == "ragged_dot":
+        contract_rows = lax.RaggedDotDimensionNumbers(
+            dot_dimension_numbers=(((0,), (0,)), ((), ())),
+            lhs_ragged_dimensions=[0], rhs_group_dimensions=[],
+        )
+        return lax.ragged_dot_general(
+            lhs, g, group_sizes[:groups], contract_rows,
+            preferred_element_type=jnp.float32,
+        )
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import tgmm
 
-
-def _gmm_fwd(lhs, rhs, group_sizes, interpret):
-    return _gmm(lhs, rhs, group_sizes, interpret), (lhs, rhs, group_sizes)
-
-
-def _gmm_bwd(interpret, res, g):
-    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm, tgmm
-
-    lhs, rhs, group_sizes = res
-    m = lhs.shape[0]
-    d_lhs = gmm(
-        g, rhs.astype(lhs.dtype), group_sizes, lhs.dtype,
-        _tiling(m, GMM_TILING), transpose_rhs=True, interpret=interpret,
-    )
-    d_rhs = tgmm(
+    return tgmm(
         lhs.swapaxes(0, 1), g, group_sizes, jnp.float32,
-        _tiling(m, TGMM_TILING), num_actual_groups=rhs.shape[0],
-        interpret=interpret,
+        _tiling(lhs.shape[0], TGMM_TILING), num_actual_groups=groups,
+        interpret=interpret_default(lhs),
     )
+
+
+@jax.custom_vjp
+def _gmm_vjp(lhs, rhs, group_sizes):
+    return _product(lhs, rhs, group_sizes, "gmm")
+
+
+def _gmm_fwd(lhs, rhs, group_sizes):
+    return _product(lhs, rhs, group_sizes, "gmm"), (lhs, rhs, group_sizes)
+
+
+def _gmm_bwd(res, g):
+    lhs, rhs, group_sizes = res
+    d_lhs = _product(g, rhs, group_sizes, "gmm", transpose_rhs=True)
+    d_rhs = _weight_gradient(lhs, g, group_sizes, rhs.shape[0], "gmm")
     return d_lhs, d_rhs.astype(rhs.dtype), None
 
 
 _gmm_vjp.defvjp(_gmm_fwd, _gmm_bwd)
+
+
+def _grouped_impl(impl: str, like: jax.Array) -> str:
+    if impl == "auto":
+        return "ragged_dot" if interpret_default(like) else "gmm"
+    if impl not in ("gmm", "ragged_dot"):
+        raise ValueError(f"unknown grouped-product {impl=}")
+    return impl
 
 
 def grouped_matmul(
@@ -431,19 +478,140 @@ def grouped_matmul(
     ``"ragged_dot"`` is ``lax.ragged_dot``; ``"auto"`` takes the kernel on a
     TPU and ``ragged_dot`` elsewhere (where the kernel runs interpreted).
     """
-    from akka_allreduce_tpu.ops._platform import interpret_default
+    if _grouped_impl(impl, lhs) == "gmm":
+        return _gmm_vjp(lhs, rhs, group_sizes)
+    return _product(lhs, rhs, group_sizes, "ragged_dot")
 
-    off_chip = interpret_default(lhs)
-    if impl == "auto":
-        impl = "ragged_dot" if off_chip else "gmm"
-    if impl == "gmm":
-        return _gmm_vjp(lhs, rhs, group_sizes, off_chip)
-    if impl != "ragged_dot":
-        raise ValueError(f"unknown grouped-product {impl=}")
-    return lax.ragged_dot(
-        lhs, rhs.astype(lhs.dtype), group_sizes[: rhs.shape[0]],
-        preferred_element_type=lhs.dtype,
+
+# -- the held experts' gated FFN over one rung of the ladder -------------------
+#
+# A rung's buffer holds the first ``rows`` of the sorted assignments: every
+# row of a held expert (the route took the rung for that) and then rows of
+# absent experts, which the grouped products leave zero. ``lax.switch`` under
+# ``jax.grad`` would make every rung return every rung's residuals, zero-
+# filled for those not taken; so the rung is picked inside a custom_vjp,
+# once forward and once backward, and the backward is written out. What the
+# forward keeps for it has one shape whatever the rung: its arguments, the
+# weights as the products take them, and the gate and up products of the
+# FIRST rung, the one a balanced router takes; a larger rung hands back
+# zeros in their place and recomputes the two products on the way to the
+# gradients. Both directions are jitted, so that the expert layers of a
+# model (same shapes) trace and lower each rung once.
+
+
+def _rows_to_sources(rows: jax.Array, inverse: jax.Array, n: int) -> jax.Array:
+    """The transpose of gathering rows from ``n`` sources, (R, d) -> (n, d):
+    ``inverse`` holds, for each source's slots in turn, the row that slot
+    went to, and a slot past R went nowhere. One gather of n rows per slot
+    (a scatter-add of the R rows takes twice as long on a TPU), summed in
+    float32."""
+    slots = inverse.reshape(n, -1)
+    return sum(
+        jnp.take(rows, slots[:, j], axis=0, mode="fill", fill_value=0)
+        .astype(jnp.float32) for j in range(slots.shape[1])
+    ).astype(rows.dtype)
+
+
+def _rung_rows(rows, x, weights, route):
+    """A rung's rows: ``(at, sizes, xs, ws)`` — the flat (token, choice) of
+    each sorted row, the rows per held expert closed by the absent rows,
+    the rows' tokens' ``x`` and the rows' routing weights (R, 1)."""
+    at = route.order[:rows]
+    held = route.group_sizes[:-1]
+    sizes = jnp.concatenate([held, rows - held.sum(keepdims=True)])
+    xs = x.at[at // weights.shape[1]].get(mode="promise_in_bounds")
+    ws = weights.reshape(-1).at[at].get(mode="promise_in_bounds")
+    return at, sizes, xs, ws[:, None]
+
+
+@functools.partial(jax.jit, static_argnames=("rows", "keep", "impl"))
+def _rung_forward(x, weights, w1, w3, w2, route, *, rows, keep, impl):
+    """The held experts' part of every token's result, moving and
+    multiplying ``rows`` rows: right where the route took a rung this large
+    or a smaller one. Also the gate and up products, (``keep``, f) each,
+    where this is the rung of ``keep`` rows, else zeros."""
+    with jax.named_scope("moe_experts"):
+        _, sizes, xs, ws = _rung_rows(rows, x, weights, route)
+        gate = _product(xs, w1, sizes, impl)
+        up = _product(xs, w3, sizes, impl)
+        ys = _product(jax.nn.silu(gate) * up, w2, sizes, impl)
+    with jax.named_scope("moe_combine"):
+        y = _rows_to_sources(ys * ws.astype(ys.dtype), route.inverse, x.shape[0])
+    if rows != keep:
+        gate = up = jnp.zeros((keep, gate.shape[1]), gate.dtype)
+    return y, (gate, up)
+
+
+@functools.partial(jax.jit, static_argnames=("rows", "keep", "impl"))
+def _rung_backward(x, weights, w1, w3, w2, route, kept, g, *, rows, keep, impl):
+    """The gradients of :func:`_rung_forward`'s five array arguments (those
+    of the weights in float32) from ``g``, its result's; ``kept`` is what it
+    handed back."""
+    f32, held = jnp.float32, w1.shape[0]
+    with jax.named_scope("moe_experts"):
+        at, sizes, xs, ws = _rung_rows(rows, x, weights, route)
+        gate, up = kept if rows == keep else (
+            _product(xs, w1, sizes, impl), _product(xs, w3, sizes, impl)
+        )
+        gate, up, ws = gate.astype(f32), up.astype(f32), ws.astype(f32)
+        sig = jax.nn.sigmoid(gate)
+        h = gate * sig * up
+    with jax.named_scope("moe_combine"):
+        gz = g.at[at // weights.shape[1]].get(mode="promise_in_bounds")
+        # d ys = ws gz, so d h = ws (gz W2^T) and d ws = <ys, gz> = <h, gz W2^T>
+        u = _product(gz, w2, sizes, impl, transpose_rhs=True).astype(f32)
+        d_weights = jnp.zeros(weights.size, f32).at[at].add((h * u).sum(axis=-1))
+    with jax.named_scope("moe_experts"):
+        d_h = u * ws
+        d_w2 = _weight_gradient((h * ws).astype(x.dtype), gz, sizes, held, impl)
+        d_gate = (d_h * up * sig * (1.0 + gate * (1.0 - sig))).astype(x.dtype)
+        d_up = (d_h * gate * sig).astype(x.dtype)
+        d_xs = _product(d_gate, w1, sizes, impl, transpose_rhs=True) + _product(
+            d_up, w3, sizes, impl, transpose_rhs=True
+        )
+        d_x = _rows_to_sources(d_xs, route.inverse, x.shape[0])
+        d_w1 = _weight_gradient(xs, d_gate, sizes, held, impl)
+        d_w3 = _weight_gradient(xs, d_up, sizes, held, impl)
+    return d_x, d_weights.reshape(weights.shape).astype(weights.dtype), d_w1, d_w3, d_w2
+
+
+def _on_rung(rungs, route, fn, impl, *operands):
+    # a rung past the first is the exception (a balanced router never takes
+    # it) and multiplies through ``lax.ragged_dot``, within 12 % of the
+    # kernels at every fill (PERF.md, PR 28): a second set of Mosaic bodies
+    # would cost every start's set-up for it (PERF.md, PR 29)
+    each = [functools.partial(fn, rows=rungs[0], keep=rungs[0], impl=impl)] + [
+        functools.partial(fn, rows=r, keep=rungs[0], impl="ragged_dot")
+        for r in rungs[1:]
+    ]
+    if len(rungs) == 1:
+        return each[0](*operands)
+    return lax.switch(route.rung, each, *operands)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _held_ffn(rungs, impl, x, weights, w1, w3, w2, route):
+    return _held_ffn_fwd(rungs, impl, x, weights, w1, w3, w2, route)[0]
+
+
+def _held_ffn_fwd(rungs, impl, x, weights, w1, w3, w2, route):
+    cast = tuple(w.astype(x.dtype) for w in (w1, w3, w2))
+    y, kept = _on_rung(rungs, route, _rung_forward, impl, x, weights, *cast, route)
+    return y, (x, weights, (w1, w3, w2), cast, route, kept)
+
+
+def _held_ffn_bwd(rungs, impl, res, g):
+    x, weights, stored, cast, route, kept = res
+    d_x, d_weights, *d_w = _on_rung(
+        rungs, route, _rung_backward, impl, x, weights, *cast, route, kept, g
     )
+    return (
+        d_x, d_weights, *(d.astype(w.dtype) for d, w in zip(d_w, stored)),
+        jax.tree.map(lambda _: None, route),
+    )
+
+
+_held_ffn.defvjp(_held_ffn_fwd, _held_ffn_bwd)
 
 
 def moe_dropless_held(
@@ -466,12 +634,15 @@ def moe_dropless_held(
     ``w2`` (H, f, d) are experts ``held_first .. held_first + H - 1``. Each
     token's result is ``sum over its selected AND held experts of
     w_e * W2_e(silu(W1_e x) * W3_e x)``, ``w`` normalised over all ``k``
-    selected. Returns ``(y, route, dropped)``: ``dropped`` counts held
-    assignments beyond the row buffer as a share of all held assignments —
-    0 by construction, the buffer being every assignment there is.
+    selected. The rows gathered, multiplied and summed back are those of
+    the smallest rung of :func:`row_rungs` (from T*k, H and E) that holds
+    the rows routed here, picked on the device each call; the last rung is
+    every assignment there is. Returns ``(y, route, dropped)``: ``dropped``
+    counts held assignments beyond the rung taken as a share of all held
+    assignments — 0 by construction.
     """
-    t, d = x.shape
     held = w1.shape[0]
+    rungs = row_rungs(x.shape[0] * k, held, router_w.shape[1])
     with jax.named_scope("moe_route"):
         # the router is float32 end to end: on a TPU a default-precision
         # f32 product would round its operands to bf16
@@ -482,21 +653,10 @@ def moe_dropless_held(
         selected, weights = sigmoid_topk_route(
             logits, select_bias, k, renormalise=renormalise, scale=scale
         )
-        route = held_route(selected, weights, held_first, held)
-        buffer_rows = t * k
+        route = held_route(selected, weights, held_first, held, rungs)
         routed_here = route.group_sizes[:held].sum()
-        dropped = jnp.maximum(routed_here - buffer_rows, 0) / jnp.maximum(
+        dropped = jnp.maximum(routed_here - route.buffer_rows, 0) / jnp.maximum(
             routed_here, 1
         ).astype(jnp.float32)
-    with jax.named_scope("moe_experts"):
-        rows = jnp.broadcast_to(x[:, None, :], (t, k, d)).reshape(t * k, d)
-        xs = permute_rows(rows, route.order, route.inverse)
-        gate = grouped_matmul(xs, w1, route.group_sizes, impl=impl)
-        up = grouped_matmul(xs, w3, route.group_sizes, impl=impl)
-        ys = grouped_matmul(
-            jax.nn.silu(gate) * up, w2, route.group_sizes, impl=impl
-        )
-    with jax.named_scope("moe_combine"):
-        back = permute_rows(ys, route.inverse, route.order).reshape(t, k, d)
-        y = (back * weights[..., None].astype(back.dtype)).sum(axis=1)
+    y = _held_ffn(rungs, _grouped_impl(impl, x), x, weights, w1, w3, w2, route)
     return y, route, dropped

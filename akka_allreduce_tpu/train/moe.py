@@ -39,6 +39,10 @@ class MoEStepMetrics:
     # rows each held expert received, (expert layers, held experts) — only
     # from a model that reports them (``model=``), else None
     expert_rows: np.ndarray | None = None
+    # rows of the row buffer each expert layer moved and multiplied for them
+    # (``ops.moe.row_rungs``: the smallest rung that held the rows routed),
+    # (expert layers,), summed over the replicas as ``expert_rows`` is
+    buffer_rows: np.ndarray | None = None
 
 
 class MoETrainer:
@@ -60,9 +64,10 @@ class MoETrainer:
       aux_coef: weight of the Switch load-balancing loss.
       model: a built module to train in place of the ``MoETransformerLM``
         the size arguments describe — ``apply(variables, tokens) -> (logits,
-        aux, dropped, expert_rows)`` (``models.hybrid_decoder``: dropless
-        routing over a held subset of the experts, no auxiliary loss). It
-        runs without an expert exchange, so the mesh is (data,) only.
+        aux, dropped, expert_rows, buffer_rows)`` (``models.hybrid_decoder``:
+        dropless routing over a held subset of the experts, no auxiliary
+        loss). It runs without an expert exchange, so the mesh is (data,)
+        only.
       params: with ``model``, its variables (seeded weights handed in);
         left out, ``model.init`` runs jitted from ``seed``.
     """
@@ -229,11 +234,11 @@ class MoETrainer:
         self._valid_sharding = NamedSharding(mesh, P(self.data_axis))
         data_axis = self.data_axis
         vary_axes = tuple(n for n in axis_names if n != data_axis)
-        n_rows = 0 if model is None else 1  # outputs past (logits, aux, dropped)
+        n_rows = 0 if model is None else 2  # outputs past (logits, aux, dropped)
 
         def model_apply(p, x):
             logits, aux, *stats = self.model.apply(p, x)
-            return logits, aux, tuple(stats)  # (dropped[, expert_rows])
+            return logits, aux, tuple(stats)  # (dropped[, expert_rows, buffer_rows])
 
         tx = self.tx
         aux_coef = self.aux_coef
@@ -317,7 +322,8 @@ class MoETrainer:
             return (
                 new_params, new_opt, loss_avg, aux_avg, dropped_avg,
                 contributors,
-                # rows per held expert, summed over the contributing replicas
+                # rows per held expert and per row buffer, summed over the
+                # contributing replicas
                 *(lax.psum(r * v, axis_names) for r in rows),
             )
 
@@ -408,6 +414,7 @@ class MoETrainer:
             dropped=float(dropped),
             contributors=float(cnt),
             expert_rows=rows[0] if rows else None,
+            buffer_rows=rows[1] if rows else None,
         )
 
     def train(self, batches: Iterable) -> list[MoEStepMetrics]:

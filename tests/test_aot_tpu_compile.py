@@ -225,7 +225,12 @@ def _lfm2_moe_step(topo, monkeypatch):
     )
     assert 486.0e6 < t.param_count < 486.2e6
     assert not t._check_vma  # flash and the grouped-product kernels: the TPU branch
-    # three flash kernels, and nine grouped products in each expert layer
+    # three flash kernels, and the nine grouped products of the first rung
+    # of the row buffer in each of the four expert layers (three forward,
+    # three and three ``tgmm`` backward; the last rung is ``lax.ragged_dot``)
+    from akka_allreduce_tpu.ops.moe import row_rungs
+
+    assert row_rungs(8192 * 4, 8, 64) == (5120, 8192 * 4)
     return lowered.compile(), 3 + 9 * 4
 
 

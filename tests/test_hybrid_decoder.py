@@ -218,21 +218,167 @@ def test_dropless_under_skew(cfg, favoured):
     _close(y, _reference_layer(cfg, a, [4, 5, 6, 7]))
 
 
-def test_pallas_grouped_products_equal_ragged_dot(cfg):
+# -- the ladder of row buffers ---------------------------------------------------
+
+
+@pytest.mark.parametrize("rows,held,experts,want", [
+    (8192 * 4, 8, 64, (5120, 32768)),  # the LFM2 cell
+    (8192 * 4, 64, 64, (32768,)),  # every expert held: the buffer alone
+    (8192 * 4, 56, 64, (32768,)),  # a quarter over the expectation is all of it
+    (1152, 2, 16, (256, 1152)),
+    (1152, 4, 16, (384, 1152)),
+    (1024, 4, 16, (512, 1024)),  # the tile is the kernels' at this buffer
+    (256, 4, 16, (256,)),
+    (100, 1, 16, (100,)),  # no kernel tiles 100 rows
+])
+def test_row_rungs(rows, held, experts, want):
+    from akka_allreduce_tpu.ops.moe import row_rungs
+
+    assert row_rungs(rows, held, experts) == want
+
+
+def test_row_rungs_at_tiny_shapes_stay_inside_the_buffer_and_on_the_tile():
+    from akka_allreduce_tpu.ops.moe import row_rungs
+
+    for rows in range(128, 4097, 128):
+        for held, experts in ((1, 64), (2, 16), (4, 16), (8, 8), (3, 7)):
+            rungs = row_rungs(rows, held, experts)
+            assert 1 <= len(rungs) <= 2 and rungs[-1] == rows
+            assert list(rungs) == sorted(set(rungs))
+            assert all(r % 128 == 0 for r in rungs)
+            # the first rung holds the load under uniform routing
+            assert rungs[0] >= min(rows, rows * held / experts)
+
+
+LADDER = (256, 1152)  # 288 tokens x 4 choices, experts 4-5 of 16 held
+
+
+def _routed_exactly(cfg, all_held, one_held, seed=7):
+    """288 tokens of which the first ``all_held`` pick two held experts (4
+    and 5: the share holds two, so no token can pick more), the next
+    ``one_held`` one (5), and the rest none: ``2 * all_held + one_held``
+    rows for the held experts, exactly."""
+    a = _layer_inputs(cfg, seed=seed, tokens=288)
+    kind = jnp.where(jnp.arange(288) < all_held, 0,
+                     jnp.where(jnp.arange(288) < all_held + one_held, 1, 2))
+    a["x"] = (0.05 * a["x"]).at[0, :, :3].set(jax.nn.one_hot(kind, 3))
+    # each kind of token reads one direction that lifts four experts well
+    # over the rest, short of p = 1 (where the router's gradient is zero)
+    for direction, favoured in enumerate(([4, 5, 8, 9], [5, 8, 9, 10], [8, 9, 10, 11])):
+        a["router.w"] = a["router.w"].at[direction, jnp.asarray(favoured)].add(8.0)
+    a["bias"] = jnp.zeros_like(a["bias"])
+    return a
+
+
+def _layer_and_gradients(cfg, a, first, count):
+    """``(y, route, dropped)`` and the gradients of a probed sum of ``y``."""
+    from akka_allreduce_tpu.ops.moe import moe_dropless_held
+
+    names = ("x", "router.w", "experts.w1", "experts.w3", "experts.w2")
+    probe = jax.random.normal(jax.random.PRNGKey(99), a["x"].shape)
+
+    def loss(*leaves):
+        y, route, dropped = _program_layer(cfg, dict(a, **dict(zip(names, leaves))),
+                                           first, count)
+        return (y * probe).sum(), (y, route, dropped)
+
+    grads, out = jax.grad(loss, argnums=range(5), has_aux=True)(*(a[n] for n in names))
+    return out, grads
+
+
+RUNG_CASES = {
+    # (tokens picking two held experts, tokens picking one) -> rung taken
+    "first_rung": (60, 40, 0),
+    "exactly_the_first_rung": (100, 56, 0),
+    "one_row_over_the_first_rung": (100, 57, 1),
+    "last_rung_half_filled": (280, 8, 1),
+    "every_token_picks_both_held": (288, 0, 1),
+    "nothing_routed_here": (0, 0, 0),
+}
+
+
+@pytest.mark.parametrize("case", sorted(RUNG_CASES))
+def test_each_rung_equals_the_whole_buffer(cfg, case, monkeypatch):
+    """Whichever rung the rows routed here ask for, the result, every
+    gradient, the counts and the picks are those of the one-rung program
+    that moves every assignment."""
+    from akka_allreduce_tpu.ops import moe
+
+    all_held, one_held, rung = RUNG_CASES[case]
+    a = _routed_exactly(cfg, all_held, one_held)
+    assert moe.row_rungs(288 * 4, 2, 16) == LADDER
+    (y, route, dropped), grads = _layer_and_gradients(cfg, a, 4, 2)
+    routed = 2 * all_held + one_held
+    assert int(route.group_sizes[:2].sum()) == routed
+    assert (int(route.rung), int(route.buffer_rows)) == (rung, LADDER[rung])
+    assert float(dropped) == 0.0
+    _close(y, _reference_layer(cfg, a, [4, 5]))
+
+    monkeypatch.setattr(moe, "row_rungs", lambda rows, held, experts: (rows,))
+    (y1, route1, dropped1), grads1 = _layer_and_gradients(cfg, a, 4, 2)
+    assert int(route1.buffer_rows) == 288 * 4 and float(dropped1) == 0.0
+    _close(y, y1)
+    for g, g1 in zip(grads, grads1):
+        _close(g, g1)
+    if routed:
+        assert all(float(jnp.abs(g1).max()) > 0 for g1 in grads1)
+    np.testing.assert_array_equal(np.asarray(route.group_sizes), np.asarray(route1.group_sizes))
+    np.testing.assert_array_equal(np.asarray(route.selected), np.asarray(route1.selected))
+
+
+def test_every_choice_held_takes_the_last_rung(cfg):
+    """The ladder's worst case through a share that CAN fill its buffer:
+    four of sixteen experts held, every token choosing those four."""
+    from akka_allreduce_tpu.ops.moe import row_rungs
+
+    a = _layer_inputs(cfg, seed=3, tokens=288)
+    a["x"] = (0.05 * a["x"]).at[..., 0].set(1.0)
+    a["router.w"] = a["router.w"].at[0, jnp.asarray([4, 5, 6, 7])].add(8.0)
+    a["bias"] = jnp.zeros_like(a["bias"])
+    assert row_rungs(288 * 4, 4, 16) == (384, 1152)
+    (y, route, dropped), grads = _layer_and_gradients(cfg, a, 4, 4)
+    assert (int(route.rung), int(route.buffer_rows)) == (1, 1152)
+    assert route.group_sizes.tolist() == [288] * 4 + [0] and float(dropped) == 0.0
+    _close(y, _reference_layer(cfg, a, [4, 5, 6, 7]))
+    assert all(float(jnp.abs(g).max()) > 0 for g in grads)
+
+
+@pytest.mark.parametrize("first,count,branches", [(0, 16, False), (4, 2, True)])
+def test_a_branch_only_where_experts_are_absent(cfg, first, count, branches):
+    """A device that holds every expert has one rung and its program no
+    conditional, forward or backward."""
+    a = _layer_inputs(cfg, seed=1, tokens=288)
+
+    def grads(x):
+        return jax.grad(lambda x: _program_layer(cfg, dict(a, x=x), first, count)[0].sum())(x)
+
+    text = str(jax.make_jaxpr(grads)(a["x"]))
+    assert (" cond[" in text or "cond(" in text) == branches, text[:2000]
+
+
+@pytest.mark.parametrize("tokens,first,count", [
+    (64, 8, 4),  # 256 rows, one rung: two 128-row tiles
+    (288, 4, 2),  # two rungs, 256 / 1152, the first taken
+])
+def test_pallas_grouped_products_equal_ragged_dot(cfg, tokens, first, count):
     """The megablox kernels (interpret mode here) against ``lax.ragged_dot``:
     the result and every gradient."""
-    a = _layer_inputs(cfg, seed=5, tokens=64)  # 256 rows: two 128-row tiles
+    a = _layer_inputs(cfg, seed=5, tokens=tokens)
 
     def loss(impl, x, w1, w3, w2):
         b = dict(a, x=x, **{"experts.w1": w1, "experts.w3": w3, "experts.w2": w2})
-        y = _program_layer(cfg, b, 8, 4, impl)[0]
-        return (y * y).sum()
+        y, route, _ = _program_layer(cfg, b, first, count, impl)
+        return (y * y).sum(), route.buffer_rows
 
     args = (a["x"], a["experts.w1"], a["experts.w3"], a["experts.w2"])
-    want = jax.value_and_grad(lambda *p: loss("ragged_dot", *p), (0, 1, 2, 3))(*args)
-    got = jax.value_and_grad(lambda *p: loss("gmm", *p), (0, 1, 2, 3))(*args)
-    _close(got[0], want[0])
-    for g, w in zip(got[1], want[1]):
+    both = [
+        jax.value_and_grad(lambda *p: loss(impl, *p), (0, 1, 2, 3), has_aux=True)(*args)
+        for impl in ("ragged_dot", "gmm")
+    ]
+    ((want, rows), want_grads), ((got, _), got_grads) = both
+    assert int(rows) == (256 if count == 4 else LADDER[0])
+    _close(got, want)
+    for g, w in zip(got_grads, want_grads):
         _close(g, w)
         assert float(jnp.abs(w).max()) > 0
 
@@ -257,10 +403,12 @@ def test_logits_match_the_reference(cfg):
     out = runner.build_model(cfg).apply(
         runner.to_program_tree(leaves, bias, cfg), tokens
     )
-    logits, aux, dropped, rows = out
+    logits, aux, dropped, rows, buffers = out
     _close(logits, ref.logits(leaves, bias, tokens, cfg))
     assert float(aux) == 0.0 and float(dropped) == 0.0
     assert rows.shape == (2, 4) and logits.dtype == jnp.float32
+    # 64 tokens x 4 choices: one rung, and each expert layer took it
+    assert buffers.tolist() == [256.0, 256.0]
 
 
 def test_selections_match_the_reference(cfg):
@@ -299,6 +447,7 @@ def test_three_steps_through_moe_trainer_match_the_reference(cfg):
     tokens = x.size
     assert m.expert_rows.shape == (2, 4)
     assert (m.expert_rows.sum(axis=1) <= tokens * cfg["num_experts_per_tok"]).all()
+    assert m.buffer_rows.tolist() == [tokens * cfg["num_experts_per_tok"]] * 2
 
     for b in batches[1:]:
         trainer.train_step(*b)
@@ -338,6 +487,8 @@ def test_moe_trainer_inits_a_handed_in_model_jitted_and_refuses_an_expert_axis(c
     assert last.loss < first.loss and last.contributors == 2.0
     # rows are summed over the replicas: 4 rows of 32 tokens, 4 choices each
     assert last.expert_rows.sum(axis=1).max() <= 4 * 32 * 4
+    # each replica's 64 tokens x 4 choices are one 256-row rung; two replicas
+    assert last.buffer_rows.tolist() == [512.0, 512.0]
     if len(jax.devices()) >= 4:
         ep_mesh = jax.make_mesh((2, 2), ("data", "expert"), devices=jax.devices()[:4])
         with pytest.raises(ValueError, match="no exchange"):
