@@ -543,6 +543,57 @@ def validate_trainer_compress(
     return compress
 
 
+def synced_value_and_grad(
+    loss_fn,
+    params,
+    specs,
+    axis_names: Axes,
+    v,
+    *,
+    compress: str | None,
+    overlap: bool,
+    has_aux: bool = False,
+):
+    """``value_and_grad`` of a sharded-param trainer's loss, with the
+    gradient summed over the contributing replicas: the ONE place that
+    chooses how (``train/long_context.py``, ``moe.py``, ``pipeline.py``).
+
+    ``loss_fn(params)`` is this device's UNMASKED loss term, already over
+    the masked denominator; ``v`` is the device's contributor mask (0/1).
+
+    - ``overlap``: per-leaf in-backward collectives
+      (:func:`overlap_value_and_grad`, SURVEY.md §8.4): each leaf's sync
+      masks its cotangent itself, at half width under ``compress="bf16"``.
+    - otherwise the loss is multiplied by ``v`` and the gradient rides ONE
+      explicit grouped collective per sharding class
+      (:func:`compressed_value_and_grad`) at ``wire_dtype=compress`` — also
+      when ``compress`` is None: shard_map's automatic transpose-psum for
+      replicated params DOES NOT RUN under ``check_vma=False`` (the
+      flash-relax configs), so relying on it would silently leave every
+      device with its LOCAL gradient — found by the runtime replica assert
+      (tests/test_vma_replication.py), VERDICT r4 #6.
+
+    Either way the loss value comes back LOCAL and masked (callers psum it,
+    or the statistics ``has_aux`` carries, with the weighting their metrics
+    need); counts and denominators stay f32.
+    """
+    validate_trainer_compress(compress, overlap=overlap)
+
+    def mask(out):
+        return (out[0] * v, out[1]) if has_aux else out * v
+
+    if overlap:
+        out, grads = overlap_value_and_grad(
+            loss_fn, params, specs, axis_names, v, has_aux=has_aux,
+            wire_dtype=jnp.bfloat16 if compress == "bf16" else None,
+        )
+        return mask(out), grads
+    return compressed_value_and_grad(
+        lambda p: mask(loss_fn(p)), params, specs, axis_names,
+        has_aux=has_aux, wire_dtype=compress,
+    )
+
+
 def expand_counts(
     count: jax.Array, data_size: int, bucket_size: int | None
 ) -> jax.Array:

@@ -45,6 +45,7 @@ from jax.sharding import PartitionSpec as P
 
 from akka_allreduce_tpu.models.transformer import Block
 from akka_allreduce_tpu.train.pipeline import _LMHead
+from akka_allreduce_tpu.train.sharded_lm import step_check_vma
 from akka_allreduce_tpu.train.trainer import (
     TrainStepMetrics,
     normalize_valid,
@@ -572,18 +573,11 @@ class FSDPLMTrainer:
             return new_params, new_opt, loss_avg, contributors
 
         data_spec = batch_spec
-        from akka_allreduce_tpu.ops.local_attention import flash_vma_relax
-
         # with sp == 1 (or Ulysses) the blocks run FULL local attention, so
-        # the flash kernel can dispatch; its outputs carry no varying-axes
-        # annotation (same check_vma gate as LongContext/MoE/Pipeline)
-        # int8's ring ppermute loop erases varying-axes typing (the same
-        # relaxation every int8 trainer path needs)
-        self._check_vma = (
-            not flash_vma_relax(
-                seq_len, d_model // n_heads, sp=self.sp, seq_impl=seq_impl
-            )
-            and compress != "int8"
+        # the flash kernel can dispatch
+        self._check_vma = step_check_vma(
+            seq_len=seq_len, head_dim=d_model // n_heads, sp=self.sp,
+            seq_impl=seq_impl, compress=compress,
         )
         self._step = jax.jit(
             jax.shard_map(

@@ -15,26 +15,26 @@ Composes the framework's two pillars in one jitted SPMD step:
 The reference itself has neither sequence parallelism nor transformers
 (SURVEY.md §6); this is the TPU rebuild's long-context layer.
 
-Gradient collective: differentiating the v-weighted *local token-loss sum*
-w.r.t. REPLICATED params makes shard_map autodiff insert the cross-device psum
-over both mesh axes itself (the transpose of the params broadcast), so
-``sum_d(v_row(d) * g_d)`` arrives in one fused collective; dividing by
-``psum(v * local_token_count)`` yields the exact masked per-token-average
-gradient. Same trick as train/trainer.py's unbucketed path.
+The step, the gradient collective and the host loop are
+``train/sharded_lm.py``'s, shared with ``MoETrainer``; this file is what a
+dense DP x SP x TP run adds to them.
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Iterable, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
-from jax import lax
-from jax.sharding import Mesh, NamedSharding
+from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
+
+from akka_allreduce_tpu.binder.api import flatten_pytree
+from akka_allreduce_tpu.comm.allreduce import validate_trainer_compress
+from akka_allreduce_tpu.train.checkpoint import place_on, state_shardings
+from akka_allreduce_tpu.train.sharded_lm import ShardedLMTrainer, step_check_vma
 
 
 @dataclasses.dataclass
@@ -44,7 +44,7 @@ class LongContextStepMetrics:
     contributors: float  # contributing DP replica rows
 
 
-class LongContextTrainer:
+class LongContextTrainer(ShardedLMTrainer):
     """DP x SP (x TP) trainer for a :class:`~akka_allreduce_tpu.models.TransformerLM`.
 
     Args:
@@ -61,6 +61,9 @@ class LongContextTrainer:
       seq_len: GLOBAL sequence length (divisible by the seq axis size).
       seq_impl: "ring" or "ulysses".
     """
+
+    metrics_cls = LongContextStepMetrics
+    _mean_names = ("loss",)
 
     def __init__(
         self,
@@ -86,8 +89,6 @@ class LongContextTrainer:
             TransformerLM,
             tp_param_specs,
         )
-
-        from akka_allreduce_tpu.comm.allreduce import validate_trainer_compress
 
         self.compress = validate_trainer_compress(compress, overlap=overlap)
         self.overlap = overlap
@@ -127,7 +128,7 @@ class LongContextTrainer:
         self.tx = optimizer or optax.adam(learning_rate)
 
         # init runs the module in single-device (dense, tp=1) form: FULL param
-        # shapes. Under TP the shard_map in_specs below slice each leaf to the
+        # shapes. Under TP the shard_map in_specs slice each leaf to the
         # local geometry the tp_size>1 module declares.
         init_model = cls(
             vocab=vocab,
@@ -139,209 +140,31 @@ class LongContextTrainer:
         )
         tokens0 = jnp.zeros((1, seq_len // self.sp), jnp.int32)
         self.params = init_model.init(jax.random.PRNGKey(seed), tokens0)
-        self.opt_state = self.tx.init(self.params)
-        if self.tp > 1:
-            assert self.model_axis is not None
-            self._param_specs = tp_param_specs(self.params, self.model_axis)
-            self._opt_specs = tp_param_specs(self.opt_state, self.model_axis)
-        else:
-            self._param_specs = jax.tree.map(lambda _: P(), self.params)
-            self._opt_specs = jax.tree.map(lambda _: P(), self.opt_state)
-        # place state on its shardings NOW: every step can then donate the
-        # buffers in place instead of resharding (and warning) on first use
-        is_spec = lambda x: isinstance(x, P)  # noqa: E731
-        self.params = jax.device_put(
-            self.params,
-            jax.tree.map(
-                lambda s: NamedSharding(mesh, s), self._param_specs,
-                is_leaf=is_spec,
-            ),
+        self._place_state(
+            (lambda tree: tp_param_specs(tree, self.model_axis))
+            if self.tp > 1 else None
         )
-        self.opt_state = jax.device_put(
-            self.opt_state,
-            jax.tree.map(
-                lambda s: NamedSharding(mesh, s), self._opt_specs,
-                is_leaf=is_spec,
-            ),
-        )
-        self.param_count = int(
-            sum(np.prod(p.shape) for p in jax.tree.leaves(self.params))
-        )
-        self.step_num = 0
-
-        data_spec = P(self.data_axis, self.seq_axis)
-        self._data_sharding = NamedSharding(mesh, data_spec)
-        self._valid_sharding = NamedSharding(mesh, P(self.data_axis))
-        axis_names = tuple(mesh.axis_names)
-        data_axis = self.data_axis
-        seq_axis = self.seq_axis
-        vary_axes = tuple(n for n in axis_names if n != data_axis)
         model_apply = self.model.apply
-        tx = self.tx
-        param_specs = self._param_specs
-        wire_dtype = jnp.bfloat16 if compress == "bf16" else None
 
-        def step(params, opt_state, x, y, valid):
-            # The mask arrives sharded on `data` only; mark it varying on the
-            # other axes too so the all-axes psums below are well-typed (the
-            # contributor count keeps the data-only form so its psum over
-            # `data` is provably replicated). Under TP every model shard of a
-            # (data, seq) coordinate computes the identical loss term, so the
-            # all-axes denominator carries the same tp-fold factor as the
-            # all-axes loss/grad sums — the ratio (and the per-leaf psum
-            # transposes) come out exactly right at any tp.
-            v0 = valid.reshape(())
-            v = v0
-            for ax in vary_axes:
-                v = lax.pcast(v, ax, to="varying")
-            tokens_local = jnp.float32(x.shape[0] * x.shape[1])
-            denom = jnp.maximum(
-                lax.psum(v * tokens_local, axis_names), 1.0
-            )
+        def local_loss(p, x, y, tokens_local):
+            logits = model_apply(p, x)  # (B_local, T_local, vocab)
+            ce = optax.softmax_cross_entropy_with_integer_labels(
+                logits, y
+            ).sum()
+            return ce, ((ce,), ())
 
-            def masked_loss_sum(p):
-                logits = model_apply(p, x)  # (B_local, T_local, vocab)
-                ce = optax.softmax_cross_entropy_with_integer_labels(logits, y)
-                return ce.sum() * v / denom
-
-            if overlap:
-                # per-leaf in-backward collectives (comm/compute overlap,
-                # SURVEY.md §8.4): the loss is UNMASKED — each leaf's sync
-                # masks its cotangent itself (sum_d v_d g_d) — so v is
-                # folded back into the metric here
-                from akka_allreduce_tpu.comm.allreduce import (
-                    overlap_value_and_grad,
-                )
-
-                def unmasked_loss_sum(ps):
-                    logits = model_apply(ps, x)
-                    ce = optax.softmax_cross_entropy_with_integer_labels(
-                        logits, y
-                    )
-                    return ce.sum() / denom
-
-                lval, gavg = overlap_value_and_grad(
-                    unmasked_loss_sum, params, param_specs, axis_names, v,
-                    wire_dtype=wire_dtype,
-                )
-                lval = lval * v
-            elif compress in ("bf16", "int8"):
-                # wire compression needs the explicit collective: one
-                # grouped collective per sharding class — bf16 psum at half
-                # width, or the explicit int8 ring at a quarter — with
-                # counts/denominator staying f32
-                # (comm.allreduce.compressed_value_and_grad)
-                from akka_allreduce_tpu.comm.allreduce import (
-                    compressed_value_and_grad,
-                )
-
-                lval, gavg = compressed_value_and_grad(
-                    masked_loss_sum, params, param_specs, axis_names,
-                    wire_dtype=compress,
-                )
-            else:
-                # EXPLICIT grouped psums even uncompressed: shard_map's
-                # automatic transpose-psum for replicated params DOES NOT
-                # RUN under check_vma=False (the flash-relax configs), so
-                # relying on it would silently leave every device with its
-                # LOCAL gradient — found by the runtime replica assert
-                # (tests/test_vma_replication.py), VERDICT r4 #6
-                from akka_allreduce_tpu.comm.allreduce import (
-                    compressed_value_and_grad,
-                )
-
-                lval, gavg = compressed_value_and_grad(
-                    masked_loss_sum, params, param_specs, axis_names,
-                    wire_dtype=None,
-                )
-            loss_avg = lax.psum(lval, axis_names)  # masked, already /denom
-            contributors = lax.psum(v0, data_axis)
-            updates, new_opt = tx.update(gavg, opt_state, params)
-            new_params = optax.apply_updates(params, updates)
-            return new_params, new_opt, loss_avg, contributors
-
-        # The Pallas flash-attention kernel emits outputs with no varying-
-        # axes annotation, so shard_map's static vma check cannot type it.
-        # Relax the check ONLY when flash can actually dispatch for this
-        # configuration (TPU backend + kernel-friendly shapes on a path that
-        # runs a full local attention: sp==1, or Ulysses' local core);
-        # everywhere else the check stays on — it is the static safety net.
-        from akka_allreduce_tpu.ops.local_attention import flash_vma_relax
-
-        self._check_vma = not overlap and compress != "int8" and not flash_vma_relax(
-            seq_len, d_model // n_heads, sp=self.sp, seq_impl=seq_impl
-        )
-        mapped = jax.shard_map(
-            step,
-            mesh=mesh,
-            in_specs=(
-                self._param_specs,
-                self._opt_specs,
-                data_spec,
-                data_spec,
-                P(self.data_axis),
+        self._build_step(
+            local_loss,
+            batch_spec=P(self.data_axis, self.seq_axis),
+            check_vma=step_check_vma(
+                seq_len=seq_len, head_dim=d_model // n_heads, sp=self.sp,
+                seq_impl=seq_impl, compress=compress, overlap=overlap,
             ),
-            out_specs=(self._param_specs, self._opt_specs, P(), P()),
-            check_vma=self._check_vma,
         )
-        self._step = jax.jit(mapped, donate_argnums=(0, 1))
-        self._raw_step = step  # reused by train_chain's on-device loop
-        self._replicated = NamedSharding(mesh, P())
-        self._chains: dict = {}
-
-    # -- stepping ------------------------------------------------------------
-
-    def _place(self, x, y):
-        from akka_allreduce_tpu.train.trainer import place_tokens
-
-        return place_tokens(
-            x, y, self._data_sharding, seq_len=self.seq_len, dp=self.dp
-        )
-
-    def train_step(
-        self,
-        tokens: np.ndarray,
-        labels: np.ndarray,
-        valid: Sequence[float] | None = None,
-    ) -> LongContextStepMetrics:
-        """One step on a GLOBAL (batch, seq_len) token array.
-
-        ``valid``: per-DP-replica-row contributor mask of shape (dp,);
-        None = all rows contribute.
-        """
-        from akka_allreduce_tpu.train.trainer import (
-            normalize_valid,
-            place_mask,
-        )
-
-        valid_arr = normalize_valid(valid, self.dp)
-        xd, yd = self._place(tokens, labels)
-        vd = place_mask(valid_arr, self._valid_sharding)
-        self.params, self.opt_state, loss, cnt = self._step(
-            self.params, self.opt_state, xd, yd, vd
-        )
-        self.step_num += 1
-        return LongContextStepMetrics(
-            step=self.step_num, loss=float(loss), contributors=float(cnt)
-        )
-
-    def train(self, batches: Iterable) -> list[LongContextStepMetrics]:
-        return [self.train_step(x, y) for x, y in batches]
-
-    def get_flat_params(self) -> np.ndarray:
-        from akka_allreduce_tpu.binder.api import flatten_pytree
-
-        return flatten_pytree(self.params)[0]
 
     def set_flat_params(self, vec: np.ndarray) -> None:
         """Replace params from a flat float32 vector (binder/cluster seam),
         honoring the trainer's sharding layout (replicated or TP specs)."""
-        from akka_allreduce_tpu.binder.api import flatten_pytree
-        from akka_allreduce_tpu.train.checkpoint import (
-            place_on,
-            state_shardings,
-        )
-
         # the tree structure never changes after __init__: build the
         # unflattener once, not one full device_get per sync round
         if getattr(self, "_unflatten", None) is None:
@@ -350,81 +173,3 @@ class LongContextTrainer:
         self.params = place_on(
             self._unflatten(np.asarray(vec, np.float32)), p_sh
         )
-
-    # -- on-device training chain (data-loader path, no host I/O per step) ---
-
-    def _build_chain(self, sampler, steps: int, rows_per_replica: int):
-        raw_step = self._raw_step
-        data_axis, seq_axis = self.data_axis, self.seq_axis
-        t_local = self.seq_len // self.sp
-
-        def chain(params, opt_state, key, valid):
-            # one stream per DP replica ROW: all seq shards of a row fold the
-            # same data-axis coordinate, so they agree on the row's tokens
-            # and each slices its own T_local columns
-            rkey = jax.random.fold_in(key, lax.axis_index(data_axis))
-            s = lax.axis_index(seq_axis)
-
-            def body(carry, i):
-                p, o = carry
-                k = jax.random.fold_in(rkey, i)
-                x_g, y_g = sampler(k, rows_per_replica)
-                x = lax.dynamic_slice_in_dim(x_g, s * t_local, t_local, axis=1)
-                y = lax.dynamic_slice_in_dim(y_g, s * t_local, t_local, axis=1)
-                p, o, loss, cnt = raw_step(p, o, x, y, valid)
-                return (p, o), (loss, cnt)
-
-            (params, opt_state), (losses, cnts) = lax.scan(
-                body, (params, opt_state), jnp.arange(steps)
-            )
-            return params, opt_state, losses, cnts
-
-        mapped = jax.shard_map(
-            chain,
-            mesh=self.mesh,
-            in_specs=(self._param_specs, self._opt_specs, P(), P(data_axis)),
-            out_specs=(self._param_specs, self._opt_specs, P(), P()),
-            check_vma=self._check_vma,  # flash outputs carry no vma (see step)
-        )
-        return jax.jit(mapped, donate_argnums=(0, 1))
-
-    def train_chain(
-        self,
-        sampler,
-        steps: int,
-        rows_per_replica: int,
-        *,
-        valid: Sequence[float] | None = None,
-        seed: int = 0,
-    ) -> list[LongContextStepMetrics]:
-        """Run ``steps`` DP x SP steps entirely on device in ONE dispatch.
-
-        ``sampler`` is a traced ``(key, rows) -> (tokens, labels)`` producing
-        GLOBAL (rows, seq_len) sequences (``SyntheticCopyLM.device_sampler``);
-        each replica row draws its own stream and its seq shards slice their
-        local columns, so nothing crosses the host inside the loop.
-        """
-        from akka_allreduce_tpu.train.trainer import run_chain_cached
-
-        losses, cnts = run_chain_cached(
-            self,
-            sampler,
-            steps,
-            rows_per_replica,
-            lambda: self._build_chain(sampler, steps, rows_per_replica),
-            valid,
-            self.dp,
-            self._valid_sharding,
-            seed,
-        )
-        out = []
-        for loss, cnt in zip(losses, cnts):
-            self.step_num += 1
-            out.append(
-                LongContextStepMetrics(
-                    step=self.step_num,
-                    loss=float(loss),
-                    contributors=float(cnt),
-                )
-            )
-        return out

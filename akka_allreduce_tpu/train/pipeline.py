@@ -59,6 +59,9 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
+from akka_allreduce_tpu.comm.allreduce import synced_value_and_grad
+from akka_allreduce_tpu.train.sharded_lm import step_check_vma
+
 
 @dataclasses.dataclass
 class PipelineStepMetrics:
@@ -267,7 +270,6 @@ class PipelineLMTrainer:
         m_count = microbatches
         tx = self.tx
         param_specs = self._param_specs
-        wire_dtype = jnp.bfloat16 if compress == "bf16" else None
         block_apply = block.apply
         embed_apply = embed.apply
         head_apply = head.apply
@@ -396,54 +398,16 @@ class PipelineLMTrainer:
                 )
                 return ces.sum()
 
-            def masked_loss(p):
+            def loss_fn(p):
                 ce_total = pipeline_ce(p)
-                return ce_total * v / denom, ce_total
+                return ce_total / denom, ce_total
 
-            if overlap:
-                # per-leaf in-backward collectives (SURVEY.md §8.4): the
-                # loss is UNMASKED — each leaf's sync masks its cotangent;
-                # loss_avg below re-applies v explicitly
-                from akka_allreduce_tpu.comm.allreduce import (
-                    overlap_value_and_grad,
-                )
-
-                def unmasked_loss(ps):
-                    ce_total = pipeline_ce(ps)
-                    return ce_total / denom, ce_total
-
-                (_, ce_total), gavg = overlap_value_and_grad(
-                    unmasked_loss, params, param_specs, axis_names, v,
-                    has_aux=True, wire_dtype=wire_dtype,
-                )
-            elif compress in ("bf16", "int8"):
-                # explicit grouped collective (see long_context.py);
-                # trunk leaves (pipe-sharded) reduce over data only,
-                # embed/head over data x pipe; int8 rides the explicit
-                # ring per reduce axis
-                from akka_allreduce_tpu.comm.allreduce import (
-                    compressed_value_and_grad,
-                )
-
-                (_, ce_total), gavg = compressed_value_and_grad(
-                    masked_loss, params, param_specs, axis_names,
-                    has_aux=True,
-                    wire_dtype=compress,
-                )
-            else:
-                # explicit grouped psums even uncompressed: the automatic
-                # transpose-psum for replicated params does not run under
-                # check_vma=False (flash-relax configs) — see
-                # long_context.py / tests/test_vma_replication.py
-                from akka_allreduce_tpu.comm.allreduce import (
-                    compressed_value_and_grad,
-                )
-
-                (_, ce_total), gavg = compressed_value_and_grad(
-                    masked_loss, params, param_specs, axis_names,
-                    has_aux=True,
-                    wire_dtype=None,
-                )
+            # trunk leaves (pipe-sharded) reduce over data only, embed/head
+            # over data x pipe
+            (_, ce_total), gavg = synced_value_and_grad(
+                loss_fn, params, param_specs, axis_names, v,
+                compress=compress, overlap=overlap, has_aux=True,
+            )
             loss_avg = lax.psum(ce_total * v * is_last / denom, axis_names)
             contributors = lax.psum(v0, data_axis)
             new_params, new_opt = apply_update(params, opt_state, gavg)
@@ -738,19 +702,12 @@ class PipelineLMTrainer:
         batch_spec = P(self.data_axis)
         self._data_sharding = NamedSharding(mesh, batch_spec)
         self._valid_sharding = NamedSharding(mesh, P(self.data_axis))
-        from akka_allreduce_tpu.ops.local_attention import flash_vma_relax
-
         # each stage runs FULL-sequence local attention, so the flash
-        # kernel can dispatch at kernel-friendly shapes; its outputs carry
-        # no vma annotation (same gate as LongContext/MoE); the 1f1b
-        # schedule's hand-rolled ppermute plumbing also erases vma (same
-        # caveat as the comm layer's rings — the GPipe-equivalence test is
-        # the oracle)
-        self._check_vma = (
-            not overlap
-            and compress != "int8"
-            and schedule not in ("1f1b", "interleaved")
-            and not flash_vma_relax(seq_len, d_model // n_heads)
+        # kernel can dispatch at kernel-friendly shapes
+        self._check_vma = step_check_vma(
+            seq_len=seq_len, head_dim=d_model // n_heads,
+            compress=compress, overlap=overlap,
+            hand_scheduled=schedule in ("1f1b", "interleaved"),
         )
         step_fns = {
             "gpipe": step,
@@ -768,8 +725,6 @@ class PipelineLMTrainer:
                 P(self.data_axis),
             ),
             out_specs=(self._param_specs, self._opt_specs, P(), P()),
-            # off under overlap (custom_vjp erases vma) or a flash
-            # dispatch (kernel outputs carry none) — see _check_vma above
             check_vma=self._check_vma,
         )
         self._step = jax.jit(mapped, donate_argnums=(0, 1))

@@ -195,7 +195,8 @@ def place_batch(x, y, n_devices: int, data_sharding):
 
 def place_tokens(x, y, data_sharding, *, seq_len: int, dp: int):
     """Token-LM twin of :func:`place_batch` (both arrays int32, batch rows
-    over the data axis, the seq dim over any seq axis in the spec).
+    over the ``dp`` row shards of the spec's first dim, the seq dim over any
+    seq axis in it).
 
     Single-process: ``x``/``y`` are the GLOBAL (batch, seq_len) arrays.
     Pod runtime (the sharding's mesh spans OS processes): each process
@@ -219,7 +220,7 @@ def place_tokens(x, y, data_sharding, *, seq_len: int, dp: int):
         )
     if x.shape[0] % dp:
         raise ValueError(
-            f"global batch {x.shape[0]} not divisible by dp={dp}"
+            f"global batch {x.shape[0]} not divisible by its {dp} row shards"
         )
     return (
         jax.device_put(np.asarray(x, np.int32), data_sharding),

@@ -20,6 +20,7 @@ from __future__ import annotations
 import re
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
@@ -215,3 +216,150 @@ class TestPipelineCompress:
             t._step, t.params, t.opt_state, xd, yd, vd
         )
         assert n_bf16 >= 2, (n_bf16, n_total)  # embed/head + trunk groups
+
+
+class TestSyncedValueAndGrad:
+    """``comm.allreduce.synced_value_and_grad`` owns the choice of sync for
+    the three sharded-param trainers: in every mode it must hand back the
+    gradient of the masked mean, with a leaf sharded over ``model`` summed
+    over ``data`` only — the oracle is plain ``jax.grad`` on whole arrays."""
+
+    @staticmethod
+    def _local(w, u_m, x_d):
+        return jnp.sum(jnp.tanh(x_d @ w) * (x_d @ u_m))
+
+    @pytest.mark.parametrize(
+        "compress,overlap,tol",
+        [
+            (None, False, 1e-5),
+            ("bf16", False, 2e-2),
+            ("int8", False, 5e-2),
+            (None, True, 1e-5),
+            ("bf16", True, 2e-2),
+        ],
+    )
+    def test_gradient_of_the_masked_mean(self, compress, overlap, tol):
+        from jax import lax
+        from jax.sharding import NamedSharding
+        from jax.sharding import PartitionSpec as P
+
+        from akka_allreduce_tpu.comm.allreduce import synced_value_and_grad
+
+        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        axes = ("data", "model")
+        rng = np.random.default_rng(0)
+        params = {
+            "w": rng.normal(size=(8,)).astype(np.float32),
+            "u": rng.normal(size=(2, 8)).astype(np.float32),
+        }
+        specs = {"w": P(), "u": P("model")}
+        x = rng.normal(size=(4, 5, 8)).astype(np.float32)
+        valid = np.array([1.0, 1.0, 0.0, 1.0], np.float32)
+        local = self._local
+
+        def oracle(p):
+            total = sum(
+                valid[d] * local(p["w"], p["u"][m], x[d])
+                for d in range(4) for m in range(2)
+            )
+            return total / (valid.sum() * 2 * 5)
+
+        want_loss, want = jax.value_and_grad(oracle)(params)
+
+        def body(p, x_d, v_d):
+            v = lax.pcast(v_d.reshape(()), "model", to="varying")
+            denom = jnp.maximum(lax.psum(v * 5.0, axes), 1.0)
+            val, grads = synced_value_and_grad(
+                lambda q: local(q["w"], q["u"][0], x_d[0]) / denom,
+                p, specs, axes, v, compress=compress, overlap=overlap,
+            )
+            return lax.psum(val, axes), grads
+
+        loss, got = jax.jit(
+            jax.shard_map(
+                body, mesh=mesh, in_specs=(specs, P("data"), P("data")),
+                out_specs=(P(), specs),
+                check_vma=not overlap and compress != "int8",
+            )
+        )(
+            jax.device_put(
+                params,
+                jax.tree.map(
+                    lambda s: NamedSharding(mesh, s), specs,
+                    is_leaf=lambda s: isinstance(s, P),
+                ),
+            ),
+            x, valid,
+        )
+        np.testing.assert_allclose(loss, want_loss, rtol=1e-5)
+        for name in params:
+            scale = np.abs(want[name]).max()
+            np.testing.assert_allclose(
+                got[name], want[name], atol=tol * scale, err_msg=name
+            )
+
+    def test_int8_with_overlap_is_refused(self):
+        from akka_allreduce_tpu.comm.allreduce import synced_value_and_grad
+
+        with pytest.raises(ValueError, match="overlap excludes compress='int8'"):
+            synced_value_and_grad(
+                lambda p: p, 1.0, None, ("data",), 1.0,
+                compress="int8", overlap=True,
+            )
+
+
+class _DenseAsMoE:
+    """A dense ``TransformerLM`` under the signature ``MoETrainer(model=)``
+    takes: no auxiliary loss, nothing dropped, no rows routed."""
+
+    def __init__(self, **kw):
+        from akka_allreduce_tpu.models.transformer import TransformerLM
+
+        self._apply = TransformerLM(**kw).apply
+
+    def apply(self, variables, tokens):
+        zero = jnp.float32(0.0)
+        return (
+            self._apply(variables, tokens), zero, zero,
+            jnp.zeros((1, 2), jnp.float32), jnp.zeros((1,), jnp.float32),
+        )
+
+
+@pytest.mark.parametrize(
+    "sync,tol",
+    [
+        (dict(compress=None), 1e-5),
+        (dict(compress="bf16"), 1e-3),
+        (dict(overlap=True), 1e-5),
+    ],
+    ids=["f32", "bf16", "overlap"],
+)
+def test_long_context_and_moe_trainers_are_one_step(lm_batches, sync, tol):
+    """The two trainers the benchmark runs share ``train/sharded_lm.py``:
+    the same dense weights through ``LongContextTrainer`` on (data=4, seq=1)
+    and through ``MoETrainer(model=)`` on (data=4,) must take the same two
+    steps, the second with one replica masked — the guard against the two
+    drifting apart again: their ``local_loss`` closures, spec trees and axis
+    wiring. It CANNOT see a fault in the shared skeleton (mask, denominator,
+    sync, update), which puts both sides wrong alike: those are held by
+    ``TestSyncedValueAndGrad`` and by each trainer's own tests against plain
+    ``jax.grad`` (test_ring_attention, test_tensor_parallel, test_moe)."""
+    from akka_allreduce_tpu.parallel import data_seq_mesh
+
+    size = dict(vocab=16, d_model=32, n_heads=4, n_layers=1)
+    kw = dict(seq_len=SEQ, optimizer=optax.sgd(1e-1), **sync)
+    dense = LongContextTrainer(data_seq_mesh(4, 1), **size, **kw)
+    as_moe = MoETrainer(
+        jax.make_mesh((4,), ("data",)), model=_DenseAsMoE(**size),
+        params=jax.tree.map(np.array, dense.params), vocab=16, **kw,
+    )
+    for (x, y), valid in zip(lm_batches, (None, [1.0, 1.0, 1.0, 0.0])):
+        m0 = dense.train_step(x, y, valid)
+        m1 = as_moe.train_step(x, y, valid)
+        assert m0.contributors == m1.contributors == (4.0 if valid is None else 3.0)
+        assert abs(m0.loss - m1.loss) < tol * abs(m0.loss)
+        assert m1.aux_loss == m1.dropped == 0.0
+        assert not m1.expert_rows.any() and not m1.buffer_rows.any()
+    np.testing.assert_allclose(
+        as_moe.get_flat_params(), dense.get_flat_params(), rtol=tol, atol=tol * 0.1
+    )

@@ -116,6 +116,19 @@ class TestMoEDtypes:
         assert all(np.isfinite(h.loss) for h in hist)
         assert hist[-1].step == 4
 
+    def test_train_moe_cli_device_data_runs_the_chain(self, capsys):
+        # README's `train-moe --ep 2 --dispatch scatter --device-data`: the
+        # one caller of train_chain(rows_per_device=) outside the tests
+        from akka_allreduce_tpu.__main__ import main
+
+        rc = main([
+            "train-moe", "--dp", "2", "--ep", "2", "--dispatch", "scatter",
+            "--device-data", "--steps", "2", "--batch", "8", "--seq-len", "32",
+            "--vocab", "16", "--d-model", "32", "--layers", "1",
+        ])
+        out = capsys.readouterr().out
+        assert rc == 0 and "on-device" in out
+
 
 class TestTop2Routing:
     """GShard-style top-2: tokens mix their two best experts with
