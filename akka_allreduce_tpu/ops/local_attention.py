@@ -12,13 +12,17 @@ was HBM-bound on score traffic, not FLOPs. Two fixes, dispatched by
   ``jax.checkpoint`` on the block step so autodiff RECOMPUTES block scores in
   the backward pass instead of saving them — O(T·block) live memory for
   forward+backward instead of O(T^2).
-- the Pallas TPU flash-attention kernel (``jax.experimental.pallas.ops``) when
-  running on a real TPU backend and the shape fits its tiling — the fused
-  MXU kernel, used for both forward and backward via its custom VJP.
+- the library's Pallas TPU splash-attention kernel
+  (``jax.experimental.pallas.ops.tpu.splash_attention``) when running on a
+  real TPU backend and the shape fits its tiling (:func:`_splash_attention`):
+  the mask is an object, so only the blocks the causal diagonal cuts pay for
+  masking and the blocks it empties are skipped; the backward is ONE fused
+  pass (``dq``, ``dk``, ``dv`` from one recomputation of the scores); K/V go
+  in at their own head count and ``dk``/``dv`` come back at it.
 
-Measured on v5e: the 4-layer LM step (B=8, H=8, T=2048, D=32) went from
-786 ms/step dense to 85 ms/step on the flash path with bf16 activations
-(BENCHMARKS.md).
+What the kernel costs is measured in the benchmark's training cells
+(``attn_kernel_ms``, PERF.md section 5); the block-size sweep behind
+:func:`_splash_blocks` is in CHANGES.md, PR 31.
 
 Convention for a query row with NO visible keys (fully-causal-masked or
 all-padding window): the output row is zero — masked positions contribute
@@ -28,6 +32,7 @@ would fall back to a uniform average of whatever it was given.
 
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
@@ -37,6 +42,7 @@ from jax import lax
 from akka_allreduce_tpu.ops.ring_attention import (
     attention_reference,
     online_softmax_update,
+    repeat_kv,
 )
 
 # dense is fine (and fastest) below this sequence length: the score block
@@ -153,11 +159,12 @@ def blockwise_attention(
 
 
 def flash_shapes_ok(t: int, d: int) -> bool:
-    """Would the Pallas TPU flash kernel accept (T=t, head_dim=d)?
+    """Would the Pallas TPU splash kernel take (T=t, head_dim=d)?
 
-    Conservative static gate (the kernel tiles T in 128-row blocks); also the
-    question trainers ask to decide whether shard_map's vma check must be
-    relaxed (the kernel's outputs carry no varying-axes annotation).
+    Conservative static gate (:func:`_splash_blocks` hands out blocks of 512
+    and up, and every block must divide T); also the question trainers ask to
+    decide whether shard_map's vma check must be relaxed (the kernel's
+    outputs carry no varying-axes annotation).
     """
     return t > _DENSE_MAX_T and t % 512 == 0 and d % 32 == 0
 
@@ -165,7 +172,7 @@ def flash_shapes_ok(t: int, d: int) -> bool:
 def flash_vma_relax(
     seq_len: int, head_dim: int, *, sp: int = 1, seq_impl: str = "ring"
 ) -> bool:
-    """True when the Pallas flash kernel CAN dispatch inside a trainer's
+    """True when the Pallas splash kernel CAN dispatch inside a trainer's
     step for this attention configuration on this backend. shard_map
     callers must then set ``check_vma=False``: the kernel's outputs carry
     no varying-axes annotation, so the static replication checker cannot
@@ -173,8 +180,8 @@ def flash_vma_relax(
 
     A FULL single-device attention runs at the whole ``seq_len`` when the
     sequence is unsharded (``sp == 1``) or under Ulysses (the all-to-all
-    reassembles full T locally); ring attention never runs one, so flash
-    never dispatches there.
+    reassembles full T locally); ring attention never runs one, so the
+    kernel never dispatches there.
     """
     local_t = seq_len if (sp == 1 or seq_impl == "ulysses") else 0
     return (
@@ -185,7 +192,7 @@ def flash_vma_relax(
 
 
 def _flash_ok(q: jax.Array, k: jax.Array, q_offset, k_offset) -> bool:
-    """Shape/placement gate for the Pallas TPU flash kernel."""
+    """Shape/placement gate for the Pallas TPU splash kernel."""
     from akka_allreduce_tpu.ops._platform import interpret_default
 
     if interpret_default(q, k):
@@ -196,6 +203,75 @@ def _flash_ok(q: jax.Array, k: jax.Array, q_offset, k_offset) -> bool:
         return False
     b, tq, h, d = q.shape
     return tq == k.shape[1] and flash_shapes_ok(tq, d)
+
+
+def _splash_blocks(t: int, d: int):
+    """The splash kernel's tiles for (T=t, head_dim=d), read from a sweep of
+    {512, 1024, 2048} (compute blocks also 256) on a v5e at the benchmark's
+    two attention shapes, (T 4096, head 128, 24 heads on 2) and (T 8192,
+    head 64, 32 on 8) — the table is in CHANGES.md, PR 31. The same tiles won
+    at both: 1024 x 1024 everywhere, the forward's scores computed 512 K/V
+    rows at a time. Larger K/V blocks run more of the diagonal's masked half
+    (and 2048 x 2048 no longer fits VMEM); smaller ones re-read q and, in the
+    fused backward, write one more bf16 partial ``dq`` of q's size per K/V
+    block for XLA to sum. Head 64 against 128 did not move the choice; past
+    128 (not measured) f32 inputs at 1024 overrun VMEM in the described-v5e
+    compile, so those heads keep 512, which :func:`flash_shapes_ok`
+    guarantees divides T."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import BlockSizes
+
+    b = 1024 if t % 1024 == 0 and d <= 128 else 512
+    return BlockSizes(
+        block_q=b, block_kv=b, block_kv_compute=512,
+        block_q_dkv=b, block_kv_dkv=b, block_kv_dkv_compute=b,
+        use_fused_bwd_kernel=True,
+    )
+
+
+@functools.lru_cache(maxsize=None)
+def _splash_kernel(t: int, h: int, causal: bool, blocks, interpret: bool):
+    """The library's kernel object for one shape, built once: the mask's
+    block tables are host numpy work (0.3-0.6 s at the benchmark's shapes),
+    and a step traces this call once per attention layer."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        CausalMask,
+        FullMask,
+        MultiHeadMask,
+        make_splash_mha,
+    )
+
+    mask = (CausalMask if causal else FullMask)((t, t))
+    # the tables become device arrays inside the library; built under a
+    # trace they would be that trace's tracers, and the cache would leak them
+    with jax.ensure_compile_time_eval():
+        return make_splash_mha(
+            MultiHeadMask([mask] * h), head_shards=1, q_seq_shards=1,
+            block_sizes=blocks, interpret=interpret,
+        )
+
+
+def _splash_attention(
+    q: jax.Array,
+    k: jax.Array,
+    v: jax.Array,
+    *,
+    causal: bool,
+    scale: float,
+    interpret: bool = False,
+) -> jax.Array:
+    """:func:`local_attention`'s kernel branch: ``q`` (B, T, H, D) against
+    COMPACT ``k``/``v`` (B, T, H_kv, D), H_kv dividing H — the kernel reads
+    each K/V head for its whole query group and accumulates ``dk``/``dv``
+    over the group itself. It has no scale argument, so the scale is folded
+    into ``q`` (exact at head size 64, one more rounding of ``q`` in its own
+    dtype otherwise). ``interpret`` is for the CPU test of these numbers."""
+    _, t, h, d = q.shape
+    kernel = _splash_kernel(t, h, causal, _splash_blocks(t, d), interpret)
+    heads_first = lambda x: x.transpose(0, 2, 1, 3)  # noqa: E731
+    out = jax.vmap(kernel)(
+        heads_first(q * scale), heads_first(k), heads_first(v)
+    )
+    return heads_first(out)
 
 
 def _scaled_masked_scores(q, k, k_scale, scale, q_offset, k_offset):
@@ -354,53 +430,25 @@ def local_attention(
     """Best single-device attention for the shape/backend at hand.
 
     Dispatch: dense for short sequences (fastest, fits on chip), the Pallas
-    TPU flash kernel when on TPU with kernel-friendly shapes, else the
+    TPU splash kernel when on TPU with kernel-friendly shapes, else the
     portable blockwise path. All three agree with the dense oracle.
 
-    Grouped-query K/V (fewer heads than ``q``) expand here — the compute
-    site; sequence-parallel wires upstream keep the compact form.
+    Grouped-query K/V (fewer heads than ``q``) go into the kernel compact;
+    the dense and blockwise cores expand them at their score matmul.
     """
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(q.shape[-1])
-    if k.shape[2] != q.shape[2]:
-        from akka_allreduce_tpu.ops.ring_attention import repeat_kv
-
-        k, v = repeat_kv(k, q.shape[2]), repeat_kv(v, q.shape[2])
     # dense is gated on the SCORE MATRIX size, not the raw lengths: a
     # short query block over a long K/V (the decode-over-cache shape,
     # Tq=1) has a tiny (B, H, Tq, Tk) score tensor, and the blockwise
     # scan would be pure launch overhead for it
     if q.shape[1] * k.shape[1] <= _DENSE_MAX_T * _DENSE_MAX_T:
+        h = q.shape[2]  # repeat_kv is the identity at equal head counts
         return attention_reference(
-            q, k, v, causal=causal, sm_scale=scale,
-            q_offset=q_offset, k_offset=k_offset,
+            q, repeat_kv(k, h), repeat_kv(v, h), causal=causal,
+            sm_scale=scale, q_offset=q_offset, k_offset=k_offset,
         )
     if _flash_ok(q, k, q_offset, k_offset):
-        from jax.experimental.pallas.ops.tpu.flash_attention import (
-            BlockSizes,
-            flash_attention,
-        )
-
-        # The kernel's DEFAULT 128-row tiling runs ~10 TF/s on v5e at the
-        # flagship shape (B8 H16 T2048 D128) — each tiny grid step re-reads
-        # its K/V slabs from HBM. 512x512 blocks hit 191 TF/s (measured
-        # sweep, BENCHMARKS.md "attention kernel tuning": 128->39.8ms,
-        # 256->14.2, 512->2.15, 1024->6.3 per fwd+bwd layer), i.e. the MXU
-        # matmul plateau. flash_shapes_ok guarantees T % 512 == 0.
-        b = 512
-        bs = BlockSizes(
-            block_q=b, block_k_major=b, block_k=b, block_b=1,
-            block_q_major_dkv=b, block_k_major_dkv=b, block_k_dkv=b,
-            block_q_dkv=b, block_k_major_dq=b, block_k_dq=b, block_q_dq=b,
-        )
-        out = flash_attention(
-            q.transpose(0, 2, 1, 3),  # (B, H, T, D) kernel layout
-            k.transpose(0, 2, 1, 3),
-            v.transpose(0, 2, 1, 3),
-            causal=causal,
-            sm_scale=scale,
-            block_sizes=bs,
-        )
-        return out.transpose(0, 2, 1, 3).astype(q.dtype)
+        return _splash_attention(q, k, v, causal=causal, scale=scale)
     return blockwise_attention(
         q, k, v, causal=causal, sm_scale=scale,
         q_offset=q_offset, k_offset=k_offset,

@@ -17,7 +17,9 @@ it HERE, in the test, not through an option of the program.
 
 from __future__ import annotations
 
+import json
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs to /tmp
 
@@ -29,6 +31,7 @@ from jax.sharding import NamedSharding, SingleDeviceSharding
 from jax.sharding import PartitionSpec as P
 
 HBM_BYTES = 16e9  # one v5e chip
+BENCH = os.path.join(os.path.dirname(os.path.dirname(__file__)), "benchmarks")
 
 FLOATS_PER_DEVICE = 64 * 1024 * 1024  # BASELINE config 2, chip_smoke's size
 
@@ -94,19 +97,51 @@ def _pallas_ring(compress):
     return build
 
 
-def _flash_attention(topo, monkeypatch):
-    """``local_attention``'s kernel branch at B8 H16 T2048 D128, fwd+bwd."""
-    from akka_allreduce_tpu.ops.local_attention import local_attention
+def _assert_splash_takes_compact_kv(text, b, t, h, h_kv, d, layers=1):
+    """The attention kernels in a compiled text are the library's splash
+    kernels, one forward and one fused backward a layer, and each takes K and
+    V at ``h_kv`` heads (the operand constraints of its custom call)."""
+    calls = [
+        line for line in text.splitlines()
+        if "tpu_custom_call" in line and re.search(r"%splash_m[hq]a_[\w.]+ = ", line)
+    ]
+    assert len(calls) == 2 * layers, [c[:60] for c in calls]
+    assert "flash_attention" not in text and "flash_mha" not in text
+    # (B, heads, T, D) operands; XLA drops a batch of one
+    kv, q = (rf"bf16\[(?:{b},)?{n},{t},{d}\]" for n in (h_kv, h))
+    for line in calls:
+        operands = line.split("operand_layout_constraints=", 1)[1]
+        assert len(re.findall(kv, operands)) == 2, line[:200]  # K and V, compact
+        assert len(re.findall(q, operands)) in (1, 2), line[:200]  # q; do backward
 
-    one = SingleDeviceSharding(topo.devices[0])
-    qkv = jax.ShapeDtypeStruct((8, 2048, 16, 128), jnp.bfloat16, sharding=one)
 
-    def loss(q, k, v):
-        out = local_attention(q, k, v, causal=True)
-        return out.astype(jnp.float32).sum()
+def _kernel_attention(b, t, h, h_kv, d):
+    """``local_attention``'s kernel branch, forward and backward, with K/V at
+    their own head count."""
 
-    grad = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
-    return grad.lower(qkv, qkv, qkv).compile(), 3  # fwd, dkv, dq kernels
+    def build(topo, monkeypatch):
+        from akka_allreduce_tpu.ops.local_attention import local_attention
+
+        one = SingleDeviceSharding(topo.devices[0])
+        q = jax.ShapeDtypeStruct((b, t, h, d), jnp.bfloat16, sharding=one)
+        kv = jax.ShapeDtypeStruct((b, t, h_kv, d), jnp.bfloat16, sharding=one)
+
+        def loss(q, k, v):
+            out = local_attention(q, k, v, causal=True)
+            return out.astype(jnp.float32).sum()
+
+        grad = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
+        compiled = grad.lower(q, kv, kv).compile()
+        if h_kv != h:
+            _assert_splash_takes_compact_kv(
+                compiled.as_text(), b, t, h, h_kv, d
+            )
+            assert [g.shape for g in compiled.out_info] == [
+                q.shape, kv.shape, kv.shape
+            ]
+        return compiled, 2  # forward, and the fused backward
+
+    return build
 
 
 def _grouped_psum(topo, monkeypatch):
@@ -157,45 +192,85 @@ class _ShapeOnlyLM:
         return jax.eval_shape(self._model.init, *args)
 
 
-def _lm_step(topo, monkeypatch):
-    """The flagship ``LongContextTrainer`` step (d2048 x 8L x seq2048 x B8,
-    bf16, no remat) on one described chip."""
-    from akka_allreduce_tpu.parallel import data_seq_mesh
-    from akka_allreduce_tpu.train import LongContextTrainer
+def _bench_json(*parts):
+    with open(os.path.join(BENCH, *parts), encoding="utf-8") as f:
+        return json.load(f)
 
-    # the trainer places its state with device_put; shapes stay where they are
-    monkeypatch.setattr(jax, "device_put", lambda x, *a, **k: x)
-    adam = optax.adam(3e-3)
-    mesh = data_seq_mesh(1, 1, devices=topo.devices[:1])
-    t = LongContextTrainer(
-        mesh, model_cls=_ShapeOnlyLM, vocab=256, d_model=2048, n_heads=16,
-        n_layers=8, seq_len=2048, compute_dtype=jnp.bfloat16,
-        optimizer=optax.GradientTransformation(
-            lambda p: jax.eval_shape(adam.init, p), adam.update
-        ),
-    )
-    assert 400e6 < t.param_count < 410e6
-    assert not t._check_vma  # the flash gate relaxed it: the TPU branch
 
-    def sds(tree, specs):
-        return jax.tree.map(
-            lambda leaf, s: jax.ShapeDtypeStruct(
-                leaf.shape, leaf.dtype, sharding=NamedSharding(mesh, s)
+def _lm_step(sizes, params_between):
+    """A ``LongContextTrainer`` step (bf16, no remat, dp = sp = 1) on one
+    described chip; ``sizes()`` gives vocab, widths, depth, T and batch."""
+
+    def build(topo, monkeypatch):
+        from akka_allreduce_tpu.parallel import data_seq_mesh
+        from akka_allreduce_tpu.train import LongContextTrainer
+
+        z = sizes()
+        # the trainer places its state with device_put; shapes stay where they are
+        monkeypatch.setattr(jax, "device_put", lambda x, *a, **k: x)
+        adam = optax.adam(3e-3)
+        mesh = data_seq_mesh(1, 1, devices=topo.devices[:1])
+        t = LongContextTrainer(
+            mesh, model_cls=_ShapeOnlyLM, vocab=z["vocab"],
+            d_model=z["d_model"], n_heads=z["n_heads"],
+            n_kv_heads=z["n_kv_heads"], n_layers=z["n_layers"],
+            seq_len=z["seq_len"], compute_dtype=jnp.bfloat16,
+            optimizer=optax.GradientTransformation(
+                lambda p: jax.eval_shape(adam.init, p), adam.update
             ),
-            tree, specs,
         )
+        lo, hi = params_between
+        assert lo < t.param_count < hi
+        assert not t._check_vma  # the kernel's gate relaxed it: the TPU branch
 
-    tokens = jax.ShapeDtypeStruct(
-        (8, 2048), jnp.int32, sharding=t._data_sharding
+        def sds(tree, specs):
+            return jax.tree.map(
+                lambda leaf, s: jax.ShapeDtypeStruct(
+                    leaf.shape, leaf.dtype, sharding=NamedSharding(mesh, s)
+                ),
+                tree, specs,
+            )
+
+        tokens = jax.ShapeDtypeStruct(
+            (z["batch"], z["seq_len"]), jnp.int32, sharding=t._data_sharding
+        )
+        valid = jax.ShapeDtypeStruct(
+            (1,), jnp.float32, sharding=t._valid_sharding
+        )
+        compiled = t._step.lower(
+            sds(t.params, t._param_specs), sds(t.opt_state, t._opt_specs),
+            tokens, tokens, valid,
+        ).compile()
+        if z["n_kv_heads"] != z["n_heads"]:
+            _assert_splash_takes_compact_kv(
+                compiled.as_text(), z["batch"], z["seq_len"], z["n_heads"],
+                z["n_kv_heads"], z["d_model"] // z["n_heads"],
+                layers=z["n_layers"],
+            )
+        return compiled, 2 * z["n_layers"]  # two attention kernels per layer
+
+    return build
+
+
+def _flagship_sizes():
+    """d2048 x 8L x seq2048 x B8, what ``chip_smoke.py`` runs."""
+    return dict(vocab=256, d_model=2048, n_heads=16, n_kv_heads=16,
+                n_layers=8, seq_len=2048, batch=8)
+
+
+def _b2_cell_sizes():
+    """The benchmark's ``sc2_3b_train_b2_t4096``: memory full (8.232 GB of
+    arguments + 4.258 of temporaries with the old kernel, PERF.md), so a
+    backward that keeps more alive fails the case's 16 GB line here."""
+    cfg = _bench_json("configs", "starcoder2_3b_d4.json")
+    traffic = _bench_json("traffic", "closed_b2_t4096.json")
+    return dict(
+        vocab=cfg["vocab_size"], d_model=cfg["hidden_size"],
+        n_heads=cfg["num_attention_heads"],
+        n_kv_heads=cfg["num_key_value_heads"],
+        n_layers=cfg["num_hidden_layers"], seq_len=traffic["seq_len"],
+        batch=traffic["batch"],
     )
-    valid = jax.ShapeDtypeStruct(
-        (1,), jnp.float32, sharding=t._valid_sharding
-    )
-    compiled = t._step.lower(
-        sds(t.params, t._param_specs), sds(t.opt_state, t._opt_specs),
-        tokens, tokens, valid,
-    ).compile()
-    return compiled, 3 * 8  # three flash kernels per layer
 
 
 def _lfm2_moe_step(topo, monkeypatch):
@@ -204,34 +279,28 @@ def _lfm2_moe_step(topo, monkeypatch):
     bf16, no remat) on one described chip: a later change that makes it too
     large for the chip fails here before it fails the cell."""
     import importlib.util
-    import json
-    import os.path
-
-    bench = os.path.join(os.path.dirname(os.path.dirname(__file__)), "benchmarks")
-
-    def load(*parts):
-        with open(os.path.join(bench, *parts), encoding="utf-8") as f:
-            return json.load(f)
 
     spec = importlib.util.spec_from_file_location(
-        "bench_runner_moe_train", os.path.join(bench, "runners", "moe_train.py")
+        "bench_runner_moe_train", os.path.join(BENCH, "runners", "moe_train.py")
     )
-    monkeypatch.syspath_prepend(bench)  # the runner imports the harness
+    monkeypatch.syspath_prepend(BENCH)  # the runner imports the harness
     runner = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(runner)
     t, lowered = runner.lower_step_on_shapes(
-        load("configs", "lfm2_24b_a2b_ep8_d5.json"),
-        load("traffic", "closed_b1_t8192.json"), topo.devices[0],
+        _bench_json("configs", "lfm2_24b_a2b_ep8_d5.json"),
+        _bench_json("traffic", "closed_b1_t8192.json"), topo.devices[0],
     )
     assert 486.0e6 < t.param_count < 486.2e6
-    assert not t._check_vma  # flash and the grouped-product kernels: the TPU branch
-    # three flash kernels, and the nine grouped products of the first rung
+    assert not t._check_vma  # attention and grouped-product kernels: the TPU branch
+    # two attention kernels, and the nine grouped products of the first rung
     # of the row buffer in each of the four expert layers (three forward,
     # three and three ``tgmm`` backward; the last rung is ``lax.ragged_dot``)
     from akka_allreduce_tpu.ops.moe import row_rungs
 
     assert row_rungs(8192 * 4, 8, 64) == (5120, 8192 * 4)
-    return lowered.compile(), 3 + 9 * 4
+    compiled = lowered.compile()
+    _assert_splash_takes_compact_kv(compiled.as_text(), 1, 8192, 32, 8, 64)
+    return compiled, 2 + 9 * 4
 
 
 CASES = {
@@ -239,10 +308,14 @@ CASES = {
     "pallas_ring_4dev_64M_f32": _pallas_ring(None),
     "pallas_ring_4dev_64M_bf16": _pallas_ring("bf16"),
     "pallas_ring_4dev_64M_int8": _pallas_ring("int8"),
-    "flash_attention_b8_h16_t2048_d128": _flash_attention,
+    "flash_attention_b8_h16_t2048_d128": _kernel_attention(8, 2048, 16, 16, 128),
     "grouped_psum_moe_shaped_grads_4dev": _grouped_psum,
-    "flagship_lm_step": _lm_step,
+    "flagship_lm_step": _lm_step(_flagship_sizes, (400e6, 410e6)),
     "lfm2_moe_cell_step": _lfm2_moe_step,
+    # the benchmark's own attention shapes, K/V compact into the kernel
+    "splash_attention_b2_t4096_h24_kv2_d128": _kernel_attention(2, 4096, 24, 2, 128),
+    "splash_attention_b1_t8192_h32_kv8_d64": _kernel_attention(1, 8192, 32, 8, 64),
+    "sc2_b2_cell_step": _lm_step(_b2_cell_sizes, (685.9e6, 686.1e6)),
 }
 
 

@@ -96,3 +96,66 @@ def test_local_attention_dispatches_and_matches():
         np.asarray(attention_reference(q, k, v, causal=True)),
         atol=2e-5,
     )
+
+
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize(
+    "h,h_kv,d", [(4, 2, 64), (4, 4, 128), (6, 2, 128)],
+    ids=["h4_kv2_d64", "h4_kv4_d128", "h6_kv2_d128"],
+)
+def test_splash_branch_matches_dense(h, h_kv, d, causal):
+    """The function ``local_attention``'s TPU branch calls, in interpret mode
+    (``local_attention`` itself never takes the branch off the chip): compact
+    K/V go in, ``dk``/``dv`` come back at H_kv heads, and output and all three
+    gradients agree with the dense oracle over the expanded K/V."""
+    from akka_allreduce_tpu.ops.local_attention import _splash_attention
+    from akka_allreduce_tpu.ops.ring_attention import repeat_kv
+
+    t, scale = 1024, 0.17  # a scale of its own: the kernel has none, q carries it
+    keys = jax.random.split(jax.random.PRNGKey(h * d + causal), 3)
+    q = jax.random.normal(keys[0], (1, t, h, d), jnp.float32)
+    k = jax.random.normal(keys[1], (1, t, h_kv, d), jnp.float32)
+    v = jax.random.normal(keys[2], (1, t, h_kv, d), jnp.float32)
+
+    def value_and_grads(attention):
+        def loss(q, k, v):
+            out = attention(q, k, v)
+            return (out ** 2).sum(), out
+
+        return jax.jit(
+            jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True)
+        )(q, k, v)
+
+    (_, got), got_grads = value_and_grads(
+        lambda q, k, v: _splash_attention(
+            q, k, v, causal=causal, scale=scale, interpret=True
+        )
+    )
+    (_, want), want_grads = value_and_grads(
+        lambda q, k, v: attention_reference(
+            q, repeat_kv(k, h), repeat_kv(v, h), causal=causal, sm_scale=scale
+        )
+    )
+    assert [g.shape for g in got_grads] == [q.shape, k.shape, v.shape]
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+    for g, w in zip(got_grads, want_grads):
+        np.testing.assert_allclose(
+            np.asarray(g), np.asarray(w), atol=2e-5 * float(jnp.abs(w).max())
+        )
+
+
+def test_local_attention_expands_grouped_kv_off_the_kernel():
+    """The dense and blockwise cores still take grouped K/V: the expansion
+    lives in them now, not in front of the dispatch."""
+    from akka_allreduce_tpu.ops.ring_attention import repeat_kv
+
+    for t in (64, 768):  # dense, then blockwise on the CPU
+        q, k, v = _qkv(tq=t, tk=t, h=4, seed=t)
+        k, v = k[:, :, :2], v[:, :, :2]
+        np.testing.assert_allclose(
+            np.asarray(local_attention(q, k, v, causal=True)),
+            np.asarray(attention_reference(
+                q, repeat_kv(k, 4), repeat_kv(v, 4), causal=True
+            )),
+            atol=2e-5,
+        )
