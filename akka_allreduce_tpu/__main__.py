@@ -1972,10 +1972,12 @@ def _cmd_train_moe(argv: list[str]) -> int:
     )
     p.add_argument(
         "--config", default=None, metavar="JSON",
-        help="build the model from an lfm2_moe config.json (conv/attention "
-        "hybrid, gated experts, dropless sigmoid top-k routing over the "
-        "experts it says are held here) in place of the size flags; its "
-        "\"program\" group, if any, gives compute_dtype",
+        help="build the model from a config.json in the dialect its keys "
+        "are of: lfm2_moe's (conv/attention hybrid) or DeepSeek-V3's, as "
+        "joyai_llm_flash has them (latent attention, a shared expert, a "
+        "multi-token-prediction module); gated experts, dropless sigmoid top-k routing "
+        "over the experts it says are held here, in place of the size "
+        "flags; its \"program\" group, if any, gives compute_dtype",
     )
     _add_sharded_compress_flag(p)
     args = p.parse_args(argv)
@@ -2124,10 +2126,13 @@ def _train_moe_from_config(args) -> int:
     dt = time.perf_counter() - t0
     rows = hist[-1].expert_rows
     fullest = rows.sum(axis=1).argmax()
+    mtp = "" if hist[-1].mtp_loss is None else (
+        f", mtp loss {hist[0].mtp_loss:.4f} -> {hist[-1].mtp_loss:.4f}"
+    )
     print(
         f"moe: {args.steps} steps on {trainer.n_devices} devices in "
         f"{dt:.2f}s ({dt / args.steps * 1e3:.1f} ms/step); "
-        f"loss {hist[0].loss:.4f} -> {hist[-1].loss:.4f} "
+        f"loss {hist[0].loss:.4f} -> {hist[-1].loss:.4f}{mtp} "
         f"(dropped {hist[-1].dropped:.1%}; rows per held expert, last "
         f"step, fullest layer: {rows[fullest].astype(int).tolist()} in a "
         f"row buffer of {int(hist[-1].buffer_rows[fullest])})"

@@ -1,12 +1,23 @@
 """A decoder built from a configuration: conv/attention hybrids with dense
-and expert feed-forward layers (the ``lfm2_moe`` family).
+and expert feed-forward layers (the ``lfm2_moe`` family), and latent-attention
+decoders with a shared expert and a multi-token-prediction module (the
+DeepSeek-V3 dialect, ``joyai_llm_flash``).
 
 Layer ``i``:  ``h = x + Op_i(RMSNorm(x))``,  ``y = h + FF_i(RMSNorm(h))``.
-``Op_i`` is a gated short convolution where ``layer_types[i] == "conv"`` and
+``Op_i`` is a gated short convolution where ``layer_types[i] == "conv"``,
 grouped-query attention (per-head RMSNorm on q and k, rotate-half RoPE) where
-``"full_attention"``; ``FF_i`` is a gated SiLU MLP for the first
+``"full_attention"`` and latent attention (:class:`LatentAttention`) where
+``"latent_attention"``; ``FF_i`` is a gated SiLU MLP for the first
 ``num_dense_layers`` layers and a sigmoid-routed, dropless expert layer after
-them. A final RMSNorm, then an untied head. No bias anywhere.
+them, with a shared expert beside the routed ones where ``shared_width`` says
+so. A final RMSNorm, then an untied head. No bias anywhere.
+
+With ``mtp_depth == 1`` one more layer predicts the token after the next
+(DeepSeek-V3's report, section 2.2): ``h' = [RMSNorm_e(Emb(t_{i+1})) |
+RMSNorm_h(h_i)] W_eh`` with ``h_i`` the last layer's output before the final
+norm, a latent-attention + expert layer over ``h'``, its own final RMSNorm,
+the SAME embedding and head. The model then takes the next tokens beside the
+tokens and returns those logits last; the trainer weighs their loss.
 
 The expert layers are told which contiguous range of the experts this device
 holds (``held_first``, ``held_count``): they route over all ``num_experts``
@@ -20,6 +31,7 @@ package's ``__init__``.
 """
 
 from __future__ import annotations
+
 
 import flax.linen as nn
 import jax
@@ -77,6 +89,86 @@ class GroupedQueryAttention(nn.Module):
             return _dense(d, dt, "out")(out.reshape(b, t, -1))
 
 
+def _rms(x, scale, eps: float):
+    """RMSNorm over the last axis, reduced in float32, in ``x``'s dtype."""
+    x32 = x.astype(jnp.float32)
+    y = x32 * lax.rsqrt(jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + eps)
+    return (y * scale.astype(jnp.float32)).astype(x.dtype)
+
+
+def _latent_up(c_q, c_kv, k_r, q_scale, kv_scale, w_qb, w_kvb, *, heads: int,
+               nope: int, rope_theta: float, eps: float):
+    """From the latents to the heads: ``q`` (B, T, H, nope + rope), ``k`` the
+    same, ``v`` (B, T, H, v)."""
+    from akka_allreduce_tpu.models.transformer import rope
+
+    b, t, _ = c_q.shape
+    dt = c_q.dtype
+    q = (_rms(c_q, q_scale, eps) @ w_qb.astype(dt)).reshape(b, t, heads, -1)
+    kv = (_rms(c_kv, kv_scale, eps) @ w_kvb.astype(dt)).reshape(b, t, heads, -1)
+    q = jnp.concatenate(
+        (q[..., :nope], rope(q[..., nope:], 0, base=rope_theta)), axis=-1
+    )
+    k_r = rope(k_r[:, :, None, :], 0, base=rope_theta)  # one key for all heads
+    k = jnp.concatenate(
+        (kv[..., :nope], jnp.broadcast_to(k_r, (b, t, heads, k_r.shape[-1]))),
+        axis=-1,
+    )
+    return q, k, kv[..., nope:]
+
+
+class LatentAttention(nn.Module):
+    """Multi-head latent attention (DeepSeek-V2/V3): low-rank queries
+    ``c_q = RMSNorm(x W_qa)``, ``[q_nope | q_r] = c_q W_qb`` per head; a
+    compressed K/V latent ``[c_kv | k_r] = x W_kva``, ``c_kv = RMSNorm(c_kv)``,
+    ``[k_nope | v] = c_kv W_kvb`` per head; ``q = [q_nope | RoPE(q_r)]``,
+    ``k = [k_nope | RoPE(k_r)]`` with the one ``k_r`` shared by all heads;
+    scores scaled by ``(nope + rope) ** -0.5``; causal softmax; ``W_o`` over
+    the heads' values, whose size differs from the queries'."""
+
+    n_heads: int
+    q_rank: int
+    kv_rank: int
+    nope_dim: int
+    rope_dim: int
+    v_dim: int
+    rope_theta: float
+    norm_eps: float
+    compute_dtype: jnp.dtype
+
+    @nn.compact
+    def __call__(self, x):
+        from akka_allreduce_tpu.ops.local_attention import local_attention
+
+        b, t, d = x.shape
+        dt, h = self.compute_dtype, self.n_heads
+        init, ones = nn.initializers.normal(0.02), nn.initializers.ones
+        with jax.named_scope("mla_down"):
+            c_q = _dense(self.q_rank, dt, "q_a")(x)
+            latent = _dense(self.kv_rank + self.rope_dim, dt, "kv_a")(x)
+        with jax.named_scope("mla_up"):
+            q, k, v = _latent_up(
+                c_q, latent[..., : self.kv_rank], latent[..., self.kv_rank:],
+                self.param("q_a_norm", ones, (self.q_rank,)),
+                self.param("kv_a_norm", ones, (self.kv_rank,)),
+                self.param(
+                    "q_b", init, (self.q_rank, h * (self.nope_dim + self.rope_dim))
+                ),
+                self.param(
+                    "kv_b", init, (self.kv_rank, h * (self.nope_dim + self.v_dim))
+                ),
+                heads=h, nope=self.nope_dim,
+                rope_theta=self.rope_theta, eps=self.norm_eps,
+            )
+        with jax.named_scope("mla_attention"):
+            out = local_attention(
+                q, k, v, causal=True,
+                sm_scale=(self.nope_dim + self.rope_dim) ** -0.5,
+            )
+        with jax.named_scope("mla_out"):
+            return _dense(d, dt, "out")(out.reshape(b, t, -1))
+
+
 class GatedMLP(nn.Module):
     width: int
     compute_dtype: jnp.dtype
@@ -90,8 +182,10 @@ class GatedMLP(nn.Module):
 
 
 class HeldExperts(nn.Module):
-    """The expert layer of one device: returns ``(y, rows, dropped)`` with
-    ``rows`` the (held_count,) rows each held expert received."""
+    """The expert layer of one device: returns ``(y, rows, dropped, taken)``
+    with ``rows`` the (held_count,) rows each held expert received. With
+    ``shared_width`` a shared expert, a gated MLP every token passes through
+    and every device computes alike, is added unweighted."""
 
     num_experts: int
     experts_per_token: int
@@ -102,6 +196,7 @@ class HeldExperts(nn.Module):
     renormalise: bool
     scale: float
     compute_dtype: jnp.dtype
+    shared_width: int = 0
 
     @nn.compact
     def __call__(self, x):
@@ -125,7 +220,11 @@ class HeldExperts(nn.Module):
             renormalise=self.renormalise, scale=self.scale,
         )
         self.sow("intermediates", "selected", route.selected)
-        return y.reshape(x.shape), route.group_sizes[:h], dropped, route.buffer_rows
+        y = y.reshape(x.shape)
+        if self.shared_width:
+            with jax.named_scope("shared_expert"):
+                y = y + GatedMLP(self.shared_width, self.compute_dtype, name="shared")(x)
+        return y, route.group_sizes[:h], dropped, route.buffer_rows
 
 
 class HybridDecoderLM(nn.Module):
@@ -134,7 +233,10 @@ class HybridDecoderLM(nn.Module):
     mean of the expert layers' (0 by construction), ``expert_rows`` (expert
     layers, held_count) float32 counts and ``buffer_rows`` (expert layers,)
     the rows of the row buffer each layer took for them — the tuple
-    ``MoETrainer`` takes."""
+    ``MoETrainer`` takes. With ``mtp_depth`` it is called with the next
+    tokens too, ``(tokens, next_tokens)``, counts the prediction module's
+    expert layer last in both counters and returns that module's float32
+    logits (position i: the token after ``next_tokens[i]``) as a sixth."""
 
     vocab: int
     d_model: int
@@ -142,7 +244,7 @@ class HybridDecoderLM(nn.Module):
     num_dense_layers: int
     n_heads: int
     n_kv_heads: int
-    head_dim: int
+    head_dim: int  # of the queries and keys (latent attention: nope + rope)
     intermediate_size: int
     moe_intermediate_size: int
     num_experts: int  # the router's width: every expert of the model
@@ -156,87 +258,104 @@ class HybridDecoderLM(nn.Module):
     renormalise: bool = True
     routed_scale: float = 1.0
     compute_dtype: jnp.dtype = jnp.float32
+    shared_width: int = 0  # the shared expert's, 0: none
+    # latent attention: ranks of the two latents, the rotary part of a head
+    # and the values' head size (head_dim - rope_head_dim is the rest of q/k)
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    rope_head_dim: int = 0
+    v_head_dim: int = 0
+    mtp_depth: int = 0  # multi-token-prediction modules (0 or 1)
+    mtp_weight: float = 0.0  # of that module's loss in the trainer's total
 
     @classmethod
     def from_config(cls, cfg: dict, **overrides) -> "HybridDecoderLM":
-        """From the keys of an ``lfm2_moe`` ``config.json``. ``num_experts``
-        there counts the experts HELD when ``router_num_experts`` states the
-        model's own count beside it (a chip's share, ``held_experts`` its
-        ids); otherwise all experts are held."""
-        if cfg.get("conv_bias"):
-            raise ValueError("conv_bias is not built")
-        total = int(cfg.get("router_num_experts", cfg["num_experts"]))
-        held = list(cfg.get("held_experts", range(int(cfg["num_experts"]))))
-        if held != list(range(held[0], held[0] + len(held))):
-            raise ValueError(f"held experts must be one range, got {held}")
-        heads = int(cfg["num_attention_heads"])
-        d = int(cfg["hidden_size"])
-        kw = dict(
-            vocab=int(cfg["vocab_size"]), d_model=d,
-            layer_types=tuple(cfg["layer_types"]),
-            num_dense_layers=int(cfg["num_dense_layers"]),
-            n_heads=heads, n_kv_heads=int(cfg["num_key_value_heads"]),
-            head_dim=int(cfg.get("head_dim") or d // heads),
-            intermediate_size=int(cfg["intermediate_size"]),
-            moe_intermediate_size=int(cfg["moe_intermediate_size"]),
-            num_experts=total,
-            experts_per_token=int(cfg["num_experts_per_tok"]),
-            held_first=held[0], held_count=len(held),
-            conv_taps=int(cfg["conv_L_cache"]),
-            norm_eps=float(cfg["norm_eps"]),
-            rope_theta=float(cfg["rope_parameters"]["rope_theta"]),
-            use_select_bias=bool(cfg["use_expert_bias"]),
-            renormalise=bool(cfg["norm_topk_prob"]),
-            routed_scale=float(cfg["routed_scaling_factor"]),
-        )
+        """From the keys of a ``config.json``, in the dialect its keys are
+        of: DeepSeek-V3's where it has ``kv_lora_rank`` (``deepseek_v3``,
+        ``joyai_llm_flash``), else ``lfm2_moe``'s. The key that counts the
+        experts (``num_experts`` / ``n_routed_experts``) counts the experts
+        HELD when ``router_num_experts`` states the model's own count beside
+        it (a chip's share, ``held_experts`` its ids); otherwise all experts
+        are held."""
+        read = _from_deepseek_v3_keys if "kv_lora_rank" in cfg else _from_lfm2_moe
+        kw = read(cfg)
         if len(kw["layer_types"]) != int(cfg["num_hidden_layers"]):
             raise ValueError("layer_types and num_hidden_layers disagree")
         kw.update(overrides)
         return cls(**kw)
 
+    def _operator(self, kind: str, pre: str):
+        dt = self.compute_dtype
+        if kind == "conv":
+            return ShortConv(self.d_model, self.conv_taps, dt, name=pre + "conv")
+        if kind == "full_attention":
+            return GroupedQueryAttention(
+                self.n_heads, self.n_kv_heads, self.head_dim,
+                self.rope_theta, self.norm_eps, dt, name=pre + "attn",
+            )
+        if kind == "latent_attention":
+            return LatentAttention(
+                self.n_heads, self.q_lora_rank, self.kv_lora_rank,
+                self.head_dim - self.rope_head_dim, self.rope_head_dim,
+                self.v_head_dim, self.rope_theta, self.norm_eps, dt,
+                name=pre + "attn",
+            )
+        raise ValueError(f"layer type {kind!r} is not built")
+
     @nn.compact
-    def __call__(self, tokens):
+    def __call__(self, tokens, next_tokens=None):
         dt = self.compute_dtype
         norm = lambda name: nn.RMSNorm(  # noqa: E731
             epsilon=self.norm_eps, dtype=dt, name=name
         )
-        x = nn.Embed(self.vocab, self.d_model, dtype=dt, name="embed")(tokens)
         rows, dropped, buffers = [], [], []
-        for i, kind in enumerate(self.layer_types):
-            pre = f"layers_{i}_"
-            h = norm(pre + "op_norm")(x)
-            if kind == "conv":
-                op = ShortConv(self.d_model, self.conv_taps, dt, name=pre + "conv")
-            elif kind == "full_attention":
-                op = GroupedQueryAttention(
-                    self.n_heads, self.n_kv_heads, self.head_dim,
-                    self.rope_theta, self.norm_eps, dt, name=pre + "attn",
-                )
-            else:
-                raise ValueError(f"layer type {kind!r} is not built")
-            x = x + op(h)
+
+        def layer(x, pre: str, kind: str, dense: bool):
+            x = x + self._operator(kind, pre)(norm(pre + "op_norm")(x))
             h = norm(pre + "ffn_norm")(x)
-            if i < self.num_dense_layers:
-                y = GatedMLP(self.intermediate_size, dt, name=pre + "mlp")(h)
-            else:
-                y, r, dr, taken = HeldExperts(
-                    self.num_experts, self.experts_per_token,
-                    self.moe_intermediate_size, self.held_first, self.held_count,
-                    self.use_select_bias, self.renormalise, self.routed_scale,
-                    dt, name=pre + "moe",
-                )(h)
-                rows.append(r)
-                dropped.append(dr)
-                buffers.append(taken)
-            x = x + y
-        x = norm("final_norm")(x)
+            if dense:
+                return x + GatedMLP(self.intermediate_size, dt, name=pre + "mlp")(h)
+            y, r, dr, taken = HeldExperts(
+                self.num_experts, self.experts_per_token,
+                self.moe_intermediate_size, self.held_first, self.held_count,
+                self.use_select_bias, self.renormalise, self.routed_scale,
+                dt, self.shared_width, name=pre + "moe",
+            )(h)
+            rows.append(r)
+            dropped.append(dr)
+            buffers.append(taken)
+            return x + y
+
+        embed = nn.Embed(self.vocab, self.d_model, dtype=dt, name="embed")
         head = self.param(
             "head", nn.initializers.normal(0.02), (self.d_model, self.vocab)
         )
-        logits = lax.dot_general(
-            x, head.astype(dt), (((x.ndim - 1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+
+        def to_logits(x):
+            return lax.dot_general(
+                x, head.astype(dt), (((x.ndim - 1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+
+        x = embed(tokens)
+        for i, kind in enumerate(self.layer_types):
+            x = layer(x, f"layers_{i}_", kind, i < self.num_dense_layers)
+        logits, mtp_logits = to_logits(norm("final_norm")(x)), ()
+        if self.mtp_depth:
+            if next_tokens is None and self.is_initializing():
+                next_tokens = tokens  # ``init(key, tokens)`` as any model's
+            if self.mtp_depth != 1 or next_tokens is None:
+                raise ValueError(
+                    "one prediction module, called with (tokens, next_tokens)"
+                )
+            with jax.named_scope("mtp"):
+                merged = jnp.concatenate(
+                    (norm("mtp_enorm")(embed(next_tokens)), norm("mtp_hnorm")(x)),
+                    axis=-1,
+                )
+                x = _dense(self.d_model, dt, "mtp_eh_proj")(merged)
+                x = layer(x, "mtp_", self.layer_types[-1], False)
+                mtp_logits = (to_logits(norm("mtp_final_norm")(x)),)
         n = max(len(rows), 1)
         return (
             logits,
@@ -246,4 +365,90 @@ class HybridDecoderLM(nn.Module):
             else jnp.zeros((0, self.held_count), jnp.float32),
             jnp.stack(buffers).astype(jnp.float32) if buffers
             else jnp.zeros((0,), jnp.float32),
+            *mtp_logits,
         )
+
+
+def _held_share(cfg: dict, count_key: str) -> tuple[int, int, int]:
+    """``(router width, first held expert, held count)``."""
+    total = int(cfg.get("router_num_experts", cfg[count_key]))
+    held = list(cfg.get("held_experts", range(int(cfg[count_key]))))
+    if held != list(range(held[0], held[0] + len(held))):
+        raise ValueError(f"held experts must be one range, got {held}")
+    return total, held[0], len(held)
+
+
+def _from_lfm2_moe(cfg: dict) -> dict:
+    if cfg.get("conv_bias"):
+        raise ValueError("conv_bias is not built")
+    total, first, count = _held_share(cfg, "num_experts")
+    heads = int(cfg["num_attention_heads"])
+    d = int(cfg["hidden_size"])
+    return dict(
+        vocab=int(cfg["vocab_size"]), d_model=d,
+        layer_types=tuple(cfg["layer_types"]),
+        num_dense_layers=int(cfg["num_dense_layers"]),
+        n_heads=heads, n_kv_heads=int(cfg["num_key_value_heads"]),
+        head_dim=int(cfg.get("head_dim") or d // heads),
+        intermediate_size=int(cfg["intermediate_size"]),
+        moe_intermediate_size=int(cfg["moe_intermediate_size"]),
+        num_experts=total,
+        experts_per_token=int(cfg["num_experts_per_tok"]),
+        held_first=first, held_count=count,
+        conv_taps=int(cfg["conv_L_cache"]),
+        norm_eps=float(cfg["norm_eps"]),
+        rope_theta=float(cfg["rope_parameters"]["rope_theta"]),
+        use_select_bias=bool(cfg["use_expert_bias"]),
+        renormalise=bool(cfg["norm_topk_prob"]),
+        routed_scale=float(cfg["routed_scaling_factor"]),
+    )
+
+
+def _from_deepseek_v3_keys(cfg: dict) -> dict:
+    """DeepSeek-V3's keys: latent attention in every layer, the first
+    ``first_k_dense_replace`` feed-forwards dense, sigmoid scores picked by
+    ``p + bias`` in one group (``noaux_tc``), ``n_shared_experts`` shared
+    experts as one MLP of their summed width, ``num_nextn_predict_layers``
+    prediction modules. ``program.mtp_loss_weight`` weighs the second loss."""
+    refused = {
+        "n_group": 1, "topk_group": 1, "rope_scaling": None, "ep_size": 1,
+        "scoring_func": "sigmoid", "topk_method": "noaux_tc",
+        "attention_bias": False, "hidden_act": "silu", "moe_layer_freq": 1,
+        "tie_word_embeddings": False,
+    }
+    for key, built in refused.items():
+        if cfg.get(key, built) != built:
+            raise ValueError(f"{key} = {cfg[key]!r} is not built (only {built!r})")
+    mtp = int(cfg.get("num_nextn_predict_layers", 0))
+    if mtp > 1:
+        raise ValueError(f"{mtp} prediction modules: one is built")
+    if not cfg.get("q_lora_rank"):
+        raise ValueError("full-rank queries (no q_lora_rank) are not built")
+    total, first, count = _held_share(cfg, "n_routed_experts")
+    layers = int(cfg["num_hidden_layers"])
+    program = cfg.get("program", {})
+    if program.get("remat"):
+        raise ValueError(f"program.remat {program['remat']!r}: recomputation is not built")
+    nope, rot = int(cfg["qk_nope_head_dim"]), int(cfg["qk_rope_head_dim"])
+    heads = int(cfg["num_attention_heads"])
+    return dict(
+        vocab=int(cfg["vocab_size"]), d_model=int(cfg["hidden_size"]),
+        layer_types=("latent_attention",) * layers,
+        num_dense_layers=int(cfg["first_k_dense_replace"]),
+        n_heads=heads, n_kv_heads=heads, head_dim=nope + rot,
+        intermediate_size=int(cfg["intermediate_size"]),
+        moe_intermediate_size=int(cfg["moe_intermediate_size"]),
+        num_experts=total,
+        experts_per_token=int(cfg["num_experts_per_tok"]),
+        held_first=first, held_count=count,
+        norm_eps=float(cfg["rms_norm_eps"]),
+        rope_theta=float(cfg["rope_theta"]),
+        use_select_bias=True,
+        renormalise=bool(cfg["norm_topk_prob"]),
+        routed_scale=float(cfg["routed_scaling_factor"]),
+        shared_width=int(cfg["n_shared_experts"]) * int(cfg["moe_intermediate_size"]),
+        q_lora_rank=int(cfg["q_lora_rank"]), kv_lora_rank=int(cfg["kv_lora_rank"]),
+        rope_head_dim=rot, v_head_dim=int(cfg["v_head_dim"]),
+        mtp_depth=mtp,
+        mtp_weight=float(program.get("mtp_loss_weight", 0.3)) if mtp else 0.0,
+    )

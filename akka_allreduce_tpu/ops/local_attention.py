@@ -85,14 +85,14 @@ def _blockwise_olm(
             k_scale = jnp.repeat(k_scale, group, axis=2)
             v_scale = jnp.repeat(v_scale, group, axis=2)
     b, tq, h, d = q.shape
-    tk = k.shape[1]
+    tk, dv = k.shape[1], v.shape[-1]  # the values' head size may differ
     nb = -(-tk // block_k)
     pad = nb * block_k - tk
     kp = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
     vp = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
     # (nb, B, block, H, D) so scan carries one block per step
     kb = kp.reshape(b, nb, block_k, h, d).transpose(1, 0, 2, 3, 4)
-    vb = vp.reshape(b, nb, block_k, h, d).transpose(1, 0, 2, 3, 4)
+    vb = vp.reshape(b, nb, block_k, h, dv).transpose(1, 0, 2, 3, 4)
     blk = (jnp.arange(nb), kb, vb)
     if k_scale is not None:
         sb = lambda s: jnp.pad(s, ((0, 0), (0, pad), (0, 0))).reshape(  # noqa: E731
@@ -118,7 +118,7 @@ def _blockwise_olm(
             valid = jnp.broadcast_to(valid[None, :], (tq, block_k))
         return online_softmax_update(olm, qf, kk, vv, scale, valid), None
 
-    o0 = jnp.zeros((b, h, tq, d), jnp.float32)
+    o0 = jnp.zeros((b, h, tq, dv), jnp.float32)
     l0 = jnp.zeros((b, h, tq), jnp.float32)
     m0 = jnp.full((b, h, tq), _MASK_VALUE, jnp.float32)
     if vary_axes:
@@ -146,8 +146,10 @@ def blockwise_attention(
     """Memory-efficient attention over K/V blocks; same result as
     :func:`attention_reference` to float tolerance.
 
-    Shapes: ``q`` (B, Tq, H, D); ``k``/``v`` (B, Tk, H, D). Offsets position
-    the local windows globally for causal masking (as in ring attention).
+    Shapes: ``q`` (B, Tq, H, D); ``k`` (B, Tk, H, D); ``v`` (B, Tk, H, Dv),
+    Dv = D or not (latent attention: 192 against 128); the result has Dv.
+    Offsets position the local windows globally for causal masking (as in
+    ring attention).
     """
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(q.shape[-1])
     o, l, _ = _blockwise_olm(
@@ -158,15 +160,16 @@ def blockwise_attention(
     return out.transpose(0, 2, 1, 3).astype(q.dtype)
 
 
-def flash_shapes_ok(t: int, d: int) -> bool:
-    """Would the Pallas TPU splash kernel take (T=t, head_dim=d)?
+def flash_shapes_ok(t: int, d: int, dv: int | None = None) -> bool:
+    """Would the Pallas TPU splash kernel take (T=t, head_dim=d), the values'
+    head size ``dv`` (``d`` where left out)?
 
     Conservative static gate (:func:`_splash_blocks` hands out blocks of 512
     and up, and every block must divide T); also the question trainers ask to
     decide whether shard_map's vma check must be relaxed (the kernel's
     outputs carry no varying-axes annotation).
     """
-    return t > _DENSE_MAX_T and t % 512 == 0 and d % 32 == 0
+    return t > _DENSE_MAX_T and t % 512 == 0 and d % 32 == 0 and (dv or d) % 32 == 0
 
 
 def flash_vma_relax(
@@ -191,7 +194,7 @@ def flash_vma_relax(
     )
 
 
-def _flash_ok(q: jax.Array, k: jax.Array, q_offset, k_offset) -> bool:
+def _flash_ok(q: jax.Array, k: jax.Array, v: jax.Array, q_offset, k_offset) -> bool:
     """Shape/placement gate for the Pallas TPU splash kernel."""
     from akka_allreduce_tpu.ops._platform import interpret_default
 
@@ -202,25 +205,28 @@ def _flash_ok(q: jax.Array, k: jax.Array, q_offset, k_offset) -> bool:
     if not (isinstance(k_offset, int) and k_offset == 0):
         return False
     b, tq, h, d = q.shape
-    return tq == k.shape[1] and flash_shapes_ok(tq, d)
+    return tq == k.shape[1] and flash_shapes_ok(tq, d, v.shape[-1])
 
 
-def _splash_blocks(t: int, d: int):
-    """The splash kernel's tiles for (T=t, head_dim=d), read from a sweep of
-    {512, 1024, 2048} (compute blocks also 256) on a v5e at the benchmark's
-    two attention shapes, (T 4096, head 128, 24 heads on 2) and (T 8192,
-    head 64, 32 on 8) — the table is in CHANGES.md, PR 31. The same tiles won
-    at both: 1024 x 1024 everywhere, the forward's scores computed 512 K/V
-    rows at a time. Larger K/V blocks run more of the diagonal's masked half
-    (and 2048 x 2048 no longer fits VMEM); smaller ones re-read q and, in the
-    fused backward, write one more bf16 partial ``dq`` of q's size per K/V
-    block for XLA to sum. Head 64 against 128 did not move the choice; past
-    128 (not measured) f32 inputs at 1024 overrun VMEM in the described-v5e
-    compile, so those heads keep 512, which :func:`flash_shapes_ok`
+def _splash_blocks(t: int, d: int, dv: int | None = None, itemsize: int = 2):
+    """The splash kernel's tiles for (T=t, head_dim=d, values' head size
+    ``dv``, bytes an element), read from sweeps of {512, 1024, 2048} (compute
+    blocks also 256) on a v5e at the benchmark's attention shapes: (T 4096,
+    head 128, 24 heads on 2) and (T 8192, head 64, 32 on 8) — the table is in
+    CHANGES.md, PR 31 — and latent attention's (T 8192, 32 heads, 192
+    against 128; CHANGES.md, PR 32). The same tiles won at all three: 1024 x
+    1024 everywhere, the forward's scores computed 512 K/V rows at a time.
+    Larger K/V blocks run more of the diagonal's masked half (and 2048 x 2048
+    no longer fits VMEM); smaller ones re-read q and, in the fused backward,
+    write one more bf16 partial ``dq`` of q's size per K/V block for XLA to
+    sum. Neither head 64 nor 192 against 128 moved the choice. Past 128,
+    float32 inputs at 1024 overrun VMEM in the described-v5e compile (bf16
+    ones fit, and ran), so those keep 512, which :func:`flash_shapes_ok`
     guarantees divides T."""
     from jax.experimental.pallas.ops.tpu.splash_attention import BlockSizes
 
-    b = 1024 if t % 1024 == 0 and d <= 128 else 512
+    fits = max(d, dv or d) <= 128 or itemsize <= 2
+    b = 1024 if t % 1024 == 0 and fits else 512
     return BlockSizes(
         block_q=b, block_kv=b, block_kv_compute=512,
         block_q_dkv=b, block_kv_dkv=b, block_kv_dkv_compute=b,
@@ -266,7 +272,10 @@ def _splash_attention(
     into ``q`` (exact at head size 64, one more rounding of ``q`` in its own
     dtype otherwise). ``interpret`` is for the CPU test of these numbers."""
     _, t, h, d = q.shape
-    kernel = _splash_kernel(t, h, causal, _splash_blocks(t, d), interpret)
+    kernel = _splash_kernel(
+        t, h, causal, _splash_blocks(t, d, v.shape[-1], q.dtype.itemsize),
+        interpret,
+    )
     heads_first = lambda x: x.transpose(0, 2, 1, 3)  # noqa: E731
     out = jax.vmap(kernel)(
         heads_first(q * scale), heads_first(k), heads_first(v)
@@ -447,7 +456,7 @@ def local_attention(
             q, repeat_kv(k, h), repeat_kv(v, h), causal=causal,
             sm_scale=scale, q_offset=q_offset, k_offset=k_offset,
         )
-    if _flash_ok(q, k, q_offset, k_offset):
+    if _flash_ok(q, k, v, q_offset, k_offset):
         return _splash_attention(q, k, v, causal=causal, scale=scale)
     return blockwise_attention(
         q, k, v, causal=causal, sm_scale=scale,

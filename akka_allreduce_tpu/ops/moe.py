@@ -341,15 +341,25 @@ def row_rungs(rows: int, held: int, experts: int) -> tuple[int, ...]:
     and a quarter more (the count scatters around its mean from layer to
     layer), rounded up to the grouped-product kernels' row tile at this
     buffer; the last is ``rows``, the worst case — every token choosing
-    only held experts — so nothing can overflow. Two rungs and none between
-    them: every rung is a body of the expert layer the step program carries,
-    2.1 s of each start's set-up at the LFM2 cell (PERF.md, PR 29). A device
-    that holds every expert gets the last rung alone."""
+    only held experts — so nothing can overflow. Every rung is a body of
+    the expert layer the step program carries, 2.1 s of each start's set-up
+    at the LFM2 cell (PERF.md, PR 29), so there are two and none between
+    them — except where the last is more than eight times the first (a
+    thirty-second of the experts held: 2,560 and 65,536 at the JoyAI cell):
+    there one more, at four times the first. A rung's rows are all moved and
+    multiplied whatever was routed, so a layer a little past the first rung
+    paid for 25 times its rows, 11.5 ms of a 318 ms step, and a layer's load
+    did leave the first rung there: 0 to 13,872 rows within 110 steps, with
+    the router trained or frozen, the selection bias seeded, balanced at the
+    start or under the balancing rule (PERF.md, PR 32). A device that holds
+    every expert gets the last rung alone."""
     tile = next((t for t in (GMM_TILING[0], 256, 128) if rows % t == 0), None)
     if tile is None:  # no kernel tiles such a buffer: one size
         return (rows,)
     first = -(-5 * rows * held // (4 * experts * tile)) * tile
-    return (first, rows) if first < rows else (rows,)
+    if first >= rows:
+        return (rows,)
+    return (first, 4 * first, rows) if rows > 8 * first else (first, rows)
 
 
 def held_route(
@@ -374,15 +384,36 @@ def held_route(
     )
 
 
-#: (rows, contracted, out) tiles of the megablox kernels: of those swept on
-#: the v5e at the LFM2 cell's shapes the fastest with an eighth of the whole
-#: buffer filled, within 8 % of the fastest when it is full (PERF.md, PR 28),
-#: and the fastest at the cell's first rung of 5,120 rows (PERF.md, PR 29)
+#: (rows, contracted, out) tiles of the megablox kernels where an expert's
+#: rows fill a 512-row tile: of those swept on the v5e at the LFM2 cell's
+#: shapes the fastest with an eighth of the whole buffer filled, within 8 %
+#: of the fastest when it is full (PERF.md, PR 28), and the fastest at the
+#: cell's first rung of 5,120 rows (PERF.md, PR 29)
 GMM_TILING = (512, 512, 512)
 TGMM_TILING = (512, 512, 512)
 
 
-def _tiling(m: int, want: tuple[int, int, int]) -> tuple[int, int, int]:
+def _fit(n: int, most: int, otherwise: int) -> int:
+    """The largest multiple of 128 up to ``most`` that divides ``n``."""
+    return next((t for t in range(most, 0, -128) if n % t == 0), otherwise)
+
+
+def grouped_tiles(
+    kind: str, m: int, k: int, n: int, groups: int
+) -> tuple[int, int, int]:
+    """The (rows, contracted, out) tiles of one grouped product from its
+    shapes: ``kind`` "gmm" (rows times an expert's (k, n) matrix) or "tgmm"
+    (the weights' gradient, (k, n) an expert); ``m`` rows of the row buffer
+    over ``groups`` experts. Where an expert's share of the buffer is a
+    512-row tile or more, the tiles swept there (:data:`GMM_TILING`). Where
+    it is less (320 rows an expert at the JoyAI cell's first rung, ~256 of
+    them real: two experts meet in every 512-row tile and the kernel visits
+    it twice), 256 rows and the widest tiles up to 1024 that divide the
+    expert's matrix - (256, 1024, 768) at 2048 x 768 - which took 39 % off
+    one layer's products there, in both kinds (CHANGES.md, PR 32)."""
+    want = {"gmm": GMM_TILING, "tgmm": TGMM_TILING}[kind]
+    if m // groups < want[0]:
+        want = (256, _fit(k, 1024, want[1]), _fit(n, 1024, want[2]))
     tm = next((t for t in (want[0], 256, 128) if t <= want[0] and m % t == 0), None)
     if tm is None:
         raise ValueError(
@@ -409,8 +440,10 @@ def _product(lhs, rhs, group_sizes, impl, transpose_rhs=False):
     from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
 
     # off the chip the Pallas kernels run interpreted
+    k, n = rhs.shape[1:][::-1] if transpose_rhs else rhs.shape[1:]
     return gmm(
-        lhs, rhs, group_sizes, lhs.dtype, _tiling(lhs.shape[0], GMM_TILING),
+        lhs, rhs, group_sizes, lhs.dtype,
+        grouped_tiles("gmm", lhs.shape[0], k, n, rhs.shape[0]),
         transpose_rhs=transpose_rhs, interpret=interpret_default(lhs),
     )
 
@@ -431,8 +464,8 @@ def _weight_gradient(lhs, g, group_sizes, groups: int, impl):
 
     return tgmm(
         lhs.swapaxes(0, 1), g, group_sizes, jnp.float32,
-        _tiling(lhs.shape[0], TGMM_TILING), num_actual_groups=groups,
-        interpret=interpret_default(lhs),
+        grouped_tiles("tgmm", lhs.shape[0], lhs.shape[1], g.shape[1], groups),
+        num_actual_groups=groups, interpret=interpret_default(lhs),
     )
 
 

@@ -46,6 +46,11 @@ class MoEStepMetrics:
     # (``ops.moe.row_rungs``: the smallest rung that held the rows routed),
     # (expert layers,), summed over the replicas as ``expert_rows`` is
     buffer_rows: np.ndarray | None = None
+    # masked per-token cross-entropy of a model's multi-token-prediction
+    # module against the token after the next (a sequence's last position
+    # has none and counts 0); in the gradient with the model's
+    # ``mtp_weight``, not in ``loss``. None from a model without one
+    mtp_loss: float | None = None
 
 
 class MoETrainer(ShardedLMTrainer):
@@ -70,7 +75,10 @@ class MoETrainer(ShardedLMTrainer):
         aux, dropped, expert_rows, buffer_rows)`` (``models.hybrid_decoder``:
         dropless routing over a held subset of the experts, no auxiliary
         loss). It runs without an expert exchange, so the mesh is (data,)
-        only.
+        only. A model with ``mtp_depth`` is applied to ``(variables,
+        tokens, labels)`` and returns its prediction module's logits last:
+        their cross-entropy against the labels one further on enters the
+        total with ``model.mtp_weight`` and is reported as ``mtp_loss``.
       params: with ``model``, its variables (seeded weights handed in);
         left out, ``model.init`` runs jitted from ``seed``.
     """
@@ -177,6 +185,7 @@ class MoETrainer(ShardedLMTrainer):
         self.tx = optimizer or optax.adam(learning_rate, mu_dtype=mu_dtype)
 
         tokens0 = jnp.zeros((1, seq_len // self.sp), jnp.int32)
+        mtp = bool(getattr(model, "mtp_depth", 0))
         if model is not None:
             self.params = (
                 params if params is not None
@@ -212,19 +221,33 @@ class MoETrainer(ShardedLMTrainer):
             batch_spec = P(axis_names[0])
         if model is not None:  # it reports the rows routed, beside `dropped`
             self._sum_names = ("expert_rows", "buffer_rows")
+        if mtp:
+            self._mean_names = (*self._mean_names, "mtp_loss")
+            mtp_weight = float(model.mtp_weight)
         model_apply = self.model.apply
         aux_coef = self.aux_coef
+        token_ce = optax.softmax_cross_entropy_with_integer_labels
 
         def local_loss(p, x, y, tokens_local):
-            logits, aux, dropped, *rows = model_apply(p, x)
-            ce = optax.softmax_cross_entropy_with_integer_labels(
-                logits, y
-            ).sum()
+            # a prediction module embeds the next tokens, which the labels are
+            logits, aux, dropped, *rows = model_apply(p, x, *((y,) if mtp else ()))
+            ce = token_ce(logits, y).sum()
             # aux and dropped are per-device means: weighted by local tokens,
             # their global sum / denom is the masked token-weighted mean
-            return ce + aux_coef * aux * tokens_local, (
-                (ce, aux * tokens_local, dropped * tokens_local), tuple(rows),
-            )
+            total = ce + aux_coef * aux * tokens_local
+            means = (ce, aux * tokens_local, dropped * tokens_local)
+            if mtp:
+                # position i saw labels[i] and predicts labels[i + 1]; the
+                # last has no target, so its term weighs 0 (all T positions
+                # stay in the shapes) and the denominator stays the tokens
+                with jax.named_scope("mtp"):
+                    has_target = jnp.arange(y.shape[1]) < y.shape[1] - 1
+                    further = jnp.sum(
+                        token_ce(rows.pop(), jnp.roll(y, -1, axis=1)) * has_target
+                    )
+                total = total + mtp_weight * further
+                means += (further,)
+            return total, (means, tuple(rows))
 
         self._build_step(
             local_loss,

@@ -230,6 +230,17 @@ class ShardedLMTrainer:
     def train(self, batches: Iterable) -> list:
         return [self.train_step(x, y) for x, y in batches]
 
+    def step_text(self, tokens: np.ndarray, labels: np.ndarray) -> str:
+        """The compiled step's HLO text for such a batch: every instruction
+        with the ``op_name`` its metadata carries (named scopes and module
+        names), which is what joins a device trace's ops to the program's
+        scopes. After a step has run it comes from the compile cache."""
+        xd, yd = self._place(tokens, labels)
+        vd = place_mask(normalize_valid(None, self.dp), self._valid_sharding)
+        return self._step.lower(
+            self.params, self.opt_state, xd, yd, vd
+        ).compile().as_text()
+
     def get_flat_params(self) -> np.ndarray:
         return flatten_pytree(self.params)[0]
 

@@ -303,6 +303,46 @@ def _lfm2_moe_step(topo, monkeypatch):
     return compiled, 2 + 9 * 4
 
 
+def _joyai_mla_moe_step(topo, monkeypatch):
+    """The ``MoETrainer`` step of the benchmark's ``joyai_ep32_train_b1_t8192``
+    cell (latent attention at 192 / 128 heads in six layers, a shared expert
+    beside 8 of 256 routed ones held, a prediction module, 1 x 8192 tokens,
+    bf16) on one described chip, inside its configuration's memory rule:
+    arguments + temporaries at most 14.5 GB without recomputation."""
+    import importlib.util
+
+    monkeypatch.syspath_prepend(BENCH)  # the runner imports the harness
+    spec = importlib.util.spec_from_file_location(
+        "bench_runner_mla_moe_train", os.path.join(BENCH, "runners", "mla_moe_train.py")
+    )
+    runner = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(runner)
+    cfg = _bench_json("configs", "joyai_llm_flash_ep32_d5_mtp1.json")
+    t, lowered = runner.lower_step_on_shapes(
+        cfg, _bench_json("traffic", "closed_b1_t8192.json"), topo.devices[0],
+    )
+    assert 491.6e6 < t.param_count - 5 * 256 < 491.8e6
+    assert not t._check_vma and not cfg["program"]["remat"]
+    from akka_allreduce_tpu.ops.moe import row_rungs
+
+    assert row_rungs(8192 * 8, 8, 256) == (2560, 10240, 8192 * 8)
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes <= 14.5e9
+    text = compiled.as_text()
+    # the library's kernels, a forward and a fused backward in each of the six
+    # layers, q and K 192 wide against V at 128
+    calls = [
+        line.split("operand_layout_constraints=", 1)[1] for line in text.splitlines()
+        if "tpu_custom_call" in line and re.search(r"%splash_mha_[\w.]+ = ", line)
+    ]
+    assert len(calls) == 2 * 6
+    for operands in calls:
+        assert len(re.findall(r"bf16\[32,8192,192\]", operands)) >= 2  # q, K
+        assert re.search(r"bf16\[32,8192,128\]", operands)  # V
+    return compiled, 2 * 6 + 9 * 5
+
+
 CASES = {
     "reduce_kernels_8x8M_f32": _reduce_kernels,
     "pallas_ring_4dev_64M_f32": _pallas_ring(None),
@@ -312,6 +352,7 @@ CASES = {
     "grouped_psum_moe_shaped_grads_4dev": _grouped_psum,
     "flagship_lm_step": _lm_step(_flagship_sizes, (400e6, 410e6)),
     "lfm2_moe_cell_step": _lfm2_moe_step,
+    "joyai_mla_moe_cell_step": _joyai_mla_moe_step,
     # the benchmark's own attention shapes, K/V compact into the kernel
     "splash_attention_b2_t4096_h24_kv2_d128": _kernel_attention(2, 4096, 24, 2, 128),
     "splash_attention_b1_t8192_h32_kv8_d64": _kernel_attention(1, 8192, 32, 8, 64),

@@ -223,6 +223,10 @@ def test_dropless_under_skew(cfg, favoured):
 
 @pytest.mark.parametrize("rows,held,experts,want", [
     (8192 * 4, 8, 64, (5120, 32768)),  # the LFM2 cell
+    # the JoyAI cell: the last rung over 8 x the first, so one between them
+    (8192 * 8, 8, 256, (2560, 10240, 65536)),
+    (8192 * 8, 8, 128, (5120, 20480, 65536)),
+    (8192 * 8, 8, 64, (10240, 65536)),
     (8192 * 4, 64, 64, (32768,)),  # every expert held: the buffer alone
     (8192 * 4, 56, 64, (32768,)),  # a quarter over the expectation is all of it
     (1152, 2, 16, (256, 1152)),
@@ -243,7 +247,8 @@ def test_row_rungs_at_tiny_shapes_stay_inside_the_buffer_and_on_the_tile():
     for rows in range(128, 4097, 128):
         for held, experts in ((1, 64), (2, 16), (4, 16), (8, 8), (3, 7)):
             rungs = row_rungs(rows, held, experts)
-            assert 1 <= len(rungs) <= 2 and rungs[-1] == rows
+            assert 1 <= len(rungs) <= 3 and rungs[-1] == rows
+            assert len(rungs) < 3 or rungs[1] == 4 * rungs[0] < rows / 2
             assert list(rungs) == sorted(set(rungs))
             assert all(r % 128 == 0 for r in rungs)
             # the first rung holds the load under uniform routing
@@ -324,6 +329,32 @@ def test_each_rung_equals_the_whole_buffer(cfg, case, monkeypatch):
         assert all(float(jnp.abs(g1).max()) > 0 for g1 in grads1)
     np.testing.assert_array_equal(np.asarray(route.group_sizes), np.asarray(route1.group_sizes))
     np.testing.assert_array_equal(np.asarray(route.selected), np.asarray(route1.selected))
+
+
+@pytest.mark.parametrize("all_held,one_held,rung", [
+    (60, 40, 0), (100, 28, 0), (100, 29, 1), (200, 88, 1),
+])
+def test_the_rung_between_equals_the_whole_buffer(cfg, all_held, one_held, rung, monkeypatch):
+    """A share whose last rung is over eight times its first (expert 5 of 16
+    alone: 128 rows against 1,152) has a rung between them, and on it too
+    the result and every gradient are the one-rung program's."""
+    from akka_allreduce_tpu.ops import moe
+
+    ladder = (128, 512, 1152)
+    a = _routed_exactly(cfg, all_held, one_held)
+    assert moe.row_rungs(288 * 4, 1, 16) == ladder
+    (y, route, dropped), grads = _layer_and_gradients(cfg, a, 5, 1)
+    assert int(route.group_sizes[0]) == all_held + one_held
+    assert (int(route.rung), int(route.buffer_rows)) == (rung, ladder[rung])
+    assert float(dropped) == 0.0
+    _close(y, _reference_layer(cfg, a, [5]))
+    monkeypatch.setattr(moe, "row_rungs", lambda rows, held, experts: (rows,))
+    (y1, route1, _), grads1 = _layer_and_gradients(cfg, a, 5, 1)
+    assert int(route1.buffer_rows) == 288 * 4
+    _close(y, y1)
+    for g, g1 in zip(grads, grads1):
+        _close(g, g1)
+        assert float(jnp.abs(g1).max()) > 0
 
 
 def test_every_choice_held_takes_the_last_rung(cfg):
