@@ -98,23 +98,73 @@ def _rms(x, scale, eps: float):
 
 def _latent_up(c_q, c_kv, k_r, q_scale, kv_scale, w_qb, w_kvb, *, heads: int,
                nope: int, rope_theta: float, eps: float):
-    """From the latents to the heads: ``q`` (B, T, H, nope + rope), ``k`` the
-    same, ``v`` (B, T, H, v)."""
-    from akka_allreduce_tpu.models.transformer import rope
+    """From the latents to the heads, in the layout the attention kernel
+    reads: ``q`` and ``k`` (B, H, T, nope + 2 rope), ``v`` (B, H, T, v), each
+    written once, heads-first, by ONE matrix product. What has to be split,
+    scaled or rotated is split, scaled or rotated in the float32 WEIGHTS
+    before their cast (``w_qb`` (q_rank, H (nope + rope)) and ``w_kvb``
+    (kv_rank, H (nope + v)) stay one leaf each), never in the (B, T, H, .)
+    activations:
 
-    b, t, _ = c_q.shape
+    ``RoPE(x) = x cos + rotate_half(x) sin`` and ``rotate_half(x) = x P`` for
+    a signed permutation ``P`` of the columns, so with ``x = c_q W_r`` and
+    ``k' = RoPE(k_r)`` a head's rotary score is ``(c_q W_r cos) . k' + (c_q
+    W_r P sin) . k'``. ``q = c_q [W_nope | W_r | W_r P] * [1 | cos | sin]``
+    against ``k = [k_nope | k' | k']`` has exactly the scores of
+    ``[q_nope | RoPE(q_r)]`` against ``[k_nope | k']``; the table is an
+    elementwise factor of the product's output (its epilogue), and ``k``
+    comes from ``[c_kv | k'] x [[W_k_nope(h), 0, 0], [0, I, I]]``, the
+    identity blocks copying the one rotary key into every head. At 128 + 64
+    the heads are 256 wide where they were 192: the same two 128-lane tiles
+    in memory and through the MXU."""
+    from akka_allreduce_tpu.models.transformer import rope, rope_angles
+
     dt = c_q.dtype
-    q = (_rms(c_q, q_scale, eps) @ w_qb.astype(dt)).reshape(b, t, heads, -1)
-    kv = (_rms(c_kv, kv_scale, eps) @ w_kvb.astype(dt)).reshape(b, t, heads, -1)
-    q = jnp.concatenate(
-        (q[..., :nope], rope(q[..., nope:], 0, base=rope_theta)), axis=-1
+    t, rot = c_q.shape[1], k_r.shape[-1]
+    up = lambda c, w: jnp.einsum("btr,rhd->bhtd", c, w.astype(dt))  # noqa: E731
+    w_q = w_qb.reshape(w_qb.shape[0], heads, -1) * (nope + rot) ** -0.5
+    w_kv = w_kvb.reshape(w_kvb.shape[0], heads, -1)
+    c_q, c_kv = _rms(c_q, q_scale, eps), _rms(c_kv, kv_scale, eps)
+    w_r = w_q[..., nope:]
+    w_q = jnp.concatenate(
+        (w_q, -w_r[..., rot // 2:], w_r[..., : rot // 2]), axis=-1
     )
-    k_r = rope(k_r[:, :, None, :], 0, base=rope_theta)  # one key for all heads
-    k = jnp.concatenate(
-        (kv[..., :nope], jnp.broadcast_to(k_r, (b, t, heads, k_r.shape[-1]))),
-        axis=-1,
+    ang = rope_angles(t, rot, 0, base=rope_theta)
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    table = jnp.concatenate((jnp.ones((t, nope)), cos, cos, sin, sin), axis=-1)
+    q = up(c_q, w_q) * table.astype(dt)
+    eye = jnp.broadcast_to(
+        jnp.eye(rot, dtype=w_kv.dtype)[:, None], (rot, heads, rot)
     )
-    return q, k, kv[..., nope:]
+    w_k = jnp.concatenate(
+        (jnp.pad(w_kv[..., :nope], ((0, 0), (0, 0), (0, 2 * rot))),
+         jnp.pad(jnp.concatenate((eye, eye), axis=-1), ((0, 0), (0, 0), (nope, 0)))),
+        axis=0,
+    )
+    k_r = rope(k_r[:, :, None, :], 0, base=rope_theta)[:, :, 0, :]
+    k = up(jnp.concatenate((c_kv, k_r), axis=-1), w_k)
+    return q, k, up(c_kv, w_kv[..., nope:])
+
+
+class _HeadsOut(nn.Module):
+    """``nn.Dense`` over the heads' values, taken heads-first: the kernel
+    ``(H v, features)`` of a ``Dense`` on (B, T, H v), applied to (B, H, T,
+    v) as ``bhtv,hvd->btd`` so that no transposed copy of the attention's
+    output is asked for."""
+
+    features: int
+    dtype: jnp.dtype
+
+    @nn.compact
+    def __call__(self, x):
+        _, h, _, v = x.shape
+        kernel = self.param(
+            "kernel", nn.initializers.lecun_normal(), (h * v, self.features)
+        )
+        return jnp.einsum(
+            "bhtv,hvd->btd", x.astype(self.dtype),
+            kernel.reshape(h, v, self.features).astype(self.dtype),
+        )
 
 
 class LatentAttention(nn.Module):
@@ -124,7 +174,8 @@ class LatentAttention(nn.Module):
     ``[k_nope | v] = c_kv W_kvb`` per head; ``q = [q_nope | RoPE(q_r)]``,
     ``k = [k_nope | RoPE(k_r)]`` with the one ``k_r`` shared by all heads;
     scores scaled by ``(nope + rope) ** -0.5``; causal softmax; ``W_o`` over
-    the heads' values, whose size differs from the queries'."""
+    the heads' values, whose size differs from the queries'. Between the
+    latents and ``W_o`` everything is heads-first (:func:`_latent_up`)."""
 
     n_heads: int
     q_rank: int
@@ -138,9 +189,9 @@ class LatentAttention(nn.Module):
 
     @nn.compact
     def __call__(self, x):
-        from akka_allreduce_tpu.ops.local_attention import local_attention
+        from akka_allreduce_tpu.ops.local_attention import heads_first_attention
 
-        b, t, d = x.shape
+        d = x.shape[-1]
         dt, h = self.compute_dtype, self.n_heads
         init, ones = nn.initializers.normal(0.02), nn.initializers.ones
         with jax.named_scope("mla_down"):
@@ -161,12 +212,9 @@ class LatentAttention(nn.Module):
                 rope_theta=self.rope_theta, eps=self.norm_eps,
             )
         with jax.named_scope("mla_attention"):
-            out = local_attention(
-                q, k, v, causal=True,
-                sm_scale=(self.nope_dim + self.rope_dim) ** -0.5,
-            )
+            out = heads_first_attention(q, k, v, causal=True)
         with jax.named_scope("mla_out"):
-            return _dense(d, dt, "out")(out.reshape(b, t, -1))
+            return _HeadsOut(d, dt, name="out")(out)
 
 
 class GatedMLP(nn.Module):

@@ -34,6 +34,15 @@ from akka_allreduce_tpu.ops.ring_attention import (
 )
 
 
+def rope_angles(t: int, d: int, offset: jax.Array | int, *, base: float):
+    """Position x frequency, float32 (T, d/2), for positions offset + arange(T)
+    and a rotary width ``d``: position precision is what long-context rope
+    depends on, so the angles and the tables made of them are float32."""
+    pos = offset + jnp.arange(t)
+    freqs = base ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    return pos[:, None].astype(jnp.float32) * freqs[None, :]
+
+
 def rope(x: jax.Array, offset: jax.Array | int, *, base: float = 10000.0):
     """Rotary embedding over the last (even) dim; positions = offset + arange(T).
 
@@ -50,9 +59,7 @@ def rope(x: jax.Array, offset: jax.Array | int, *, base: float = 10000.0):
     d = x.shape[-1]
     if d % 2:
         raise ValueError(f"rope needs an even head dim, got {d}")
-    pos = offset + jnp.arange(x.shape[1])
-    freqs = base ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-    ang = pos[:, None].astype(jnp.float32) * freqs[None, :]  # (T, D/2)
+    ang = rope_angles(x.shape[1], d, offset, base=base)
     cos = jnp.cos(ang)[None, :, None, :].astype(x.dtype)
     sin = jnp.sin(ang)[None, :, None, :].astype(x.dtype)
     x1, x2 = jnp.split(x, 2, axis=-1)

@@ -194,8 +194,11 @@ def flash_vma_relax(
     )
 
 
-def _flash_ok(q: jax.Array, k: jax.Array, v: jax.Array, q_offset, k_offset) -> bool:
-    """Shape/placement gate for the Pallas TPU splash kernel."""
+def _flash_ok(
+    q: jax.Array, k: jax.Array, v: jax.Array, q_offset, k_offset, seq_axis: int = 1
+) -> bool:
+    """Shape/placement gate for the Pallas TPU splash kernel; ``seq_axis`` is
+    where the operands hold T (2 for heads-first ones)."""
     from akka_allreduce_tpu.ops._platform import interpret_default
 
     if interpret_default(q, k):
@@ -204,8 +207,8 @@ def _flash_ok(q: jax.Array, k: jax.Array, v: jax.Array, q_offset, k_offset) -> b
         return False
     if not (isinstance(k_offset, int) and k_offset == 0):
         return False
-    b, tq, h, d = q.shape
-    return tq == k.shape[1] and flash_shapes_ok(tq, d, v.shape[-1])
+    tq = q.shape[seq_axis]
+    return tq == k.shape[seq_axis] and flash_shapes_ok(tq, q.shape[-1], v.shape[-1])
 
 
 def _splash_blocks(t: int, d: int, dv: int | None = None, itemsize: int = 2):
@@ -256,6 +259,29 @@ def _splash_kernel(t: int, h: int, causal: bool, blocks, interpret: bool):
         )
 
 
+def _splash_heads_first(
+    q: jax.Array,
+    k: jax.Array,
+    v: jax.Array,
+    *,
+    causal: bool,
+    interpret: bool = False,
+) -> jax.Array:
+    """The kernel on the layout it reads: ``q`` (B, H, T, D) against COMPACT
+    ``k`` (B, H_kv, T, D) and ``v`` (B, H_kv, T, Dv), H_kv dividing H, to
+    (B, H, T, Dv) — the kernel reads each K/V head for its whole query group
+    and accumulates ``dk``/``dv`` over the group itself. It has no scale
+    argument: ``q`` comes with the scale in it. Both entries end here:
+    :func:`_splash_attention` for callers that hold (B, T, H, D),
+    :func:`heads_first_attention` for one whose products wrote this layout."""
+    _, h, t, d = q.shape
+    kernel = _splash_kernel(
+        t, h, causal, _splash_blocks(t, d, v.shape[-1], q.dtype.itemsize),
+        interpret,
+    )
+    return jax.vmap(kernel)(q, k, v)
+
+
 def _splash_attention(
     q: jax.Array,
     k: jax.Array,
@@ -266,19 +292,13 @@ def _splash_attention(
     interpret: bool = False,
 ) -> jax.Array:
     """:func:`local_attention`'s kernel branch: ``q`` (B, T, H, D) against
-    COMPACT ``k``/``v`` (B, T, H_kv, D), H_kv dividing H — the kernel reads
-    each K/V head for its whole query group and accumulates ``dk``/``dv``
-    over the group itself. It has no scale argument, so the scale is folded
-    into ``q`` (exact at head size 64, one more rounding of ``q`` in its own
-    dtype otherwise). ``interpret`` is for the CPU test of these numbers."""
-    _, t, h, d = q.shape
-    kernel = _splash_kernel(
-        t, h, causal, _splash_blocks(t, d, v.shape[-1], q.dtype.itemsize),
-        interpret,
-    )
+    COMPACT ``k``/``v`` (B, T, H_kv, D). The scale is folded into ``q``
+    (exact at head size 64, one more rounding of ``q`` in its own dtype
+    otherwise). ``interpret`` is for the CPU test of these numbers."""
     heads_first = lambda x: x.transpose(0, 2, 1, 3)  # noqa: E731
-    out = jax.vmap(kernel)(
-        heads_first(q * scale), heads_first(k), heads_first(v)
+    out = _splash_heads_first(
+        heads_first(q * scale), heads_first(k), heads_first(v),
+        causal=causal, interpret=interpret,
     )
     return heads_first(out)
 
@@ -461,4 +481,21 @@ def local_attention(
     return blockwise_attention(
         q, k, v, causal=causal, sm_scale=scale,
         q_offset=q_offset, k_offset=k_offset,
+    )
+
+
+def heads_first_attention(
+    q: jax.Array, k: jax.Array, v: jax.Array, *, causal: bool = False
+) -> jax.Array:
+    """:func:`local_attention` for a caller whose products already wrote the
+    kernel's layout: ``q`` (B, H, T, D) WITH the score scale in it (folded
+    into the weight that made it), ``k`` (B, H_kv, T, D), ``v`` (B, H_kv, T,
+    Dv); returns (B, H, T, Dv). Where the kernel takes the shape nothing is
+    transposed, scaled or copied on the way in or out; elsewhere the portable
+    cores get the sequence-first views."""
+    if _flash_ok(q, k, v, 0, 0, seq_axis=2):
+        return _splash_heads_first(q, k, v, causal=causal)
+    swap = lambda x: x.transpose(0, 2, 1, 3)  # noqa: E731
+    return swap(
+        local_attention(swap(q), swap(k), swap(v), causal=causal, sm_scale=1.0)
     )

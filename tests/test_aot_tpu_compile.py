@@ -305,9 +305,9 @@ def _lfm2_moe_step(topo, monkeypatch):
 
 def _joyai_mla_moe_step(topo, monkeypatch):
     """The ``MoETrainer`` step of the benchmark's ``joyai_ep32_train_b1_t8192``
-    cell (latent attention at 192 / 128 heads in six layers, a shared expert
-    beside 8 of 256 routed ones held, a prediction module, 1 x 8192 tokens,
-    bf16) on one described chip, inside its configuration's memory rule:
+    cell (latent attention at 192 / 128 heads, 256 wide in the kernels, in
+    six layers, a shared expert beside 8 of 256 routed ones held, a
+    prediction module, 1 x 8192 tokens, bf16) on one described chip, inside its configuration's memory rule:
     arguments + temporaries at most 14.5 GB without recomputation."""
     import importlib.util
 
@@ -331,15 +331,18 @@ def _joyai_mla_moe_step(topo, monkeypatch):
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes <= 14.5e9
     text = compiled.as_text()
     # the library's kernels, a forward and a fused backward in each of the six
-    # layers, q and K 192 wide against V at 128
+    # layers, q and K 256 wide (128 + the 64 rotary columns twice, under cos
+    # and under sin) against V at 128
     calls = [
         line.split("operand_layout_constraints=", 1)[1] for line in text.splitlines()
         if "tpu_custom_call" in line and re.search(r"%splash_mha_[\w.]+ = ", line)
     ]
     assert len(calls) == 2 * 6
     for operands in calls:
-        assert len(re.findall(r"bf16\[32,8192,192\]", operands)) >= 2  # q, K
+        assert len(re.findall(r"bf16\[32,8192,256\]", operands)) >= 2  # q, K
         assert re.search(r"bf16\[32,8192,128\]", operands)  # V
+    # and the products write that layout: no (B, T, H, .) activation anywhere
+    assert "bf16[1,8192,32," not in text
     return compiled, 2 * 6 + 9 * 5
 
 

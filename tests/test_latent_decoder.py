@@ -235,6 +235,96 @@ def test_latent_attention_forward_and_gradient(cfg):
     )
 
 
+def _parents_latent_attention(x, p, *, h, nope, rope_dim, theta, eps):
+    """The composition this module had before its products wrote the
+    kernel's layout (PR 32), as the plain formula: ONE product per
+    up-projection, the activations sliced, ``k_r`` broadcast over the heads,
+    ``q * scale``, a dense causal softmax."""
+    from akka_allreduce_tpu.models.transformer import rope
+
+    b, t, _ = x.shape
+    rms = lambda c, s: c * jax.lax.rsqrt(jnp.mean(c * c, -1, keepdims=True) + eps) * s  # noqa: E731
+    c_q, latent = x @ p["q_a"]["kernel"], x @ p["kv_a"]["kernel"]
+    rank = p["kv_a_norm"].shape[0]
+    q = (rms(c_q, p["q_a_norm"]) @ p["q_b"]).reshape(b, t, h, -1)
+    kv = (rms(latent[..., :rank], p["kv_a_norm"]) @ p["kv_b"]).reshape(b, t, h, -1)
+    q = jnp.concatenate((q[..., :nope], rope(q[..., nope:], 0, base=theta)), -1)
+    k_r = rope(latent[:, :, None, rank:], 0, base=theta)
+    k = jnp.concatenate((kv[..., :nope], jnp.broadcast_to(k_r, (b, t, h, rope_dim))), -1)
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q * (nope + rope_dim) ** -0.5, k)
+    scores = jnp.where(jnp.tril(jnp.ones((t, t), bool)), scores, -jnp.inf)
+    out = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(scores, axis=-1), kv[..., nope:])
+    return out.reshape(b, t, -1) @ p["out"]["kernel"]
+
+
+@pytest.mark.parametrize("nope,rope_dim,vd", [(128, 64, 128), (24, 8, 16)],
+                         ids=["192_128", "32_16"])
+def test_latent_attention_equals_its_parents_composition(nope, rope_dim, vd):
+    """The weights are split and scaled, not the activations: in float32 the
+    output and the gradient of every leaf and of ``x`` are the parent's to
+    1e-5, at the cell's head sizes and at a second pair."""
+    from akka_allreduce_tpu.models.hybrid_decoder import LatentAttention
+
+    jax.config.update("jax_default_matmul_precision", "highest")
+    try:
+        h, d, q_rank, kv_rank, t = 3, 40, 48, 32, 24
+        module = LatentAttention(h, q_rank, kv_rank, nope, rope_dim, vd, 32e6, 1e-6,
+                                 jnp.float32)
+        x = jax.random.normal(jax.random.PRNGKey(1), (2, t, d))
+        params = module.init(jax.random.PRNGKey(2), x)["params"]
+        # norms off one, weights large enough that no gradient is noise
+        keys = iter(jax.random.split(jax.random.PRNGKey(3), 16))
+        params = jax.tree.map(
+            lambda leaf: (1.0 if leaf.ndim == 1 else 0.0)
+            + 0.2 * jax.random.normal(next(keys), leaf.shape), params)
+        program = lambda x, p: module.apply({"params": p}, x)  # noqa: E731
+        parent = lambda x, p: _parents_latent_attention(  # noqa: E731
+            x, p, h=h, nope=nope, rope_dim=rope_dim, theta=32e6, eps=1e-6)
+        _close(program(x, params), parent(x, params), 1e-5)
+        probe = jax.random.normal(jax.random.PRNGKey(4), x.shape)
+        got = jax.grad(lambda *a: (program(*a) * probe).sum(), (0, 1))(x, params)
+        want = jax.grad(lambda *a: (parent(*a) * probe).sum(), (0, 1))(x, params)
+        _close(got[0], want[0], 1e-5)
+        flat = lambda tree: dict(jax.tree_util.tree_leaves_with_path(tree))  # noqa: E731
+        got_leaves, want_leaves = flat(got[1]), flat(want[1])
+        assert got_leaves.keys() == want_leaves.keys() and len(want_leaves) == 7
+        for path, leaf in want_leaves.items():
+            assert float(jnp.abs(leaf).max()) > 0, path
+            _close(got_leaves[path], leaf, 1e-5)
+    finally:
+        jax.config.update("jax_default_matmul_precision", None)
+
+
+def test_latent_attention_keeps_its_parameter_tree(cfg):
+    """One leaf per up-projection, as the name map, the reference and the
+    checkpoint layout have them; the DeepSeek-V3 dialect still builds it."""
+    from akka_allreduce_tpu.models.hybrid_decoder import HybridDecoderLM, LatentAttention
+
+    module = LatentAttention(32, 1536, 512, 128, 64, 128, 32e6, 1e-6, jnp.bfloat16)
+    shapes = jax.eval_shape(
+        module.init, jax.random.PRNGKey(0), jnp.zeros((1, 8, 2048), jnp.bfloat16))["params"]
+    assert jax.tree.map(lambda s: (s.shape, s.dtype.name), shapes) == {
+        "q_a": {"kernel": ((2048, 1536), "float32")}, "q_a_norm": ((1536,), "float32"),
+        "q_b": ((1536, 32 * 192), "float32"),
+        "kv_a": {"kernel": ((2048, 576), "float32")}, "kv_a_norm": ((512,), "float32"),
+        "kv_b": ((512, 32 * 256), "float32"),
+        "out": {"kernel": ((32 * 128, 2048), "float32")},
+    }
+    model = HybridDecoderLM.from_config(dict(cfg, model_type="deepseek_v3"))
+    tokens = jnp.zeros((1, 8), jnp.int32)
+    tree = jax.eval_shape(model.init, jax.random.PRNGKey(0), tokens, tokens)["params"]
+    s = ref.dims(cfg)
+    for prefix in ("layers_0_attn", "layers_1_attn", "mtp_attn"):
+        assert set(tree[prefix]) == {"q_a", "q_a_norm", "q_b", "kv_a", "kv_a_norm", "kv_b", "out"}
+        assert tree[prefix]["q_b"].shape == (s["q_rank"], s["h"] * (s["nope"] + s["rope"]))
+        assert tree[prefix]["kv_b"].shape == (s["kv_rank"], s["h"] * (s["nope"] + s["vd"]))
+        assert tree[prefix]["out"]["kernel"].shape == (s["h"] * s["vd"], s["d"])
+    # the reference's names still find every leaf
+    assert {runner.program_path("layers.0." + n)[2:][0] for n in
+            ("q_b.w", "kv_b.w", "q_a_norm.scale", "kv_a_norm.scale")} == {
+        "q_b", "kv_b", "q_a_norm", "kv_a_norm"}
+
+
 # -- the expert layer: shared expert, shares ------------------------------------
 
 
@@ -573,6 +663,20 @@ def test_readers_of_the_new_metrics_on_a_made_up_record():
     per_token = flops.train_flops_per_token(real, 8192, 5 * 2304 / 8192)["total"]
     assert read("mfu_pct.mla") == pytest.approx(100 * per_token * 20480 / 197e12)
     assert 0 < read("mfu_pct.mla") < 100 and 0 < read("attn_kernel_roofline_pct.mla") < 100
+    # what latent attention does around its kernels: the ops under the scope
+    # that are no kernel (the kernel itself carries the scope too), a wrapper
+    # on neither side
+    around = Trace(dict(ops, **{"reduce.5": [60, 0.12, "reduce"], "copy.7": [60, 0.03, "copy"],
+                                "cond.11": [10, 0.4, "conditional"]}))
+    scopes.update({
+        "reduce.5": "jit(step)/transpose(jvp(HybridDecoderLM))/layers_0_attn/mla_attention/"
+                    "vmap(jit(_splash_attention))/reduce_sum",
+        "copy.7": "jit(step)/jvp(HybridDecoderLM)/mtp/mtp_attn/mla_attention/transpose",
+        "cond.11": "jit(step)/jvp(HybridDecoderLM)/layers_0_attn/mla_attention/cond",
+    })
+    assert read("mla_around_kernel_ms", around) == pytest.approx(15.0)
+    assert read("mla_proj_ms", around) == pytest.approx(50.0)
+    assert read("mla_around_kernel_ms") is None  # the kernel alone under the scope
     # a program without the scopes or the counters, a trace without the
     # kernels: nothing, and no raise
     bare = dict(record, window=dict(record["window"], units=[
@@ -580,13 +684,15 @@ def test_readers_of_the_new_metrics_on_a_made_up_record():
         for u in units]))
     empty = Trace({"fusion.4": [10, 1.0, "fusion"]})
     for name in ("mfu_pct.mla", "attn_kernel_roofline_pct.mla", "moe_gmm_roofline_pct.mla",
-                 "moe_row_buffer_fill_pct.mla", "mla_proj_ms", "mtp_share_pct"):
+                 "moe_row_buffer_fill_pct.mla", "mla_proj_ms", "mtp_share_pct",
+                 "mla_around_kernel_ms"):
         assert spec.load_module("layer_metrics", name).compute(bare, empty) is None
+    assert spec.load_module("layer_metrics", "mla_around_kernel_ms").compute(bare, around) is None
     unscoped = dict(record, window=dict(record["window"], units=[
         dict(u, op_scopes={k: "" for k in scopes}) if "op_scopes" in u else u
         for u in units]))
-    for name in ("mla_proj_ms", "mtp_share_pct"):
-        assert spec.load_module("layer_metrics", name).compute(unscoped, Trace(ops)) is None
+    for name in ("mla_proj_ms", "mtp_share_pct", "mla_around_kernel_ms"):
+        assert spec.load_module("layer_metrics", name).compute(unscoped, around) is None
 
 
 def test_op_scopes_gives_a_fusion_the_product_inside_it():

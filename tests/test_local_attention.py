@@ -159,3 +159,67 @@ def test_local_attention_expands_grouped_kv_off_the_kernel():
             )),
             atol=2e-5,
         )
+
+
+@pytest.mark.parametrize(
+    "h,h_kv,d", [(24, 2, 128), (32, 8, 64)], ids=["starcoder2", "lfm2"]
+)
+def test_the_two_entries_into_the_kernel_are_one_kernel(h, h_kv, d):
+    """The benchmark's grouped-query shapes through ``_splash_attention``
+    ((B, T, H, D) in, the scale folded into q) and through the heads-first
+    core it is written on, called directly on the transposed, scaled inputs:
+    the same bits, output and all three gradients."""
+    from akka_allreduce_tpu.ops.local_attention import (
+        _splash_attention,
+        _splash_heads_first,
+    )
+
+    t, scale = 1024, d ** -0.5
+    keys = jax.random.split(jax.random.PRNGKey(h), 4)
+    q = jax.random.normal(keys[0], (1, t, h, d), jnp.float32)
+    k = jax.random.normal(keys[1], (1, t, h_kv, d), jnp.float32)
+    v = jax.random.normal(keys[2], (1, t, h_kv, d), jnp.float32)
+    probe = jax.random.normal(keys[3], (1, t, h, d), jnp.float32)
+    swap = lambda x: x.transpose(0, 2, 1, 3)  # noqa: E731
+
+    def through_entry(q, k, v):
+        out = _splash_attention(q, k, v, causal=True, scale=scale, interpret=True)
+        return (out * probe).sum(), out
+
+    def through_core(q, k, v):
+        out = swap(_splash_heads_first(
+            swap(q * scale), swap(k), swap(v), causal=True, interpret=True
+        ))
+        return (out * probe).sum(), out
+
+    run = lambda f: jax.jit(  # noqa: E731
+        jax.value_and_grad(f, argnums=(0, 1, 2), has_aux=True)
+    )(q, k, v)
+    (_, got), got_grads = run(through_entry)
+    (_, want), want_grads = run(through_core)
+    assert got.shape == q.shape and float(jnp.abs(got).max()) > 0
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    for g, w in zip(got_grads, want_grads):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+@pytest.mark.parametrize("t", [64, 768], ids=["dense", "blockwise"])
+def test_heads_first_attention_off_the_kernel(t):
+    """A caller that holds heads-first operands with the scale already in q:
+    off the chip the portable cores answer, on the sequence-first views, with
+    grouped K/V and a values' head size of its own."""
+    from akka_allreduce_tpu.ops.local_attention import heads_first_attention
+    from akka_allreduce_tpu.ops.ring_attention import repeat_kv
+
+    keys = jax.random.split(jax.random.PRNGKey(t), 3)
+    q = jax.random.normal(keys[0], (2, 4, t, 24), jnp.float32)
+    k = jax.random.normal(keys[1], (2, 2, t, 24), jnp.float32)
+    v = jax.random.normal(keys[2], (2, 2, t, 16), jnp.float32)
+    swap = lambda x: x.transpose(0, 2, 1, 3)  # noqa: E731
+    got = heads_first_attention(q * 0.2, k, v, causal=True)
+    want = attention_reference(
+        swap(q), repeat_kv(swap(k), 4), repeat_kv(swap(v), 4), causal=True,
+        sm_scale=0.2,
+    )
+    assert got.shape == (2, 4, t, 16)
+    np.testing.assert_allclose(np.asarray(swap(got)), np.asarray(want), atol=2e-5)
