@@ -1,16 +1,20 @@
 """A decoder built from a configuration: conv/attention hybrids with dense
-and expert feed-forward layers (the ``lfm2_moe`` family), and latent-attention
+and expert feed-forward layers (the ``lfm2_moe`` family), latent-attention
 decoders with a shared expert and a multi-token-prediction module (the
-DeepSeek-V3 dialect, ``joyai_llm_flash``).
+DeepSeek-V3 dialect, ``joyai_llm_flash``), and decoders that mix windowed and
+full attention at different head counts (the ``laguna`` dialect).
 
 Layer ``i``:  ``h = x + Op_i(RMSNorm(x))``,  ``y = h + FF_i(RMSNorm(h))``.
 ``Op_i`` is a gated short convolution where ``layer_types[i] == "conv"``,
-grouped-query attention (per-head RMSNorm on q and k, rotate-half RoPE) where
-``"full_attention"`` and latent attention (:class:`LatentAttention`) where
+grouped-query attention (:class:`GroupedQueryAttention`: rotate-half RoPE,
+per-head RMSNorm on q and k or none, a per-head output gate or none) where
+``"full_attention"``, the same under a causal window where
+``"sliding_attention"``, and latent attention (:class:`LatentAttention`) where
 ``"latent_attention"``; ``FF_i`` is a gated SiLU MLP for the first
-``num_dense_layers`` layers and a sigmoid-routed, dropless expert layer after
-them, with a shared expert beside the routed ones where ``shared_width`` says
-so. A final RMSNorm, then an untied head. No bias anywhere.
+``num_dense_layers`` layers and a dropless expert layer after them, routed
+by sigmoid or softmax scores, with a shared expert beside the routed ones
+where ``shared_width`` says so. A final RMSNorm, then an untied head. No
+bias anywhere.
 
 With ``mtp_depth == 1`` one more layer predicts the token after the next
 (DeepSeek-V3's report, section 2.2): ``h' = [RMSNorm_e(Emb(t_{i+1})) |
@@ -32,11 +36,24 @@ package's ``__init__``.
 
 from __future__ import annotations
 
+import math
+from typing import NamedTuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+
+class RotaryRule(NamedTuple):
+    """How one kind of attention layer rotates q and k, as
+    :func:`models.transformer.rope` takes it."""
+
+    kind: str  # the ``layer_types`` entry it serves
+    theta: float
+    rotary_dim: int  # columns of each head that rotate
+    yarn: tuple[float, int, float, float] | None
+    attention_factor: float
 
 
 def _dense(features: int, dtype, name: str) -> nn.Dense:
@@ -62,12 +79,29 @@ class ShortConv(nn.Module):
 
 
 class GroupedQueryAttention(nn.Module):
+    """``n_heads`` queries on ``n_kv_heads`` keys and values, rotate-half
+    RoPE, causal. As ``lfm2_moe`` has it: per-head RMSNorm on q and k, one
+    ``rope_theta`` over the whole head. The further fields are the ``laguna``
+    dialect's: no such norm; ``gated``, a per-head ``sigmoid(x W_g)`` (in
+    float32, read from the layer's normed input) on the kernel's output
+    before ``W_o``; ``window``, query ``i`` sees keys ``i - window + 1 .. i``;
+    ``rotary_dim`` / ``yarn`` / ``attention_factor`` as
+    :func:`models.transformer.rope` takes them; ``scope_name``, the named scope
+    around the layer."""
+
     n_heads: int
     n_kv_heads: int
     head_dim: int
     rope_theta: float
     norm_eps: float
     compute_dtype: jnp.dtype
+    qk_norm: bool = True
+    gated: bool = False
+    window: int | None = None
+    rotary_dim: int | None = None
+    yarn: tuple[float, int, float, float] | None = None
+    attention_factor: float = 1.0
+    scope_name: str = "attention"
 
     @nn.compact
     def __call__(self, x):
@@ -76,17 +110,30 @@ class GroupedQueryAttention(nn.Module):
 
         b, t, d = x.shape
         dt, hd = self.compute_dtype, self.head_dim
-        head_norm = lambda name: nn.RMSNorm(  # noqa: E731
-            epsilon=self.norm_eps, dtype=dt, name=name
-        )
-        with jax.named_scope("attention"):
-            q = _dense(self.n_heads * hd, dt, "q")(x).reshape(b, t, -1, hd)
-            k = _dense(self.n_kv_heads * hd, dt, "k")(x).reshape(b, t, -1, hd)
-            v = _dense(self.n_kv_heads * hd, dt, "v")(x).reshape(b, t, -1, hd)
-            q = rope(head_norm("q_norm")(q), 0, base=self.rope_theta)
-            k = rope(head_norm("k_norm")(k), 0, base=self.rope_theta)
-            out = local_attention(q, k, v, causal=True)
-            return _dense(d, dt, "out")(out.reshape(b, t, -1))
+
+        def turned(name: str, y):
+            if self.qk_norm:
+                y = nn.RMSNorm(epsilon=self.norm_eps, dtype=dt, name=name)(y)
+            return rope(
+                y, 0, base=self.rope_theta, rotary_dim=self.rotary_dim,
+                yarn=self.yarn, attention_factor=self.attention_factor,
+            )
+
+        with jax.named_scope(self.scope_name):
+            with jax.named_scope("attn_qkv"):
+                q = _dense(self.n_heads * hd, dt, "q")(x).reshape(b, t, -1, hd)
+                k = _dense(self.n_kv_heads * hd, dt, "k")(x).reshape(b, t, -1, hd)
+                v = _dense(self.n_kv_heads * hd, dt, "v")(x).reshape(b, t, -1, hd)
+                if self.gated:
+                    gate = _dense(self.n_heads, dt, "gate")(x)
+            with jax.named_scope("attn_core"):
+                q, k = turned("q_norm", q), turned("k_norm", k)
+                out = local_attention(q, k, v, causal=True, window=self.window)
+                if self.gated:
+                    gate = jax.nn.sigmoid(gate.astype(jnp.float32)).astype(dt)
+                    out = out * gate[..., None]
+            with jax.named_scope("attn_out"):
+                return _dense(d, dt, "out")(out.reshape(b, t, -1))
 
 
 def _rms(x, scale, eps: float):
@@ -245,6 +292,7 @@ class HeldExperts(nn.Module):
     scale: float
     compute_dtype: jnp.dtype
     shared_width: int = 0
+    score: str = "sigmoid"  # the router's score function, or "softmax"
 
     @nn.compact
     def __call__(self, x):
@@ -265,7 +313,7 @@ class HeldExperts(nn.Module):
         y, route, dropped = moe_dropless_held(
             x.reshape(-1, d).astype(self.compute_dtype), router, bias,
             w1, w3, w2, k=self.experts_per_token, held_first=self.held_first,
-            renormalise=self.renormalise, scale=self.scale,
+            renormalise=self.renormalise, scale=self.scale, score=self.score,
         )
         self.sow("intermediates", "selected", route.selected)
         y = y.reshape(x.shape)
@@ -315,27 +363,52 @@ class HybridDecoderLM(nn.Module):
     v_head_dim: int = 0
     mtp_depth: int = 0  # multi-token-prediction modules (0 or 1)
     mtp_weight: float = 0.0  # of that module's loss in the trainer's total
+    # attention that differs from layer to layer (the ``laguna`` dialect)
+    heads_per_layer: tuple[int, ...] = ()  # (): ``n_heads`` in every layer
+    sliding_window: int | None = None  # of the "sliding_attention" layers
+    # per kind of attention layer, where given; such a layer has no per-head
+    # norm and is scoped by its kind
+    rope_by_kind: tuple[RotaryRule, ...] = ()
+    attn_gate: bool = False  # a per-head sigmoid gate on attention's output
+    router_score: str = "sigmoid"
 
     @classmethod
     def from_config(cls, cfg: dict, **overrides) -> "HybridDecoderLM":
         """From the keys of a ``config.json``, in the dialect its keys are
         of: DeepSeek-V3's where it has ``kv_lora_rank`` (``deepseek_v3``,
-        ``joyai_llm_flash``), else ``lfm2_moe``'s. The key that counts the
+        ``joyai_llm_flash``), ``laguna``'s where it has
+        ``num_attention_heads_per_layer``, else ``lfm2_moe``'s. The key that counts the
         experts (``num_experts`` / ``n_routed_experts``) counts the experts
         HELD when ``router_num_experts`` states the model's own count beside
         it (a chip's share, ``held_experts`` its ids); otherwise all experts
         are held."""
-        read = _from_deepseek_v3_keys if "kv_lora_rank" in cfg else _from_lfm2_moe
+        if "kv_lora_rank" in cfg:
+            read = _from_deepseek_v3_keys
+        elif "num_attention_heads_per_layer" in cfg:
+            read = _from_laguna
+        else:
+            read = _from_lfm2_moe
         kw = read(cfg)
         if len(kw["layer_types"]) != int(cfg["num_hidden_layers"]):
             raise ValueError("layer_types and num_hidden_layers disagree")
         kw.update(overrides)
         return cls(**kw)
 
-    def _operator(self, kind: str, pre: str):
+    def _operator(self, kind: str, pre: str, index: int):
         dt = self.compute_dtype
         if kind == "conv":
             return ShortConv(self.d_model, self.conv_taps, dt, name=pre + "conv")
+        if kind in ("full_attention", "sliding_attention") and self.rope_by_kind:
+            rule = next(r for r in self.rope_by_kind if r.kind == kind)
+            return GroupedQueryAttention(
+                self.heads_per_layer[index] if self.heads_per_layer else self.n_heads,
+                self.n_kv_heads, self.head_dim, rule.theta, self.norm_eps, dt,
+                qk_norm=False, gated=self.attn_gate,
+                window=self.sliding_window if kind == "sliding_attention" else None,
+                rotary_dim=rule.rotary_dim, yarn=rule.yarn,
+                attention_factor=rule.attention_factor,
+                scope_name=kind, name=pre + "attn",
+            )
         if kind == "full_attention":
             return GroupedQueryAttention(
                 self.n_heads, self.n_kv_heads, self.head_dim,
@@ -358,8 +431,9 @@ class HybridDecoderLM(nn.Module):
         )
         rows, dropped, buffers = [], [], []
 
-        def layer(x, pre: str, kind: str, dense: bool):
-            x = x + self._operator(kind, pre)(norm(pre + "op_norm")(x))
+        def layer(x, pre: str, index: int, dense: bool):
+            op = self._operator(self.layer_types[index], pre, index)
+            x = x + op(norm(pre + "op_norm")(x))
             h = norm(pre + "ffn_norm")(x)
             if dense:
                 return x + GatedMLP(self.intermediate_size, dt, name=pre + "mlp")(h)
@@ -367,7 +441,7 @@ class HybridDecoderLM(nn.Module):
                 self.num_experts, self.experts_per_token,
                 self.moe_intermediate_size, self.held_first, self.held_count,
                 self.use_select_bias, self.renormalise, self.routed_scale,
-                dt, self.shared_width, name=pre + "moe",
+                dt, self.shared_width, self.router_score, name=pre + "moe",
             )(h)
             rows.append(r)
             dropped.append(dr)
@@ -386,8 +460,8 @@ class HybridDecoderLM(nn.Module):
             )
 
         x = embed(tokens)
-        for i, kind in enumerate(self.layer_types):
-            x = layer(x, f"layers_{i}_", kind, i < self.num_dense_layers)
+        for i in range(len(self.layer_types)):
+            x = layer(x, f"layers_{i}_", i, i < self.num_dense_layers)
         logits, mtp_logits = to_logits(norm("final_norm")(x)), ()
         if self.mtp_depth:
             if next_tokens is None and self.is_initializing():
@@ -402,7 +476,7 @@ class HybridDecoderLM(nn.Module):
                     axis=-1,
                 )
                 x = _dense(self.d_model, dt, "mtp_eh_proj")(merged)
-                x = layer(x, "mtp_", self.layer_types[-1], False)
+                x = layer(x, "mtp_", len(self.layer_types) - 1, False)
                 mtp_logits = (to_logits(norm("mtp_final_norm")(x)),)
         n = max(len(rows), 1)
         return (
@@ -499,4 +573,81 @@ def _from_deepseek_v3_keys(cfg: dict) -> dict:
         rope_head_dim=rot, v_head_dim=int(cfg["v_head_dim"]),
         mtp_depth=mtp,
         mtp_weight=float(program.get("mtp_loss_weight", 0.3)) if mtp else 0.0,
+    )
+
+
+def _from_laguna(cfg: dict) -> dict:
+    """``laguna``'s keys: grouped-query attention whose head count
+    (``num_attention_heads_per_layer``), mask (``layer_types``:
+    ``full_attention`` | ``sliding_attention`` under ``sliding_window``) and
+    rotary rule (``rope_parameters[kind]``: ``default`` | ``yarn``, a
+    ``partial_rotary_factor``) go by the layer, a per-head output gate
+    (``gating``), the leading ``dense`` feed-forwards of ``mlp_layer_types``
+    and then expert layers with softmax scores renormalised over the picks,
+    no selection bias, and one shared expert."""
+    refused = {
+        "attention_bias": False, "tie_word_embeddings": False,
+        "moe_apply_router_weight_on_input": False,
+        "moe_router_logit_softcapping": 0, "hidden_act": "silu", "ep_size": 1,
+    }
+    for key, built in refused.items():
+        if cfg.get(key, built) != built:
+            raise ValueError(f"{key} = {cfg[key]!r} is not built (only {built!r})")
+    if cfg.get("gating") not in (True, "per-head") or any(
+        g != "per_head" for g in cfg.get("gating_types", ())
+    ):
+        raise ValueError(
+            f"gating = {cfg.get('gating')!r} / {cfg.get('gating_types')!r} is "
+            "not built (only a gate per head)"
+        )
+    program = cfg.get("program", {})
+    if program.get("remat"):
+        raise ValueError(f"program.remat {program['remat']!r}: recomputation is not built")
+    layers, kinds = int(cfg["num_hidden_layers"]), tuple(cfg["layer_types"])
+    heads = tuple(int(h) for h in cfg["num_attention_heads_per_layer"])
+    mlps = list(cfg["mlp_layer_types"])
+    dense = mlps.index("sparse") if "sparse" in mlps else len(mlps)
+    if mlps != ["dense"] * dense + ["sparse"] * (len(mlps) - dense):
+        raise ValueError(f"mlp_layer_types {mlps}: dense layers lead, sparse ones follow")
+    if not len(heads) == len(mlps) == layers:
+        raise ValueError("the per-layer lists and num_hidden_layers disagree")
+    head_dim = int(cfg["head_dim"])
+    rules = []
+    for kind in sorted(set(kinds)):
+        if kind not in ("full_attention", "sliding_attention"):
+            raise ValueError(f"layer type {kind!r} is not built")
+        r = cfg["rope_parameters"][kind]
+        rope_type, yarn, factor = r.get("rope_type", "default"), None, 1.0
+        if rope_type == "yarn":
+            yarn = (
+                float(r["factor"]), int(r["original_max_position_embeddings"]),
+                float(r.get("beta_fast", 32)), float(r.get("beta_slow", 1)),
+            )
+            factor = float(
+                r.get("attention_factor") or 0.1 * math.log(yarn[0]) + 1.0
+            )
+        elif rope_type != "default":
+            raise ValueError(f"rope_type {rope_type!r} is not built (default, yarn)")
+        part = float(r.get("partial_rotary_factor", cfg.get("partial_rotary_factor", 1)))
+        rules.append(
+            RotaryRule(kind, float(r["rope_theta"]), int(head_dim * part), yarn, factor)
+        )
+    total, first, count = _held_share(cfg, "num_experts")
+    return dict(
+        vocab=int(cfg["vocab_size"]), d_model=int(cfg["hidden_size"]),
+        layer_types=kinds, num_dense_layers=dense,
+        n_heads=int(cfg["num_attention_heads"]), heads_per_layer=heads,
+        n_kv_heads=int(cfg["num_key_value_heads"]), head_dim=head_dim,
+        intermediate_size=int(cfg["intermediate_size"]),
+        moe_intermediate_size=int(cfg["moe_intermediate_size"]),
+        num_experts=total,
+        experts_per_token=int(cfg["num_experts_per_tok"]),
+        held_first=first, held_count=count,
+        norm_eps=float(cfg["rms_norm_eps"]),
+        sliding_window=int(cfg["sliding_window"]),
+        rope_by_kind=tuple(rules), attn_gate=True,
+        use_select_bias=False, router_score="softmax",
+        renormalise=bool(cfg.get("norm_topk_prob", True)),
+        routed_scale=float(cfg.get("moe_routed_scaling_factor", 1.0)),
+        shared_width=int(cfg.get("shared_expert_intermediate_size", 0)),
     )

@@ -22,6 +22,8 @@ shard.
 
 from __future__ import annotations
 
+import math
+
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
@@ -34,16 +36,53 @@ from akka_allreduce_tpu.ops.ring_attention import (
 )
 
 
-def rope_angles(t: int, d: int, offset: jax.Array | int, *, base: float):
+def rope_angles(
+    t: int,
+    d: int,
+    offset: jax.Array | int,
+    *,
+    base: float,
+    yarn: tuple[float, int, float, float] | None = None,
+):
     """Position x frequency, float32 (T, d/2), for positions offset + arange(T)
     and a rotary width ``d``: position precision is what long-context rope
-    depends on, so the angles and the tables made of them are float32."""
+    depends on, so the angles and the tables made of them are float32.
+
+    ``yarn`` = ``(factor, original positions L, beta_fast, beta_slow)``
+    blends the frequencies as YaRN does (Peng et al. 2023, section 3.2, as
+    transformers computes it). ``c(n) = d ln(L / (2 pi n)) / (2 ln base)`` is
+    the column that makes ``n`` turns over ``L`` positions: the columns up to
+    ``floor(c(beta_fast))`` keep their frequency, those from
+    ``ceil(c(beta_slow))`` on (both clipped to [0, d - 1]) have it divided by
+    ``factor``, and a linear ramp lies between."""
     pos = offset + jnp.arange(t)
     freqs = base ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    if yarn is not None:
+        factor, original, beta_fast, beta_slow = yarn
+
+        def column(turns: float) -> float:
+            return d * math.log(original / (turns * 2 * math.pi)) / (2 * math.log(base))
+
+        low = max(math.floor(column(beta_fast)), 0)
+        high = min(math.ceil(column(beta_slow)), d - 1)
+        if high == low:
+            high += 0.001  # transformers' guard against a ramp of no width
+        ramp = jnp.clip(
+            (jnp.arange(d // 2, dtype=jnp.float32) - low) / (high - low), 0, 1
+        )
+        freqs = (freqs / factor) * ramp + freqs * (1 - ramp)
     return pos[:, None].astype(jnp.float32) * freqs[None, :]
 
 
-def rope(x: jax.Array, offset: jax.Array | int, *, base: float = 10000.0):
+def rope(
+    x: jax.Array,
+    offset: jax.Array | int,
+    *,
+    base: float = 10000.0,
+    rotary_dim: int | None = None,
+    yarn: tuple[float, int, float, float] | None = None,
+    attention_factor: float = 1.0,
+):
     """Rotary embedding over the last (even) dim; positions = offset + arange(T).
 
     ``x``: (B, T, H, D). Pure elementwise after a cos/sin table build, so XLA
@@ -55,13 +94,31 @@ def rope(x: jax.Array, offset: jax.Array | int, *, base: float = 10000.0):
     under bf16 compute the (B, T, H, D) tensors would otherwise make four
     f32 round trips per projection, a measured ~2.8 ms/step of pure cast
     traffic at the MoE bench shape (BENCHMARKS.md round 4).
+
+    With ``rotary_dim`` below D the first ``rotary_dim`` columns of each head
+    rotate (in halves of that width) and the rest pass as they are. ``yarn``
+    blends the frequencies (:func:`rope_angles`) and ``attention_factor``
+    multiplies cos and sin alike in float32, so the rotated columns' part of a
+    score carries its square.
     """
     d = x.shape[-1]
     if d % 2:
         raise ValueError(f"rope needs an even head dim, got {d}")
-    ang = rope_angles(x.shape[1], d, offset, base=base)
-    cos = jnp.cos(ang)[None, :, None, :].astype(x.dtype)
-    sin = jnp.sin(ang)[None, :, None, :].astype(x.dtype)
+    if rotary_dim is not None and rotary_dim != d:
+        if rotary_dim % 2 or not 0 < rotary_dim < d:
+            raise ValueError(f"rotary width {rotary_dim} of a head of {d}")
+        turned = rope(
+            x[..., :rotary_dim], offset, base=base, yarn=yarn,
+            attention_factor=attention_factor,
+        )
+        return jnp.concatenate((turned, x[..., rotary_dim:]), axis=-1)
+    ang = rope_angles(x.shape[1], d, offset, base=base, yarn=yarn)
+
+    def table(fn):
+        y = fn(ang) if attention_factor == 1.0 else fn(ang) * attention_factor
+        return y[None, :, None, :].astype(x.dtype)
+
+    cos, sin = table(jnp.cos), table(jnp.sin)
     x1, x2 = jnp.split(x, 2, axis=-1)
     return jnp.concatenate(
         (x1 * cos - x2 * sin, x1 * sin + x2 * cos), axis=-1

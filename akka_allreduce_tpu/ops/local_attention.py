@@ -63,6 +63,7 @@ def _blockwise_olm(
     k_scale: jax.Array | None = None,
     v_scale: jax.Array | None = None,
     vary_axes: tuple = (),
+    window: int | None = None,
 ):
     """Blockwise online-softmax PARTIALS ``(o, l, m)`` over a local K/V
     slice — the un-normalized core of :func:`blockwise_attention`, also
@@ -114,6 +115,8 @@ def _blockwise_olm(
         valid = k_pos < k_offset + tk  # mask the zero-padding tail
         if causal:
             valid = valid[None, :] & (q_pos[:, None] >= k_pos[None, :])
+            if window is not None:  # a K/V block wholly outside adds nothing
+                valid &= q_pos[:, None] - k_pos[None, :] < window
         else:
             valid = jnp.broadcast_to(valid[None, :], (tq, block_k))
         return online_softmax_update(olm, qf, kk, vv, scale, valid), None
@@ -142,6 +145,7 @@ def blockwise_attention(
     q_offset: int | jax.Array = 0,
     k_offset: int | jax.Array = 0,
     block_k: int = 512,
+    window: int | None = None,
 ) -> jax.Array:
     """Memory-efficient attention over K/V blocks; same result as
     :func:`attention_reference` to float tolerance.
@@ -149,12 +153,14 @@ def blockwise_attention(
     Shapes: ``q`` (B, Tq, H, D); ``k`` (B, Tk, H, D); ``v`` (B, Tk, H, Dv),
     Dv = D or not (latent attention: 192 against 128); the result has Dv.
     Offsets position the local windows globally for causal masking (as in
-    ring attention).
+    ring attention); ``window`` as :func:`attention_reference` has it.
     """
+    if window is not None and not causal:
+        raise ValueError("a window is built for causal attention only")
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(q.shape[-1])
     o, l, _ = _blockwise_olm(
         q, k, v, causal=causal, scale=scale,
-        q_offset=q_offset, k_offset=k_offset, block_k=block_k,
+        q_offset=q_offset, k_offset=k_offset, block_k=block_k, window=window,
     )
     out = o / jnp.maximum(l, 1e-30)[..., None]
     return out.transpose(0, 2, 1, 3).astype(q.dtype)
@@ -211,7 +217,10 @@ def _flash_ok(
     return tq == k.shape[seq_axis] and flash_shapes_ok(tq, q.shape[-1], v.shape[-1])
 
 
-def _splash_blocks(t: int, d: int, dv: int | None = None, itemsize: int = 2):
+def _splash_blocks(
+    t: int, d: int, dv: int | None = None, itemsize: int = 2,
+    window: int | None = None,
+):
     """The splash kernel's tiles for (T=t, head_dim=d, values' head size
     ``dv``, bytes an element), read from sweeps of {512, 1024, 2048} (compute
     blocks also 256) on a v5e at the benchmark's attention shapes: (T 4096,
@@ -225,31 +234,58 @@ def _splash_blocks(t: int, d: int, dv: int | None = None, itemsize: int = 2):
     sum. Neither head 64 nor 192 against 128 moved the choice. Past 128,
     float32 inputs at 1024 overrun VMEM in the described-v5e compile (bf16
     ones fit, and ran), so those keep 512, which :func:`flash_shapes_ok`
-    guarantees divides T."""
+    guarantees divides T. With ``window`` (a causal band, :func:`local_attention`)
+    the rule in the body; without one, the tiles above."""
     from jax.experimental.pallas.ops.tpu.splash_attention import BlockSizes
 
     fits = max(d, dv or d) <= 128 or itemsize <= 2
     b = 1024 if t % 1024 == 0 and fits else 512
+    fused = True
+    if window is not None:
+        # Under a band the tiles are the window's own size (in 512s), and
+        # where that leaves a K/V block no more than two q blocks the
+        # backward is the library's TWO kernels: the fused one runs its whole
+        # (K/V block, q block) grid whatever the mask and writes a zero
+        # ``dq`` partial of q's size for every K/V block the band leaves
+        # empty - 16 of 134 MB a layer at (T 8192, 64 heads of 128, window
+        # 512) - which XLA then sums; ``dkv`` and ``dq`` apart run shrunk
+        # grids and write no partial. Swept there (CHANGES.md, PR 35; forward
+        # / forward + backward ms a layer): 512 tiles 3.2 / 11.0 two-kernel
+        # and 16.9 fused; 1024 tiles 4.5 / 16.2 and 16.7; 256 tiles 4.8 /
+        # 15.0 and 34.6; a 256-row compute block lost 0.7 ms at 512. A wider
+        # window than a tile keeps the fused backward: not measured.
+        b = min(b, -(-window // 512) * 512)
+        fused = window > b
     return BlockSizes(
         block_q=b, block_kv=b, block_kv_compute=512,
         block_q_dkv=b, block_kv_dkv=b, block_kv_dkv_compute=b,
-        use_fused_bwd_kernel=True,
+        block_q_dq=None if fused else b, block_kv_dq=None if fused else b,
+        use_fused_bwd_kernel=fused,
     )
 
 
 @functools.lru_cache(maxsize=None)
-def _splash_kernel(t: int, h: int, causal: bool, blocks, interpret: bool):
+def _splash_kernel(
+    t: int, h: int, causal: bool, blocks, interpret: bool,
+    window: int | None = None,
+):
     """The library's kernel object for one shape, built once: the mask's
     block tables are host numpy work (0.3-0.6 s at the benchmark's shapes),
-    and a step traces this call once per attention layer."""
+    and a step traces this call once per attention layer. With ``window`` the
+    mask is the band ``0 <= i - j < window`` and the K/V blocks it leaves
+    empty on either side are skipped."""
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         CausalMask,
         FullMask,
+        LocalMask,
         MultiHeadMask,
         make_splash_mha,
     )
 
-    mask = (CausalMask if causal else FullMask)((t, t))
+    if window is not None:
+        mask = LocalMask((t, t), window_size=(window - 1, 0), offset=0)
+    else:
+        mask = (CausalMask if causal else FullMask)((t, t))
     # the tables become device arrays inside the library; built under a
     # trace they would be that trace's tracers, and the cache would leak them
     with jax.ensure_compile_time_eval():
@@ -266,6 +302,7 @@ def _splash_heads_first(
     *,
     causal: bool,
     interpret: bool = False,
+    window: int | None = None,
 ) -> jax.Array:
     """The kernel on the layout it reads: ``q`` (B, H, T, D) against COMPACT
     ``k`` (B, H_kv, T, D) and ``v`` (B, H_kv, T, Dv), H_kv dividing H, to
@@ -275,11 +312,8 @@ def _splash_heads_first(
     :func:`_splash_attention` for callers that hold (B, T, H, D),
     :func:`heads_first_attention` for one whose products wrote this layout."""
     _, h, t, d = q.shape
-    kernel = _splash_kernel(
-        t, h, causal, _splash_blocks(t, d, v.shape[-1], q.dtype.itemsize),
-        interpret,
-    )
-    return jax.vmap(kernel)(q, k, v)
+    blocks = _splash_blocks(t, d, v.shape[-1], q.dtype.itemsize, window)
+    return jax.vmap(_splash_kernel(t, h, causal, blocks, interpret, window))(q, k, v)
 
 
 def _splash_attention(
@@ -290,6 +324,7 @@ def _splash_attention(
     causal: bool,
     scale: float,
     interpret: bool = False,
+    window: int | None = None,
 ) -> jax.Array:
     """:func:`local_attention`'s kernel branch: ``q`` (B, T, H, D) against
     COMPACT ``k``/``v`` (B, T, H_kv, D). The scale is folded into ``q``
@@ -298,7 +333,7 @@ def _splash_attention(
     heads_first = lambda x: x.transpose(0, 2, 1, 3)  # noqa: E731
     out = _splash_heads_first(
         heads_first(q * scale), heads_first(k), heads_first(v),
-        causal=causal, interpret=interpret,
+        causal=causal, interpret=interpret, window=window,
     )
     return heads_first(out)
 
@@ -455,6 +490,7 @@ def local_attention(
     sm_scale: float | None = None,
     q_offset: int | jax.Array = 0,
     k_offset: int | jax.Array = 0,
+    window: int | None = None,
 ) -> jax.Array:
     """Best single-device attention for the shape/backend at hand.
 
@@ -464,7 +500,12 @@ def local_attention(
 
     Grouped-query K/V (fewer heads than ``q``) go into the kernel compact;
     the dense and blockwise cores expand them at their score matmul.
+
+    ``window`` (causal only): query ``i`` sees key ``j`` iff
+    ``0 <= i - j < window``, in all three cores; a row always sees itself.
     """
+    if window is not None and not causal:
+        raise ValueError("a window is built for causal attention only")
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(q.shape[-1])
     # dense is gated on the SCORE MATRIX size, not the raw lengths: a
     # short query block over a long K/V (the decode-over-cache shape,
@@ -474,18 +515,19 @@ def local_attention(
         h = q.shape[2]  # repeat_kv is the identity at equal head counts
         return attention_reference(
             q, repeat_kv(k, h), repeat_kv(v, h), causal=causal,
-            sm_scale=scale, q_offset=q_offset, k_offset=k_offset,
+            sm_scale=scale, q_offset=q_offset, k_offset=k_offset, window=window,
         )
     if _flash_ok(q, k, v, q_offset, k_offset):
-        return _splash_attention(q, k, v, causal=causal, scale=scale)
+        return _splash_attention(q, k, v, causal=causal, scale=scale, window=window)
     return blockwise_attention(
         q, k, v, causal=causal, sm_scale=scale,
-        q_offset=q_offset, k_offset=k_offset,
+        q_offset=q_offset, k_offset=k_offset, window=window,
     )
 
 
 def heads_first_attention(
-    q: jax.Array, k: jax.Array, v: jax.Array, *, causal: bool = False
+    q: jax.Array, k: jax.Array, v: jax.Array, *, causal: bool = False,
+    window: int | None = None,
 ) -> jax.Array:
     """:func:`local_attention` for a caller whose products already wrote the
     kernel's layout: ``q`` (B, H, T, D) WITH the score scale in it (folded
@@ -494,8 +536,10 @@ def heads_first_attention(
     transposed, scaled or copied on the way in or out; elsewhere the portable
     cores get the sequence-first views."""
     if _flash_ok(q, k, v, 0, 0, seq_axis=2):
-        return _splash_heads_first(q, k, v, causal=causal)
+        return _splash_heads_first(q, k, v, causal=causal, window=window)
     swap = lambda x: x.transpose(0, 2, 1, 3)  # noqa: E731
     return swap(
-        local_attention(swap(q), swap(k), swap(v), causal=causal, sm_scale=1.0)
+        local_attention(
+            swap(q), swap(k), swap(v), causal=causal, sm_scale=1.0, window=window
+        )
     )

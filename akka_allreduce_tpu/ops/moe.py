@@ -334,6 +334,21 @@ def sigmoid_topk_route(
     return selected.astype(jnp.int32), w * scale
 
 
+def softmax_topk_route(
+    logits: jax.Array, k: int, *, renormalise: bool = True, scale: float = 1.0
+) -> tuple[jax.Array, jax.Array]:
+    """``p = softmax(logits)`` over all experts; the ``k`` largest are
+    selected (ties: the lower index) and weighed by ``p``, over their own sum
+    where ``renormalise`` (no epsilon: eight of 256 softmax scores sum to
+    1/32 at the least). Returns ``(selected, weights)`` as
+    :func:`sigmoid_topk_route` does; everything float32."""
+    p = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    w, selected = lax.top_k(p, k)
+    if renormalise:
+        w = w / w.sum(axis=-1, keepdims=True)
+    return selected.astype(jnp.int32), w * scale
+
+
 def row_rungs(rows: int, held: int, experts: int) -> tuple[int, ...]:
     """The row-buffer sizes, smallest first, for ``rows`` (token, choice)
     assignments routed over ``experts`` of which ``held`` are here. The
@@ -660,6 +675,7 @@ def moe_dropless_held(
     renormalise: bool = True,
     scale: float = 1.0,
     impl: str = "auto",
+    score: str = "sigmoid",
 ) -> tuple[jax.Array, HeldRoute, jax.Array]:
     """``x`` (T, d) through the gated experts this device holds.
 
@@ -672,7 +688,9 @@ def moe_dropless_held(
     the rows routed here, picked on the device each call; the last rung is
     every assignment there is. Returns ``(y, route, dropped)``: ``dropped``
     counts held assignments beyond the rung taken as a share of all held
-    assignments — 0 by construction.
+    assignments — 0 by construction. ``score`` names the router's score
+    function: "sigmoid" (:func:`sigmoid_topk_route`, with ``select_bias``)
+    or "softmax" (:func:`softmax_topk_route`, which has no bias).
     """
     held = w1.shape[0]
     rungs = row_rungs(x.shape[0] * k, held, router_w.shape[1])
@@ -683,9 +701,17 @@ def moe_dropless_held(
             x.astype(jnp.float32), router_w.astype(jnp.float32),
             precision=lax.Precision.HIGHEST,
         )
-        selected, weights = sigmoid_topk_route(
-            logits, select_bias, k, renormalise=renormalise, scale=scale
-        )
+        if score == "sigmoid":
+            selected, weights = sigmoid_topk_route(
+                logits, select_bias, k, renormalise=renormalise, scale=scale
+            )
+        elif score == "softmax" and select_bias is None:
+            selected, weights = softmax_topk_route(
+                logits, k, renormalise=renormalise, scale=scale
+            )
+        else:
+            raise ValueError(f"router score {score!r} (selection bias: "
+                             f"{select_bias is not None}) is not built")
         route = held_route(selected, weights, held_first, held, rungs)
         routed_here = route.group_sizes[:held].sum()
         dropped = jnp.maximum(routed_here - route.buffer_rows, 0) / jnp.maximum(

@@ -91,20 +91,27 @@ def attention_reference(
     sm_scale: float | None = None,
     q_offset: int | jax.Array = 0,
     k_offset: int | jax.Array = 0,
+    window: int | None = None,
 ) -> jax.Array:
     """Dense softmax attention; the single-device oracle and the Ulysses core.
 
     Shapes: ``q`` (B, Tq, H, D); ``k``/``v`` (B, Tk, H, D). Offsets give the
     global positions of the local windows for causal masking under sharding.
+    With ``window`` (causal only) query ``i`` sees key ``j`` iff
+    ``0 <= i - j < window``: itself and the ``window - 1`` before it.
     """
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(q.shape[-1])
     scores = jnp.einsum(
         "bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32
     ) * scale
+    if window is not None and not causal:
+        raise ValueError("a window is built for causal attention only")
     if causal:
         q_pos = q_offset + jnp.arange(q.shape[1])
         k_pos = k_offset + jnp.arange(k.shape[1])
         mask = q_pos[:, None] >= k_pos[None, :]
+        if window is not None:
+            mask &= q_pos[:, None] - k_pos[None, :] < window
         scores = jnp.where(mask[None, None], scores, _MASK_VALUE)
     probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum(

@@ -346,6 +346,49 @@ def _joyai_mla_moe_step(topo, monkeypatch):
     return compiled, 2 * 6 + 9 * 5
 
 
+def _laguna_moe_step(topo, monkeypatch):
+    """The ``MoETrainer`` step of the benchmark's ``laguna_xs2_train_b1_t8192``
+    cell (two full-attention layers at 48 query heads and three windowed ones
+    at 64, all on 8 K/V heads of 128, a per-head output gate, a shared expert
+    beside the 16 (or 32: the file says) of 256 softmax-routed experts held,
+    1 x 8192 tokens, bf16) on one described chip, inside its configuration's
+    memory rule: arguments + temporaries at most 14.5 GB without recomputation."""
+    import importlib.util
+
+    monkeypatch.syspath_prepend(BENCH)  # the runner imports the harness
+    spec = importlib.util.spec_from_file_location(
+        "bench_runner_laguna_moe_train", os.path.join(BENCH, "runners", "laguna_moe_train.py")
+    )
+    runner = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(runner)
+    cfg = _bench_json("configs", "laguna_xs2_d5.json")
+    held = cfg["num_experts"]
+    t, lowered = runner.lower_step_on_shapes(
+        cfg, _bench_json("traffic", "closed_b1_t8192.json"), topo.devices[0],
+    )
+    assert t.param_count == {32: 691_623_936, 16: 490_297_344}[held]
+    assert not t._check_vma and not cfg["program"]["remat"]
+    from akka_allreduce_tpu.ops.moe import row_rungs
+
+    assert row_rungs(8192 * 8, held, 256)[0] == 320 * held
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes <= 14.5e9
+    text = compiled.as_text()
+    # the library's kernels in each of the five layers: q at the layer's own
+    # head count (48 in the two full layers, 64 in the three windowed ones),
+    # K/V compact at 8
+    calls = [
+        line.split("operand_layout_constraints=", 1)[1] for line in text.splitlines()
+        if "tpu_custom_call" in line and re.search(r"%splash_mha_[\w.]+ = ", line)
+    ]
+    at = lambda heads: sum(  # noqa: E731
+        1 for c in calls if re.search(rf"bf16\[{heads},8192,128\]", c))
+    assert at(48) >= 2 * 2 and at(64) >= 2 * 3 and len(calls) == at(48) + at(64)
+    assert all(re.search(r"bf16\[8,8192,128\]", c) for c in calls)
+    return compiled, len(calls) + 9 * 4
+
+
 CASES = {
     "reduce_kernels_8x8M_f32": _reduce_kernels,
     "pallas_ring_4dev_64M_f32": _pallas_ring(None),
@@ -356,6 +399,7 @@ CASES = {
     "flagship_lm_step": _lm_step(_flagship_sizes, (400e6, 410e6)),
     "lfm2_moe_cell_step": _lfm2_moe_step,
     "joyai_mla_moe_cell_step": _joyai_mla_moe_step,
+    "laguna_moe_cell_step": _laguna_moe_step,
     # the benchmark's own attention shapes, K/V compact into the kernel
     "splash_attention_b2_t4096_h24_kv2_d128": _kernel_attention(2, 4096, 24, 2, 128),
     "splash_attention_b1_t8192_h32_kv8_d64": _kernel_attention(1, 8192, 32, 8, 64),
