@@ -78,6 +78,52 @@ class ShortConv(nn.Module):
             return _dense(self.d_model, self.compute_dtype, "out_proj")(y)
 
 
+class _HeadsIn(nn.Module):
+    """``nn.Dense`` to ``heads`` heads of ``head_dim``, written heads-first:
+    the kernel ``(features, heads x head_dim)`` of a ``Dense``, applied to (B,
+    T, features) as ``btd,dhk->bhtk``, so the product itself writes the
+    layout the attention kernel reads. With ``head_dim`` None one number a
+    head, (B, H, T)."""
+
+    heads: int
+    head_dim: int | None
+    dtype: jnp.dtype
+
+    @nn.compact
+    def __call__(self, x):
+        kernel = self.param(
+            "kernel", nn.initializers.lecun_normal(),
+            (x.shape[-1], self.heads * (self.head_dim or 1)),
+        )
+        x, kernel = x.astype(self.dtype), kernel.astype(self.dtype)
+        if self.head_dim is None:
+            return jnp.einsum("btd,dh->bht", x, kernel)
+        return jnp.einsum(
+            "btd,dhk->bhtk", x, kernel.reshape(-1, self.heads, self.head_dim)
+        )
+
+
+class _HeadsOut(nn.Module):
+    """``nn.Dense`` over the heads' values, taken heads-first: the kernel
+    ``(H v, features)`` of a ``Dense`` on (B, T, H v), applied to (B, H, T,
+    v) as ``bhtv,hvd->btd`` so that no transposed copy of the attention's
+    output is asked for."""
+
+    features: int
+    dtype: jnp.dtype
+
+    @nn.compact
+    def __call__(self, x):
+        _, h, _, v = x.shape
+        kernel = self.param(
+            "kernel", nn.initializers.lecun_normal(), (h * v, self.features)
+        )
+        return jnp.einsum(
+            "bhtv,hvd->btd", x.astype(self.dtype),
+            kernel.reshape(h, v, self.features).astype(self.dtype),
+        )
+
+
 class GroupedQueryAttention(nn.Module):
     """``n_heads`` queries on ``n_kv_heads`` keys and values, rotate-half
     RoPE, causal. As ``lfm2_moe`` has it: per-head RMSNorm on q and k, one
@@ -87,7 +133,15 @@ class GroupedQueryAttention(nn.Module):
     before ``W_o``; ``window``, query ``i`` sees keys ``i - window + 1 .. i``;
     ``rotary_dim`` / ``yarn`` / ``attention_factor`` as
     :func:`models.transformer.rope` takes them; ``scope_name``, the named scope
-    around the layer."""
+    around the layer.
+
+    Between the layer's input and ``W_o``'s output every activation is
+    heads-first, (B, H, T, D), the layout the attention kernel reads and
+    writes, and each is written once: by its product (:class:`_HeadsIn`), by
+    the one pass that norms and turns it (:func:`models.transformer.
+    rope_heads_first`; the score scale is in q's float32 table, so q is never
+    scaled in its own dtype), by the kernel, by the gate; ``W_o`` reads it
+    heads-first (:class:`_HeadsOut`)."""
 
     n_heads: int
     n_kv_heads: int
@@ -105,35 +159,35 @@ class GroupedQueryAttention(nn.Module):
 
     @nn.compact
     def __call__(self, x):
-        from akka_allreduce_tpu.models.transformer import rope
-        from akka_allreduce_tpu.ops.local_attention import local_attention
+        from akka_allreduce_tpu.models.transformer import rope_heads_first
+        from akka_allreduce_tpu.ops.local_attention import heads_first_attention
 
-        b, t, d = x.shape
+        d = x.shape[-1]
         dt, hd = self.compute_dtype, self.head_dim
 
-        def turned(name: str, y):
+        def turned(name: str, y, scale: float):
             if self.qk_norm:
                 y = nn.RMSNorm(epsilon=self.norm_eps, dtype=dt, name=name)(y)
-            return rope(
+            return rope_heads_first(
                 y, 0, base=self.rope_theta, rotary_dim=self.rotary_dim,
-                yarn=self.yarn, attention_factor=self.attention_factor,
+                yarn=self.yarn, attention_factor=self.attention_factor, scale=scale,
             )
 
         with jax.named_scope(self.scope_name):
             with jax.named_scope("attn_qkv"):
-                q = _dense(self.n_heads * hd, dt, "q")(x).reshape(b, t, -1, hd)
-                k = _dense(self.n_kv_heads * hd, dt, "k")(x).reshape(b, t, -1, hd)
-                v = _dense(self.n_kv_heads * hd, dt, "v")(x).reshape(b, t, -1, hd)
+                q = _HeadsIn(self.n_heads, hd, dt, name="q")(x)
+                k = _HeadsIn(self.n_kv_heads, hd, dt, name="k")(x)
+                v = _HeadsIn(self.n_kv_heads, hd, dt, name="v")(x)
                 if self.gated:
-                    gate = _dense(self.n_heads, dt, "gate")(x)
+                    gate = _HeadsIn(self.n_heads, None, dt, name="gate")(x)
             with jax.named_scope("attn_core"):
-                q, k = turned("q_norm", q), turned("k_norm", k)
-                out = local_attention(q, k, v, causal=True, window=self.window)
+                q, k = turned("q_norm", q, hd ** -0.5), turned("k_norm", k, 1.0)
+                out = heads_first_attention(q, k, v, causal=True, window=self.window)
                 if self.gated:
                     gate = jax.nn.sigmoid(gate.astype(jnp.float32)).astype(dt)
                     out = out * gate[..., None]
             with jax.named_scope("attn_out"):
-                return _dense(d, dt, "out")(out.reshape(b, t, -1))
+                return _HeadsOut(d, dt, name="out")(out)
 
 
 def _rms(x, scale, eps: float):
@@ -191,27 +245,6 @@ def _latent_up(c_q, c_kv, k_r, q_scale, kv_scale, w_qb, w_kvb, *, heads: int,
     k_r = rope(k_r[:, :, None, :], 0, base=rope_theta)[:, :, 0, :]
     k = up(jnp.concatenate((c_kv, k_r), axis=-1), w_k)
     return q, k, up(c_kv, w_kv[..., nope:])
-
-
-class _HeadsOut(nn.Module):
-    """``nn.Dense`` over the heads' values, taken heads-first: the kernel
-    ``(H v, features)`` of a ``Dense`` on (B, T, H v), applied to (B, H, T,
-    v) as ``bhtv,hvd->btd`` so that no transposed copy of the attention's
-    output is asked for."""
-
-    features: int
-    dtype: jnp.dtype
-
-    @nn.compact
-    def __call__(self, x):
-        _, h, _, v = x.shape
-        kernel = self.param(
-            "kernel", nn.initializers.lecun_normal(), (h * v, self.features)
-        )
-        return jnp.einsum(
-            "bhtv,hvd->btd", x.astype(self.dtype),
-            kernel.reshape(h, v, self.features).astype(self.dtype),
-        )
 
 
 class LatentAttention(nn.Module):

@@ -27,6 +27,7 @@ import math
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from akka_allreduce_tpu.ops.ring_attention import (
@@ -123,6 +124,72 @@ def rope(
     return jnp.concatenate(
         (x1 * cos - x2 * sin, x1 * sin + x2 * cos), axis=-1
     )
+
+
+def rope_tables(
+    t: int,
+    d: int,
+    offset: jax.Array | int,
+    *,
+    base: float = 10000.0,
+    rotary_dim: int | None = None,
+    yarn: tuple[float, int, float, float] | None = None,
+    attention_factor: float = 1.0,
+    scale: float = 1.0,
+):
+    """:func:`rope`'s rule as two float32 (T, d) tables ``cos``, ``sin`` over
+    the WHOLE head, as :func:`rope_heads_first` takes it: ``scale * rope(x) = x * cos
+    + partner(x) * sin``. In the ``rotary_dim`` columns that rotate, ``cos``
+    and ``sin`` of :func:`rope_angles` times ``attention_factor * scale``, the
+    sine negative in their first half (``x1 cos - x2 sin``); in the columns
+    that pass, ``cos = scale`` and ``sin = 0``. ``scale`` is where a caller
+    puts the score scale of q: in float32, before the table's one cast."""
+    r = d if rotary_dim is None else rotary_dim
+    if r % 2 or not 0 < r <= d:
+        raise ValueError(f"rotary width {r} of a head of {d}")
+    ang = rope_angles(t, r, offset, base=base, yarn=yarn)
+    cos, sin = (fn(ang) * (attention_factor * scale) for fn in (jnp.cos, jnp.sin))
+    return (
+        jnp.concatenate((cos, cos, jnp.full((t, d - r), scale, jnp.float32)), axis=-1),
+        jnp.concatenate((-sin, sin, jnp.zeros((t, d - r), jnp.float32)), axis=-1),
+    )
+
+
+def rope_heads_first(
+    x: jax.Array,
+    offset: jax.Array | int,
+    *,
+    base: float = 10000.0,
+    rotary_dim: int | None = None,
+    yarn: tuple[float, int, float, float] | None = None,
+    attention_factor: float = 1.0,
+    scale: float = 1.0,
+) -> jax.Array:
+    """``scale *`` :func:`rope` on ``x`` (B, H, T, D), the layout the
+    attention kernel reads: ``x * cos + partner(x) * sin`` in ``x``'s dtype
+    under :func:`rope_tables`' tables. With ``h`` half the rotary width,
+    column ``j``'s partner is ``j + h`` in the first ``h`` columns and ``j -
+    h`` in the next; past them ``sin`` is 0.
+
+    The partners are brought over by a product with the (D, D) permutation,
+    not by slices: one term a column, so exact, and the rest is that
+    product's elementwise epilogue - ONE read and one write of ``x`` each
+    way whatever the rule, where the halves of a 128-lane head sliced apart
+    and put together again are passes of their own on the chip (CHANGES.md,
+    PR 36)."""
+    t, d = x.shape[-2:]
+    r = d if rotary_dim is None else rotary_dim
+    cos, sin = rope_tables(
+        t, d, offset, base=base, rotary_dim=r, yarn=yarn,
+        attention_factor=attention_factor, scale=scale,
+    )
+    j = np.arange(d)
+    swap = np.zeros((d, d), np.float32)
+    swap[np.where(j < r // 2, j + r // 2, np.where(j < r, j - r // 2, j)), j] = 1.0
+    partner = jnp.einsum(
+        "...d,de->...e", x, jnp.asarray(swap, x.dtype), precision=lax.Precision.HIGHEST
+    )
+    return x * cos.astype(x.dtype) + partner * sin.astype(x.dtype)
 
 
 class Attention(nn.Module):

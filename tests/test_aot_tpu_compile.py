@@ -299,7 +299,10 @@ def _lfm2_moe_step(topo, monkeypatch):
 
     assert row_rungs(8192 * 4, 8, 64) == (5120, 8192 * 4)
     compiled = lowered.compile()
-    _assert_splash_takes_compact_kv(compiled.as_text(), 1, 8192, 32, 8, 64)
+    text = compiled.as_text()
+    _assert_splash_takes_compact_kv(text, 1, 8192, 32, 8, 64)
+    # the products write the kernel's layout: no (B, T, H, D) activation anywhere
+    assert "bf16[1,8192,32,64]" not in text and "bf16[1,8192,8,64]" not in text
     return compiled, 2 + 9 * 4
 
 
@@ -386,6 +389,9 @@ def _laguna_moe_step(topo, monkeypatch):
         1 for c in calls if re.search(rf"bf16\[{heads},8192,128\]", c))
     assert at(48) >= 2 * 2 and at(64) >= 2 * 3 and len(calls) == at(48) + at(64)
     assert all(re.search(r"bf16\[8,8192,128\]", c) for c in calls)
+    # and the products write that layout: no (B, T, H, D) activation anywhere
+    for heads in (64, 48, 8):
+        assert f"bf16[1,8192,{heads},128]" not in text
     return compiled, len(calls) + 9 * 4
 
 

@@ -292,6 +292,66 @@ def test_reference_rope_is_the_programs(cfg):
         "full_attention": 8, "sliding_attention": 16}
 
 
+_TURNS = {
+    "default": dict(base=10000.0),
+    "rotary_below_the_head": dict(base=10000.0, rotary_dim=8),
+    "yarn": dict(base=500000.0, rotary_dim=8, yarn=(64.0, 16, 4.0, 1.0)),
+    "attention_factor": dict(base=500000.0, rotary_dim=8, yarn=(64.0, 16, 4.0, 1.0),
+                             attention_factor=1.4158883083359672),
+    "scale_in_the_table": dict(base=10000.0, attention_factor=1.25, scale=16 ** -0.5),
+    "scale_and_rotary_below_the_head": dict(base=500000.0, rotary_dim=8, attention_factor=1.4,
+                                            scale=16 ** -0.5),
+}
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("rule", sorted(_TURNS))
+def test_heads_first_turn_is_rope_on_the_transposed_input(rule, dtype):
+    """``rope_heads_first`` under ``rope_tables`` on (B, H, T, D) against
+    ``rope`` on (B, T, H, D), every rule the layer knows and the score scale
+    folded into q's table: forward and gradient, f32 to 1e-6, bf16 within
+    its roundings of the exact rotation (the scale in the float32 table costs
+    none of its own)."""
+    from akka_allreduce_tpu.models.transformer import rope, rope_heads_first, rope_tables
+
+    kw = dict(_TURNS[rule])
+    scale = kw.pop("scale", 1.0)
+    swap = lambda a: a.transpose(0, 2, 1, 3)  # noqa: E731
+    x = jax.random.normal(jax.random.PRNGKey(3), (2, 3, 40, 16)).astype(dtype)
+    probe = jax.random.normal(jax.random.PRNGKey(4), x.shape).astype(dtype)
+    x32, probe32 = x.astype(jnp.float32), probe.astype(jnp.float32)  # what bf16 kept of them
+    rotary = kw.get("rotary_dim", 16)
+    cos, sin = rope_tables(40, 16, 0, scale=scale, **kw)
+    assert cos.dtype == sin.dtype == jnp.float32 and cos.shape == sin.shape == (40, 16)
+    np.testing.assert_array_equal(np.asarray(cos[:, rotary:]), np.float32(scale))
+    np.testing.assert_array_equal(np.asarray(sin[:, rotary:]), 0.0)
+    mine = lambda a: rope_heads_first(a, 0, scale=scale, **kw)  # noqa: E731
+    todays = lambda a: swap(rope(swap(a), 0, **kw)) * scale  # noqa: E731
+    got, pull = jax.vjp(mine, x)
+    want, pull_todays = jax.vjp(todays, x32)
+    (dx,), (dx_want,) = pull(probe), pull_todays(probe32)
+    assert got.dtype == dx.dtype == x.dtype and got.shape == x.shape
+    if dtype == "float32":
+        _close(got, want, 1e-6)
+        _close(dx, dx_want, 1e-6)
+        return
+    # a bf16 rounding (half an ulp: up to 2 ** -8 of a value) of the table, of
+    # the product and of the sum, on each of the two terms a column sums
+    for mine16, exact, of in ((got, want, x32), (dx, dx_want, probe32)):
+        h = rotary // 2
+        partner = jnp.concatenate((of[..., h:rotary], of[..., :h], of[..., rotary:]), -1)
+        room = 3 * 2.0 ** -8 * (jnp.abs(of * cos) + jnp.abs(partner * sin)) + 1e-6
+        assert bool((jnp.abs(mine16.astype(jnp.float32) - exact) <= room).all())
+
+
+def test_heads_first_turn_refuses_an_odd_or_too_wide_rotary_width():
+    from akka_allreduce_tpu.models.transformer import rope_tables
+
+    for bad in (7, 18, 0):
+        with pytest.raises(ValueError, match="rotary width"):
+            rope_tables(8, 16, 0, rotary_dim=bad)
+
+
 # -- the attention layer: gate, head counts, window ---------------------------------
 
 
@@ -409,6 +469,71 @@ def test_lfm2s_attention_keeps_its_tree_and_scope():
     assert set(tree["params"]["layers_1_attn"]) == {"q", "k", "v", "out", "q_norm", "k_norm"}
     with pytest.raises(ValueError, match="not built"):
         _operator_of(model, "sliding_attention", 1, {}, x)
+
+
+def _parents_composition(m, params, x):
+    """The layer as its parent composed it, kept here as the plain formula:
+    ``nn.Dense`` to (B, T, H, D), the per-head norm, ``rope``,
+    ``local_attention``, the gate on the kernel's output, ``W_o``."""
+    from akka_allreduce_tpu.models.transformer import rope
+    from akka_allreduce_tpu.ops.local_attention import local_attention
+
+    b, t, _ = x.shape
+    heads = lambda n: (x @ params[n]["kernel"]).reshape(b, t, -1, m.head_dim)  # noqa: E731
+
+    def turned(name, y):
+        if m.qk_norm:
+            y = y * jax.lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True) + m.norm_eps)
+            y = y * params[name]["scale"]
+        return rope(y, 0, base=m.rope_theta, rotary_dim=m.rotary_dim, yarn=m.yarn,
+                    attention_factor=m.attention_factor)
+
+    out = local_attention(turned("q_norm", heads("q")), turned("k_norm", heads("k")),
+                          heads("v"), causal=True, window=m.window)
+    if m.gated:
+        out = out * jax.nn.sigmoid(x @ params["gate"]["kernel"])[..., None]
+    return out.reshape(b, t, -1) @ params["out"]["kernel"]
+
+
+@pytest.mark.parametrize("which", ["lfm2", "laguna_full", "laguna_sliding"])
+def test_grouped_query_attention_equals_its_parents_composition(cfg, which):
+    """Heads-first from the products to ``W_o``, the scale in q's table:
+    the same function of the same leaves as the sequence-first composition -
+    output, the gradient of ``x`` and of every leaf to 1e-5 in f32, with
+    per-head norms (LFM2's), with YaRN on half a head under
+    ``attention_factor`` and the gate, and under a window of 8."""
+    if which == "lfm2":
+        config = _json(os.path.join(BENCH, "tests", "tiny_lfm2_moe.json"))
+        model, kind, i = spec.load_module("runners", "moe_train").build_model(config), "full_attention", 1
+    else:
+        model = runner.build_model(cfg)
+        kind, i = {"laguna_full": ("full_attention", 0), "laguna_sliding": ("sliding_attention", 1)}[which]
+    tree = jax.eval_shape(model.init, jax.random.PRNGKey(0), jnp.zeros((1, 16), jnp.int32))
+    shapes = tree["params"][f"layers_{i}_attn"]
+    leaves, treedef = jax.tree.flatten(shapes)
+    keys = jax.random.split(jax.random.PRNGKey(5), len(leaves) + 2)
+    params = jax.tree.unflatten(treedef, [
+        (1.0 if a.ndim == 1 else 0.0) + 0.3 * jax.random.normal(k, a.shape)
+        for a, k in zip(leaves, keys)])
+    x = jax.random.normal(keys[-2], (2, 32, 64))
+    probe = jax.random.normal(keys[-1], x.shape)
+    module, got = _operator_of(model, kind, i, params, x)
+    assert (module.qk_norm, module.gated, module.window) == {
+        "lfm2": (True, False, None), "laguna_full": (False, True, None),
+        "laguna_sliding": (False, True, 8)}[which]
+    if which == "laguna_full":
+        assert module.rotary_dim == 8 and module.yarn and module.attention_factor > 1.4
+    mine = lambda p, x: module.apply({"params": p}, x)  # noqa: E731
+    _close(mine(params, x), got, 0.0)
+    _close(got, _parents_composition(module, params, x), 1e-5)
+    loss = lambda f: lambda p, x: (f(p, x) * probe).sum()  # noqa: E731
+    g, gx = jax.grad(loss(mine), (0, 1))(params, x)
+    w, wx = jax.grad(loss(lambda p, x: _parents_composition(module, p, x)), (0, 1))(params, x)
+    _close(gx, wx, 1e-5)
+    assert jax.tree.structure(g) == jax.tree.structure(w) == treedef
+    for (path, a), b in zip(jax.tree.leaves_with_path(g), jax.tree.leaves(w)):
+        assert float(jnp.abs(b).max()) > 0, path  # no leaf is a no-op
+        _close(a, b, 1e-5)
 
 
 # -- the softmax router and the shares ---------------------------------------------
