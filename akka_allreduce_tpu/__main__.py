@@ -60,6 +60,27 @@ def _write_trace(args) -> None:
         print(f"trace written to {path}", flush=True)
 
 
+def _add_profile_flag(p: argparse.ArgumentParser) -> None:
+    p.add_argument(
+        "--profile-dir",
+        default=None,
+        help="capture a jax.profiler trace of the step loop (SURVEY.md §6); "
+        "view with tensorboard or xprof. train_step's spans (trainer.step*) "
+        "sit in its host plane beside the device's ops",
+    )
+
+
+def _profile(args):
+    """The step loop's profiler session (``--profile-dir``), or nothing."""
+    import contextlib
+
+    if getattr(args, "profile_dir", None):
+        import jax
+
+        return jax.profiler.trace(args.profile_dir)
+    return contextlib.nullcontext()
+
+
 def _add_chaos_flags(p: argparse.ArgumentParser) -> None:
     """Chaos + retry-policy flags for the cluster master roles. The chaos
     spec is distributed to every node via Welcome (like every other knob),
@@ -376,12 +397,7 @@ def _train_flags(p: argparse.ArgumentParser) -> None:
     _add_mesh_flags(p)
     _basic_train_flags(p)
     p.add_argument("--bucket", type=int, default=None, help="grad bucket (elements)")
-    p.add_argument(
-        "--profile-dir",
-        default=None,
-        help="capture a jax.profiler trace of the step loop (SURVEY.md §6); "
-        "view with tensorboard or xprof",
-    )
+    _add_profile_flag(p)
     p.add_argument(
         "--device-data",
         action="store_true",
@@ -459,8 +475,6 @@ def _run_training_chain(trainer, ds, args, *, label: str, flops_per_step=None) -
     """On-device block training: steps run in jitted blocks with no per-step
     host I/O. Honors the same checkpoint/profile/metrics flags as the host
     loop (checkpoints land between blocks of ``--checkpoint-every`` steps)."""
-    import contextlib
-
     import numpy as np
 
     from akka_allreduce_tpu.utils.metrics import MetricsLogger
@@ -475,11 +489,7 @@ def _run_training_chain(trainer, ds, args, *, label: str, flops_per_step=None) -
             "--accum is not supported with --device-data (the on-device "
             "chain samples fixed per-device batches); drop one of the flags"
         )
-    profile = contextlib.nullcontext()
-    if getattr(args, "profile_dir", None):
-        import jax
-
-        profile = jax.profiler.trace(args.profile_dir)
+    profile = _profile(args)
     ckpt = None
     if args.checkpoint_dir:
         ckpt = _make_checkpointer(args)
@@ -547,8 +557,6 @@ def _run_training_chain(trainer, ds, args, *, label: str, flops_per_step=None) -
 
 
 def _run_training(trainer, ds, args, *, label: str, flops_per_step=None) -> int:
-    import contextlib
-
     import numpy as np
 
     from akka_allreduce_tpu.utils.metrics import MetricsLogger
@@ -558,11 +566,7 @@ def _run_training(trainer, ds, args, *, label: str, flops_per_step=None) -> int:
             trainer, ds, args, label=label, flops_per_step=flops_per_step
         )
 
-    profile = contextlib.nullcontext()
-    if getattr(args, "profile_dir", None):
-        import jax
-
-        profile = jax.profiler.trace(args.profile_dir)
+    profile = _profile(args)
 
     logger = MetricsLogger(args.metrics_out)
     ckpt = None
@@ -574,13 +578,10 @@ def _run_training(trainer, ds, args, *, label: str, flops_per_step=None) -> int:
     accum = getattr(args, "accum", 1)
     if accum < 1:
         raise SystemExit(f"--accum must be >= 1, got {accum}")
-    # trainer numbers feed the process registry too (OBSERVABILITY.md):
-    # step count / last loss / step time, MFU at the end
+    # step count, tokens, last loss and step time reach the process registry
+    # from inside the sharded-LM trainers' train_step; MFU from here, at the end
     from akka_allreduce_tpu.obs.metrics import REGISTRY
 
-    c_steps = REGISTRY.counter("trainer.steps")
-    g_loss = REGISTRY.gauge("trainer.loss")
-    h_step = REGISTRY.histogram("trainer.step_time_s")
     t0 = time.perf_counter()
     losses = []
     with profile:
@@ -592,9 +593,6 @@ def _run_training(trainer, ds, args, *, label: str, flops_per_step=None) -> int:
                 m = trainer.train_step(x, y)
             dt = time.perf_counter() - st
             losses.append(m.loss)
-            c_steps.inc()
-            g_loss.set(m.loss)
-            h_step.observe(dt)
             logger.log_event(
                 kind="train_step", workload=label, step=m.step, loss=m.loss,
                 contributors=m.contributors, step_time_s=round(dt, 6),
@@ -984,7 +982,10 @@ def _cmd_train_lm(argv: list[str]) -> int:
     )
     _checkpoint_flags(p)
     _add_sharded_compress_flag(p)
+    _add_profile_flag(p)
+    _add_obs_flags(p)
     args = p.parse_args(argv)
+    _install_obs(args)
 
     import jax.numpy as jnp
 
@@ -1027,9 +1028,11 @@ def _cmd_train_lm(argv: list[str]) -> int:
     )
     # --device-data is handled inside _run_training via _run_training_chain
     # (trainer.data_shards tells it rows are per DP replica, not per device)
-    return _run_training(
+    rc = _run_training(
         trainer, ds, args, label=f"lm_{args.impl}", flops_per_step=flops
     )
+    _write_trace(args)
+    return rc
 
 
 def _cmd_cluster_master(argv: list[str]) -> int:
@@ -1980,7 +1983,10 @@ def _cmd_train_moe(argv: list[str]) -> int:
         "flags; its \"program\" group, if any, gives compute_dtype",
     )
     _add_sharded_compress_flag(p)
+    _add_profile_flag(p)
+    _add_obs_flags(p)
     args = p.parse_args(argv)
+    _install_obs(args)
 
     import jax
     import jax.numpy as jnp
@@ -2048,11 +2054,13 @@ def _cmd_train_moe(argv: list[str]) -> int:
             ds.device_sampler(), args.steps, rows_per_device=rows
         )
     else:
-        hist = [
-            trainer.train_step(x, y)
-            for x, y in ds.batches(args.batch, args.steps)
-        ]
+        with _profile(args):
+            hist = [
+                trainer.train_step(x, y)
+                for x, y in ds.batches(args.batch, args.steps)
+            ]
     dt = time.perf_counter() - t0
+    _write_trace(args)
     mode = "on-device " if args.device_data else ""
     from akka_allreduce_tpu.utils.benchmarking import (
         moe_active_params,
@@ -2120,10 +2128,13 @@ def _train_moe_from_config(args) -> int:
         return 0
     ds = data.lm_copy_task(args.seq_len, vocab=model.vocab)
     t0 = time.perf_counter()
-    hist = [
-        trainer.train_step(x, y) for x, y in ds.batches(args.batch, args.steps)
-    ]
+    with _profile(args):
+        hist = [
+            trainer.train_step(x, y)
+            for x, y in ds.batches(args.batch, args.steps)
+        ]
     dt = time.perf_counter() - t0
+    _write_trace(args)
     rows = hist[-1].expert_rows
     fullest = rows.sum(axis=1).argmax()
     mtp = "" if hist[-1].mtp_loss is None else (

@@ -8,7 +8,9 @@ Model (OBSERVABILITY.md "Span model"):
   scatter, peer reduce, completion report — inherits the id through the
   wire trailer (``control/wire.py``), so one round stitches across every
   process it touched.
-- A **span** is one timed operation inside a trace: name, wall-clock start,
+- A **span** is one timed operation inside a trace: name, wall-clock start
+  (``ts``, for merging processes), its start on ``time.perf_counter`` too
+  (``t0``, the clock a caller's own timings and a profiler window run on),
   duration, attributes, and parent span id. The *current* trace context is
   a ``contextvars.ContextVar`` set by the transport around each handler
   invocation; ``span()`` opens a child of it.
@@ -48,6 +50,7 @@ __all__ = [
     "start_span",
     "enabled",
     "set_enabled",
+    "set_annotator",
     "drain",
     "snapshot",
     "chrome_events",
@@ -89,6 +92,21 @@ def set_enabled(on: bool) -> None:
     _enabled = bool(on)
 
 
+#: ``factory(name)`` -> a context manager that ``span()`` enters beside a
+#: recorded span; None = spans go to this module's buffer alone
+_annotator = None
+
+
+def set_annotator(factory) -> None:
+    """Also put every recorded ``span()`` into a second timeline: the
+    with-body runs inside ``factory(name)``. ``train/sharded_lm.py`` hands
+    in ``jax.profiler.TraceAnnotation``, so the program's spans sit in the
+    profiler's host plane beside the device's ops whenever a profile is
+    taken; this package itself stays stdlib-only. ``None`` unsets."""
+    global _annotator
+    _annotator = factory
+
+
 def _new_id() -> int:
     return _ids.getrandbits(63) or 1
 
@@ -122,7 +140,7 @@ class Span:
 
     __slots__ = (
         "name", "trace_id", "span_id", "parent_id", "sampled", "attrs",
-        "_t_wall", "_t0", "ended",
+        "_t_wall", "_t0", "ended", "dur",
     )
 
     def __init__(
@@ -149,6 +167,8 @@ class Span:
         self._t_wall = time.time()
         self._t0 = time.perf_counter()
         self.ended = False
+        #: seconds from start to ``end()``, sampled or not; None while open
+        self.dur: float | None = None
 
     @property
     def context(self) -> TraceContext:
@@ -165,12 +185,14 @@ class Span:
         if self.ended:
             return
         self.ended = True
+        self.dur = time.perf_counter() - self._t0
         if not self.sampled:
             return
         rec = {
             "name": self.name,
             "ts": self._t_wall,
-            "dur": time.perf_counter() - self._t0,
+            "t0": self._t0,
+            "dur": self.dur,
             "trace_id": self.trace_id,
             "span_id": self.span_id,
             "parent_id": self.parent_id,
@@ -211,12 +233,18 @@ def span(
 ):
     """Span around the with-body; the body runs with the span as the
     current context, so nested spans (and envelopes sent from inside) are
-    its children."""
+    its children. A recorded span is also entered into the annotator's
+    timeline, if one is set (:func:`set_annotator`)."""
     s = start_span(name, ctx=ctx, root=root, **attrs)
     token = _current.set(s.context)
+    note = _annotator(name) if _annotator is not None and s.sampled else None
+    if note is not None:
+        note.__enter__()
     try:
         yield s
     finally:
+        if note is not None:
+            note.__exit__(None, None, None)
         _current.reset(token)
         s.end()
 

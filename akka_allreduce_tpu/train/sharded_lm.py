@@ -31,6 +31,8 @@ from jax.sharding import PartitionSpec as P
 
 from akka_allreduce_tpu.binder.api import flatten_pytree
 from akka_allreduce_tpu.comm.allreduce import synced_value_and_grad
+from akka_allreduce_tpu.obs import metrics as obs_metrics
+from akka_allreduce_tpu.obs import trace as obs_trace
 from akka_allreduce_tpu.ops.local_attention import flash_vma_relax
 from akka_allreduce_tpu.train.checkpoint import state_shardings
 from akka_allreduce_tpu.train.trainer import (
@@ -39,6 +41,16 @@ from akka_allreduce_tpu.train.trainer import (
     place_tokens,
     run_chain_cached,
 )
+
+# train_step's spans also go into the profiler's host plane, beside the
+# device's ops, in every profile anyone takes of a process that trains
+obs_trace.set_annotator(jax.profiler.TraceAnnotation)
+
+# written by train_step, so every caller of it feeds them (OBSERVABILITY.md)
+_STEPS = obs_metrics.counter("trainer.steps")
+_TOKENS = obs_metrics.counter("trainer.tokens")
+_LOSS = obs_metrics.gauge("trainer.loss")
+_STEP_TIME = obs_metrics.histogram("trainer.step_time_s")
 
 
 def step_check_vma(
@@ -152,9 +164,14 @@ class ShardedLMTrainer:
                 loss_fn, params, param_specs, axis_names, v,
                 compress=compress, overlap=overlap, has_aux=True,
             )
-            updates, new_opt = tx.update(gavg, opt_state, params)
+            # what is left under this scope in a compiled step are the
+            # optimizer's passes of their own: an update that XLA fuses into
+            # a weight-gradient product keeps the product's scope
+            with jax.named_scope("optimizer"):
+                updates, new_opt = tx.update(gavg, opt_state, params)
+                new_params = optax.apply_updates(params, updates)
             return (
-                optax.apply_updates(params, updates),
+                new_params,
                 new_opt,
                 *(lax.psum(m * v / denom, axis_names) for m in means),
                 lax.psum(v0, data_axis),  # contributing replica rows
@@ -218,14 +235,29 @@ class ShardedLMTrainer:
 
         ``valid``: per-DP-replica-row contributor mask of shape (dp,);
         None = all rows contribute.
+
+        One trace a step (``obs.trace``): the root ``trainer.step`` and a
+        child around each call that can wait, none of which adds a sync.
         """
-        xd, yd = self._place(tokens, labels)
-        vd = place_mask(normalize_valid(valid, self.dp), self._valid_sharding)
-        self.params, self.opt_state, *metrics = self._step(
-            self.params, self.opt_state, xd, yd, vd
-        )
-        # one fetch for all of the step's metrics, not one sync each
-        return self._metrics(jax.device_get(metrics))
+        span = obs_trace.span
+        with span("trainer.step", root=True, step=self.step_num + 1) as root:
+            valid = normalize_valid(valid, self.dp)
+            with span("trainer.step.place"):
+                xd, yd = self._place(tokens, labels)
+                vd = place_mask(valid, self._valid_sharding)
+            with span("trainer.step.dispatch"):  # returns when enqueued
+                self.params, self.opt_state, *metrics = self._step(
+                    self.params, self.opt_state, xd, yd, vd
+                )
+            with span("trainer.step.fetch"):
+                # one fetch for all of the step's metrics, not one sync each
+                values = jax.device_get(metrics)
+            out = self._metrics(values)
+        _STEPS.inc()
+        _TOKENS.inc(tokens.size)
+        _LOSS.set(out.loss)
+        _STEP_TIME.observe(root.dur)
+        return out
 
     def train(self, batches: Iterable) -> list:
         return [self.train_step(x, y) for x, y in batches]

@@ -1,0 +1,12 @@
+"""Over the window's slow steps (``slow_steps``), the sum of the time inside
+``trainer.step.fetch`` less that span's median over the window: how much
+longer the device, or its result's way back, made them. 0 where none is slow."""
+
+from harness.spec import load_module
+
+UNIT = "ms"
+
+
+def compute(record, trace):
+    spans = load_module("layer_metrics", "step_span_ms_p50")
+    return spans.slow_excess_ms(record, lambda s: spans.seconds(s["fetch"]))

@@ -997,7 +997,12 @@ def test_the_benchmark_lists_the_cell_where_the_issue_says():
         "step_ms_p50", "step_ms_p90", "host_gap_ms.train", "device_idle_pct.train",
         "attn_kernel_ms", "moe_gmm_ms", "moe_load_max_over_mean",
         "moe_row_buffer_fill_pct", "mfu_pct.swa", "attn_kernel_roofline_pct.swa",
-        "swa_kernel_ms", "swa_kernel_roofline_pct", "gqa_proj_ms", "gqa_around_kernel_ms"}
+        "swa_kernel_ms", "swa_kernel_roofline_pct", "gqa_proj_ms", "gqa_around_kernel_ms",
+        # PR 37: the readers of the spans inside ``train_step`` (every training
+        # cell) and of the step's ``optimizer`` scope (this cell and JoyAI's)
+        "step_span_ms_p50", "step_span_ms_p90", "host_gap_ms.around_run",
+        "host_gap_ms.caller", "host_gap_ms.place", "slow_steps",
+        "slow_step_excess_ms.fetch", "slow_step_excess_ms.host", "optimizer_own_pass_ms"}
     loaded = spec.load_cell(CELL)
     assert loaded.end_to_end == ["train_tokens_per_s", "setup_s"]
     assert set(loaded.per_layer) == listed | {"compile_or_load_s"}

@@ -175,6 +175,134 @@ class TestTrace:
         doc = json.loads(open(merged).read())
         assert {e["name"] for e in doc["traceEvents"]} == {"a.one", "b.two"}
 
+    def test_record_holds_its_start_on_perf_counter(self):
+        """``t0`` is the span's start on the clock a caller's own timings (and
+        the benchmark's window) run on; ``ts`` stays the wall clock."""
+        import time
+
+        before = time.perf_counter()
+        with trace.span("layer.timed"):
+            inside = time.perf_counter()
+        after = time.perf_counter()
+        (rec,) = trace.drain()
+        assert before <= rec["t0"] <= inside
+        assert rec["t0"] + rec["dur"] <= after
+        assert abs(rec["ts"] - time.time()) < 60  # epoch seconds, not perf_counter
+
+    def test_span_keeps_its_duration_sampled_or_not(self):
+        with trace.span("layer.kept") as s:
+            assert s.dur is None
+        assert s.dur == trace.drain()[0]["dur"]
+        with trace.use(trace.TraceContext(1, 2, sampled=False)):
+            with trace.span("x.skipped") as s:
+                pass
+        assert s.dur >= 0 and trace.drain() == []
+
+
+class _Notes:
+    """An annotator that keeps what was entered and left."""
+
+    def __init__(self):
+        self.log = []
+
+    def __call__(self, name):
+        notes = self
+
+        class _Note:
+            def __enter__(self):
+                notes.log.append(("enter", name))
+
+            def __exit__(self, *exc):
+                notes.log.append(("exit", name))
+
+        return _Note()
+
+
+class TestAnnotator:
+    """``set_annotator``: a recorded ``span()`` also runs inside
+    ``factory(name)`` (the trainers hand in the profiler's annotation)."""
+
+    def setup_method(self):
+        trace.drain()
+        self.before = trace._annotator
+
+    def teardown_method(self):
+        trace.set_annotator(self.before)
+
+    def test_span_enters_and_leaves_it_once(self):
+        notes = _Notes()
+        trace.set_annotator(notes)
+        with trace.span("layer.outer"):
+            assert notes.log == [("enter", "layer.outer")]
+            with trace.span("layer.inner"):
+                pass
+        assert notes.log == [
+            ("enter", "layer.outer"), ("enter", "layer.inner"),
+            ("exit", "layer.inner"), ("exit", "layer.outer"),
+        ]
+        assert [r["name"] for r in trace.drain()] == ["layer.inner", "layer.outer"]
+
+    def test_it_is_left_when_the_body_raises(self):
+        notes = _Notes()
+        trace.set_annotator(notes)
+        with pytest.raises(KeyError):
+            with trace.span("layer.fails"):
+                raise KeyError("x")
+        assert notes.log == [("enter", "layer.fails"), ("exit", "layer.fails")]
+        assert trace.current() is None
+
+    def test_unrecorded_spans_are_not_annotated(self):
+        notes = _Notes()
+        trace.set_annotator(notes)
+        trace.set_enabled(False)
+        try:
+            with trace.span("x.off"):
+                pass
+        finally:
+            trace.set_enabled(True)
+        with trace.use(trace.TraceContext(1, 2, sampled=False)):
+            with trace.span("x.unsampled"):
+                pass
+        s = trace.start_span("x.manual")  # start_span has no with-body to wrap
+        s.end()
+        assert notes.log == []
+
+    def test_with_none_set_nothing_changes(self):
+        trace.set_annotator(None)
+        with trace.span("layer.plain", tag=1):
+            pass
+        (rec,) = trace.drain()
+        assert set(rec) == {
+            "name", "ts", "t0", "dur", "trace_id", "span_id", "parent_id", "attrs",
+        }
+
+    def test_a_step_worth_of_spans_costs_microseconds(self):
+        """A loose guard (the measurement is the chip's A/B, CHANGES.md PR 37):
+        one root and three children, as ``train_step`` opens them, under the
+        profiler's annotation where jax is there, 1,000 times."""
+        import statistics
+        import time
+
+        try:
+            import jax
+
+            trace.set_annotator(jax.profiler.TraceAnnotation)
+        except ImportError:
+            trace.set_annotator(None)
+        costs = []
+        for i in range(1000):
+            t = time.perf_counter()
+            with trace.span("trainer.step", root=True, step=i):
+                with trace.span("trainer.step.place"):
+                    pass
+                with trace.span("trainer.step.dispatch"):
+                    pass
+                with trace.span("trainer.step.fetch"):
+                    pass
+            costs.append(time.perf_counter() - t)
+        assert len(trace.drain()) == 4000
+        assert statistics.median(costs) < 200e-6, statistics.median(costs)
+
 
 # --- flight recorder ----------------------------------------------------------
 

@@ -639,3 +639,150 @@ class TestFileDataset:
             np.savez(f, a=np.zeros(3))
         with pytest.raises(KeyError, match="lacks"):
             FileDataset(tmp_path / "bad.npz")
+
+
+# -- spans, counters and the optimizer's scope inside the sharded LM step -----
+
+
+_TINY_LM = dict(vocab=16, d_model=32, n_heads=4, n_layers=1, seq_len=16)
+_STEP_SPANS = (
+    "trainer.step.place", "trainer.step.dispatch", "trainer.step.fetch",
+    "trainer.step",
+)
+
+
+def _tiny_sharded_trainer(kind):
+    from akka_allreduce_tpu.parallel import data_seq_mesh
+    from akka_allreduce_tpu.train import LongContextTrainer, MoETrainer
+
+    if kind == "lm":
+        return LongContextTrainer(data_seq_mesh(2, 2), **_TINY_LM)
+    return MoETrainer(
+        jax.make_mesh((2, 2), ("data", "expert")), n_experts=4, **_TINY_LM
+    )
+
+
+def _lowered_step_text(trainer, tokens):
+    from akka_allreduce_tpu.train.trainer import normalize_valid, place_mask
+
+    xd, yd = trainer._place(tokens, tokens)
+    vd = place_mask(normalize_valid(None, trainer.dp), trainer._valid_sharding)
+    return trainer._step.lower(
+        trainer.params, trainer.opt_state, xd, yd, vd
+    ).as_text(dialect="hlo", debug_info=True)
+
+
+@pytest.fixture(scope="module", params=["lm", "moe"])
+def three_steps(request):
+    """Three host-loop steps of a tiny trainer: what they left in the span
+    buffer and how far they moved the registry."""
+    import types
+
+    from akka_allreduce_tpu.obs import trace
+    from akka_allreduce_tpu.obs.metrics import REGISTRY
+
+    trainer = _tiny_sharded_trainer(request.param)
+    tokens = np.random.default_rng(0).integers(0, 16, (4, 16)).astype(np.int32)
+
+    def read():
+        snap = REGISTRY.snapshot()
+        return (snap["trainer.steps"], snap["trainer.tokens"],
+                snap["trainer.step_time_s"]["count"],
+                snap["trainer.step_time_s"]["sum"])
+
+    trace.drain()
+    before = read()
+    out = [trainer.train_step(tokens, tokens) for _ in range(3)]
+    records = [r for r in trace.drain() if r["name"].startswith("trainer.step")]
+    moved = tuple(b - a for a, b in zip(before, read()))
+    return types.SimpleNamespace(
+        trainer=trainer, tokens=tokens, out=out, records=records, moved=moved,
+        last_loss=REGISTRY.snapshot()["trainer.loss"],
+    )
+
+
+class TestStepSpans:
+    def test_each_step_is_one_trace_of_a_root_and_three_children(self, three_steps):
+        out, records = three_steps.out, three_steps.records
+        assert [r["name"] for r in records] == list(_STEP_SPANS) * 3
+        for i in range(3):
+            place, dispatch, fetch, root = records[4 * i: 4 * i + 4]
+            assert root["parent_id"] == 0 and root["attrs"] == {"step": out[i].step}
+            for child in (place, dispatch, fetch):
+                assert child["trace_id"] == root["trace_id"]
+                assert child["parent_id"] == root["span_id"]
+        assert len({r["trace_id"] for r in records}) == 3
+
+    def test_children_lie_inside_the_root_in_order(self, three_steps):
+        records = three_steps.records
+        for i in range(3):
+            place, dispatch, fetch, root = records[4 * i: 4 * i + 4]
+            end = lambda r: r["t0"] + r["dur"]  # noqa: E731
+            assert root["t0"] <= place["t0"] <= end(place) <= dispatch["t0"]
+            assert end(dispatch) <= fetch["t0"] <= end(fetch) <= end(root)
+        roots = [r for r in records if r["name"] == "trainer.step"]
+        assert all(a["t0"] + a["dur"] <= b["t0"] for a, b in zip(roots, roots[1:]))
+
+    def test_counters_are_written_where_the_step_runs(self, three_steps):
+        steps, seen, timed, seconds = three_steps.moved
+        assert (steps, seen, timed) == (3, 3 * three_steps.tokens.size, 3)
+        roots = [r["dur"] for r in three_steps.records if r["name"] == "trainer.step"]
+        assert seconds == pytest.approx(sum(roots), rel=1e-9)
+        assert three_steps.last_loss == three_steps.out[-1].loss
+
+    def test_recording_off_leaves_no_span_and_keeps_the_counters(self, three_steps):
+        from akka_allreduce_tpu.obs import trace
+        from akka_allreduce_tpu.obs.metrics import REGISTRY
+
+        trainer, tokens = three_steps.trainer, three_steps.tokens
+        before = REGISTRY.snapshot()["trainer.steps"]
+        trace.set_enabled(False)
+        try:
+            trainer.train_step(tokens, tokens)
+        finally:
+            trace.set_enabled(True)
+        assert not [r for r in trace.drain() if r["name"].startswith("trainer.")]
+        assert REGISTRY.snapshot()["trainer.steps"] == before + 1
+
+    def test_the_profiler_gets_the_spans_too(self, three_steps):
+        from akka_allreduce_tpu.obs import trace
+
+        assert trace._annotator is jax.profiler.TraceAnnotation
+
+    def test_optimizer_scope_is_metadata_and_nothing_else(
+        self, three_steps, monkeypatch
+    ):
+        """The lowered step names Adam's ops ``optimizer/...``; with every
+        instruction's ``metadata={...}`` taken out it is the text of the same
+        step lowered with no named scope at all."""
+        import contextlib
+        import re
+
+        trainer, tokens = three_steps.trainer, three_steps.tokens
+        scoped = _lowered_step_text(trainer, tokens)
+        names = re.findall(r'op_name="([^"]*)"', scoped)
+        assert any(re.search(r"(?:^|/)optimizer(?:/|$)", n) for n in names)
+        monkeypatch.setattr(
+            jax, "named_scope", lambda name: contextlib.nullcontext()
+        )
+        kind = "lm" if type(trainer).__name__ == "LongContextTrainer" else "moe"
+        bare = _lowered_step_text(_tiny_sharded_trainer(kind), tokens)
+        assert not re.search(r'op_name="[^"]*optimizer', bare)
+
+        def instructions(text):
+            """Without each instruction's metadata and the tables of source
+            locations it points into, and with the instructions numbered in
+            their order: XLA names one after its ``op_name``'s last part."""
+            blocks = re.sub(r",? ?metadata=\{[^}]*\}", "", text).split("\n\n")
+            tables = ("FileNames", "FunctionNames", "FileLocations", "StackFrames")
+            body = "\n\n".join(
+                b for b in blocks if not b.lstrip().startswith(tables)
+            )
+            defined = re.findall(r"^\s*(?:ROOT )?([\w.\-]+) = ", body, flags=re.M)
+            number = {n: f"v{i}" for i, n in enumerate(dict.fromkeys(defined))}
+            return re.sub(
+                r"[\w.\-]+", lambda m: number.get(m.group(0), m.group(0)), body
+            ).splitlines()
+
+        assert len(instructions(scoped)) > 1000
+        assert instructions(scoped) == instructions(bare)
