@@ -7,19 +7,50 @@ config 5) the TPU build provides the trainer-layer equivalent: Orbax
 checkpoints of ``{params, opt_state, step}``, plus a zero-copy in-memory
 snapshot used by the elastic re-mesh path (SURVEY.md §8.4 — "checkpoint-in-HBM
 → reinit mesh → resume").
+
+``orbax.checkpoint`` is NOT imported with this module: it is 12-13 s of a
+process start on the chip's host (11-12 of them ``google.cloud.logging``;
+PERF.md section 6, PR 38) that only :class:`TrainerCheckpointer` and its
+async subclass use, and every trainer enters through
+``akka_allreduce_tpu.train``. :func:`_orbax` loads it, once a
+process, when the first of them is CONSTRUCTED — not at the first ``save``,
+which must not stall by those seconds and whose writer thread must find the
+library loaded. The load runs under the span ``checkpoint.import_orbax`` and
+leaves its seconds in the gauge ``checkpoint.orbax_import_s`` (absent in a
+process that built no Orbax checkpointer). The delta store, ``Snapshot`` and
+the capture / placement helpers never touch it.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import threading
 from pathlib import Path
 from typing import Any
 
 import jax
 import numpy as np
-import orbax.checkpoint as ocp
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
+
+from akka_allreduce_tpu.obs import metrics as obs_metrics
+from akka_allreduce_tpu.obs import trace as obs_trace
+
+_ocp = None
+_ocp_lock = threading.Lock()
+
+
+def _orbax():
+    """``orbax.checkpoint``, imported by the first call of a process (module
+    docstring)."""
+    global _ocp
+    with _ocp_lock:
+        if _ocp is None:
+            with obs_trace.span("checkpoint.import_orbax") as s:
+                import orbax.checkpoint as ocp
+            obs_metrics.gauge("checkpoint.orbax_import_s").set(s.dur)
+            _ocp = ocp
+    return _ocp
 
 
 def state_shardings(trainer) -> tuple[Any, Any]:
@@ -179,15 +210,11 @@ class _BackgroundWriter:
     re-raised on the next ``busy``/``save``/``restore``/``close``."""
 
     def _writer_init(self) -> None:
-        import threading
-
         self._lock = threading.Lock()  # serializes store access
-        self._inflight: "threading.Thread | None" = None
+        self._inflight: threading.Thread | None = None
         self._errors: list = []
 
     def _launch(self, write, name: str) -> None:
-        import threading
-
         def guarded():
             try:
                 write()
@@ -304,6 +331,7 @@ class TrainerCheckpointer:
 
     def __init__(self, directory: str | Path, *, max_to_keep: int = 3) -> None:
         self.directory = Path(directory).absolute()
+        ocp = _orbax()
         self._mgr = ocp.CheckpointManager(
             self.directory,
             options=ocp.CheckpointManagerOptions(
@@ -319,7 +347,9 @@ class TrainerCheckpointer:
         state, _ = capture_state(trainer)
         state["step"] = trainer.step_num
         saved = self._mgr.save(
-            trainer.step_num, args=ocp.args.StandardSave(state), force=force
+            trainer.step_num,
+            args=_orbax().args.StandardSave(state),
+            force=force,
         )
         self._mgr.wait_until_finished()
         return bool(saved)
@@ -362,7 +392,7 @@ class TrainerCheckpointer:
                         target.pop(k)
             try:
                 restored = self._mgr.restore(
-                    step, args=ocp.args.StandardRestore(target)
+                    step, args=_orbax().args.StandardRestore(target)
                 )
             except Exception as e:
                 if (
@@ -392,7 +422,7 @@ class TrainerCheckpointer:
         if has_ef:
             target["ef"] = trainer._ef
         restored = self._mgr.restore(
-            step, args=ocp.args.StandardRestore(target)
+            step, args=_orbax().args.StandardRestore(target)
         )
         # Orbax may hand back single-device arrays; re-place onto the
         # trainer's CURRENT layout — replicated for plain DP, per-leaf
@@ -698,7 +728,7 @@ class AsyncTrainerCheckpointer(_BackgroundWriter, TrainerCheckpointer):
             state["step"] = step
             with self._lock:
                 self._mgr.save(
-                    step, args=ocp.args.StandardSave(state), force=force
+                    step, args=_orbax().args.StandardSave(state), force=force
                 )
                 self._mgr.wait_until_finished()
 

@@ -9,9 +9,12 @@ This is the CPU recipe: ``JAX_PLATFORMS=cpu``, 8 virtual devices, Pallas kernels
 interpret mode (ops/_platform.py), and no persistent compile cache.
 """
 
+import json
 import os
 import signal
+import subprocess
 import sys
+import textwrap
 
 import pytest
 
@@ -62,3 +65,61 @@ def pytest_runtest_call(item):
     finally:
         signal.alarm(0)
         signal.signal(signal.SIGALRM, old)
+
+
+#: top-level packages that only the Orbax checkpointer needs (PERF.md
+#: section 5, set-up): seconds of a start, so no way into the package loads
+#: them; ``TrainerCheckpointer``'s construction does
+DEFERRED_IMPORTS = ("orbax", "tensorstore", "google.cloud")
+
+_FRESH_REPORT = textwrap.dedent(
+    """
+    import json, sys
+    from akka_allreduce_tpu.obs import metrics, trace
+    print(json.dumps({
+        "deferred": sorted(
+            m for m in set(sys.modules) - at_start
+            if any(m == p or m.startswith(p + ".") for p in %r)
+        ),
+        "import_s": metrics.REGISTRY.snapshot().get(
+            "checkpoint.orbax_import_s"
+        ),
+        "spans": sum(
+            r["name"] == "checkpoint.import_orbax" for r in trace.snapshot()
+        ),
+        "extra": extra,
+    }))
+    """
+    % (DEFERRED_IMPORTS,)
+)
+
+
+@pytest.fixture
+def run_fresh():
+    """``run_fresh(body, *argv)``: run ``body`` in a NEW interpreter on the
+    CPU (this one has long since imported everything) and return what it
+    left behind: ``deferred``, the modules of ``DEFERRED_IMPORTS`` that
+    ``body`` loaded (``site`` leaves the bare namespace packages ``google``
+    and ``google.cloud`` in ``sys.modules`` before any import, so only what
+    was added counts); ``import_s``, the gauge
+    ``checkpoint.orbax_import_s`` or None; ``spans``, how many
+    ``checkpoint.import_orbax`` spans ended; ``extra``, whatever JSON-ready
+    value ``body`` bound to that name."""
+
+    def run(body: str, *argv: str) -> dict:
+        script = (
+            "import sys\nat_start = set(sys.modules)\nextra = None\n"
+            + textwrap.dedent(body)
+            + _FRESH_REPORT
+        )
+        out = subprocess.run(
+            [sys.executable, "-c", script, *argv],
+            cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            capture_output=True,
+            text=True,
+            timeout=240,
+        )
+        assert out.returncode == 0, out.stderr[-4000:]
+        return json.loads(out.stdout.strip().splitlines()[-1])
+
+    return run

@@ -20,6 +20,72 @@ def make_trainer(mesh, seed=0):
     )
 
 
+#: a small trainer after one step, for the fresh-interpreter tests
+#: (``run_fresh``, conftest.py): what this file's ``make_trainer`` builds
+_FRESH_TRAINER = """
+    import numpy as np, optax
+    from akka_allreduce_tpu.models import MLP, data
+    from akka_allreduce_tpu.parallel import line_mesh
+
+    def make_trainer(seed=0):
+        from akka_allreduce_tpu.train import DPTrainer
+        return DPTrainer(
+            MLP(hidden=(16,), classes=10), line_mesh(8),
+            example_input=np.zeros((1, 28, 28, 1), np.float32),
+            optimizer=optax.adam(1e-3), seed=seed,
+        )
+
+    t = make_trainer()
+    t.train(data.mnist_like().batches(32, 1))
+    ref = t.get_flat_params().copy()
+"""
+
+
+_ASYNC_FIRST_SAVE = _FRESH_TRAINER + """
+    import threading
+    from akka_allreduce_tpu.obs import metrics
+    from akka_allreduce_tpu.train import AsyncTrainerCheckpointer
+    from akka_allreduce_tpu.train import checkpoint as ckpt_mod
+    assert "orbax.checkpoint" not in sys.modules
+    ckpt = AsyncTrainerCheckpointer(sys.argv[1])
+    built = metrics.gauge("checkpoint.orbax_import_s").value
+    callers = []
+    real = ckpt_mod._orbax
+    def spy():
+        callers.append(threading.current_thread().name)
+        return real()
+    ckpt_mod._orbax = spy
+    assert ckpt.save(t)
+    ckpt.wait_until_finished()
+    fresh = make_trainer(seed=3)
+    step = ckpt.restore(fresh)
+    ckpt.close()
+    extra = {
+        "built_import_s": built,
+        "callers": callers,
+        "step": step,
+        "equal": bool((fresh.get_flat_params() == ref).all()),
+    }
+"""
+
+_DELTA_WITHOUT_ORBAX = _FRESH_TRAINER + """
+    from akka_allreduce_tpu.train import DeltaCheckpointer, Snapshot
+    store = DeltaCheckpointer(sys.argv[1])
+    stats = store.save(t)
+    snap = Snapshot.capture(t)
+    fresh = make_trainer(seed=3)
+    step = store.restore(fresh)
+    from_snap = make_trainer(seed=4)
+    snap.restore_into(from_snap)
+    extra = {
+        "written": stats["written_leaves"],
+        "step": step,
+        "equal": bool((fresh.get_flat_params() == ref).all()),
+        "snap_equal": bool((from_snap.get_flat_params() == ref).all()),
+    }
+"""
+
+
 def _flat_tree(tree) -> np.ndarray:
     import jax
 
@@ -285,6 +351,20 @@ class TestAsyncCheckpointer:
             step = ckpt.restore(fresh)
         assert step == 2
         np.testing.assert_array_equal(fresh.get_flat_params(), ref)
+
+    def test_first_save_from_the_writer_thread_imports_nothing(
+        self, tmp_path, run_fresh
+    ):
+        """Orbax is loaded when the checkpointer is BUILT (train/checkpoint.py
+        ``_orbax``): the first save, on the writer thread, finds it there."""
+        got = run_fresh(_ASYNC_FIRST_SAVE, str(tmp_path / "a"))
+        extra = got["extra"]
+        assert extra["step"] == 1 and extra["equal"]
+        # the save's use of the library ran on the writer thread ...
+        assert extra["callers"][0] == "ckpt-save-1"
+        # ... and found it loaded: one import, timed at construction
+        assert got["spans"] == 1
+        assert got["import_s"] == extra["built_import_s"] > 0
 
     def test_second_save_skipped_while_busy(self, tmp_path, monkeypatch):
         import threading
@@ -743,6 +823,15 @@ class TestDeltaCheckpointer:
         fresh = make_trainer(line_mesh(8), seed=3)
         assert store.restore(fresh, 1) == 1
         np.testing.assert_array_equal(fresh.get_flat_params(), ref)
+
+    def test_roundtrip_in_a_process_that_never_loads_orbax(
+        self, tmp_path, run_fresh
+    ):
+        got = run_fresh(_DELTA_WITHOUT_ORBAX, str(tmp_path / "d"))
+        assert got["deferred"] == [] and got["import_s"] is None
+        extra = got["extra"]
+        assert extra["written"] > 0 and extra["step"] == 1
+        assert extra["equal"] and extra["snap_equal"]
 
     def test_partial_change_writes_only_delta(self, tmp_path):
         from akka_allreduce_tpu.train import DeltaCheckpointer
