@@ -2,7 +2,8 @@
 and expert feed-forward layers (the ``lfm2_moe`` family), latent-attention
 decoders with a shared expert and a multi-token-prediction module (the
 DeepSeek-V3 dialect, ``joyai_llm_flash``), and decoders that mix windowed and
-full attention at different head counts (the ``laguna`` dialect).
+full attention by the layer's kind, at one head count or at one per layer
+(the ``laguna`` dialect, which ``mellum`` writes too).
 
 Layer ``i``:  ``h = x + Op_i(RMSNorm(x))``,  ``y = h + FF_i(RMSNorm(h))``.
 ``Op_i`` is a gated short convolution where ``layer_types[i] == "conv"``,
@@ -409,15 +410,17 @@ class HybridDecoderLM(nn.Module):
     def from_config(cls, cfg: dict, **overrides) -> "HybridDecoderLM":
         """From the keys of a ``config.json``, in the dialect its keys are
         of: DeepSeek-V3's where it has ``kv_lora_rank`` (``deepseek_v3``,
-        ``joyai_llm_flash``), ``laguna``'s where it has
-        ``num_attention_heads_per_layer``, else ``lfm2_moe``'s. The key that counts the
-        experts (``num_experts`` / ``n_routed_experts``) counts the experts
-        HELD when ``router_num_experts`` states the model's own count beside
-        it (a chip's share, ``held_experts`` its ids); otherwise all experts
-        are held."""
+        ``joyai_llm_flash``); ``laguna``'s where ``rope_parameters`` holds its
+        rules under the kinds of ``layer_types`` (``laguna``, ``mellum``:
+        :func:`_from_laguna` says which keys may be absent); else
+        ``lfm2_moe``'s. The key that counts the experts (``num_experts`` /
+        ``n_routed_experts``) counts the experts HELD when
+        ``router_num_experts`` states the model's own count beside it (a
+        chip's share, ``held_experts`` its ids); otherwise all experts are
+        held."""
         if "kv_lora_rank" in cfg:
             read = _from_deepseek_v3_keys
-        elif "num_attention_heads_per_layer" in cfg:
+        elif _rope_by_layer_kind(cfg):
             read = _from_laguna
         else:
             read = _from_lfm2_moe
@@ -426,6 +429,17 @@ class HybridDecoderLM(nn.Module):
             raise ValueError("layer_types and num_hidden_layers disagree")
         kw.update(overrides)
         return cls(**kw)
+
+    def first_rung(self, tokens: int) -> int:
+        """The smallest row buffer an expert layer of this model takes at
+        ``tokens`` tokens a replica: the first of the ladder
+        ``moe_dropless_held`` builds from the same three sizes. What
+        ``buffer_rows`` reads while a layer's load is near the uniform one."""
+        from akka_allreduce_tpu.ops.moe import row_rungs
+
+        return row_rungs(
+            tokens * self.experts_per_token, self.held_count, self.num_experts
+        )[0]
 
     def _operator(self, kind: str, pre: str, index: int):
         dt = self.compute_dtype
@@ -609,15 +623,28 @@ def _from_deepseek_v3_keys(cfg: dict) -> dict:
     )
 
 
+def _rope_by_layer_kind(cfg: dict) -> bool:
+    """Does ``rope_parameters`` hold rules under the kinds of
+    ``layer_types`` (and not one rule for the model)?"""
+    rules = cfg.get("rope_parameters")
+    return isinstance(rules, dict) and any(
+        isinstance(rules.get(kind), dict) for kind in cfg.get("layer_types", ())
+    )
+
+
 def _from_laguna(cfg: dict) -> dict:
-    """``laguna``'s keys: grouped-query attention whose head count
-    (``num_attention_heads_per_layer``), mask (``layer_types``:
-    ``full_attention`` | ``sliding_attention`` under ``sliding_window``) and
-    rotary rule (``rope_parameters[kind]``: ``default`` | ``yarn``, a
-    ``partial_rotary_factor``) go by the layer, a per-head output gate
-    (``gating``), the leading ``dense`` feed-forwards of ``mlp_layer_types``
-    and then expert layers with softmax scores renormalised over the picks,
-    no selection bias, and one shared expert."""
+    """``laguna``'s keys, which ``mellum`` shares: grouped-query attention
+    whose mask (``layer_types``: ``full_attention`` | ``sliding_attention``
+    under ``sliding_window``) and rotary rule (``rope_parameters[kind]``:
+    ``default`` | ``yarn``, a ``partial_rotary_factor`` or the whole head) go
+    by the layer's kind, then expert layers with softmax scores renormalised
+    over the picks and no selection bias. Four keys may be absent, each
+    meaning the plain form: ``num_attention_heads_per_layer`` (absent:
+    ``num_attention_heads`` in every layer), ``gating`` (absent or false: no
+    output gate; true or "per-head": a sigmoid gate per head),
+    ``shared_expert_intermediate_size`` (absent or 0: no shared expert),
+    ``moe_routed_scaling_factor`` (absent: 1); and ``mlp_layer_types`` may
+    be ``sparse`` throughout (no leading dense feed-forward)."""
     refused = {
         "attention_bias": False, "tie_word_embeddings": False,
         "moe_apply_router_weight_on_input": False,
@@ -626,23 +653,24 @@ def _from_laguna(cfg: dict) -> dict:
     for key, built in refused.items():
         if cfg.get(key, built) != built:
             raise ValueError(f"{key} = {cfg[key]!r} is not built (only {built!r})")
-    if cfg.get("gating") not in (True, "per-head") or any(
+    gating = cfg.get("gating", False)
+    if gating not in (False, True, "per-head") or any(
         g != "per_head" for g in cfg.get("gating_types", ())
     ):
         raise ValueError(
-            f"gating = {cfg.get('gating')!r} / {cfg.get('gating_types')!r} is "
-            "not built (only a gate per head)"
+            f"gating = {gating!r} / {cfg.get('gating_types')!r} is not built "
+            "(a gate per head, or none)"
         )
     program = cfg.get("program", {})
     if program.get("remat"):
         raise ValueError(f"program.remat {program['remat']!r}: recomputation is not built")
     layers, kinds = int(cfg["num_hidden_layers"]), tuple(cfg["layer_types"])
-    heads = tuple(int(h) for h in cfg["num_attention_heads_per_layer"])
+    heads = tuple(int(h) for h in cfg.get("num_attention_heads_per_layer", ()))
     mlps = list(cfg["mlp_layer_types"])
     dense = mlps.index("sparse") if "sparse" in mlps else len(mlps)
     if mlps != ["dense"] * dense + ["sparse"] * (len(mlps) - dense):
         raise ValueError(f"mlp_layer_types {mlps}: dense layers lead, sparse ones follow")
-    if not len(heads) == len(mlps) == layers:
+    if len(mlps) != layers or len(heads) not in (0, layers):
         raise ValueError("the per-layer lists and num_hidden_layers disagree")
     head_dim = int(cfg["head_dim"])
     rules = []
@@ -678,7 +706,7 @@ def _from_laguna(cfg: dict) -> dict:
         held_first=first, held_count=count,
         norm_eps=float(cfg["rms_norm_eps"]),
         sliding_window=int(cfg["sliding_window"]),
-        rope_by_kind=tuple(rules), attn_gate=True,
+        rope_by_kind=tuple(rules), attn_gate=bool(gating),
         use_select_bias=False, router_score="softmax",
         renormalise=bool(cfg.get("norm_topk_prob", True)),
         routed_scale=float(cfg.get("moe_routed_scaling_factor", 1.0)),

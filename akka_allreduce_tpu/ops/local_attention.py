@@ -241,21 +241,26 @@ def _splash_blocks(
     fits = max(d, dv or d) <= 128 or itemsize <= 2
     b = 1024 if t % 1024 == 0 and fits else 512
     fused = True
-    if window is not None:
-        # Under a band the tiles are the window's own size (in 512s), and
-        # where that leaves a K/V block no more than two q blocks the
-        # backward is the library's TWO kernels: the fused one runs its whole
-        # (K/V block, q block) grid whatever the mask and writes a zero
-        # ``dq`` partial of q's size for every K/V block the band leaves
-        # empty - 16 of 134 MB a layer at (T 8192, 64 heads of 128, window
-        # 512) - which XLA then sums; ``dkv`` and ``dq`` apart run shrunk
-        # grids and write no partial. Swept there (CHANGES.md, PR 35; forward
-        # / forward + backward ms a layer): 512 tiles 3.2 / 11.0 two-kernel
-        # and 16.9 fused; 1024 tiles 4.5 / 16.2 and 16.7; 256 tiles 4.8 /
-        # 15.0 and 34.6; a 256-row compute block lost 0.7 ms at 512. A wider
-        # window than a tile keeps the fused backward: not measured.
-        b = min(b, -(-window // 512) * 512)
-        fused = window > b
+    if window is not None and window <= 1024:
+        # Under a band of up to 1,024 keys 512 tiles and the library's TWO
+        # backward kernels: the grid visits every tile the band touches, so
+        # the share of visited pairs the mask leaves is about window / (window
+        # + tile) (gauges ``attention.band.*``, :func:`_gauge_band`), and the
+        # fused backward runs its whole (K/V block, q block) grid whatever the
+        # mask and writes a zero ``dq`` partial of q's size for every K/V
+        # block the band leaves empty, which XLA then sums; ``dkv`` and ``dq``
+        # apart run shrunk grids and write no partial. Swept on a v5e at T
+        # 8192, head 128 (forward / forward + backward ms a layer). Window 512,
+        # 64 heads on 8 (CHANGES.md, PR 35): 512 tiles 3.2 / 11.0 two-kernel
+        # and 16.9 fused; 1024 tiles 4.5 / 16.2 and 16.7; 256 tiles 4.8 / 15.0
+        # and 34.6. Window 1024, 32 heads on 4 (CHANGES.md, PR 39): 512 tiles
+        # 2.10 / 7.12 two-kernel and 9.23 fused; 1024 tiles 2.35 / 8.02 and
+        # 7.89 (there the band is a tile wide and the fused grid has little to
+        # skip). A 256-row compute block lost at every tile (0.5 ms at 512).
+        # A window past 1,024 keeps the tiles and the fused backward of no
+        # window: not swept, and at 1,024 the fused backward on wide tiles
+        # already tied.
+        b, fused = 512, False
     return BlockSizes(
         block_q=b, block_kv=b, block_kv_compute=512,
         block_q_dkv=b, block_kv_dkv=b, block_kv_dkv_compute=b,
@@ -289,10 +294,36 @@ def _splash_kernel(
     # the tables become device arrays inside the library; built under a
     # trace they would be that trace's tracers, and the cache would leak them
     with jax.ensure_compile_time_eval():
-        return make_splash_mha(
+        kernel = make_splash_mha(
             MultiHeadMask([mask] * h), head_shards=1, q_seq_shards=1,
             block_sizes=blocks, interpret=interpret,
         )
+        if window is not None:
+            _gauge_band(mask, kernel.fwd_mask_info, blocks.block_q, blocks.block_kv)
+    return kernel
+
+
+def _gauge_band(mask, info, block_q: int, block_kv: int) -> None:
+    """What the band's tiles run of masked-out pairs, as the forward
+    kernel's own block tables say it (a head's; every head has the same):
+    gauges ``attention.band.visited_pairs`` ((query, key) pairs of the blocks
+    the grid visits) and ``.mask_pairs`` (those of them the mask leaves: a
+    full block's all, a partial block's counted on the mask). Once a shape, at
+    the kernel's build (OBSERVABILITY.md)."""
+    import numpy as np
+
+    from akka_allreduce_tpu.obs import metrics as obs_metrics
+
+    block, at = np.asarray(info.block_mask)[0], np.asarray(info.data_next)[0]
+    visited = inside = 0
+    for i, s in zip(*np.nonzero(block)):
+        j = int(at[i, s])  # the K/V block this slot of the shrunk grid reads
+        visited += block_q * block_kv
+        inside += block_q * block_kv if block[i, s] == 2 else int(np.sum(
+            mask[i * block_q:(i + 1) * block_q, j * block_kv:(j + 1) * block_kv]
+        ))
+    obs_metrics.gauge("attention.band.visited_pairs").set(visited)
+    obs_metrics.gauge("attention.band.mask_pairs").set(inside)
 
 
 def _splash_heads_first(

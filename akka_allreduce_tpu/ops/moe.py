@@ -425,10 +425,21 @@ def grouped_tiles(
     them real: two experts meet in every 512-row tile and the kernel visits
     it twice), 256 rows and the widest tiles up to 1024 that divide the
     expert's matrix - (256, 1024, 768) at 2048 x 768 - which took 39 % off
-    one layer's products there, in both kinds (CHANGES.md, PR 32)."""
+    one layer's products there, in both kinds (CHANGES.md, PR 32). Where a
+    side of the expert's matrix is no multiple of the swept tile (2304 x 896:
+    eighteen and seven 128-lanes), the widest tile up to 1024 that divides
+    it in that tile's place - (512, 768, 896) and (512, 896, 768) - so that
+    no tile is a remainder: 18 % off the forward and 21 % off the backward
+    of one layer's products at 20,480 rows, 1,024 real rows an expert
+    (CHANGES.md, PR 39; 1152-wide tiles and 1024-row ones overran VMEM)."""
     want = {"gmm": GMM_TILING, "tgmm": TGMM_TILING}[kind]
     if m // groups < want[0]:
         want = (256, _fit(k, 1024, want[1]), _fit(n, 1024, want[2]))
+    else:
+        want = (want[0],) + tuple(
+            t if side % t == 0 else _fit(side, 1024, t)
+            for side, t in ((k, want[1]), (n, want[2]))
+        )
     tm = next((t for t in (want[0], 256, 128) if t <= want[0] and m % t == 0), None)
     if tm is None:
         raise ValueError(
@@ -626,8 +637,9 @@ def _rung_backward(x, weights, w1, w3, w2, route, kept, g, *, rows, keep, impl):
 def _on_rung(rungs, route, fn, impl, *operands):
     # a rung past the first is the exception (a balanced router never takes
     # it) and multiplies through ``lax.ragged_dot``, within 12 % of the
-    # kernels at every fill (PERF.md, PR 28): a second set of Mosaic bodies
-    # would cost every start's set-up for it (PERF.md, PR 29)
+    # kernels at every fill at LFM2's shape (PERF.md, PR 28; 1.7 to 2.7 times
+    # them at 2304 x 896, PR 39): a second set of Mosaic bodies would cost
+    # every start's set-up for it (PERF.md, PR 29)
     each = [functools.partial(fn, rows=rungs[0], keep=rungs[0], impl=impl)] + [
         functools.partial(fn, rows=r, keep=rungs[0], impl="ragged_dot")
         for r in rungs[1:]

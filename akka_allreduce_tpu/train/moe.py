@@ -27,7 +27,15 @@ from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
 from akka_allreduce_tpu.comm.allreduce import validate_trainer_compress
+from akka_allreduce_tpu.obs import metrics as obs_metrics
 from akka_allreduce_tpu.train.sharded_lm import ShardedLMTrainer, step_check_vma
+
+
+# written by MoETrainer.train_step from the step's own ``MoEStepMetrics``,
+# for a model that reports the rows routed (OBSERVABILITY.md)
+_ROUTED_ROWS = obs_metrics.counter("trainer.moe.routed_rows")
+_BUFFER_ROWS = obs_metrics.counter("trainer.moe.buffer_rows")
+_PAST_FIRST_RUNG = obs_metrics.counter("trainer.moe.layers_past_first_rung")
 
 
 @dataclasses.dataclass
@@ -262,6 +270,23 @@ class MoETrainer(ShardedLMTrainer):
                 pallas_grouped=getattr(self.model, "held_count", 0) > 0,
             ),
         )
+
+    def train_step(self, tokens, labels, valid=None) -> MoEStepMetrics:
+        """The skeleton's step; where the model holds experts and reports the
+        rows routed to them (``models.hybrid_decoder``), three counters move
+        by what the step fetched anyway: the rows routed to held experts, the
+        rows of the row buffers moved for them, and the expert layers whose
+        buffer was larger than the model's ``first_rung`` at a replica's
+        tokens (summed over the replicas, so a layer counts where any replica
+        left the rung)."""
+        out = super().train_step(tokens, labels, valid)
+        first_rung = getattr(self.model, "first_rung", None)
+        if first_rung is not None and out.buffer_rows is not None:
+            first = first_rung(tokens.size // self.dp) * self.dp
+            _ROUTED_ROWS.inc(float(out.expert_rows.sum()))
+            _BUFFER_ROWS.inc(float(out.buffer_rows.sum()))
+            _PAST_FIRST_RUNG.inc(int((out.buffer_rows > first).sum()))
+        return out
 
     def train_chain(
         self, sampler, steps: int, rows_per_device: int, *, valid=None, seed=0

@@ -231,6 +231,8 @@ def test_dropless_under_skew(cfg, favoured):
     (8192 * 4, 56, 64, (32768,)),  # a quarter over the expectation is all of it
     (1152, 2, 16, (256, 1152)),
     (1152, 4, 16, (384, 1152)),
+    # the Mellum2 cell's shape: a quarter held, the last 3.2 times the first
+    (8192 * 8, 16, 64, (20480, 65536)),
     (1024, 4, 16, (512, 1024)),  # the tile is the kernels' at this buffer
     (256, 4, 16, (256,)),
     (100, 1, 16, (100,)),  # no kernel tiles 100 rows

@@ -1,10 +1,14 @@
-"""The configuration-built decoder that mixes windowed and full attention at
-different head counts (``models/hybrid_decoder.py`` in the ``laguna`` dialect:
-a per-head output gate, YaRN on a part of each head, a softmax router, a
-shared expert), ``local_attention`` under a window in all three cores and
-``rope``'s partial rotation and YaRN, against the benchmark's plain reference
-``benchmarks/reference/laguna_moe_plain.py`` and against formulas written out
-here, at tiny widths on the CPU, on seeded weights."""
+"""The configuration-built decoder that mixes windowed and full attention by
+the layer's kind (``models/hybrid_decoder.py`` in the ``laguna`` dialect, in
+both of its presets: Laguna's - a head count per layer, a per-head output
+gate, YaRN on a part of each head, a shared expert, a leading dense layer -
+and Mellum2's - one head count, no gate, YaRN over the whole head, experts in
+every layer and nothing beside them), ``local_attention`` under a window in
+all three cores and ``rope``'s partial rotation and YaRN, against the
+benchmark's plain references ``benchmarks/reference/laguna_moe_plain.py`` and
+``mellum_moe_plain.py`` and against formulas written out here, at tiny widths
+on the CPU, on seeded weights. The tests that take ``cfg`` run on both
+presets."""
 
 from __future__ import annotations
 
@@ -29,11 +33,21 @@ from harness import spec, traffic  # noqa: E402
 
 ref = spec.load_module("reference", "laguna_moe_plain")
 runner = spec.load_module("runners", "laguna_moe_train")
+mellum_ref = spec.load_module("reference", "mellum_moe_plain")
+mellum_runner = spec.load_module("runners", "mellum_moe_train")
 
 TRAFFIC = {"batch": 2, "seq_len": 32, "tokens": "copy_half"}
 TINY = os.path.join(BENCH, "tests", "tiny_laguna_moe.json")
 REAL = os.path.join(BENCH, "configs", "laguna_xs2_d5.json")
 CELL = "laguna_xs2_train_b1_t8192"
+MELLUM_TINY = os.path.join(BENCH, "tests", "tiny_mellum_moe.json")
+MELLUM_REAL = os.path.join(BENCH, "configs", "mellum2_12b_d4.json")
+MELLUM_CELL = "mellum2_train_b1_t8192"
+#: by ``model_type``: the preset's reference, runner, cell and tiny file
+PRESETS = {
+    "laguna": (ref, runner, CELL, TINY),
+    "mellum": (mellum_ref, mellum_runner, MELLUM_CELL, MELLUM_TINY),
+}
 
 
 def _json(path):
@@ -41,9 +55,29 @@ def _json(path):
         return json.load(f)
 
 
+@pytest.fixture(scope="module", params=sorted(PRESETS))
+def cfg(request):
+    """Each preset's tiny configuration; ``use_expert_bias`` is the runners'
+    key for "no bias"."""
+    return dict(_json(PRESETS[request.param][3]), use_expert_bias=False)
+
+
 @pytest.fixture(scope="module")
-def cfg():
-    return dict(_json(TINY), use_expert_bias=False)  # the runner's key for "no bias"
+def laguna():
+    return dict(_json(TINY), use_expert_bias=False)
+
+
+def _ref(cfg):
+    return PRESETS[cfg["model_type"]][0]
+
+
+def _runner(cfg):
+    return PRESETS[cfg["model_type"]][1]
+
+
+def _heads(cfg, i):
+    per_layer = cfg.get("num_attention_heads_per_layer")
+    return per_layer[i] if per_layer else cfg["num_attention_heads"]
 
 
 def _close(got, want, tol=2e-5):
@@ -56,23 +90,24 @@ def _batches(cfg, seed, n=3):
 
 
 def _variables(cfg, seed):
-    return runner.to_program_tree(ref.init_params(cfg, seed), None, cfg)
+    return _runner(cfg).to_program_tree(_ref(cfg).init_params(cfg, seed), None, cfg)
 
 
 _TRAINERS: dict = {}
 
 
 def _trainer(cfg, seed):
-    """ONE trainer (one compile of the step) given the seed's weights anew."""
-    variables = _variables(cfg, seed)
-    if "one" not in _TRAINERS:
-        _TRAINERS["one"] = runner.build_trainer(
+    """ONE trainer a preset (one compile of its step) given the seed's
+    weights anew."""
+    variables, one = _variables(cfg, seed), cfg["model_type"]
+    if one not in _TRAINERS:
+        _TRAINERS[one] = _runner(cfg).build_trainer(
             cfg, TRAFFIC["seq_len"], variables, jax.devices()
         )
     else:
-        t = _TRAINERS["one"]
+        t = _TRAINERS[one]
         t.params, t.opt_state = variables, t.tx.init(variables)
-    return _TRAINERS["one"]
+    return _TRAINERS[one]
 
 
 # -- local_attention under a window ---------------------------------------------
@@ -180,6 +215,52 @@ def test_splash_blocks_without_a_window_are_todays(t, d, dv):
     assert banded.block_kv_dkv % banded.block_kv_dkv_compute == 0
 
 
+@pytest.mark.parametrize("window,tile,fused", [(1024, 512, False), (512, 512, False),
+                                               (None, 1024, True), (2048, 1024, True),
+                                               (4096, 1024, True)])
+def test_splash_blocks_under_a_band_are_the_sweeps_winners(window, tile, fused):
+    """At (T 8192, head 128): under the 1,024-wide band 512 tiles and the
+    two-kernel backward (PR 39's sweep: 2.10 / 7.12 ms a layer against 2.35 /
+    8.02 at 1024 tiles), at 512 and without a window what PR 35 and PR 31
+    chose; past 1,024 (unswept) what the rule returned before PR 39, the
+    tiles and the fused backward of no window."""
+    from akka_allreduce_tpu.ops.local_attention import _splash_blocks
+
+    b = _splash_blocks(8192, 128, 128, 2, window)
+    assert (b.block_q, b.block_kv, b.block_q_dkv, b.block_kv_dkv) == (tile,) * 4
+    assert (b.block_kv_compute, b.block_kv_dkv_compute) == (512, tile)
+    assert b.use_fused_bwd_kernel == fused
+    assert (b.block_q_dq, b.block_kv_dq) == ((None, None) if fused else (tile, tile))
+
+
+def test_the_band_kernels_build_states_what_its_tiles_run_of_the_mask():
+    """``attention.band.*``: at (T 8192, window 1024) a 512 tile visits three
+    K/V blocks a query block past the first two, two thirds of their pairs
+    inside the mask; counted from the kernel's own block tables, the mask's
+    pairs are ``sum_i min(i + 1, 1024)`` exactly. A kernel without a window
+    writes none."""
+    import importlib
+
+    from akka_allreduce_tpu.obs import metrics
+
+    # the package exports the function under the module's name
+    la = importlib.import_module("akka_allreduce_tpu.ops.local_attention")
+    band = lambda: {k.rsplit(".", 1)[1]: v for k, v in metrics.REGISTRY.snapshot().items()  # noqa: E731
+                    if k.startswith("attention.band.")}
+    for name in ("visited_pairs", "mask_pairs"):
+        metrics.gauge(f"attention.band.{name}").set(0)
+    la._splash_kernel(8192, 2, True, la._splash_blocks(8192, 128), True, None)
+    assert band() == {"visited_pairs": 0, "mask_pairs": 0}
+    la._splash_kernel(8192, 2, True, la._splash_blocks(8192, 128, 128, 2, 1024), True, 1024)
+    exact = sum(min(i + 1, 1024) for i in range(8192))
+    assert band() == {"visited_pairs": (16 * 3 - 3) * 512 * 512, "mask_pairs": exact}
+    assert 100 * exact / band()["visited_pairs"] == pytest.approx(66.67, abs=0.01)
+    # Laguna's band: two 512 tiles a query block, half inside
+    la._splash_kernel(8192, 2, True, la._splash_blocks(8192, 128, 128, 2, 512), True, 512)
+    assert band()["visited_pairs"] == (16 * 2 - 1) * 512 * 512
+    assert band()["mask_pairs"] == sum(min(i + 1, 512) for i in range(8192))
+
+
 @pytest.mark.parametrize("rows,held,experts,want", [
     (8192 * 4, 8, 64, (5120, 32768)), (8192 * 8, 8, 256, (2560, 10240, 65536)),
 ])
@@ -211,6 +292,27 @@ def test_rungs_and_tiles_at_this_cells_shape():
     assert rungs == (5120, 20480, 65536)
     assert grouped_tiles("gmm", rungs[0], 2048, 512, 16) == (256, 1024, 512)
     assert grouped_tiles("tgmm", rungs[0], 512, 2048, 16) == (256, 512, 1024)
+
+
+@pytest.mark.parametrize("kind", ["gmm", "tgmm"])
+def test_rungs_and_tiles_at_the_mellum2_cells_shape(kind):
+    """A quarter of 64 experts held at 8,192 tokens x 8 choices: the first
+    rung a quarter over the uniform load, the last 3.2 times it, under the
+    eight times that bring a rung between (on seeded weights this cell's
+    layers do flood past the first: the load's to cure, PERF.md, PR 39);
+    1,280 rows an expert, so the swept 512-row tile, and an expert of 2304 x
+    896 (eighteen and seven 128-lanes, no multiple of 512): the widest tiles
+    up to 1024 that divide it (PR 39's sweep), at either rung."""
+    from akka_allreduce_tpu.ops.moe import grouped_tiles, row_rungs
+
+    rungs = row_rungs(8192 * 8, 16, 64)
+    assert rungs == (20480, 65536)
+    for rows in rungs:
+        assert grouped_tiles(kind, rows, 2304, 896, 16) == (512, 768, 896)
+        assert grouped_tiles(kind, rows, 896, 2304, 16) == (512, 896, 768)
+    # an eighth held keeps two rungs, a share that holds everything one
+    assert row_rungs(8192 * 8, 8, 64) == (10240, 65536)
+    assert row_rungs(8192 * 8, 64, 64) == (65536,)
 
 
 # -- rope: default arguments, partial rotation, YaRN -------------------------------
@@ -283,13 +385,16 @@ def test_reference_rope_is_the_programs(cfg):
     from akka_allreduce_tpu.models.transformer import rope
 
     x = jax.random.normal(jax.random.PRNGKey(2), (1, 32, 2, 16))
-    model = runner.build_model(cfg)
+    model, ref = _runner(cfg).build_model(cfg), _ref(cfg)
     for r in model.rope_by_kind:
         got = rope(x, 0, base=r.theta, rotary_dim=r.rotary_dim, yarn=r.yarn,
                    attention_factor=r.attention_factor)
         _close(got, ref.rope(x, cfg["rope_parameters"][r.kind], cfg, ref.REFERENCE), 1e-6)
+    # Laguna turns half of a full layer's head, Mellum2 the whole of it
     assert {r.kind: r.rotary_dim for r in model.rope_by_kind} == {
-        "full_attention": 8, "sliding_attention": 16}
+        "full_attention": {"laguna": 8, "mellum": 16}[cfg["model_type"]],
+        "sliding_attention": 16}
+    assert [r.yarn is not None for r in model.rope_by_kind] == [True, False]
 
 
 _TURNS = {
@@ -360,9 +465,9 @@ def _attention_layer(cfg, i, seed=0, t=32):
     reference on the same seeded leaves."""
     from akka_allreduce_tpu.models.hybrid_decoder import HybridDecoderLM
 
-    model = HybridDecoderLM.from_config(cfg)
+    model, ref = HybridDecoderLM.from_config(cfg), _ref(cfg)
     s = ref.dims(cfg)
-    h, d, hd, kv = s["heads"][i], s["d"], s["hd"], s["kv"]
+    h, d, hd, kv = _heads(cfg, i), s["d"], s["hd"], s["kv"]
     k = jax.random.split(jax.random.PRNGKey(seed), 6)
     leaves = {
         "q.w": 0.2 * jax.random.normal(k[0], (d, h * hd)),
@@ -371,9 +476,12 @@ def _attention_layer(cfg, i, seed=0, t=32):
         "g.w": 0.5 * jax.random.normal(k[3], (d, h)),
         "o.w": 0.2 * jax.random.normal(k[4], (h * hd, d)),
     }
+    if not cfg.get("gating"):
+        del leaves["g.w"]
     x = jax.random.normal(k[5], (2, t, d))
     params = {n: {"kernel": leaves[f"{r}.w"]} for n, r in
-              (("q", "q"), ("k", "k"), ("v", "v"), ("gate", "g"), ("out", "o"))}
+              (("q", "q"), ("k", "k"), ("v", "v"), ("gate", "g"), ("out", "o"))
+              if f"{r}.w" in leaves}
     module, got = _operator_of(model, cfg["layer_types"][i], i, params, x)
     want = ref.attention(x, lambda n: leaves[n], i, cfg, ref.REFERENCE)
     return module, leaves, x, got, want
@@ -401,21 +509,26 @@ def _operator_of(model, kind, i, params, x):
     return seen["fields"], got
 
 
-@pytest.mark.parametrize("layer", [0, 1, 2])
+@pytest.mark.parametrize("layer", [0, 1, 2, 3])
 def test_attention_layer_matches_the_reference(cfg, layer):
-    """Full attention at 6 heads with YaRN on half of each head, windowed at
-    8 heads with the default rule: per-layer head counts, both masks, the
-    gate; T 32 is four windows deep."""
+    """Laguna's: full attention at 6 heads with YaRN on half of each head,
+    windowed at 8 heads with the default rule: per-layer head counts, both
+    masks, the gate. Mellum2's: three windowed layers and a full one at 8
+    heads, YaRN over the whole head, no gate. T 32 is four windows deep."""
+    if layer >= cfg["num_hidden_layers"]:
+        pytest.skip("the Laguna preset has three layers")
     module, leaves, x, got, want = _attention_layer(cfg, layer)
     _close(got, want, 1e-5)
-    assert module.n_heads == cfg["num_attention_heads_per_layer"][layer]
+    assert module.n_heads == _heads(cfg, layer)
+    assert module.gated == bool(cfg.get("gating")) == ("g.w" in leaves)
     assert (module.window == 8) == (cfg["layer_types"][layer] == "sliding_attention")
     assert module.scope_name == cfg["layer_types"][layer] and not module.qk_norm
 
 
-def test_the_gate_is_a_sigmoid_per_head_on_the_kernels_output(cfg):
+def test_the_gate_is_a_sigmoid_per_head_on_the_kernels_output(laguna):
     """With ``W_g`` zero every gate is a half; a column of ``W_g`` pushed far
     negative shuts that head and no other."""
+    cfg = laguna
     module, leaves, x, got, _ = _attention_layer(cfg, 1, seed=4)
     w = lambda n, over: over.get(n, leaves[n])  # noqa: E731
     by = lambda over: ref.attention(  # noqa: E731
@@ -438,7 +551,8 @@ def test_the_gate_is_a_sigmoid_per_head_on_the_kernels_output(cfg):
     _close(with_shut, without, 1e-5)  # head 3's rows of W_o (3 x 16 .. 4 x 16) see nothing
 
 
-def test_layers_of_a_model_differ_in_head_count(cfg):
+def test_layers_of_a_model_differ_in_head_count(laguna):
+    cfg = laguna
     two = dict(cfg, num_hidden_layers=2, layer_types=["full_attention", "sliding_attention"],
                mlp_layer_types=["dense", "sparse"], num_attention_heads_per_layer=[6, 8])
     model = runner.build_model(two)
@@ -451,6 +565,76 @@ def test_layers_of_a_model_differ_in_head_count(cfg):
     assert p["layers_0_attn"]["k"]["kernel"].shape == p["layers_1_attn"]["v"]["kernel"].shape
     assert "q_norm" not in p["layers_0_attn"] and "fixed" not in tree
     assert set(p["layers_1_moe"]) == {"router", "w1", "w2", "w3", "shared"}
+
+
+def test_mellum2_builds_no_gate_shared_or_mlp_leaf_and_no_gate_pass():
+    """``from_config`` on the Mellum2 preset: four leaves an attention layer,
+    four an expert layer, nothing else; the lowered forward and backward carry
+    the scopes of the layer's kind and none of a gate, a shared expert or a
+    dense MLP."""
+    cfg = _json(MELLUM_TINY)
+    model = mellum_runner.build_model(cfg)
+    tokens = jnp.zeros((1, 32), jnp.int32)
+    tree = jax.eval_shape(model.init, jax.random.PRNGKey(0), tokens)
+    assert set(tree) == {"params"}  # no ``fixed`` collection: no selection bias
+    p = tree["params"]
+    assert set(p) == {"embed", "head", "final_norm"} | {
+        f"layers_{i}_{m}" for i in range(4) for m in ("op_norm", "attn", "ffn_norm", "moe")}
+    for i in range(4):
+        assert set(p[f"layers_{i}_attn"]) == {"q", "k", "v", "out"}
+        assert set(p[f"layers_{i}_moe"]) == {"router", "w1", "w3", "w2"}
+        assert p[f"layers_{i}_attn"]["q"]["kernel"].shape == (64, 8 * 16)
+        assert p[f"layers_{i}_moe"]["router"].shape == (64, 16)
+    grad = jax.grad(lambda v: model.apply(v, tokens)[0].sum())
+    text = jax.jit(grad).lower(
+        jax.tree.map(lambda a: jnp.zeros(a.shape, a.dtype), tree)).as_text(debug_info=True)
+    for scope in ("sliding_attention/", "full_attention/", "attn_qkv/", "attn_core/",
+                  "attn_out/", "moe_route/", "moe_experts/", "moe_combine/"):
+        assert scope in text, scope
+    for absent in ("/gate/", "shared_expert", "_mlp/"):
+        assert absent not in text, absent
+    # the Laguna preset's lowering has all four: the probe can see them
+    laguna = _json(TINY)
+    model = runner.build_model(laguna)
+    tree = jax.eval_shape(model.init, jax.random.PRNGKey(0), tokens)
+    text = jax.jit(jax.grad(lambda v: model.apply(v, tokens)[0].sum())).lower(
+        jax.tree.map(lambda a: jnp.zeros(a.shape, a.dtype), tree)).as_text(debug_info=True)
+    for present in ("/gate/", "shared_expert", "_mlp/"):
+        assert present in text, present
+
+
+def test_the_laguna_files_tree_is_what_it_was():
+    """``laguna_xs2_d5.json`` through the reader that now takes Mellum2's keys
+    too: the same module fields and the same tree, leaf for leaf (names,
+    shapes, dtypes), as PR 35 built - written out here."""
+    real = _json(REAL)
+    model = runner.build_model(real)
+    assert (model.heads_per_layer, model.attn_gate, model.shared_width, model.num_dense_layers,
+            model.routed_scale, model.sliding_window) == ((48, 64, 64, 64, 48), True, 512, 1, 2.5, 512)
+    tree = jax.eval_shape(model.init, jax.random.PRNGKey(0), jnp.zeros((1, 16), jnp.int32))
+    assert set(tree) == {"params"}
+    flat = {"/".join(str(getattr(k, "key", k)) for k in path): (leaf.shape, str(leaf.dtype))
+            for path, leaf in jax.tree.leaves_with_path(tree["params"])}
+    want = {"embed/embedding": (12544, 2048), "head": (2048, 12544), "final_norm/scale": (2048,)}
+    for i, h in enumerate((48, 64, 64, 64, 48)):
+        pre = f"layers_{i}_"
+        want.update({
+            pre + "op_norm/scale": (2048,), pre + "ffn_norm/scale": (2048,),
+            pre + "attn/q/kernel": (2048, h * 128), pre + "attn/k/kernel": (2048, 1024),
+            pre + "attn/v/kernel": (2048, 1024), pre + "attn/gate/kernel": (2048, h),
+            pre + "attn/out/kernel": (h * 128, 2048)})
+        if i == 0:
+            want.update({pre + "mlp/w1/kernel": (2048, 8192), pre + "mlp/w3/kernel": (2048, 8192),
+                         pre + "mlp/w2/kernel": (8192, 2048)})
+        else:
+            want.update({
+                pre + "moe/router": (2048, 256), pre + "moe/w1": (16, 2048, 512),
+                pre + "moe/w3": (16, 2048, 512), pre + "moe/w2": (16, 512, 2048),
+                pre + "moe/shared/w1/kernel": (2048, 512),
+                pre + "moe/shared/w3/kernel": (2048, 512),
+                pre + "moe/shared/w2/kernel": (512, 2048)})
+    assert flat == {k: (v, "float32") for k, v in want.items()}
+    assert sum(int(np.prod(s)) for s, _ in flat.values()) == 490_297_344
 
 
 def test_lfm2s_attention_keeps_its_tree_and_scope():
@@ -495,19 +679,23 @@ def _parents_composition(m, params, x):
     return out.reshape(b, t, -1) @ params["out"]["kernel"]
 
 
-@pytest.mark.parametrize("which", ["lfm2", "laguna_full", "laguna_sliding"])
-def test_grouped_query_attention_equals_its_parents_composition(cfg, which):
+@pytest.mark.parametrize("which", ["lfm2", "laguna_full", "laguna_sliding",
+                                   "mellum_full", "mellum_sliding"])
+def test_grouped_query_attention_equals_its_parents_composition(which):
     """Heads-first from the products to ``W_o``, the scale in q's table:
     the same function of the same leaves as the sequence-first composition -
     output, the gradient of ``x`` and of every leaf to 1e-5 in f32, with
     per-head norms (LFM2's), with YaRN on half a head under
-    ``attention_factor`` and the gate, and under a window of 8."""
+    ``attention_factor`` and the gate, and under a window of 8 (Laguna's),
+    with YaRN over the whole head and no gate, full and windowed (Mellum2's)."""
     if which == "lfm2":
         config = _json(os.path.join(BENCH, "tests", "tiny_lfm2_moe.json"))
         model, kind, i = spec.load_module("runners", "moe_train").build_model(config), "full_attention", 1
     else:
-        model = runner.build_model(cfg)
-        kind, i = {"laguna_full": ("full_attention", 0), "laguna_sliding": ("sliding_attention", 1)}[which]
+        cfg = _json(MELLUM_TINY if which.startswith("mellum") else TINY)
+        model = _runner(cfg).build_model(cfg)
+        kind, i = {"laguna_full": ("full_attention", 0), "laguna_sliding": ("sliding_attention", 1),
+                   "mellum_full": ("full_attention", 3), "mellum_sliding": ("sliding_attention", 0)}[which]
     tree = jax.eval_shape(model.init, jax.random.PRNGKey(0), jnp.zeros((1, 16), jnp.int32))
     shapes = tree["params"][f"layers_{i}_attn"]
     leaves, treedef = jax.tree.flatten(shapes)
@@ -520,9 +708,14 @@ def test_grouped_query_attention_equals_its_parents_composition(cfg, which):
     module, got = _operator_of(model, kind, i, params, x)
     assert (module.qk_norm, module.gated, module.window) == {
         "lfm2": (True, False, None), "laguna_full": (False, True, None),
-        "laguna_sliding": (False, True, 8)}[which]
+        "laguna_sliding": (False, True, 8), "mellum_full": (False, False, None),
+        "mellum_sliding": (False, False, 8)}[which]
     if which == "laguna_full":
         assert module.rotary_dim == 8 and module.yarn and module.attention_factor > 1.4
+    if which == "mellum_full":
+        assert module.rotary_dim == 16 and module.yarn and module.attention_factor > 1.27
+    if which.startswith("mellum"):
+        assert set(shapes) == {"q", "k", "v", "out"}
     mine = lambda p, x: module.apply({"params": p}, x)  # noqa: E731
     _close(mine(params, x), got, 0.0)
     _close(got, _parents_composition(module, params, x), 1e-5)
@@ -602,11 +795,12 @@ def _program_layer(cfg, a, first, count, shared_width):
     return y, rows, dropped
 
 
-def test_the_shares_add_up_to_the_uncut_layer_with_the_shared_expert_once(cfg):
+def test_the_shares_add_up_to_the_uncut_layer_with_the_shared_expert_once(laguna):
     """16 experts in 4 shares: every share routes over all the experts by
     softmax scores and computes its own part; the parts of all shares, with
     the shared expert (which every share computes alike) counted once, are
     the whole layer."""
+    cfg = laguna
     a = _layer_inputs(cfg, seed=2)
     s = ref.dims(cfg)
     experts, per_share = s["experts"], 4
@@ -628,30 +822,81 @@ def test_the_shares_add_up_to_the_uncut_layer_with_the_shared_expert_once(cfg):
     _close(every - whole, 3 * shared, 1e-4)
 
 
+def test_the_four_shares_of_sixteen_add_up_to_the_uncut_layer_of_sixty_four():
+    """Mellum2's layer at its own counts (64 experts, 8 a token, 16 held a
+    share, tiny widths): every share routes over all 64 by softmax scores
+    renormalised over the picks, with no scale, and computes its own sixteen's
+    part; the four parts are the whole layer, every (token, choice) pair
+    counted once and nothing counted in every share: there is no shared
+    expert."""
+    from akka_allreduce_tpu.models.hybrid_decoder import HeldExperts
+
+    cfg = dict(_json(MELLUM_TINY), num_experts=64, router_num_experts=64,
+               num_experts_per_tok=8)
+    del cfg["held_experts"]
+    d, fe, experts, k, tokens = 64, 32, 64, 8, 96
+    key = jax.random.split(jax.random.PRNGKey(6), 5)
+    a = {"x": jax.random.normal(key[0], (1, tokens, d)),
+         "router.w": 0.3 * jax.random.normal(key[1], (d, experts)),
+         "experts.w1": 0.2 * jax.random.normal(key[2], (experts, d, fe)),
+         "experts.w3": 0.2 * jax.random.normal(key[3], (experts, d, fe)),
+         "experts.w2": 0.2 * jax.random.normal(key[4], (experts, fe, d))}
+
+    def reference(held):
+        w = lambda n: a[n][jnp.asarray(held)] if n.startswith("experts.") else a[n]  # noqa: E731
+        return mellum_ref.expert_layer(a["x"], w, cfg, jnp.float32, held)[0]
+
+    whole, parts, rows = reference(list(range(experts))), 0.0, 0
+    for first in range(0, experts, 16):
+        module = HeldExperts(experts, k, fe, first, 16, False, True, 1.0, jnp.float32,
+                             0, "softmax")
+        hold = slice(first, first + 16)
+        params = {"router": a["router.w"], "w1": a["experts.w1"][hold],
+                  "w3": a["experts.w3"][hold], "w2": a["experts.w2"][hold]}
+        y, r, dropped, _ = module.apply({"params": params}, a["x"])
+        _close(y, reference(list(range(first, first + 16))))
+        assert float(dropped) == 0.0 and r.shape == (16,)
+        parts, rows = parts + y, rows + int(r.sum())
+    _close(parts, whole)
+    assert rows == tokens * k  # each (token, choice) pair in exactly one share
+    assert float(jnp.abs(whole).max()) > 0
+
+
 # -- the whole model against the reference ---------------------------------------
 
 
+def _controls(cfg):
+    """The names of the preset's controls: Mellum2's reference has one more."""
+    return ["CONTROL", "NO_WINDOW", "NO_ATTENTION_FACTOR"] + (
+        ["HALF_WINDOW"] if cfg["model_type"] == "mellum" else [])
+
+
 def test_logits_match_the_reference(cfg):
+    ref, runner = _ref(cfg), _runner(cfg)
     leaves = ref.init_params(cfg, 3)
     x, _ = _batches(cfg, 3, 1)[0]
     out = runner.build_model(cfg).apply(runner.to_program_tree(leaves, None, cfg), x)
     logits, aux, dropped, rows, buffers = out
     _close(logits, ref.logits(leaves, jnp.asarray(x), cfg))
     assert float(aux) == 0.0 and float(dropped) == 0.0 and logits.dtype == jnp.float32
-    assert rows.shape == (2, 4) and buffers.tolist() == [256.0] * 2
+    moe_layers = len(ref.expert_layers(cfg))
+    assert moe_layers == {"laguna": 2, "mellum": 4}[cfg["model_type"]]
+    assert rows.shape == (moe_layers, 4) and buffers.tolist() == [256.0] * moe_layers
     # each control is another function of the same leaves
-    for control in (ref.CONTROL, ref.NO_WINDOW, ref.NO_ATTENTION_FACTOR):
-        other = ref.logits(leaves, jnp.asarray(x), cfg, control)
-        assert float(jnp.abs(other - logits).max()) > 1e-3
+    for control in _controls(cfg):
+        other = ref.logits(leaves, jnp.asarray(x), cfg, getattr(ref, control))
+        assert float(jnp.abs(other - logits).max()) > 1e-3, control
 
 
 def test_selections_match_the_reference(cfg):
+    ref, runner = _ref(cfg), _runner(cfg)
     leaves = ref.init_params(cfg, 4)
     x, _ = _batches(cfg, 4, 1)[0]
     _, state = runner.build_model(cfg).apply(
         runner.to_program_tree(leaves, None, cfg), x, mutable=["intermediates"])
     got = jnp.stack([
-        state["intermediates"][m]["selected"][0] for m in ("layers_1_moe", "layers_2_moe")])
+        state["intermediates"][f"layers_{i}_moe"]["selected"][0]
+        for i in ref.expert_layers(cfg)])
     np.testing.assert_array_equal(
         np.asarray(got), np.asarray(ref.selections(leaves, jnp.asarray(x), cfg)))
 
@@ -660,6 +905,7 @@ def test_three_steps_through_moe_trainer_match_the_reference(cfg):
     """The loss, the first gradient of EVERY leaf (element by element, as
     Adam's first moment holds it) and the parameters' change after three
     steps."""
+    ref, runner = _ref(cfg), _runner(cfg)
     seed, names = 11, list(ref.param_shapes(cfg))
     trainer, batches = _trainer(cfg, seed), _batches(cfg, seed)
     m = trainer.train_step(*batches[0])
@@ -674,7 +920,8 @@ def test_three_steps_through_moe_trainer_match_the_reference(cfg):
         _close(grads[n], want[n], 1e-4)
         assert float(jnp.abs(want[n]).max()) > 0, n  # no leaf is a no-op
     assert m.dropped == 0.0 and m.aux_loss == 0.0 and m.contributors == 1.0
-    assert m.expert_rows.shape == (2, 4) and m.buffer_rows.shape == (2,)
+    moe_layers = len(ref.expert_layers(cfg))
+    assert m.expert_rows.shape == (moe_layers, 4) and m.buffer_rows.shape == (moe_layers,)
     assert m.mtp_loss is None
     for b in batches[1:]:
         trainer.train_step(*b)
@@ -684,8 +931,71 @@ def test_three_steps_through_moe_trainer_match_the_reference(cfg):
         assert abs(got[n] - followed["delta_norms"][n]) <= 1e-3 * followed["delta_norms"][n], n
 
 
-@pytest.mark.parametrize("control", ["CONTROL", "NO_WINDOW", "NO_ATTENTION_FACTOR"])
+def test_the_moe_counters_move_by_the_steps_own_metrics(cfg):
+    """``trainer.moe.routed_rows`` / ``.buffer_rows`` /
+    ``.layers_past_first_rung`` beside ``trainer.steps``: each step adds what
+    its ``MoEStepMetrics`` says, nothing else is read from the device."""
+    from akka_allreduce_tpu.obs import metrics
+    from akka_allreduce_tpu.ops.moe import row_rungs
+
+    names = ("trainer.steps", "trainer.moe.routed_rows", "trainer.moe.buffer_rows",
+             "trainer.moe.layers_past_first_rung")
+    read = lambda: [metrics.REGISTRY.snapshot().get(n, 0) for n in names]  # noqa: E731
+    trainer, before = _trainer(cfg, 17), read()
+    steps = [trainer.train_step(*b) for b in _batches(cfg, 17)]
+    first = row_rungs(TRAFFIC["batch"] * TRAFFIC["seq_len"] * cfg["num_experts_per_tok"],
+                      cfg["num_experts"], cfg["router_num_experts"])[0]
+    assert trainer.model.first_rung(TRAFFIC["batch"] * TRAFFIC["seq_len"]) == first
+    moved = [a - b for a, b in zip(read(), before)]
+    assert moved == [
+        3, sum(float(m.expert_rows.sum()) for m in steps),
+        sum(float(m.buffer_rows.sum()) for m in steps),
+        sum(int((m.buffer_rows > first).sum()) for m in steps)]
+    assert moved[1] > 0 and moved[2] >= moved[1] and moved[3] == 0  # one rung at this size
+
+
+def test_the_moe_counters_count_a_layer_past_the_first_rung(monkeypatch):
+    """Of a step whose metrics say two of four layers took a larger buffer
+    than the model's first rung at the step's own tokens (8,192 x 8 over 16
+    of 64: 20,480), the third counter moves by two. (The skeleton's
+    step is stood in for: on the CPU a ladder of several rungs does not pass
+    ``shard_map``'s varying-axes check, which the chip's kernels relax.)"""
+    from akka_allreduce_tpu.obs import metrics
+    from akka_allreduce_tpu.train import MoETrainer
+    from akka_allreduce_tpu.train.moe import MoEStepMetrics
+    from akka_allreduce_tpu.train.sharded_lm import ShardedLMTrainer
+
+    made = MoEStepMetrics(
+        step=1, loss=1.0, aux_loss=0.0, dropped=0.0, contributors=1.0,
+        expert_rows=np.full((4, 16), 1500.0),
+        buffer_rows=np.asarray([20480.0, 65536.0, 20480.0, 65536.0]))
+    monkeypatch.setattr(ShardedLMTrainer, "train_step", lambda self, *a: made)
+    trainer = object.__new__(MoETrainer)
+    trainer.dp = 1
+    asked = []
+    trainer.model = types.SimpleNamespace(
+        first_rung=lambda tokens: asked.append(tokens) or 20480)
+    names = ("trainer.moe.routed_rows", "trainer.moe.buffer_rows",
+             "trainer.moe.layers_past_first_rung")
+    before = [metrics.counter(n).value for n in names]
+    tokens = np.zeros((1, 8192), np.int32)
+    assert trainer.train_step(tokens, tokens) is made and asked == [8192]
+    assert [metrics.counter(n).value - b for n, b in zip(names, before)] == [
+        4 * 16 * 1500.0, 172032.0, 2]
+    # a model that reports no rows (``MoETransformerLM``): nothing moves
+    monkeypatch.setattr(ShardedLMTrainer, "train_step", lambda self, *a: MoEStepMetrics(
+        step=2, loss=1.0, aux_loss=0.0, dropped=0.0, contributors=1.0))
+    trainer.train_step(tokens, tokens)
+    assert [metrics.counter(n).value - b for n, b in zip(names, before)] == [
+        4 * 16 * 1500.0, 172032.0, 2]
+
+
+@pytest.mark.parametrize("control", ["CONTROL", "NO_WINDOW", "NO_ATTENTION_FACTOR",
+                                     "HALF_WINDOW"])
 def test_runner_check_passes_sound_and_fails_each_control(cfg, control):
+    if control not in _controls(cfg):
+        pytest.skip("the halved window is the Mellum2 reference's control")
+    ref, runner = _ref(cfg), _runner(cfg)
     compare = spec.load_module("runners", "lm_train").compare
     seed, names = 13, list(ref.param_shapes(cfg))
     batches = _batches(cfg, seed)
@@ -705,11 +1015,12 @@ def test_a_whole_tiny_run_of_the_cell_is_correct(cfg):
 
     traffic_cfg = dict(TRAFFIC, loop="closed", unit="train_step", warmup_units=3,
                        trace_seconds=0.5)
+    _, _, cell, tiny = PRESETS[cfg["model_type"]]
     result = run_cell(
-        CELL, 2**31 + 9, 0.4, False, devices=jax.devices(),
+        cell, 2**31 + 9, 0.4, False, devices=jax.devices(),
         peak={"bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e11},
         t_process=time.perf_counter(),
-        overrides={"config": _json(TINY), "traffic": traffic_cfg},
+        overrides={"config": _json(tiny), "traffic": traffic_cfg},
     )
     assert result["correct"] and result["failed"] == 0 and result["attempted"] >= 1
     assert set(result["metrics"]) == {"train_tokens_per_s", "setup_s"}
@@ -721,21 +1032,37 @@ def test_a_whole_tiny_run_of_the_cell_is_correct(cfg):
 def test_from_config_reads_the_dialect(cfg):
     from akka_allreduce_tpu.models.hybrid_decoder import HybridDecoderLM
 
-    m = HybridDecoderLM.from_config(cfg)
+    m, laguna = HybridDecoderLM.from_config(cfg), cfg["model_type"] == "laguna"
     assert (m.num_experts, m.held_first, m.held_count) == (16, 4, 4)
-    assert m.layer_types == ("full_attention", "sliding_attention", "full_attention")
-    assert m.heads_per_layer == (6, 8, 6) and m.num_dense_layers == 1
-    assert (m.n_kv_heads, m.head_dim, m.sliding_window, m.shared_width) == (2, 16, 8, 32)
-    assert (m.router_score, m.use_select_bias, m.renormalise, m.routed_scale) == (
-        "softmax", False, True, 2.5)
-    assert m.attn_gate and m.norm_eps == 1e-6 and m.mtp_depth == 0
-    assert m.rope_by_kind == (
-        ("full_attention", 500000.0, 8, (64.0, 16, 4.0, 1.0), 1.4158883083359672),
-        ("sliding_attention", 10000.0, 16, None, 1.0),
-    )
+    assert (m.router_score, m.use_select_bias, m.renormalise) == ("softmax", False, True)
+    assert (m.n_kv_heads, m.head_dim, m.sliding_window) == (2, 16, 8)
+    assert m.norm_eps == 1e-6 and m.mtp_depth == 0
+    if laguna:
+        assert m.layer_types == ("full_attention", "sliding_attention", "full_attention")
+        assert m.heads_per_layer == (6, 8, 6) and m.num_dense_layers == 1
+        assert (m.shared_width, m.routed_scale, m.attn_gate) == (32, 2.5, True)
+        assert m.rope_by_kind == (
+            ("full_attention", 500000.0, 8, (64.0, 16, 4.0, 1.0), 1.4158883083359672),
+            ("sliding_attention", 10000.0, 16, None, 1.0),
+        )
+        assert HybridDecoderLM.from_config(dict(cfg, gating="per-head")) == m
+    else:  # the same reader with four of Laguna's keys absent: each the plain form
+        assert m.layer_types == ("sliding_attention",) * 3 + ("full_attention",)
+        assert m.heads_per_layer == () and m.n_heads == 8 and m.num_dense_layers == 0
+        assert (m.shared_width, m.routed_scale, m.attn_gate) == (0, 1.0, False)
+        assert m.rope_by_kind == (
+            ("full_attention", 500000.0, 16, (16.0, 16, 4.0, 1.0), 1.2772588722239782),
+            ("sliding_attention", 500000.0, 16, None, 1.0),
+        )
+        assert HybridDecoderLM.from_config(dict(cfg, gating=False)) == m
+        gated = HybridDecoderLM.from_config(dict(cfg, gating=True))
+        assert gated.attn_gate and gated == m.clone(attn_gate=True)
+        # keys of the family's older spelling, read by nothing
+        assert HybridDecoderLM.from_config(
+            {k: v for k, v in cfg.items() if k not in ("max_window_layers", "use_sliding_window")}
+        ) == m
     # the dialect is its keys': under another model's name the same model
     assert HybridDecoderLM.from_config(dict(cfg, model_type="other")) == m
-    assert HybridDecoderLM.from_config(dict(cfg, gating="per-head")) == m
     whole = {k: v for k, v in cfg.items() if k not in ("router_num_experts", "held_experts")}
     m = HybridDecoderLM.from_config(whole)
     assert (m.num_experts, m.held_first, m.held_count) == (4, 0, 4)
@@ -743,7 +1070,7 @@ def test_from_config_reads_the_dialect(cfg):
     bare = copy.deepcopy(cfg)
     del bare["rope_parameters"]["full_attention"]["attention_factor"]
     assert HybridDecoderLM.from_config(bare).rope_by_kind[0].attention_factor == pytest.approx(
-        0.1 * math.log(64) + 1)
+        0.1 * math.log(64 if laguna else 16) + 1)
 
 
 def _with_rope_type(cfg, rope_type):
@@ -753,7 +1080,7 @@ def _with_rope_type(cfg, rope_type):
 
 
 @pytest.mark.parametrize("key,bad", [
-    ("attention_bias", True), ("tie_word_embeddings", True), ("gating", False),
+    ("attention_bias", True), ("tie_word_embeddings", True), ("gating", "sigmoid"),
     ("gating", "per-layer"), ("gating_types", ["per_head", "per_layer", "per_head"]),
     ("moe_apply_router_weight_on_input", True), ("moe_router_logit_softcapping", 30.0),
     ("ep_size", 2), ("program", {"remat": "full"}), ("held_experts", [1, 3]),
@@ -762,22 +1089,27 @@ def _with_rope_type(cfg, rope_type):
     ("num_attention_heads_per_layer", [6, 8]), ("rope_type", "linear"), ("rope_type", "llama3"),
 ])
 def test_from_config_refuses_what_is_not_built(cfg, key, bad):
+    """On both presets: a spelling of ``gating`` the reader does not know
+    among them (false, or the key absent, is no gate)."""
     from akka_allreduce_tpu.models.hybrid_decoder import HybridDecoderLM
 
+    if key in ("mlp_layer_types", "layer_types") and len(bad) != cfg["num_hidden_layers"]:
+        bad = bad + bad[-1:]  # Mellum2's preset has four layers
     wrong = _with_rope_type(cfg, bad) if key == "rope_type" else dict(
         copy.deepcopy(cfg), **{key: bad})
     with pytest.raises(ValueError):
         HybridDecoderLM.from_config(wrong)
 
 
-def test_train_moe_cli_trains_from_the_configuration_file(capsys):
+def test_train_moe_cli_trains_from_the_configuration_file(cfg, capsys):
     from akka_allreduce_tpu.__main__ import main
 
-    rc = main(["train-moe", "--config", TINY, "--steps", "3", "--batch", "8",
-               "--seq-len", "32", "--lr", "1e-3"])
+    rc = main(["train-moe", "--config", PRESETS[cfg["model_type"]][3], "--steps", "3",
+               "--batch", "8", "--seq-len", "32", "--lr", "1e-3"])
     out = capsys.readouterr().out
     assert rc == 0 and "experts 4-7 of 16 held, top-4" in out
-    assert "full/slid/full" in out and "dropped 0.0%" in out and "mtp loss" not in out
+    kinds = {"laguna": "full/slid/full", "mellum": "slid/slid/slid/full"}[cfg["model_type"]]
+    assert kinds in out and "dropped 0.0%" in out and "mtp loss" not in out
 
 
 def test_the_cells_configuration_counts_as_the_issue_says():
@@ -829,6 +1161,84 @@ def test_the_cells_configuration_counts_as_the_issue_says():
     need = moe_flops.grouped_products(real, 8192)
     assert need["flops"] == 18 * 8192 * 2048 * 512
     assert need["bytes"] == 3 * (3 * 2 * 8192 * 2560 + 8 * held * 2048 * 512)
+
+
+def test_the_mellum2_seeded_weights_spread_the_embedding_on_its_own():
+    """The reference makes every matrix at ``initializer_range`` and the
+    embedding's rows at ``embedding_initializer_range`` where the
+    configuration gives one (the cell's file: 8.0, so that a token's router
+    reads the token's own vector and the held experts' load does not follow
+    the seed); the norm of the parameters' change makes the same weights
+    again; a configuration without the key is as before."""
+    real, tiny = _json(MELLUM_REAL), _json(MELLUM_TINY)
+    assert real["initializer_range"] == 0.02
+    assert real["embedding_initializer_range"] == 8.0
+    assert mellum_ref.leaf_stds(real) == (0.02, 8.0)
+    assert mellum_ref.leaf_stds(tiny) == (0.05, 0.05)
+    plain = mellum_ref.init_params(tiny, 3)
+    wide = mellum_ref.init_params({**tiny, "embedding_initializer_range": 1.0}, 3)
+    for name, leaf in plain.items():
+        if name == "embed":
+            np.testing.assert_allclose(wide[name], 20.0 * np.asarray(leaf), rtol=1e-6)
+            assert abs(float(np.std(wide[name])) - 1.0) < 0.05
+        else:
+            np.testing.assert_array_equal(wide[name], leaf)
+    moved = mellum_ref.delta_norms(
+        wide, {**tiny, "embedding_initializer_range": 1.0}, 3)
+    assert set(moved) == set(wide) and max(moved.values()) < 1e-5  # rounding's
+    assert mellum_ref.delta_norms(wide, tiny, 3)["embed"] > 1.0
+
+
+def test_the_mellum2_configuration_counts_as_the_issue_says():
+    from harness import mellum_flops
+
+    real = _json(MELLUM_REAL)
+    shapes = mellum_ref.param_shapes(real)
+    count = lambda keep: sum(  # noqa: E731
+        int(np.prod(s)) for n, s in shapes.items() if keep(n))
+    assert real["held_experts"] == list(range(16)) and real["num_experts"] == 16
+    assert real["router_num_experts"] == 64 and real["vocab_size"] == 98304 // 4
+    assert count(lambda n: True) == 595_153_152 == 4 * (
+        21_233_664 + 147_456 + 4_608 + 16 * 6_193_152) + 2 * 56_623_104 + 2_304
+    attn = lambda i: count(lambda n: n.startswith(f"layers.{i}.") and n.split(".")[2] in "qkvo")  # noqa: E731
+    assert [attn(i) for i in range(4)] == [21_233_664] * 4
+    assert count(lambda n: n == "layers.2.router.w") == 147_456
+    assert count(lambda n: ".experts." in n) == 4 * 16 * 6_193_152  # 66.6 % of all
+    assert not any(".mlp." in n or ".shared." in n or n.endswith("g.w") for n in shapes)
+    # the file: the source's widths uncut, the cuts, the deployment
+    catalog = "/opt/skills/guides/model-configs/architectures.jsonl"
+    if os.path.exists(catalog):
+        with open(catalog, encoding="utf-8") as f:
+            row = next(r for r in map(json.loads, f)
+                       if r["name"] == "Mellum2-12B-A2.5B-Instruct")
+        differs = {k for k, v in row["config"].items() if real.get(k, "absent") != v}
+        assert differs == set(real["reduced"]) == set(real["reduced_from"])
+        assert real["source"] == row["source_url"]
+        for key in ("layer_types", "mlp_layer_types"):
+            assert real[key] == row["config"][key][:4]
+    assert real["reduced"] == ["num_hidden_layers", "layer_types", "mlp_layer_types",
+                               "num_experts", "vocab_size"]
+    assert real["layer_types"] == ["sliding_attention"] * 3 + ["full_attention"]
+    assert not real["program"]["remat"] and "EP4" in real["stands_for"]
+    for key in ("assumed", "departures", "memory_plan", "correct_limits_why"):
+        assert real[key], key
+    assert real["memory_plan"]["batch1_t8192_gb"]["sum"] <= 14.2
+    # the benchmark's count: pairs exactly under each mask
+    assert mellum_flops.pairs(8192, 1024) == sum(min(i + 1, 1024) for i in range(8192))
+    assert mellum_flops.windows(real) == [1024, 1024, 1024, None]
+    step = mellum_flops.train_flops_per_step(real, 1, 8192, 4 * 8192 * 8 * 16 / 64)
+    band = mellum_flops.attention_train_flops(real, 1, 8192, windowed=True)
+    full = mellum_flops.attention_train_flops(real, 1, 8192, windowed=False)
+    assert band + full == step["attention"]
+    assert [round(x / 1e12, 2) for x in (band, full, step["experts"], step["total"])] == [
+        1.16, 1.65, 2.44, 12.23]
+    n = mellum_flops.matmul_params(real)
+    assert n == {"attention": 4 * 21_233_664, "router": 4 * 147_456,
+                 "head": 56_623_104, "one_expert": 6_193_152}
+    need = mellum_flops.grouped_products(real, 16384, 16)
+    assert need["flops"] == 18 * 16384 * 2304 * 896
+    assert need["bytes"] == 9 * 2 * 16384 * (2304 + 896) + 24 * 16 * 2304 * 896
+    assert mellum_flops.grouped_products(real, 100, 1)["bytes"] < need["bytes"] / 16
 
 
 def _made_up_record(real, tr, scopes):
@@ -1008,9 +1418,255 @@ def test_the_benchmark_lists_the_cell_where_the_issue_says():
     assert set(loaded.per_layer) == listed | {"compile_or_load_s"}
     for name in loaded.per_layer:  # every reader is a file beside the others
         assert hasattr(spec.load_module("layer_metrics", name), "compute")
-    # no process of an older cell loads a file this PR adds
+    # no process of an older cell loads a file PR 35 added (the Mellum2 cell,
+    # of the same dialect, joins the readers of its scopes)
     for w in bench["workloads"]:
-        if w["name"] != CELL:
+        if w["name"] not in (CELL, MELLUM_CELL):
             older = spec.load_cell(w["name"])
             assert older.config["runner"] != "laguna_moe_train"
             assert not {"swa_kernel_ms", "gqa_proj_ms", "mfu_pct.swa"} & set(older.per_layer)
+
+
+MELLUM_NEW = ("mfu_pct.mellum", "swa_kernel_roofline_pct.mellum",
+              "attn_kernel_roofline_pct.mellum", "moe_gmm_roofline_pct.mellum",
+              "moe_path_ms", "moe_past_first_rung_pct", "swa_tile_useful_pct")
+
+
+def test_the_benchmark_lists_the_mellum2_cell_where_the_issue_says():
+    bench = _json(os.path.join(ROOT, "BENCHMARK.json"))
+    assert [c["name"] for c in bench["configs"]][-1] == "mellum2_12b_d4"
+    config = bench["configs"][-1]
+    assert config["file"] == "benchmarks/configs/mellum2_12b_d4.json"
+    assert config["reduced"] == _json(MELLUM_REAL)["reduced"]
+    assert config["source"] == _json(MELLUM_REAL)["source"]
+    cell = bench["workloads"][-1]
+    assert (cell["name"], cell["config"], cell["traffic"], cell["chips"]) == (
+        MELLUM_CELL, "mellum2_12b_d4", "closed_b1_t8192", 1)
+    assert all(len(x["why"]) <= 200 for x in (config, cell))
+    listed = {m["name"] for m in bench["per_layer"] if MELLUM_CELL in m.get("workloads", ())}
+    assert listed == set(MELLUM_NEW) | {
+        "step_ms_p50", "step_ms_p90", "step_span_ms_p50", "step_span_ms_p90",
+        "host_gap_ms.train", "host_gap_ms.around_run", "host_gap_ms.caller",
+        "host_gap_ms.place", "slow_steps", "slow_step_excess_ms.fetch",
+        "slow_step_excess_ms.host", "device_idle_pct.train", "optimizer_own_pass_ms",
+        "attn_kernel_ms", "swa_kernel_ms", "gqa_proj_ms", "gqa_around_kernel_ms",
+        "moe_gmm_ms", "moe_load_max_over_mean", "moe_row_buffer_fill_pct"}
+    # not the readers silent since PR 31, nor another configuration's
+    assert not listed & {"flash_attn_ms", "flash_attn_roofline_pct", "flash_attn_roofline_pct.moe",
+                         "mfu_pct.swa", "swa_kernel_roofline_pct", "moe_gmm_roofline_pct"}
+    for m in bench["per_layer"]:
+        if m["name"] in MELLUM_NEW:  # new metrics: this cell's alone, at the lists' end
+            assert m["workloads"] == [MELLUM_CELL] and m["moves"] == "train_tokens_per_s"
+    assert [m["name"] for m in bench["per_layer"]][-7:] == list(MELLUM_NEW)
+    loaded = spec.load_cell(MELLUM_CELL)
+    assert loaded.end_to_end == ["train_tokens_per_s", "setup_s"]
+    assert set(loaded.per_layer) == listed | {"compile_or_load_s"}
+    for name in loaded.per_layer:  # every reader is a file beside the others
+        assert hasattr(spec.load_module("layer_metrics", name), "compute")
+    # no process of an older cell loads a file this PR adds
+    for w in bench["workloads"][:-1]:
+        older = spec.load_cell(w["name"])
+        assert older.config["runner"] != "mellum_moe_train"
+        assert not set(MELLUM_NEW) & set(older.per_layer)
+
+
+def _mellum_record(units_extra=None, counters=None):
+    real, tr = _json(MELLUM_REAL), _json(os.path.join(BENCH, "traffic", "closed_b1_t8192.json"))
+    pre = "jit(step)/jvp(HybridDecoderLM)/"
+    scopes = {
+        "fusion.1": pre + "layers_0_attn/sliding_attention/attn_qkv/q/dot_general",
+        "fusion.2": pre + "layers_1_moe/moe_route/dot_general",
+        "fusion.3": "jit(step)/transpose(jvp(HybridDecoderLM))/layers_1_moe/jit(_rung_backward)/"
+                    "moe_experts/gather",
+        "fusion.4": pre + "layers_2_moe/jit(_rung_forward)/moe_combine/add",
+        "gmm.3": pre + "layers_1_moe/jit(_rung_forward)/moe_experts/gmm/pallas_call",
+        "tgmm.1": "jit(step)/transpose(jvp(HybridDecoderLM))/layers_1_moe/moe_experts/tgmm",
+        "splash_mha_fwd.1": pre + "layers_1_attn/sliding_attention/attn_core/x",
+        "splash_mha_dkv.2": pre + "layers_2_attn/sliding_attention/attn_core/x",
+        "splash_mha_fwd.3": pre + "layers_3_attn/full_attention/attn_core/x",
+        "cond.9": pre + "layers_1_moe/moe_experts/cond",
+        "fusion.7": "jit(step)/optimizer/add",
+        # what XLA makes of ``lax.ragged_dot`` on a larger rung: no scope in its name
+        "ragged-dot-none.5": "jit(step)/transpose(jvp(HybridDecoderLM))/layers_3_moe/cond/"
+                             "branch_1_fun/jit(_rung_backward)/ragged-dot-none",
+    }
+    rows = [[1024.0] * 15 + [0.0]] * 4  # one held expert without a row
+    units = [{"t0": i * 0.2, "t1": i * 0.2 + 0.2, "work": 8192, "ok": True,
+              "expert_rows": rows, "buffer_rows": [20480.0, 20480.0, 20480.0, 65536.0],
+              **({"op_scopes": scopes, "counters": counters} if i == 0 else {})}
+             for i in range(10)]
+    record = {
+        "cell": types.SimpleNamespace(config=real, traffic=tr), "chips": 1,
+        "peak": {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9},
+        "window": {"units": units, "start": 0.0, "paused": 0.0},
+    }
+    ops = {"fusion.1": [10, 0.30, "fusion"], "fusion.2": [10, 0.04, "fusion"],
+           "fusion.3": [10, 0.10, "fusion"], "fusion.4": [10, 0.05, "fusion"],
+           "gmm.3": [360, 0.12, "custom-call"], "tgmm.1": [120, 0.06, "custom-call"],
+           "splash_mha_fwd.1": [30, 0.06, "custom-call"],
+           "splash_mha_dkv.2": [30, 0.14, "custom-call"],
+           "splash_mha_fwd.3": [10, 0.13, "custom-call"],
+           # a conditional's event spans its body's events: on neither side
+           "cond.9": [10, 0.5, "conditional"], "fusion.7": [10, 0.2, "fusion"],
+           "ragged-dot-none.5": [10, 0.07, "custom-call"]}
+    return real, record, ops
+
+
+def test_readers_of_the_mellum2_metrics_on_a_made_up_record():
+    from harness import mellum_flops as flops
+
+    counters = {"trainer.steps": 10, "trainer.moe.routed_rows": 614400.0,
+                "trainer.moe.buffer_rows": 1024000.0,
+                "trainer.moe.layers_past_first_rung": 10,
+                "attention.band.visited_pairs": 11796480,
+                "attention.band.mask_pairs": 7864832}
+    real, record, ops = _mellum_record(counters=counters)
+    read = lambda n, t=_Trace(ops), r=record: spec.load_module(  # noqa: E731
+        "layer_metrics", n).compute(r, t)
+    # route 4 + experts 10 + combine 5 + the kernels 12 + 6 + the larger rung's
+    # product 7, the conditional left out
+    assert read("moe_path_ms") == pytest.approx(44.0)
+    assert read("moe_past_first_rung_pct") == pytest.approx(25.0)  # one layer of four
+    assert read("swa_tile_useful_pct") == pytest.approx(100 * 7864832 / 11796480)
+    band = flops.attention_train_flops(real, 1, 8192, windowed=True)
+    assert read("swa_kernel_ms") == pytest.approx(20.0)
+    assert read("swa_kernel_roofline_pct.mellum") == pytest.approx(100 * band / 197e12 / 0.020)
+    every = flops.attention_train_flops(real, 1, 8192)
+    assert read("attn_kernel_roofline_pct.mellum") == pytest.approx(100 * every / 197e12 / 0.033)
+    per_step = flops.train_flops_per_step(real, 1, 8192, 4 * 15 * 1024)["total"]
+    assert read("mfu_pct.mellum") == pytest.approx(100 * per_step * 5 / 197e12)
+    # three layers on the first rung, fifteen experts with a row; the layer past
+    # it multiplied through ragged_dot (``_on_rung``: the test below), so its
+    # time is not in ``moe_gmm_ms`` and its work is not counted
+    need = flops.grouped_products(real, 15 * 1024, 15)
+    least = 3 * max(need["flops"] / 197e12, need["bytes"] / 819e9)
+    assert need["flops"] / 197e12 > need["bytes"] / 819e9  # compute-bound at this load
+    assert read("moe_gmm_roofline_pct.mellum") == pytest.approx(100 * least / 0.018)
+    for name in MELLUM_NEW:
+        assert 0 < read(name) < 100 or name == "moe_path_ms", name
+    # the older readers the cell joins, from these keys
+    assert read("gqa_proj_ms") == pytest.approx(30.0)
+    assert read("optimizer_own_pass_ms") == pytest.approx(20.0)
+    assert read("moe_load_max_over_mean") == pytest.approx(16 / 15)
+    fills = [15 * 1024 / 20480] * 3 + [15 * 1024 / 20480]  # the rung the shapes give
+    assert read("moe_row_buffer_fill_pct") == pytest.approx(100 * sum(fills) / 4)
+    # a program without the counters, the scopes or the gauges, a trace without
+    # the kernels: every new reader says nothing, and none raises
+    bare = dict(record, window=dict(record["window"], units=[
+        {k: v for k, v in u.items()
+         if k not in ("expert_rows", "buffer_rows", "op_scopes", "counters")}
+        for u in record["window"]["units"]]))
+    empty = _Trace({"fusion.9": [10, 1.0, "fusion"]})
+    for name in MELLUM_NEW:
+        assert spec.load_module("layer_metrics", name).compute(bare, empty) is None, name
+    # the parent's program under this PR's runner: units with an empty
+    # ``counters`` (no ``trainer.moe.*``, no ``attention.band.*`` in its registry)
+    _, parents, _ = _mellum_record(counters={})
+    for name in ("moe_past_first_rung_pct", "swa_tile_useful_pct"):
+        assert spec.load_module("layer_metrics", name).compute(parents, _Trace(ops)) is None
+    _, older, _ = _mellum_record(counters={"trainer.steps": 10})
+    assert spec.load_module("layer_metrics", "moe_past_first_rung_pct").compute(
+        older, _Trace(ops)) is None
+
+
+def test_a_rung_past_the_first_multiplies_through_ragged_dot():
+    """What ``moe_gmm_roofline_pct.mellum`` rests on when it leaves a layer
+    past the first rung out on both sides: only the first rung's body is
+    handed the kernels (``gmm`` / ``tgmm``: the ops ``moe_gmm_ms`` matches),
+    every other ``lax.ragged_dot``, at this cell's ladder and at JoyAI's."""
+    from akka_allreduce_tpu.ops.moe import _on_rung, row_rungs
+
+    for rungs in (row_rungs(8192 * 8, 16, 64), row_rungs(8192 * 8, 8, 256)):
+        seen = []
+
+        def body(x, *, rows, keep, impl, seen=seen):
+            seen.append((rows, keep, impl))
+            return x
+
+        _on_rung(rungs, types.SimpleNamespace(rung=jnp.int32(0)), body, "gmm", jnp.zeros(()))
+        assert seen == [(rungs[0], rungs[0], "gmm")] + [
+            (r, rungs[0], "ragged_dot") for r in rungs[1:]]
+
+
+def _mellum_recorded_breakdown():
+    """``mellum_on_chip.py breakdown``'s recording of ten steps on a v5e as a
+    record and a trace the readers take."""
+    rec = _json(os.path.join(BENCH, "tests", "data", "mellum2_breakdown_10steps.json"))
+    steps = rec["steps"]
+    units = [{"t0": i * 0.19, "t1": i * 0.19 + 0.185, "work": 8192, "ok": True,
+              "expert_rows": rec["expert_rows"][i], "buffer_rows": rec["buffer_rows"][i],
+              **({"op_scopes": {k: v[3] for k, v in rec["ops"].items()},
+                  "counters": rec["counters"]} if i == 0 else {})}
+             for i in range(steps)]
+    record = {
+        "cell": types.SimpleNamespace(
+            config=_json(MELLUM_REAL),
+            traffic=_json(os.path.join(BENCH, "traffic", "closed_b1_t8192.json"))),
+        "chips": 1, "peak": {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9},
+        "window": {"units": units, "start": 0.0, "paused": 0.0},
+    }
+    trace = _Trace({k: v[:3] for k, v in rec["ops"].items()})
+    trace.main_module = lambda: [(0.0, s) for s in rec["step_device_s"]]
+    return rec, record, trace
+
+
+@pytest.mark.parametrize("name", MELLUM_NEW + (
+    "swa_kernel_ms", "attn_kernel_ms", "gqa_proj_ms", "gqa_around_kernel_ms", "moe_gmm_ms",
+    "optimizer_own_pass_ms"))
+def test_each_mellum2_reader_reads_the_recorded_breakdown(name):
+    """Every reader the cell lists that reads the program's scopes, kernels,
+    counters or gauges returns a number on a recording of the cell's own
+    steps, the one it returned on the chip; no share passes 100 %."""
+    rec, record, trace = _mellum_recorded_breakdown()
+    value = spec.load_module("layer_metrics", name).compute(record, trace)
+    assert value is not None and value > 0
+    if name in rec["readers_on_the_chip"]:
+        # the recording leaves out ops under 0.005 ms a step
+        assert value == pytest.approx(rec["readers_on_the_chip"][name], rel=3e-2)
+    if name.endswith("_pct") or "_pct." in name:
+        assert value < 100
+    if name == "moe_past_first_rung_pct":  # eleven of the forty layer-steps recorded
+        assert value == pytest.approx(27.5)
+    if name == "moe_gmm_roofline_pct.mellum":
+        # the work of the twenty-nine layer-steps on the first rung over their
+        # kernels' time: the eleven past it are in neither
+        assert 55 < value < 65
+
+
+def test_the_recorded_mellum2_steps_hold_what_the_issue_says_of_the_program():
+    """Three band layers on two backward kernels each and a full layer on
+    the fused one, every kernel under ``attn_core`` of its layer's kind; the
+    held experts' path under its three scopes with the grouped products in
+    it; no gate, no shared expert, no dense MLP anywhere in the step."""
+    import re
+
+    rec, record, trace = _mellum_recorded_breakdown()
+    read = lambda n: spec.load_module("layer_metrics", n).compute(record, trace)  # noqa: E731
+    kernels = {k: v for k, v in rec["ops"].items() if re.match(r"splash_m[hq]a", k)}
+    assert len(kernels) == 3 * 3 + 2
+    assert sum("/sliding_attention/attn_core/" in v[3] for v in kernels.values()) == 9
+    assert sum("/full_attention/attn_core/" in v[3] for v in kernels.values()) == 2
+    per_step = lambda pick: 1e3 * sum(  # noqa: E731
+        v[1] for v in kernels.values() if pick in v[3]) / rec["steps"]
+    assert read("swa_kernel_ms") == pytest.approx(per_step("/sliding_attention/"))
+    assert read("swa_kernel_ms") + per_step("/full_attention/") == pytest.approx(
+        read("attn_kernel_ms"), rel=1e-2)
+    grouped = [v for k, v in rec["ops"].items() if re.match(r"t?gmm", k)]
+    assert grouped and all(re.search(r"moe_(experts|combine)", v[3]) for v in grouped)
+    assert read("moe_path_ms") > read("moe_gmm_ms") > 0
+    names = " ".join(v[3] for v in rec["ops"].values())
+    for scope in ("attn_qkv", "attn_out", "moe_route", "moe_experts", "moe_combine", "optimizer"):
+        assert f"{scope}/" in names, scope
+    for absent in ("/gate/", "shared_expert", "_mlp/"):
+        assert absent not in names, absent
+
+
+def test_the_mellum2_counter_readers_say_nothing_on_another_programs_recording():
+    """The Laguna cell's recorded breakdown (PR 35's program: no
+    ``trainer.moe.*`` counter, no ``attention.band.*`` gauge, its runner hands
+    no ``counters``): the two readers of them return None; ``moe_path_ms``
+    reads that program's own three scopes."""
+    _, record, trace = _recorded_breakdown()
+    for name in ("moe_past_first_rung_pct", "swa_tile_useful_pct"):
+        assert spec.load_module("layer_metrics", name).compute(record, trace) is None
+    assert spec.load_module("layer_metrics", "moe_path_ms").compute(record, trace) > 0
