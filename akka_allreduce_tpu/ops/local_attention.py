@@ -18,7 +18,10 @@ was HBM-bound on score traffic, not FLOPs. Two fixes, dispatched by
   the mask is an object, so only the blocks the causal diagonal cuts pay for
   masking and the blocks it empties are skipped; the backward is ONE fused
   pass (``dq``, ``dk``, ``dv`` from one recomputation of the scores); K/V go
-  in at their own head count and ``dk``/``dv`` come back at it.
+  in at their own head count and ``dk``/``dv`` come back at it. Under a
+  window of up to 1,024 keys the same branch calls the repo's own band
+  kernel instead (``ops/band_attention.py``, :func:`_takes_band`): the
+  library skips whole blocks only, so its tiles run a band half masked out.
 
 What the kernel costs is measured in the benchmark's training cells
 (``attn_kernel_ms``, PERF.md section 5); the block-size sweep behind
@@ -234,32 +237,20 @@ def _splash_blocks(
     sum. Neither head 64 nor 192 against 128 moved the choice. Past 128,
     float32 inputs at 1024 overrun VMEM in the described-v5e compile (bf16
     ones fit, and ran), so those keep 512, which :func:`flash_shapes_ok`
-    guarantees divides T. With ``window`` (a causal band, :func:`local_attention`)
-    the rule in the body; without one, the tiles above."""
+    guarantees divides T. ``window``: a causal band :func:`_takes_band` left
+    to the library (the band kernel's tile does not divide it, or it is past
+    1,024)."""
     from jax.experimental.pallas.ops.tpu.splash_attention import BlockSizes
 
     fits = max(d, dv or d) <= 128 or itemsize <= 2
     b = 1024 if t % 1024 == 0 and fits else 512
     fused = True
     if window is not None and window <= 1024:
-        # Under a band of up to 1,024 keys 512 tiles and the library's TWO
-        # backward kernels: the grid visits every tile the band touches, so
-        # the share of visited pairs the mask leaves is about window / (window
-        # + tile) (gauges ``attention.band.*``, :func:`_gauge_band`), and the
-        # fused backward runs its whole (K/V block, q block) grid whatever the
-        # mask and writes a zero ``dq`` partial of q's size for every K/V
-        # block the band leaves empty, which XLA then sums; ``dkv`` and ``dq``
-        # apart run shrunk grids and write no partial. Swept on a v5e at T
-        # 8192, head 128 (forward / forward + backward ms a layer). Window 512,
-        # 64 heads on 8 (CHANGES.md, PR 35): 512 tiles 3.2 / 11.0 two-kernel
-        # and 16.9 fused; 1024 tiles 4.5 / 16.2 and 16.7; 256 tiles 4.8 / 15.0
-        # and 34.6. Window 1024, 32 heads on 4 (CHANGES.md, PR 39): 512 tiles
-        # 2.10 / 7.12 two-kernel and 9.23 fused; 1024 tiles 2.35 / 8.02 and
-        # 7.89 (there the band is a tile wide and the fused grid has little to
-        # skip). A 256-row compute block lost at every tile (0.5 ms at 512).
-        # A window past 1,024 keeps the tiles and the fused backward of no
-        # window: not swept, and at 1,024 the fused backward on wide tiles
-        # already tied.
+        # The fused backward runs its whole (K/V block, q block) grid whatever
+        # the mask and writes a zero ``dq`` partial for every K/V block the
+        # band leaves empty; ``dkv`` and ``dq`` apart run shrunk grids. At
+        # windows 512 and 1,024, 512 tiles and two kernels won (CHANGES.md,
+        # PRs 35 and 39); past 1,024 not swept: the tiles of no window.
         b, fused = 512, False
     return BlockSizes(
         block_q=b, block_kv=b, block_kv_compute=512,
@@ -294,36 +285,41 @@ def _splash_kernel(
     # the tables become device arrays inside the library; built under a
     # trace they would be that trace's tracers, and the cache would leak them
     with jax.ensure_compile_time_eval():
-        kernel = make_splash_mha(
+        return make_splash_mha(
             MultiHeadMask([mask] * h), head_shards=1, q_seq_shards=1,
             block_sizes=blocks, interpret=interpret,
         )
-        if window is not None:
-            _gauge_band(mask, kernel.fwd_mask_info, blocks.block_q, blocks.block_kv)
-    return kernel
 
 
-def _gauge_band(mask, info, block_q: int, block_kv: int) -> None:
-    """What the band's tiles run of masked-out pairs, as the forward
-    kernel's own block tables say it (a head's; every head has the same):
-    gauges ``attention.band.visited_pairs`` ((query, key) pairs of the blocks
-    the grid visits) and ``.mask_pairs`` (those of them the mask leaves: a
-    full block's all, a partial block's counted on the mask). Once a shape, at
-    the kernel's build (OBSERVABILITY.md)."""
-    import numpy as np
+def _takes_band(t: int, d: int, dv: int, window: int | None) -> bool:
+    """Does the repo's band kernel (``ops/band_attention.py``) take the shape?
+    A causal window of up to 1,024 keys that its tile divides, as it does T,
+    T holding a window and a tile, heads of up to 128: the slab of ``window +
+    TILE`` keys and a group's scores against it fit VMEM at once. On shapes
+    alone. At both windows the cells run (512 on 64 heads of 8, 1,024 on 32 of
+    4) it took 4.6 and 4.1 ms a layer where the library's 512 tiles took 8.5
+    and 6.5 (CHANGES.md, PR 40)."""
+    if window is None or window > 1024 or max(d, dv) > 128:
+        return False
+    from akka_allreduce_tpu.ops.band_attention import TILE
 
+    return window % TILE == 0 and t % TILE == 0 and t >= window + TILE
+
+
+@functools.lru_cache(maxsize=None)
+def _gauge_band(t: int, window: int) -> None:
+    """What the band kernel's tiles run of the mask, once a shape, to the
+    gauges ``attention.band.*`` (a head's; every head has the same;
+    OBSERVABILITY.md): ``visited_pairs``, each tile of queries against its
+    slab of ``window + TILE`` keys, and ``mask_pairs``, those of them the band
+    leaves, ``sum_i min(i + 1, window)``."""
     from akka_allreduce_tpu.obs import metrics as obs_metrics
+    from akka_allreduce_tpu.ops.band_attention import TILE
 
-    block, at = np.asarray(info.block_mask)[0], np.asarray(info.data_next)[0]
-    visited = inside = 0
-    for i, s in zip(*np.nonzero(block)):
-        j = int(at[i, s])  # the K/V block this slot of the shrunk grid reads
-        visited += block_q * block_kv
-        inside += block_q * block_kv if block[i, s] == 2 else int(np.sum(
-            mask[i * block_q:(i + 1) * block_q, j * block_kv:(j + 1) * block_kv]
-        ))
-    obs_metrics.gauge("attention.band.visited_pairs").set(visited)
-    obs_metrics.gauge("attention.band.mask_pairs").set(inside)
+    obs_metrics.gauge("attention.band.visited_pairs").set(t * (window + TILE))
+    obs_metrics.gauge("attention.band.mask_pairs").set(
+        window * (window + 1) // 2 + (t - window) * window
+    )
 
 
 def _splash_heads_first(
@@ -341,8 +337,15 @@ def _splash_heads_first(
     and accumulates ``dk``/``dv`` over the group itself. It has no scale
     argument: ``q`` comes with the scale in it. Both entries end here:
     :func:`_splash_attention` for callers that hold (B, T, H, D),
-    :func:`heads_first_attention` for one whose products wrote this layout."""
+    :func:`heads_first_attention` for one whose products wrote this layout.
+    Under a window :func:`_takes_band` takes, the repo's own band kernel
+    (``ops/band_attention.py``); else the library's splash kernel."""
     _, h, t, d = q.shape
+    if _takes_band(t, d, v.shape[-1], window):
+        from akka_allreduce_tpu.ops.band_attention import band_attention
+
+        _gauge_band(t, window)
+        return band_attention(q, k, v, window, interpret)
     blocks = _splash_blocks(t, d, v.shape[-1], q.dtype.itemsize, window)
     return jax.vmap(_splash_kernel(t, h, causal, blocks, interpret, window))(q, k, v)
 

@@ -378,17 +378,23 @@ def _laguna_moe_step(topo, monkeypatch):
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes <= 14.5e9
     text = compiled.as_text()
-    # the library's kernels in each of the five layers: q at the layer's own
-    # head count (48 in the two full layers, 64 in the three windowed ones),
-    # K/V compact at 8
-    calls = [
-        line.split("operand_layout_constraints=", 1)[1] for line in text.splitlines()
-        if "tpu_custom_call" in line and re.search(r"%splash_mha_[\w.]+ = ", line)
-    ]
-    at = lambda heads: sum(  # noqa: E731
-        1 for c in calls if re.search(rf"bf16\[{heads},8192,128\]", c))
-    assert at(48) >= 2 * 2 and at(64) >= 2 * 3 and len(calls) == at(48) + at(64)
-    assert all(re.search(r"bf16\[8,8192,128\]", c) for c in calls)
+    # the two full layers (48 query heads) keep the library's kernels, a
+    # forward and a fused backward each; the three windowed ones (64) run the
+    # repo's band kernels, forward, dq and dkv; K/V compact at 8 everywhere
+    calls = {
+        family: [
+            line.split("operand_layout_constraints=", 1)[1] for line in text.splitlines()
+            if "tpu_custom_call" in line and re.search(rf"%{family}_[\w.]+ = ", line)
+        ] for family in ("splash_mha", "flash_mha_band")
+    }
+    at = lambda family, heads: sum(  # noqa: E731
+        1 for c in calls[family] if re.search(rf"bf16\[(?:1,)?{heads},8192,128\]", c))
+    assert at("splash_mha", 48) == len(calls["splash_mha"]) == 2 * 2
+    assert at("flash_mha_band", 64) == len(calls["flash_mha_band"]) == 3 * 3
+    for name in ("fwd", "dq", "dkv"):
+        assert len(re.findall(rf"%flash_mha_band_{name}[\w.]* = ", text)) == 3
+    calls = calls["splash_mha"] + calls["flash_mha_band"]
+    assert all(re.search(r"bf16\[(?:1,)?8,8192,128\]", c) for c in calls)
     # and the products write that layout: no (B, T, H, D) activation anywhere
     for heads in (64, 48, 8):
         assert f"bf16[1,8192,{heads},128]" not in text
@@ -420,6 +426,40 @@ def test_compiles_for_described_v5e(case, topo, as_tpu, monkeypatch):
     assert compiled.as_text().count("tpu_custom_call") >= min_kernels
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < HBM_BYTES
+
+
+@pytest.mark.parametrize("preset,runner_file", [
+    ("tiny_laguna_moe.json", "laguna_moe_train.py"),
+    ("tiny_mellum_moe.json", "mellum_moe_train.py"),
+])
+def test_tiny_mixed_steps_hold_the_band_kernels(preset, runner_file, topo, as_tpu, monkeypatch):
+    """The tiny Laguna and Mellum2 steps, widened to shapes the kernel branch
+    takes (head 32, a window of 128, T 1,024) and compiled for the described
+    chip: three band kernels a windowed layer (forward, dq, dkv), the
+    library's forward and fused backward a full layer, and so no ``LocalMask``
+    kernel, which would be a splash kernel in a windowed layer's scope."""
+    import importlib.util
+
+    monkeypatch.syspath_prepend(BENCH)  # the runner imports the harness
+    spec = importlib.util.spec_from_file_location(
+        "bench_runner_" + runner_file[:-3], os.path.join(BENCH, "runners", runner_file)
+    )
+    runner = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(runner)
+    cfg = dict(_bench_json("tests", preset), head_dim=32, sliding_window=128,
+               use_expert_bias=False)
+    kinds = cfg["layer_types"]
+    _, lowered = runner.lower_step_on_shapes(
+        cfg, {"batch": 1, "seq_len": 1024, "tokens": "copy_half"}, topo.devices[0]
+    )
+    text = lowered.compile().as_text()
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    named = lambda family: [  # noqa: E731
+        m.group(1) for m in (re.search(rf"%({family}\w*?)[.\d]* = ", c) for c in calls) if m]
+    windowed, full = kinds.count("sliding_attention"), kinds.count("full_attention")
+    assert sorted(named("flash_mha_band_")) == sorted(
+        ["flash_mha_band_fwd", "flash_mha_band_dq", "flash_mha_band_dkv"] * windowed)
+    assert len(named("splash_mha_")) == 2 * full  # a ``LocalMask`` kernel would add three a band
 
 
 CELL_FLOATS = 268_435_456  # benchmarks/configs/threshold_allreduce_256m.json

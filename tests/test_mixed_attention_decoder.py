@@ -163,8 +163,10 @@ def test_local_attention_under_a_window_matches_a_dense_masked_softmax(core, t, 
 
 @pytest.mark.parametrize("heads,window", [(6, 512), (8, 200)])
 def test_splash_branch_under_a_window_matches_a_dense_masked_softmax(heads, window):
-    """The kernel branch, interpreted (tiny: T 1024 on one K/V head), the
-    library's ``LocalMask`` as the band: forward and gradient."""
+    """The kernel branch, interpreted (tiny: T 1024 on one K/V head): the
+    repo's band kernel at 512 (``tests/test_window_band_attention.py`` has its own
+    cases), the library's ``LocalMask`` at 200, which no tile divides:
+    forward and gradient."""
     from akka_allreduce_tpu.ops.local_attention import _splash_attention
 
     q, k, v = _qkv(1, 1024, heads, 1, 64, seed=3)
@@ -201,7 +203,8 @@ def test_a_window_is_causal_only_and_heads_first_takes_it():
 def test_splash_blocks_without_a_window_are_todays(t, d, dv):
     """At StarCoder2's, LFM2's and JoyAI's attention shapes, and at this
     dialect's full layers (a new head count only), the tiles PR 31's sweep
-    chose; under a window the rule's own, which divide T."""
+    chose; under a window left to the library the rule's own, which divide T
+    (the band kernel's rule and gauges: ``tests/test_window_band_attention.py``)."""
     from akka_allreduce_tpu.ops.local_attention import _splash_blocks
 
     b = _splash_blocks(t, d, dv)
@@ -213,52 +216,6 @@ def test_splash_blocks_without_a_window_are_todays(t, d, dv):
         assert t % tile == 0 and tile <= 1024
     assert banded.block_kv % banded.block_kv_compute == 0
     assert banded.block_kv_dkv % banded.block_kv_dkv_compute == 0
-
-
-@pytest.mark.parametrize("window,tile,fused", [(1024, 512, False), (512, 512, False),
-                                               (None, 1024, True), (2048, 1024, True),
-                                               (4096, 1024, True)])
-def test_splash_blocks_under_a_band_are_the_sweeps_winners(window, tile, fused):
-    """At (T 8192, head 128): under the 1,024-wide band 512 tiles and the
-    two-kernel backward (PR 39's sweep: 2.10 / 7.12 ms a layer against 2.35 /
-    8.02 at 1024 tiles), at 512 and without a window what PR 35 and PR 31
-    chose; past 1,024 (unswept) what the rule returned before PR 39, the
-    tiles and the fused backward of no window."""
-    from akka_allreduce_tpu.ops.local_attention import _splash_blocks
-
-    b = _splash_blocks(8192, 128, 128, 2, window)
-    assert (b.block_q, b.block_kv, b.block_q_dkv, b.block_kv_dkv) == (tile,) * 4
-    assert (b.block_kv_compute, b.block_kv_dkv_compute) == (512, tile)
-    assert b.use_fused_bwd_kernel == fused
-    assert (b.block_q_dq, b.block_kv_dq) == ((None, None) if fused else (tile, tile))
-
-
-def test_the_band_kernels_build_states_what_its_tiles_run_of_the_mask():
-    """``attention.band.*``: at (T 8192, window 1024) a 512 tile visits three
-    K/V blocks a query block past the first two, two thirds of their pairs
-    inside the mask; counted from the kernel's own block tables, the mask's
-    pairs are ``sum_i min(i + 1, 1024)`` exactly. A kernel without a window
-    writes none."""
-    import importlib
-
-    from akka_allreduce_tpu.obs import metrics
-
-    # the package exports the function under the module's name
-    la = importlib.import_module("akka_allreduce_tpu.ops.local_attention")
-    band = lambda: {k.rsplit(".", 1)[1]: v for k, v in metrics.REGISTRY.snapshot().items()  # noqa: E731
-                    if k.startswith("attention.band.")}
-    for name in ("visited_pairs", "mask_pairs"):
-        metrics.gauge(f"attention.band.{name}").set(0)
-    la._splash_kernel(8192, 2, True, la._splash_blocks(8192, 128), True, None)
-    assert band() == {"visited_pairs": 0, "mask_pairs": 0}
-    la._splash_kernel(8192, 2, True, la._splash_blocks(8192, 128, 128, 2, 1024), True, 1024)
-    exact = sum(min(i + 1, 1024) for i in range(8192))
-    assert band() == {"visited_pairs": (16 * 3 - 3) * 512 * 512, "mask_pairs": exact}
-    assert 100 * exact / band()["visited_pairs"] == pytest.approx(66.67, abs=0.01)
-    # Laguna's band: two 512 tiles a query block, half inside
-    la._splash_kernel(8192, 2, True, la._splash_blocks(8192, 128, 128, 2, 512), True, 512)
-    assert band()["visited_pairs"] == (16 * 2 - 1) * 512 * 512
-    assert band()["mask_pairs"] == sum(min(i + 1, 512) for i in range(8192))
 
 
 @pytest.mark.parametrize("rows,held,experts,want", [
