@@ -2140,6 +2140,12 @@ def _train_moe_from_config(args) -> int:
     mtp = "" if hist[-1].mtp_loss is None else (
         f", mtp loss {hist[0].mtp_loss:.4f} -> {hist[-1].mtp_loss:.4f}"
     )
+    if hist[-1].indexer_loss is not None:  # attention under a learned mask
+        mtp += (
+            f", indexer loss {hist[0].indexer_loss:.4f} -> "
+            f"{hist[-1].indexer_loss:.4f} ({int(hist[-1].selected_pairs.sum())} "
+            f"pairs kept of {len(model.layer_types) * args.batch * args.seq_len * (args.seq_len + 1) // 2})"
+        )
     print(
         f"moe: {args.steps} steps on {trainer.n_devices} devices in "
         f"{dt:.2f}s ({dt / args.steps * 1e3:.1f} ms/step); "

@@ -3,7 +3,9 @@ and expert feed-forward layers (the ``lfm2_moe`` family), latent-attention
 decoders with a shared expert and a multi-token-prediction module (the
 DeepSeek-V3 dialect, ``joyai_llm_flash``), and decoders that mix windowed and
 full attention by the layer's kind, at one head count or at one per layer
-(the ``laguna`` dialect, which ``mellum`` writes too).
+(the ``laguna`` dialect, which ``mellum`` writes too), and decoders whose
+attention runs under a mask a learned indexer makes from the data (the
+Qwen3-MoE key set with ``sa_config``, ``KeyeVL2``'s language model).
 
 Layer ``i``:  ``h = x + Op_i(RMSNorm(x))``,  ``y = h + FF_i(RMSNorm(h))``.
 ``Op_i`` is a gated short convolution where ``layer_types[i] == "conv"``,
@@ -37,6 +39,7 @@ package's ``__init__``.
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import NamedTuple
 
@@ -55,6 +58,16 @@ class RotaryRule(NamedTuple):
     rotary_dim: int  # columns of each head that rotate
     yarn: tuple[float, int, float, float] | None
     attention_factor: float
+
+
+class IndexerRule(NamedTuple):
+    """The learned indexer of sparse attention (``sa_config``): ``heads``
+    index heads of ``head_dim`` on ONE key head of that width, and the
+    ``topk`` keys a query keeps."""
+
+    heads: int
+    head_dim: int
+    topk: int
 
 
 def _dense(features: int, dtype, name: str) -> nn.Dense:
@@ -142,7 +155,27 @@ class GroupedQueryAttention(nn.Module):
     the one pass that norms and turns it (:func:`models.transformer.
     rope_heads_first`; the score scale is in q's float32 table, so q is never
     scaled in its own dtype), by the kernel, by the gate; ``W_o`` reads it
-    heads-first (:class:`_HeadsOut`)."""
+    heads-first (:class:`_HeadsOut`).
+
+    ``mrope_sections`` with ``positions`` (3, T) in the call is multimodal
+    RoPE (:func:`models.transformer.rope_angles`); without positions, text:
+    the rows are equal and the one-row tables are the same tables.
+
+    ``indexer`` (an :class:`IndexerRule`; off for every configuration without
+    ``sa_config``) is DeepSeek-V3.2-Exp's sparse attention: on the layer's
+    input with its gradient stopped, ``q_I = x W_qI`` (heads, head_dim),
+    ``k_I = LayerNorm(x W_kI)`` one key head, ``w = x W_w * heads^-0.5 *
+    head_dim^-0.5``, rotate-half RoPE over the whole index head by the
+    temporal row; ``I[t, s] = sum_j w[t, j] relu(q_I[t, j] . k_I[s])``; query
+    t keeps the ``min(t + 1, topk)`` keys ``s <= t`` of largest ``I`` (ties:
+    the lower s) and attends to those alone (``ops/sparse_attention.py``).
+    The call then returns ``(out, kl, pairs)``: the indexer's loss ``mean_t
+    KL(pbar_t || softmax over the kept keys of I[t, .])`` against the head
+    mean ``pbar`` of this layer's own attention probabilities, whose gradient
+    reaches the indexer's leaves alone as the cross-entropy's reaches none of
+    them (a hard top-k passes none), and the pairs the mask keeps. Scopes:
+    ``attn_indexer`` beside ``attn_core``, in it ``indexer_proj``,
+    ``indexer_scores``, ``indexer_select``, ``indexer_target``."""
 
     n_heads: int
     n_kv_heads: int
@@ -157,14 +190,39 @@ class GroupedQueryAttention(nn.Module):
     yarn: tuple[float, int, float, float] | None = None
     attention_factor: float = 1.0
     scope_name: str = "attention"
+    mrope_sections: tuple[int, ...] | None = None
+    indexer: IndexerRule | None = None
+
+    def _index_operands(self, x, positions):
+        """``(q_I (B, J, T, D), k_I (B, T, D), w (B, T, J))`` from the layer's
+        normed input, through which no gradient passes back."""
+        from akka_allreduce_tpu.models.transformer import rope_heads_first
+
+        rule, dt = self.indexer, self.compute_dtype
+        x = lax.stop_gradient(x)
+        q_i = _HeadsIn(rule.heads, rule.head_dim, dt, name="index_q")(x)
+        k_i = nn.LayerNorm(epsilon=self.norm_eps, dtype=dt, name="index_k_norm")(
+            _dense(rule.head_dim, dt, "index_k")(x)
+        )
+        w = _dense(rule.heads, dt, "index_w")(x).astype(jnp.float32) * (
+            rule.heads ** -0.5 * rule.head_dim ** -0.5
+        )
+        by_time = {} if positions is None else {  # the temporal row, the whole head
+            "positions": positions[:1], "sections": (rule.head_dim // 2,)
+        }
+        turn = functools.partial(rope_heads_first, offset=0, base=self.rope_theta, **by_time)
+        return turn(q_i), turn(k_i[:, None])[:, 0], w
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, positions=None):
         from akka_allreduce_tpu.models.transformer import rope_heads_first
         from akka_allreduce_tpu.ops.local_attention import heads_first_attention
 
         d = x.shape[-1]
         dt, hd = self.compute_dtype, self.head_dim
+        mrope = {} if positions is None else {
+            "positions": positions, "sections": self.mrope_sections
+        }
 
         def turned(name: str, y, scale: float):
             if self.qk_norm:
@@ -172,6 +230,7 @@ class GroupedQueryAttention(nn.Module):
             return rope_heads_first(
                 y, 0, base=self.rope_theta, rotary_dim=self.rotary_dim,
                 yarn=self.yarn, attention_factor=self.attention_factor, scale=scale,
+                **mrope,
             )
 
         with jax.named_scope(self.scope_name):
@@ -181,6 +240,8 @@ class GroupedQueryAttention(nn.Module):
                 v = _HeadsIn(self.n_kv_heads, hd, dt, name="v")(x)
                 if self.gated:
                     gate = _HeadsIn(self.n_heads, None, dt, name="gate")(x)
+            if self.indexer is not None:
+                return self._under_learned_mask(x, q, k, v, turned, positions)
             with jax.named_scope("attn_core"):
                 q, k = turned("q_norm", q, hd ** -0.5), turned("k_norm", k, 1.0)
                 out = heads_first_attention(q, k, v, causal=True, window=self.window)
@@ -189,6 +250,37 @@ class GroupedQueryAttention(nn.Module):
                     out = out * gate[..., None]
             with jax.named_scope("attn_out"):
                 return _HeadsOut(d, dt, name="out")(out)
+
+    def _under_learned_mask(self, x, q, k, v, turned, positions):
+        """The rest of a layer whose mask the indexer makes (inside the
+        layer's scope): ``(out, kl, pairs)``."""
+        from akka_allreduce_tpu.ops.local_attention import heads_first_attention
+        from akka_allreduce_tpu.ops.sparse_attention import indexer_kl, indexer_mask
+
+        if self.gated or self.window is not None:
+            raise ValueError("the indexer is built for ungated causal attention")
+        rule, dt = self.indexer, self.compute_dtype
+        samples = range(x.shape[0])
+        with jax.named_scope("attn_indexer"):
+            with jax.named_scope("indexer_proj"):
+                q_i, k_i, w = self._index_operands(x, positions)
+            mask = jnp.stack(
+                [indexer_mask(q_i[b], k_i[b], w[b], rule.topk) for b in samples]
+            )
+        with jax.named_scope("attn_core"):
+            q = turned("q_norm", q, self.head_dim ** -0.5)
+            k = turned("k_norm", k, 1.0)
+            out, lse = heads_first_attention(q, k, v, causal=True, mask=mask)
+        with jax.named_scope("attn_indexer"):
+            qs, ks, lse = (lax.stop_gradient(a) for a in (q, k, lse))
+            kl = sum(
+                indexer_kl(q_i[b], k_i[b], w[b], mask[b], qs[b], ks[b], lse[b])
+                for b in samples
+            ) / (x.shape[0] * x.shape[1])
+            pairs = jnp.sum(mask, dtype=jnp.int32).astype(jnp.float32)
+        self.sow("intermediates", "mask", mask)
+        with jax.named_scope("attn_out"):
+            return _HeadsOut(x.shape[-1], dt, name="out")(out), kl, pairs
 
 
 def _rms(x, scale, eps: float):
@@ -366,7 +458,13 @@ class HybridDecoderLM(nn.Module):
     ``MoETrainer`` takes. With ``mtp_depth`` it is called with the next
     tokens too, ``(tokens, next_tokens)``, counts the prediction module's
     expert layer last in both counters and returns that module's float32
-    logits (position i: the token after ``next_tokens[i]``) as a sixth."""
+    logits (position i: the token after ``next_tokens[i]``) as a sixth. With
+    ``indexer`` two more come last: the indexer's loss (the layers' mean
+    KL summed over the layers, a scalar: the trainer adds it to its
+    total) and the (query, key) pairs each layer's mask keeps,
+    (layers,) float32. ``positions`` (3, T): the temporal, height and width
+    rows of multimodal RoPE where the model has ``mrope_sections``; left
+    out, text (three equal rows 0 .. T - 1)."""
 
     vocab: int
     d_model: int
@@ -405,6 +503,9 @@ class HybridDecoderLM(nn.Module):
     rope_by_kind: tuple[RotaryRule, ...] = ()
     attn_gate: bool = False  # a per-head sigmoid gate on attention's output
     router_score: str = "sigmoid"
+    # attention under a learned mask (``sa_config``) and multimodal RoPE
+    indexer: IndexerRule | None = None
+    mrope_sections: tuple[int, ...] | None = None
 
     @classmethod
     def from_config(cls, cfg: dict, **overrides) -> "HybridDecoderLM":
@@ -412,8 +513,11 @@ class HybridDecoderLM(nn.Module):
         of: DeepSeek-V3's where it has ``kv_lora_rank`` (``deepseek_v3``,
         ``joyai_llm_flash``); ``laguna``'s where ``rope_parameters`` holds its
         rules under the kinds of ``layer_types`` (``laguna``, ``mellum``:
-        :func:`_from_laguna` says which keys may be absent); else
-        ``lfm2_moe``'s. The key that counts the experts (``num_experts`` /
+        :func:`_from_laguna` says which keys may be absent); Qwen3-MoE's
+        where it has ``sa_config``, or ``decoder_sparse_step`` with
+        ``mlp_only_layers`` and ``moe_intermediate_size`` (``KeyeVL2``'s
+        language model: :func:`_from_qwen3_moe_keys` says what it builds and
+        what it refuses by name); else ``lfm2_moe``'s. The key that counts the experts (``num_experts`` /
         ``n_routed_experts``) counts the experts HELD when
         ``router_num_experts`` states the model's own count beside it (a
         chip's share, ``held_experts`` its ids); otherwise all experts are
@@ -422,6 +526,11 @@ class HybridDecoderLM(nn.Module):
             read = _from_deepseek_v3_keys
         elif _rope_by_layer_kind(cfg):
             read = _from_laguna
+        elif "sa_config" in cfg or all(
+            key in cfg
+            for key in ("decoder_sparse_step", "mlp_only_layers", "moe_intermediate_size")
+        ):
+            read = _from_qwen3_moe_keys
         else:
             read = _from_lfm2_moe
         kw = read(cfg)
@@ -460,6 +569,8 @@ class HybridDecoderLM(nn.Module):
             return GroupedQueryAttention(
                 self.n_heads, self.n_kv_heads, self.head_dim,
                 self.rope_theta, self.norm_eps, dt, name=pre + "attn",
+                scope_name="sparse_attention" if self.indexer else "attention",
+                mrope_sections=self.mrope_sections, indexer=self.indexer,
             )
         if kind == "latent_attention":
             return LatentAttention(
@@ -471,16 +582,24 @@ class HybridDecoderLM(nn.Module):
         raise ValueError(f"layer type {kind!r} is not built")
 
     @nn.compact
-    def __call__(self, tokens, next_tokens=None):
+    def __call__(self, tokens, next_tokens=None, positions=None):
         dt = self.compute_dtype
         norm = lambda name: nn.RMSNorm(  # noqa: E731
             epsilon=self.norm_eps, dtype=dt, name=name
         )
-        rows, dropped, buffers = [], [], []
+        rows, dropped, buffers, index_kl, index_pairs = [], [], [], [], []
+        if positions is not None and not self.mrope_sections:
+            raise ValueError("positions are taken by a model with mrope_sections")
 
         def layer(x, pre: str, index: int, dense: bool):
             op = self._operator(self.layer_types[index], pre, index)
-            x = x + op(norm(pre + "op_norm")(x))
+            extra = (positions,) if self.indexer or self.mrope_sections else ()
+            y = op(norm(pre + "op_norm")(x), *extra)
+            if self.indexer:
+                y, kl, pairs = y
+                index_kl.append(kl)
+                index_pairs.append(pairs)
+            x = x + y
             h = norm(pre + "ffn_norm")(x)
             if dense:
                 return x + GatedMLP(self.intermediate_size, dt, name=pre + "mlp")(h)
@@ -535,6 +654,7 @@ class HybridDecoderLM(nn.Module):
             jnp.stack(buffers).astype(jnp.float32) if buffers
             else jnp.zeros((0,), jnp.float32),
             *mtp_logits,
+            *((sum(index_kl), jnp.stack(index_pairs)) if self.indexer else ()),
         )
 
 
@@ -711,4 +831,72 @@ def _from_laguna(cfg: dict) -> dict:
         renormalise=bool(cfg.get("norm_topk_prob", True)),
         routed_scale=float(cfg.get("moe_routed_scaling_factor", 1.0)),
         shared_width=int(cfg.get("shared_expert_intermediate_size", 0)),
+    )
+
+
+def _from_qwen3_moe_keys(cfg: dict) -> dict:
+    """Qwen3-MoE's keys, as ``KeyeVL2``'s language model writes them: causal
+    grouped-query attention with a learned per-head RMSNorm on q and k, one
+    ``rope_theta`` over the whole head, turned by three rows of positions
+    where ``rope_scaling.mrope_section`` says so (consecutive sections);
+    every layer an expert layer (``decoder_sparse_step`` 1, ``mlp_only_layers``
+    empty) with softmax scores renormalised over the picks where
+    ``norm_topk_prob``, no selection bias, no shared expert, no scale. With
+    ``sa_config`` attention runs under the mask its indexer learns
+    (:class:`IndexerRule`); without it the same reader builds plain causal
+    attention. Refused by name, because not built: a sliding window in
+    use, a ``rope_scaling`` type other than ``default``, interleaved
+    sections (``mrope_interleaved``), a dense layer among the expert layers
+    (``decoder_sparse_step`` != 1, a non-empty ``mlp_only_layers``), more
+    than one index key head, biases, tied embeddings, recomputation."""
+    refused = {
+        "use_sliding_window": False, "decoder_sparse_step": 1, "mlp_only_layers": [],
+        "attention_bias": False, "tie_word_embeddings": False, "hidden_act": "silu",
+    }
+    for key, built in refused.items():
+        if cfg.get(key, built) != built:
+            raise ValueError(f"{key} = {cfg[key]!r} is not built (only {built!r})")
+    scaling = cfg.get("rope_scaling") or {}
+    for key in ("rope_type", "type"):
+        if scaling.get(key, "default") != "default":
+            raise ValueError(
+                f"rope_scaling.{key} = {scaling[key]!r} is not built (only 'default')"
+            )
+    if "mrope_interleaved" in scaling or "mrope_interleaved" in cfg:
+        raise ValueError(
+            "mrope_interleaved is not built (consecutive mrope_section columns only)"
+        )
+    program = cfg.get("program", {})
+    if program.get("remat"):
+        raise ValueError(f"program.remat {program['remat']!r}: recomputation is not built")
+    head_dim = int(cfg["head_dim"])
+    sections = tuple(int(n) for n in scaling.get("mrope_section", ()))
+    if sections and sum(sections) != head_dim // 2:
+        raise ValueError(f"mrope_section {sections} does not fill half a head of {head_dim}")
+    indexer, sa = None, cfg.get("sa_config")
+    if sa is not None:
+        if int(sa.get("indexer_num_kv_heads", 1)) != 1:
+            raise ValueError(
+                f"sa_config.indexer_num_kv_heads = {sa['indexer_num_kv_heads']!r} "
+                "is not built (only one index key head)"
+            )
+        indexer = IndexerRule(
+            int(sa["indexer_num_heads"]), int(sa["indexer_head_dim"]), int(sa["topk"])
+        )
+    total, first, count = _held_share(cfg, "num_experts")
+    layers = int(cfg["num_hidden_layers"])
+    return dict(
+        vocab=int(cfg["vocab_size"]), d_model=int(cfg["hidden_size"]),
+        layer_types=("full_attention",) * layers, num_dense_layers=0,
+        n_heads=int(cfg["num_attention_heads"]),
+        n_kv_heads=int(cfg["num_key_value_heads"]), head_dim=head_dim,
+        intermediate_size=int(cfg["intermediate_size"]),
+        moe_intermediate_size=int(cfg["moe_intermediate_size"]),
+        num_experts=total,
+        experts_per_token=int(cfg["num_experts_per_tok"]),
+        held_first=first, held_count=count,
+        norm_eps=float(cfg["rms_norm_eps"]), rope_theta=float(cfg["rope_theta"]),
+        use_select_bias=False, router_score="softmax",
+        renormalise=bool(cfg["norm_topk_prob"]), routed_scale=1.0,
+        indexer=indexer, mrope_sections=sections or None,
     )

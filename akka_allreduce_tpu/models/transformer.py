@@ -44,10 +44,17 @@ def rope_angles(
     *,
     base: float,
     yarn: tuple[float, int, float, float] | None = None,
+    positions: jax.Array | None = None,
+    sections: tuple[int, ...] | None = None,
 ):
     """Position x frequency, float32 (T, d/2), for positions offset + arange(T)
     and a rotary width ``d``: position precision is what long-context rope
     depends on, so the angles and the tables made of them are float32.
+
+    ``positions`` (S, T) with ``sections`` (S column counts that sum to d/2)
+    is multimodal RoPE: frequency ``i`` turns by the row of positions whose
+    section holds it, the sections lying one after another (text has equal
+    rows, and its angles are bit-equal to the one-row ones).
 
     ``yarn`` = ``(factor, original positions L, beta_fast, beta_slow)``
     blends the frequencies as YaRN does (Peng et al. 2023, section 3.2, as
@@ -72,6 +79,20 @@ def rope_angles(
             (jnp.arange(d // 2, dtype=jnp.float32) - low) / (high - low), 0, 1
         )
         freqs = (freqs / factor) * ramp + freqs * (1 - ramp)
+    if positions is not None:
+        if positions.shape != (len(sections), t) or sum(sections) != d // 2:
+            raise ValueError(
+                f"positions {positions.shape} and sections {sections} for "
+                f"{t} positions and {d // 2} frequencies"
+            )
+        ends = np.cumsum(sections)
+        return jnp.concatenate(
+            [
+                row[:, None].astype(jnp.float32) * freqs[None, end - n: end]
+                for row, n, end in zip(positions, sections, ends)
+            ],
+            axis=-1,
+        )
     return pos[:, None].astype(jnp.float32) * freqs[None, :]
 
 
@@ -136,6 +157,8 @@ def rope_tables(
     yarn: tuple[float, int, float, float] | None = None,
     attention_factor: float = 1.0,
     scale: float = 1.0,
+    positions: jax.Array | None = None,
+    sections: tuple[int, ...] | None = None,
 ):
     """:func:`rope`'s rule as two float32 (T, d) tables ``cos``, ``sin`` over
     the WHOLE head, as :func:`rope_heads_first` takes it: ``scale * rope(x) = x * cos
@@ -143,11 +166,14 @@ def rope_tables(
     and ``sin`` of :func:`rope_angles` times ``attention_factor * scale``, the
     sine negative in their first half (``x1 cos - x2 sin``); in the columns
     that pass, ``cos = scale`` and ``sin = 0``. ``scale`` is where a caller
-    puts the score scale of q: in float32, before the table's one cast."""
+    puts the score scale of q: in float32, before the table's one cast.
+    ``positions`` / ``sections`` as :func:`rope_angles` takes them."""
     r = d if rotary_dim is None else rotary_dim
     if r % 2 or not 0 < r <= d:
         raise ValueError(f"rotary width {r} of a head of {d}")
-    ang = rope_angles(t, r, offset, base=base, yarn=yarn)
+    ang = rope_angles(
+        t, r, offset, base=base, yarn=yarn, positions=positions, sections=sections
+    )
     cos, sin = (fn(ang) * (attention_factor * scale) for fn in (jnp.cos, jnp.sin))
     return (
         jnp.concatenate((cos, cos, jnp.full((t, d - r), scale, jnp.float32)), axis=-1),
@@ -164,6 +190,8 @@ def rope_heads_first(
     yarn: tuple[float, int, float, float] | None = None,
     attention_factor: float = 1.0,
     scale: float = 1.0,
+    positions: jax.Array | None = None,
+    sections: tuple[int, ...] | None = None,
 ) -> jax.Array:
     """``scale *`` :func:`rope` on ``x`` (B, H, T, D), the layout the
     attention kernel reads: ``x * cos + partner(x) * sin`` in ``x``'s dtype
@@ -182,6 +210,7 @@ def rope_heads_first(
     cos, sin = rope_tables(
         t, d, offset, base=base, rotary_dim=r, yarn=yarn,
         attention_factor=attention_factor, scale=scale,
+        positions=positions, sections=sections,
     )
     j = np.arange(d)
     swap = np.zeros((d, d), np.float32)

@@ -67,6 +67,7 @@ def _blockwise_olm(
     v_scale: jax.Array | None = None,
     vary_axes: tuple = (),
     window: int | None = None,
+    mask: jax.Array | None = None,
 ):
     """Blockwise online-softmax PARTIALS ``(o, l, m)`` over a local K/V
     slice — the un-normalized core of :func:`blockwise_attention`, also
@@ -77,7 +78,10 @@ def _blockwise_olm(
     full-precision memory stays O(block), never the whole slice.
     ``vary_axes``: mesh axes the K/V slice is device-varying over when
     called inside ``shard_map`` — the scan's zero-initialized carry must
-    be pcast to match, or the vma typecheck rejects the loop.
+    be pcast to match, or the vma typecheck rejects the loop (the axes the
+    operands' own types show are added to them).
+    ``mask`` (B, Tq, Tk), nonzero where the query sees the key: a key is
+    visible iff the mask AND the causal rule (where asked for) say so.
     """
     from akka_allreduce_tpu.ops.ring_attention import _MASK_VALUE, repeat_kv
 
@@ -103,11 +107,16 @@ def _blockwise_olm(
             b, nb, block_k, h
         ).transpose(1, 0, 2, 3)
         blk = blk + (sb(k_scale), sb(v_scale))
+    if mask is not None:  # (nb, B, 1, Tq, block): one block of keys a step
+        seen = jnp.pad(mask != 0, ((0, 0), (0, 0), (0, pad)))
+        blk = blk + (seen.reshape(b, 1, tq, nb, block_k).transpose(3, 0, 1, 2, 4),)
 
     qf = q.astype(jnp.float32)
     q_pos = q_offset + jnp.arange(tq)
 
     def block_step(olm, blk):
+        if mask is not None:
+            *blk, seen = blk
         if k_scale is not None:
             idx, kk, vv, ks, vs = blk
             kk = kk.astype(jnp.float32) * ks[..., None]
@@ -122,11 +131,18 @@ def _blockwise_olm(
                 valid &= q_pos[:, None] - k_pos[None, :] < window
         else:
             valid = jnp.broadcast_to(valid[None, :], (tq, block_k))
+        if mask is not None:
+            valid = valid[None, None] & seen
         return online_softmax_update(olm, qf, kk, vv, scale, valid), None
 
     o0 = jnp.zeros((b, h, tq, dv), jnp.float32)
     l0 = jnp.zeros((b, h, tq), jnp.float32)
     m0 = jnp.full((b, h, tq), _MASK_VALUE, jnp.float32)
+    # besides the axes named, those the operands are seen to vary over (a
+    # caller under shard_map that names none: attention under an array mask)
+    operands = (q, k, v) if mask is None else (q, k, v, mask)
+    seen_to_vary = set().union(*(jax.typeof(a).vma for a in operands))
+    vary_axes = tuple(vary_axes) + tuple(sorted(seen_to_vary - set(vary_axes)))
     if vary_axes:
         o0, l0, m0 = (
             lax.pcast(x, vary_axes, to="varying") for x in (o0, l0, m0)
@@ -149,7 +165,9 @@ def blockwise_attention(
     k_offset: int | jax.Array = 0,
     block_k: int = 512,
     window: int | None = None,
-) -> jax.Array:
+    mask: jax.Array | None = None,
+    with_lse: bool = False,
+):
     """Memory-efficient attention over K/V blocks; same result as
     :func:`attention_reference` to float tolerance.
 
@@ -157,16 +175,22 @@ def blockwise_attention(
     Dv = D or not (latent attention: 192 against 128); the result has Dv.
     Offsets position the local windows globally for causal masking (as in
     ring attention); ``window`` as :func:`attention_reference` has it.
+    ``mask`` (B, Tq, Tk), nonzero where a query sees a key, narrows what the
+    other rules leave (the portable core of attention under a mask that
+    arrives as an array). ``with_lse``: also the rows' float32 log-sum-exp
+    over their visible keys, (B, H, Tq).
     """
     if window is not None and not causal:
         raise ValueError("a window is built for causal attention only")
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(q.shape[-1])
-    o, l, _ = _blockwise_olm(
+    o, l, m = _blockwise_olm(
         q, k, v, causal=causal, scale=scale,
         q_offset=q_offset, k_offset=k_offset, block_k=block_k, window=window,
+        mask=mask,
     )
-    out = o / jnp.maximum(l, 1e-30)[..., None]
-    return out.transpose(0, 2, 1, 3).astype(q.dtype)
+    l = jnp.maximum(l, 1e-30)
+    out = (o / l[..., None]).transpose(0, 2, 1, 3).astype(q.dtype)
+    return (out, m + jnp.log(l)) if with_lse else out
 
 
 def flash_shapes_ok(t: int, d: int, dv: int | None = None) -> bool:
@@ -559,16 +583,60 @@ def local_attention(
     )
 
 
+@functools.lru_cache(maxsize=None)
+def _gauge_sparse(t: int) -> None:
+    """What the masked kernels' tiles run, once a shape, to the gauge
+    ``attention.sparse.visited_pairs`` (a head's; OBSERVABILITY.md): every
+    tile the kernels' own grid rule does not skip, whole. The pairs the mask
+    keeps, ``attention.sparse.mask_pairs``, are written where the mask is
+    made (``sparse_attention.indexer_mask``)."""
+    from akka_allreduce_tpu.obs import metrics as obs_metrics
+    from akka_allreduce_tpu.ops.sparse_attention import visited_pairs
+
+    obs_metrics.gauge("attention.sparse.visited_pairs").set(visited_pairs(t))
+
+
+def _masked_heads_first(q, k, v, mask):
+    """:func:`heads_first_attention` under ``mask``: ``(out, lse)`` with
+    ``lse`` float32 (B, H_kv, G, T). The repo's masked kernels
+    (``ops/sparse_attention.py``) where they take the shape, engaged by the
+    presence of a mask alone; else the portable core."""
+    from akka_allreduce_tpu.ops._platform import interpret_default
+    from akka_allreduce_tpu.ops.sparse_attention import sparse_attention, takes_sparse
+
+    b, h, t, _ = q.shape
+    h_kv = k.shape[1]
+    if not interpret_default(q, k) and takes_sparse(t, q.shape[-1], v.shape[-1]):
+        _gauge_sparse(t)
+        return sparse_attention(q, k, v, mask.astype(jnp.int8), False)
+    swap = lambda x: x.transpose(0, 2, 1, 3)  # noqa: E731
+    out, lse = blockwise_attention(
+        swap(q), swap(k), swap(v), causal=True, sm_scale=1.0, mask=mask,
+        with_lse=True, block_k=min(t, 512),
+    )
+    return swap(out), lse.reshape(b, h_kv, h // h_kv, t)
+
+
 def heads_first_attention(
     q: jax.Array, k: jax.Array, v: jax.Array, *, causal: bool = False,
-    window: int | None = None,
-) -> jax.Array:
+    window: int | None = None, mask: jax.Array | None = None,
+):
     """:func:`local_attention` for a caller whose products already wrote the
     kernel's layout: ``q`` (B, H, T, D) WITH the score scale in it (folded
     into the weight that made it), ``k`` (B, H_kv, T, D), ``v`` (B, H_kv, T,
     Dv); returns (B, H, T, Dv). Where the kernel takes the shape nothing is
     transposed, scaled or copied on the way in or out; elsewhere the portable
-    cores get the sequence-first views."""
+    cores get the sequence-first views.
+
+    ``mask`` (B, T, T), nonzero where a query sees a key (causal attention
+    only: a key is seen iff the mask AND the causal rule say so; every query
+    sees a key): attention under a mask that arrives as an array. The result
+    is then ``(out, lse)``, the rows' float32 log-sum-exp (B, H_kv, G, T)
+    beside the output."""
+    if mask is not None:
+        if not causal or window is not None:
+            raise ValueError("an array mask is built for causal attention without a window")
+        return _masked_heads_first(q, k, v, mask)
     if _flash_ok(q, k, v, 0, 0, seq_axis=2):
         return _splash_heads_first(q, k, v, causal=causal, window=window)
     swap = lambda x: x.transpose(0, 2, 1, 3)  # noqa: E731

@@ -58,8 +58,8 @@ def online_softmax_update(olm, qf, kk, vv, scale, mask):
     """One flash-style block fold: merge K/V block (kk, vv) into the running
     ``(o, l, m)`` statistics for queries ``qf`` (all fp32).
 
-    ``mask``: boolean (Tq, Tk_block) visibility, or None for a fully visible
-    block. Masked positions contribute EXACTLY zero — including the corner
+    ``mask``: boolean (Tq, Tk_block) visibility (or (B, 1, Tq, Tk_block),
+    one a sample), or None for a fully visible block. Masked positions contribute EXACTLY zero — including the corner
     case where a whole row has seen nothing yet (m still at the sentinel):
     there ``exp(score - m) = 1`` would otherwise leak mask/padding entries
     into ``l``. Shared by ring attention (cross-device blocks) and the
@@ -68,12 +68,14 @@ def online_softmax_update(olm, qf, kk, vv, scale, mask):
     """
     o, l, m = olm
     scores = jnp.einsum("bqhd,bkhd->bhqk", qf, kk.astype(jnp.float32)) * scale
+    if mask is not None and mask.ndim == 2:
+        mask = mask[None, None]
     if mask is not None:
-        scores = jnp.where(mask[None, None], scores, _MASK_VALUE)
+        scores = jnp.where(mask, scores, _MASK_VALUE)
     m_new = jnp.maximum(m, scores.max(axis=-1))
     p = jnp.exp(scores - m_new[..., None])
     if mask is not None:
-        p = jnp.where(mask[None, None], p, 0.0)
+        p = jnp.where(mask, p, 0.0)
     corr = jnp.exp(m - m_new)
     l = l * corr + p.sum(axis=-1)
     o = o * corr[..., None] + jnp.einsum(

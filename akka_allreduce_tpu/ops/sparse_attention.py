@@ -1,0 +1,508 @@
+"""Attention under a mask that arrives as an array, and the learned indexer
+that makes one (DeepSeek-V3.2-Exp's sparse attention, as ``sa_config`` of
+``KeyeVL2`` sizes it): every query keeps the ``topk`` keys at or before it
+that an index score ranks highest, and attends to those alone.
+
+Four parts, each a function of its own:
+
+- :func:`index_scores`: ``I[t, s] = sum_j w[t, j] relu(q_I[t, j] . k_I[s])``,
+  float32, from the operands' dtype into the MXU.
+- :func:`select_keys`: scores in, a mask out with exactly ``min(t + 1,
+  topk)`` keys a row among the keys ``s <= t``, ties to the lower index. The
+  mathematics needs the SET, not its order, so no row is sorted: the
+  ``topk``-th largest value of a row is found by bisection over the 32 bits
+  of a key that orders as the floats do (32 counting passes over the block),
+  and the keys tied with it are ranked by position only where a row has more
+  of them than it may keep.
+- :func:`sparse_attention`: three Pallas TPU kernels of the repo's own,
+  ``flash_mha_sparse_fwd`` / ``_dq`` / ``_dkv``, in ``ops/band_attention.py``'s
+  manner (a group's query heads stacked as rows of one product, f32 scores
+  and statistics, the operands' dtype into the MXU) with what a mask known
+  only at run time asks for beside it: a loop over K/V tiles with a running
+  maximum, the mask an int8 operand read a (queries, keys) tile at a time,
+  the tiles above the diagonal skipped from the grid position, the one
+  astride it cut by the causal rule from iotas. Returns the
+  rows' log-sum-exp beside the output: the indexer's loss reads it.
+- :func:`indexer_kl`: the indexer's own loss, ``sum_t KL(pbar_t || softmax
+  over S_t of I[t, .])`` with ``pbar`` the head mean of the main attention's
+  probabilities on the selected set, a block of query rows at a time so that
+  no (heads, T, T) array is ever whole. Its gradient reaches ``q_I``, ``k_I``
+  and ``w`` alone and is made in the forward pass, block by block (the
+  block's scores are alive then anyway), and kept as the residual: three
+  small arrays where the scores of every block would have been.
+
+The kernels take T that their tiles divide and heads of up to 128
+(:func:`takes_sparse`); any other shape goes to the portable core
+(``local_attention._blockwise_olm`` with the mask).
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from akka_allreduce_tpu.ops.ring_attention import _MASK_VALUE
+
+#: queries and keys a grid step of each kernel: a group's eight heads stack
+#: ``BLOCK_Q`` rows each into one product against ``BLOCK_K`` keys
+BLOCK_Q = 256
+BLOCK_K = 512
+#: query rows of index scores (and of the loss's target) alive at once, and
+#: the runs of such blocks that share their columns (:func:`_stages`)
+INDEX_ROWS = 512
+STAGES = 4
+_NT = (((1,), (1,)), ((), ()))  # a @ b.T
+_TN = (((0,), (0,)), ((), ()))  # a.T @ b
+_VMEM_LIMIT = 100 * 1024 * 1024  # of a v5e core's 128 MiB
+
+
+# -- the indexer's scores and the selection ---------------------------------------
+
+
+def index_scores(q_i: jax.Array, k_i: jax.Array, w: jax.Array) -> jax.Array:
+    """``q_i`` (J, R, D), ``k_i`` (C, D), ``w`` (R, J) -> float32 (R, C):
+    ``sum_j w[r, j] relu(q_i[j, r] . k_i[c])``."""
+    pre = jnp.einsum("jrd,cd->jrc", q_i, k_i, preferred_element_type=jnp.float32)
+    return jnp.einsum("jrc,rj->rc", jax.nn.relu(pre), w.astype(jnp.float32))
+
+
+def _ordered_bits(x: jax.Array) -> jax.Array:
+    """uint32 keys that order as the float32 ``x`` do (-0.0 as +0.0)."""
+    bits = lax.bitcast_convert_type(jnp.where(x == 0, 0.0, x), jnp.uint32)
+    return jnp.where(bits >> 31 == 1, ~bits, bits | jnp.uint32(1 << 31))
+
+
+def select_keys(scores: jax.Array, topk: int, row0: int = 0) -> jax.Array:
+    """float32 ``scores`` (R, C) of the queries at positions ``row0 .. row0 +
+    R - 1`` against the keys at 0 .. C - 1 -> bool (R, C): for the query at
+    ``t`` the ``min(t + 1, topk)`` keys ``s <= t`` of largest score, of equal
+    scores the lower ``s`` first. No gradient passes."""
+    r, c = scores.shape
+    t = row0 + jnp.arange(r, dtype=jnp.int32)
+    causal = jnp.arange(c, dtype=jnp.int32)[None, :] <= t[:, None]
+    want = jnp.minimum(t + 1, topk)
+    # a key no causal entry has: below every float, NaN apart
+    key = jnp.where(causal, _ordered_bits(lax.stop_gradient(scores)), jnp.uint32(0))
+
+    def at_least(v):
+        return jnp.sum(key >= v[:, None], axis=1, dtype=jnp.int32)
+
+    def bit(i, v):  # the largest v with ``want`` keys at or above it, bit by bit
+        cand = v | (jnp.uint32(1 << 31) >> i.astype(jnp.uint32))
+        return jnp.where(at_least(cand) >= want, cand, v)
+
+    # from the keys, so that the carry varies over a mesh as they do
+    kth = lax.fori_loop(0, 32, bit, key[:, 0] & jnp.uint32(0))
+
+    def ranked():  # some row holds more keys tied at its threshold than it may keep
+        above, tied = key > kth[:, None], key == kth[:, None]
+        room = want - jnp.sum(above, axis=1, dtype=jnp.int32)
+        return above | (tied & (jnp.cumsum(tied, axis=1, dtype=jnp.int32) <= room[:, None]))
+
+    return lax.cond(
+        jnp.all(at_least(kth) == want), lambda: key >= kth[:, None], ranked
+    )
+
+
+def _stages(t: int, rows: int) -> list[tuple[int, int, int]]:
+    """``(first row, end, rows a block)`` of up to :data:`STAGES` runs of
+    whole blocks of ``rows`` query rows. A run's blocks go through one loop,
+    each against the keys before the run's end: one compiled body a run, one
+    block alive at a time, and of the pairs above the diagonal only those
+    inside a run's own columns are made. T that ``rows`` does not divide (or
+    does not pass) is one block of all its rows."""
+    if t <= rows or t % rows:
+        return [(0, t, t)]
+    blocks = t // rows
+    per = -(-blocks // STAGES)
+    return [(b * rows, min(b + per, blocks) * rows, rows) for b in range(0, blocks, per)]
+
+
+def _rows_at(a, start, rows: int, axis: int):
+    return lax.dynamic_slice_in_dim(a, start, rows, axis=axis)
+
+
+@functools.lru_cache(maxsize=None)
+def _gauge_selected(t: int, topk: int) -> None:
+    """The pairs a sequence's mask keeps, once a shape, to the gauge
+    ``attention.sparse.mask_pairs`` (OBSERVABILITY.md): ``sum_t min(t + 1,
+    topk)``, what :func:`select_keys` keeps of ANY scores."""
+    from akka_allreduce_tpu.obs import metrics as obs_metrics
+
+    keys = min(topk, t)
+    obs_metrics.gauge("attention.sparse.mask_pairs").set(
+        keys * (keys + 1) // 2 + (t - keys) * keys
+    )
+
+
+def indexer_mask(q_i, k_i, w, topk: int):
+    """One sequence's mask from its indexer's operands (``q_i`` (J, T, D),
+    ``k_i`` (T, D), ``w`` (T, J)): int8 (T, T), 1 where the query of the row
+    sees the key of the column. :data:`INDEX_ROWS` query rows at a time
+    (:func:`_stages`); a run wholly inside the first ``topk`` positions sees
+    every causal key and makes no score."""
+    t = k_i.shape[0]
+    _gauge_selected(t, topk)
+    out = []
+    for r0, r1, n in _stages(t, INDEX_ROWS):
+        if r1 <= topk:
+            seen = (jnp.arange(r1)[None, :] <= jnp.arange(r0, r1)[:, None]).astype(jnp.int8)
+        else:
+            keys = k_i[:r1]
+
+            def block(start, n=n, keys=keys):
+                with jax.named_scope("indexer_scores"):
+                    scores = index_scores(
+                        _rows_at(q_i, start, n, 1), keys, _rows_at(w, start, n, 0)
+                    )
+                with jax.named_scope("indexer_select"):
+                    return select_keys(scores, topk, start).astype(jnp.int8)
+
+            seen = lax.map(block, jnp.arange(r0, r1, n, dtype=jnp.int32))
+            seen = seen.reshape(r1 - r0, r1)
+        out.append(jnp.pad(seen, ((0, 0), (0, t - r1))))
+    return jnp.concatenate(out, axis=0)
+
+
+# -- the indexer's loss -------------------------------------------------------------
+
+
+def _target_block(q, k, lse, seen):
+    """Head mean of the main attention's probabilities, (R, C) float32:
+    ``q`` (H_kv, G, R, D) with the score scale in it, ``k`` (H_kv, C, D),
+    ``lse`` (H_kv, G, R) the rows' log-sum-exp over their selected keys."""
+    s = jnp.einsum("kgrd,kcd->kgrc", q, k, preferred_element_type=jnp.float32)
+    p = jnp.exp(s - lse[..., None]).sum(axis=(0, 1)) / (q.shape[0] * q.shape[1])
+    return jnp.where(seen, p, 0.0)
+
+
+def _kl_block(q_i, k_i, w, seen, target):
+    """``sum_r KL(target_r || softmax over the seen keys of I[r, .])``."""
+    scores = jnp.where(seen, index_scores(q_i, k_i, w), -jnp.inf)
+    log_q = scores - jax.nn.logsumexp(scores, axis=1, keepdims=True)
+    held = seen & (target > 0)
+    log_p = jnp.log(jnp.where(held, target, 1.0))
+    return jnp.sum(jnp.where(held, target * (log_p - log_q), 0.0))
+
+
+def _kl_blocks(q_i, k_i, w, mask, q, k, lse, with_grads: bool):
+    """The loss, and with it ``(dq_i, dk_i, dw)`` where asked for: a block of
+    rows at a time through each run's loop, the keys' gradient carried."""
+    h_kv, g = lse.shape[:2]
+    t = k_i.shape[0]
+    q = q.reshape(h_kv, g, t, -1)
+    # zeros made from the operands, so that the carries vary over a mesh as they do
+    dk = k_i.astype(jnp.float32) * 0.0
+    total = dk[0, 0]
+    dq, dw = [], []
+    for r0, r1, n in _stages(t, INDEX_ROWS):
+        keys_i, keys = k_i[:r1], k[:, :r1]
+
+        def block(carry, start, n=n, keys_i=keys_i, keys=keys, r1=r1):
+            seen = _rows_at(mask, start, n, 0)[:, :r1] != 0
+            with jax.named_scope("indexer_target"):
+                target = _target_block(
+                    _rows_at(q, start, n, 2), keys, _rows_at(lse, start, n, 2), seen
+                )
+            kl_of = functools.partial(_kl_block, seen=seen, target=target)
+            operands = (_rows_at(q_i, start, n, 1), keys_i, _rows_at(w, start, n, 0))
+            with jax.named_scope("indexer_scores"):
+                if not with_grads:
+                    return (carry[0] + kl_of(*operands), carry[1]), None
+                kl, pull = jax.vjp(kl_of, *operands)
+                dq_b, dk_b, dw_b = pull(kl * 0.0 + 1.0)  # a one that varies as kl
+            return (carry[0] + kl, carry[1] + dk_b.astype(jnp.float32)), (dq_b, dw_b)
+
+        (total, dk_run), grads = lax.scan(
+            block, (total, dk[:r1]), jnp.arange(r0, r1, n, dtype=jnp.int32)
+        )
+        if with_grads:
+            dk = dk.at[:r1].set(dk_run)
+            dq.append(grads[0].transpose(1, 0, 2, 3).reshape(q_i.shape[0], r1 - r0, -1))
+            dw.append(grads[1].reshape(r1 - r0, -1))
+    if not with_grads:
+        return total
+    return total, (jnp.concatenate(dq, axis=1), dk.astype(k_i.dtype), jnp.concatenate(dw))
+
+
+@jax.custom_vjp
+def indexer_kl(q_i, k_i, w, mask, q, k, lse):
+    """One sequence's ``sum_t KL(pbar_t || softmax over S_t of I[t, .])``,
+    float32: ``q_i`` (J, T, D), ``k_i`` (T, D), ``w`` (T, J) the indexer's
+    operands; ``mask`` int8 (T, T) the selection ``S``; ``q`` (H, T, D) WITH
+    the score scale, ``k`` (H_kv, T, D) and ``lse`` (H_kv, G, T) the main
+    attention's, through which no gradient passes. :data:`INDEX_ROWS` query
+    rows at a time (:func:`_stages`)."""
+    return _kl_blocks(q_i, k_i, w, mask, q, k, lse, False)
+
+
+def _indexer_kl_fwd(q_i, k_i, w, mask, q, k, lse):
+    return _kl_blocks(q_i, k_i, w, mask, q, k, lse, True)
+
+
+def _indexer_kl_bwd(grads, g):
+    dq, dk, dw = grads
+    scaled = lambda a: (g * a.astype(jnp.float32)).astype(a.dtype)  # noqa: E731
+    return scaled(dq), scaled(dk), scaled(dw), None, None, None, None
+
+
+indexer_kl.defvjp(_indexer_kl_fwd, _indexer_kl_bwd)
+
+
+# -- attention under the mask: the kernels ---------------------------------------
+
+
+def takes_sparse(t: int, d: int, dv: int) -> bool:
+    """Do the kernels take the shape? T that both tiles divide, heads of up
+    to 128 (a group's stacked scores against a tile of keys fit VMEM)."""
+    return t % BLOCK_Q == 0 and t % BLOCK_K == 0 and max(d, dv) <= 128
+
+
+def visited_pairs(t: int) -> int:
+    """(query, key) pairs a head's tiles run, by the rule the kernels' grids
+    skip by (:func:`_last_key_tile`): every tile that holds a causal pair,
+    whole. A tile without a selected key is not skipped (on a learned mask
+    over 8,192 keys a 256 x 512 tile without one is not to be had)."""
+    return sum(
+        BLOCK_Q * BLOCK_K * (_last_key_tile(i) + 1) for i in range(t // BLOCK_Q)
+    )
+
+
+def _last_key_tile(i):
+    """The last tile of keys that holds a key at or before a query of tile i."""
+    return ((i + 1) * BLOCK_Q - 1) // BLOCK_K
+
+
+def _first_query_tile(j):
+    """The first tile of queries that holds a query at or after a key of tile j."""
+    return (j * BLOCK_K) // BLOCK_Q
+
+
+def _dot(a, b, dims=(((1,), (0,)), ((), ()))):
+    return lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
+
+
+def _visit(i, j, body):
+    """Run ``body`` at the grid steps whose tile (query tile ``i``, key tile
+    ``j``) holds a causal pair: ``body(None)`` on the tiles wholly below the
+    diagonal, compiled without the causal rule, and ``body((i, j))`` on the
+    one astride it, the last of the row (a query tile lies inside ONE key
+    tile, :data:`BLOCK_K` being a multiple of :data:`BLOCK_Q`)."""
+    assert BLOCK_K % BLOCK_Q == 0
+    last = _last_key_tile(i)
+    pl.when(j < last)(lambda: body(None))
+    pl.when(j == last)(lambda: body((i, j)))
+
+
+def _scores(q_ref, k_ref, mask_ref, astride):
+    """A group's stacked scores against a tile of keys, (G, rows, keys) f32,
+    the unseen ones at the mask value, and which are seen, (1, rows, keys):
+    where the mask says so and, on the tile ``astride`` the diagonal (its
+    grid position), the causal rule too."""
+    g, b, d = q_ref.shape
+    kept = mask_ref[...].astype(jnp.int32)
+    if astride is not None:
+        rows = astride[0] * BLOCK_Q + lax.broadcasted_iota(jnp.int32, kept.shape, 0)
+        keys = astride[1] * BLOCK_K + lax.broadcasted_iota(jnp.int32, kept.shape, 1)
+        kept = jnp.where(keys <= rows, kept, 0)
+    seen = (kept != 0)[None]
+    s = _dot(q_ref[...].reshape(g * b, d), k_ref[...], _NT).reshape(g, b, -1)
+    return jnp.where(seen, s, _MASK_VALUE), seen
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr):
+    g, b, _ = q_ref.shape
+    i, j = pl.program_id(2), pl.program_id(3)
+
+    @pl.when(j == 0)
+    def _():
+        m_scr[...] = jnp.full(m_scr.shape, _MASK_VALUE, jnp.float32)
+        l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
+        acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
+
+    def visit(astride):
+        s, seen = _scores(q_ref, k_ref, mask_ref, astride)
+        m_old = m_scr[...]
+        m_new = jnp.maximum(m_old, s.max(axis=-1, keepdims=True))
+        # a row that has seen nothing yet keeps the mask value as its maximum:
+        # exp(s - m) would be 1 on its unseen keys
+        p = jnp.where(seen, jnp.exp(s - m_new), 0.0)
+        fade = jnp.exp(m_old - m_new)
+        l_scr[...] = fade * l_scr[...] + p.sum(axis=-1, keepdims=True)
+        v = v_ref[...]
+        pv = _dot(p.reshape(g * b, -1).astype(v.dtype), v).reshape(g, b, -1)
+        acc_scr[...] = fade * acc_scr[...] + pv
+        m_scr[...] = m_new
+
+    _visit(i, j, visit)
+
+    @pl.when(j == pl.num_programs(3) - 1)
+    def _():
+        l = l_scr[...]
+        o_ref[...] = (acc_scr[...] / l).astype(o_ref.dtype)
+        lse_ref[...] = (m_scr[...] + jnp.log(l))[..., 0]
+
+
+def _probs(q_ref, k_ref, mask_ref, lse_ref, astride):
+    s, seen = _scores(q_ref, k_ref, mask_ref, astride)
+    return jnp.where(seen, jnp.exp(s - lse_ref[...][..., None]), 0.0)
+
+
+def _dq_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref, dq_ref, acc_scr):
+    g, b, d = q_ref.shape
+    i, j = pl.program_id(2), pl.program_id(3)
+
+    @pl.when(j == 0)
+    def _():
+        acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
+
+    def visit(astride):
+        k = k_ref[...]
+        p = _probs(q_ref, k_ref, mask_ref, lse_ref, astride)
+        dp = _dot(do_ref[...].reshape(g * b, -1), v_ref[...], _NT).reshape(p.shape)
+        ds = p * (dp - delta_ref[...][..., None])
+        acc_scr[...] += _dot(ds.reshape(g * b, -1).astype(k.dtype), k).reshape(g, b, d)
+
+    _visit(i, j, visit)
+
+    @pl.when(j == pl.num_programs(3) - 1)
+    def _():
+        dq_ref[...] = acc_scr[...].astype(dq_ref.dtype)
+
+
+def _dkv_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
+                dk_ref, dv_ref, dk_scr, dv_scr):
+    g, b, d = q_ref.shape
+    j, i = pl.program_id(2), pl.program_id(3)
+
+    @pl.when(i == 0)
+    def _():
+        dk_scr[...] = jnp.zeros(dk_scr.shape, jnp.float32)
+        dv_scr[...] = jnp.zeros(dv_scr.shape, jnp.float32)
+
+    def visit(astride):
+        # rows are the group's stacked queries: contracting them sums the
+        # group's heads into the one K/V head
+        q, do = q_ref[...].reshape(g * b, d), do_ref[...].reshape(g * b, -1)
+        p = _probs(q_ref, k_ref, mask_ref, lse_ref, astride)
+        dp = _dot(do, v_ref[...], _NT).reshape(p.shape)
+        ds = p * (dp - delta_ref[...][..., None])
+        dv_scr[...] += _dot(p.reshape(g * b, -1).astype(do.dtype), do, _TN)
+        dk_scr[...] += _dot(ds.reshape(g * b, -1).astype(q.dtype), q, _TN)
+
+    _visit(i, j, visit)  # the query tiles before key tile j's first lie above the diagonal
+
+    @pl.when(i == pl.num_programs(3) - 1)
+    def _():
+        dk_ref[...] = dk_scr[...].astype(dk_ref.dtype)
+        dv_ref[...] = dv_scr[...].astype(dv_ref.dtype)
+
+
+def _call(kernel, name, grid, in_specs, out_specs, out_shape, scratch, interpret):
+    return pl.pallas_call(
+        kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
+        out_shape=out_shape, scratch_shapes=scratch, name=name, interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT,
+        ),
+    )
+
+
+# Index maps. Query-major kernels (forward, dq) run (n, h, i, j), the key
+# tiles above the diagonal clamped onto the last one below it, so that the
+# skipped steps fetch nothing; the key-major one (dkv) runs (n, h, j, i) with
+# the query tiles before the first useful one clamped onto it.
+def _specs(g: int, key_major: bool):
+    if key_major:
+        at = lambda j, i: (jnp.maximum(i, _first_query_tile(j)), j)  # noqa: E731
+    else:
+        at = lambda i, j: (i, jnp.minimum(j, _last_key_tile(i)))  # noqa: E731
+    queries = lambda w: pl.BlockSpec(  # noqa: E731
+        (None, g, BLOCK_Q, w), lambda n, h, a, b: (n, h, at(a, b)[0], 0)
+    )
+    keys = lambda w: pl.BlockSpec(  # noqa: E731
+        (None, None, BLOCK_K, w), lambda n, h, a, b: (n, h, at(a, b)[1], 0)
+    )
+    mask = pl.BlockSpec((None, BLOCK_Q, BLOCK_K), lambda n, h, a, b: (n, *at(a, b)))
+    rows = pl.BlockSpec(
+        (None, None, g, BLOCK_Q), lambda n, h, a, b: (n, h, 0, at(a, b)[0])
+    )
+    return queries, keys, mask, rows
+
+
+def _forward(q, k, v, mask, interpret):
+    n, h, t, d = q.shape
+    h_kv, dv = k.shape[1], v.shape[-1]
+    g = h // h_kv
+    queries, keys, tile, rows = _specs(g, False)
+    stat = pltpu.VMEM((g, BLOCK_Q, 1), jnp.float32)
+    return _call(
+        _fwd_kernel, "flash_mha_sparse_fwd", (n, h_kv, t // BLOCK_Q, t // BLOCK_K),
+        [queries(d), keys(d), keys(dv), tile], [queries(dv), rows],
+        [jax.ShapeDtypeStruct((n, h, t, dv), q.dtype),
+         jax.ShapeDtypeStruct((n, h_kv, g, t), jnp.float32)],
+        [stat, stat, pltpu.VMEM((g, BLOCK_Q, dv), jnp.float32)], interpret,
+    )(q, k, v, mask)
+
+
+def _dq(q, k, v, mask, do, lse, delta, interpret):
+    n, h, t, d = q.shape
+    h_kv, dv = k.shape[1], v.shape[-1]
+    g = h // h_kv
+    queries, keys, tile, rows = _specs(g, False)
+    return _call(
+        _dq_kernel, "flash_mha_sparse_dq", (n, h_kv, t // BLOCK_Q, t // BLOCK_K),
+        [queries(d), keys(d), keys(dv), tile, queries(dv), rows, rows],
+        queries(d), jax.ShapeDtypeStruct(q.shape, q.dtype),
+        [pltpu.VMEM((g, BLOCK_Q, d), jnp.float32)], interpret,
+    )(q, k, v, mask, do, lse, delta)
+
+
+def _dkv(q, k, v, mask, do, lse, delta, interpret):
+    n, h, t, d = q.shape
+    h_kv, dv = k.shape[1], v.shape[-1]
+    g = h // h_kv
+    queries, keys, tile, rows = _specs(g, True)
+    return _call(
+        _dkv_kernel, "flash_mha_sparse_dkv", (n, h_kv, t // BLOCK_K, t // BLOCK_Q),
+        [queries(d), keys(d), keys(dv), tile, queries(dv), rows, rows],
+        [keys(d), keys(dv)],
+        [jax.ShapeDtypeStruct(k.shape, k.dtype), jax.ShapeDtypeStruct(v.shape, v.dtype)],
+        [pltpu.VMEM((BLOCK_K, d), jnp.float32), pltpu.VMEM((BLOCK_K, dv), jnp.float32)],
+        interpret,
+    )(q, k, v, mask, do, lse, delta)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def sparse_attention(q, k, v, mask, interpret: bool = False):
+    """Attention of ``q`` (B, H, T, D) WITH the score scale in it against
+    compact ``k`` (B, H_kv, T, D) and ``v`` (B, H_kv, T, Dv) under ``mask``,
+    int8 (B, T, T): query t sees key s iff ``mask[b, t, s] != 0`` and ``s <=
+    t`` (a tile above the diagonal is not read, the one that straddles it is
+    cut by it), and every query sees a key.
+    Returns ``(o (B, H, T, Dv), lse float32 (B, H_kv, G, T))``; the gradient
+    is the output's alone (``lse`` is a statistic here, not a path)."""
+    return _forward(q, k, v, mask, interpret)
+
+
+def _sparse_fwd(q, k, v, mask, interpret):
+    o, lse = _forward(q, k, v, mask, interpret)
+    return (o, lse), (q, k, v, mask, o, lse)
+
+
+def _sparse_bwd(interpret, residuals, cotangents):
+    q, k, v, mask, o, lse = residuals
+    do = cotangents[0]
+    delta = (o.astype(jnp.float32) * do.astype(jnp.float32)).sum(axis=-1).reshape(lse.shape)
+    dq = _dq(q, k, v, mask, do, lse, delta, interpret)
+    dk, dv = _dkv(q, k, v, mask, do, lse, delta, interpret)
+    return dq, dk, dv, None
+
+
+sparse_attention.defvjp(_sparse_fwd, _sparse_bwd)

@@ -36,6 +36,11 @@ from akka_allreduce_tpu.train.sharded_lm import ShardedLMTrainer, step_check_vma
 _ROUTED_ROWS = obs_metrics.counter("trainer.moe.routed_rows")
 _BUFFER_ROWS = obs_metrics.counter("trainer.moe.buffer_rows")
 _PAST_FIRST_RUNG = obs_metrics.counter("trainer.moe.layers_past_first_rung")
+# for a model whose attention runs under a learned mask (``model.indexer``):
+# the indexer's loss of the last step, and the (query, key) pairs its masks
+# kept, summed over the layers and the steps
+_INDEXER_LOSS = obs_metrics.gauge("trainer.indexer.loss")
+_SELECTED_PAIRS = obs_metrics.counter("trainer.indexer.selected_pairs")
 
 
 @dataclasses.dataclass
@@ -59,6 +64,15 @@ class MoEStepMetrics:
     # has none and counts 0); in the gradient with the model's
     # ``mtp_weight``, not in ``loss``. None from a model without one
     mtp_loss: float | None = None
+    # the indexer's loss of a model whose attention runs under a learned mask
+    # (``models.hybrid_decoder``: per layer the mean over the tokens of KL(the
+    # head mean of attention's probabilities || the indexer's distribution
+    # over the kept keys), summed over the layers); in the gradient, not in
+    # ``loss``. None from a model without an indexer
+    indexer_loss: float | None = None
+    # (query, key) pairs each layer's mask kept, (layers,), summed over the
+    # replicas
+    selected_pairs: np.ndarray | None = None
 
 
 class MoETrainer(ShardedLMTrainer):
@@ -86,7 +100,11 @@ class MoETrainer(ShardedLMTrainer):
         only. A model with ``mtp_depth`` is applied to ``(variables,
         tokens, labels)`` and returns its prediction module's logits last:
         their cross-entropy against the labels one further on enters the
-        total with ``model.mtp_weight`` and is reported as ``mtp_loss``.
+        total with ``model.mtp_weight`` and is reported as ``mtp_loss``. A
+        model with an ``indexer`` returns its indexer's loss and the pairs
+        its masks kept last of all: the loss enters the total as it is
+        (its gradient reaches the indexer's leaves alone) and is reported as
+        ``indexer_loss``, the pairs as ``selected_pairs``.
       params: with ``model``, its variables (seeded weights handed in);
         left out, ``model.init`` runs jitted from ``seed``.
     """
@@ -194,6 +212,7 @@ class MoETrainer(ShardedLMTrainer):
 
         tokens0 = jnp.zeros((1, seq_len // self.sp), jnp.int32)
         mtp = bool(getattr(model, "mtp_depth", 0))
+        indexer = getattr(model, "indexer", None) is not None
         if model is not None:
             self.params = (
                 params if params is not None
@@ -232,6 +251,9 @@ class MoETrainer(ShardedLMTrainer):
         if mtp:
             self._mean_names = (*self._mean_names, "mtp_loss")
             mtp_weight = float(model.mtp_weight)
+        if indexer:
+            self._mean_names = (*self._mean_names, "indexer_loss")
+            self._sum_names = (*self._sum_names, "selected_pairs")
         model_apply = self.model.apply
         aux_coef = self.aux_coef
         token_ce = optax.softmax_cross_entropy_with_integer_labels
@@ -244,6 +266,8 @@ class MoETrainer(ShardedLMTrainer):
             # their global sum / denom is the masked token-weighted mean
             total = ce + aux_coef * aux * tokens_local
             means = (ce, aux * tokens_local, dropped * tokens_local)
+            if indexer:  # last of the model's outputs; the pairs stay a sum
+                pairs, index_kl = rows.pop(), rows.pop() * tokens_local
             if mtp:
                 # position i saw labels[i] and predicts labels[i + 1]; the
                 # last has no target, so its term weighs 0 (all T positions
@@ -255,6 +279,10 @@ class MoETrainer(ShardedLMTrainer):
                     )
                 total = total + mtp_weight * further
                 means += (further,)
+            if indexer:
+                total = total + index_kl
+                means += (index_kl,)
+                rows.append(pairs)
             return total, (means, tuple(rows))
 
         self._build_step(
@@ -286,6 +314,9 @@ class MoETrainer(ShardedLMTrainer):
             _ROUTED_ROWS.inc(float(out.expert_rows.sum()))
             _BUFFER_ROWS.inc(float(out.buffer_rows.sum()))
             _PAST_FIRST_RUNG.inc(int((out.buffer_rows > first).sum()))
+        if out.selected_pairs is not None:
+            _INDEXER_LOSS.set(out.indexer_loss)
+            _SELECTED_PAIRS.inc(float(out.selected_pairs.sum()))
         return out
 
     def train_chain(

@@ -1378,7 +1378,8 @@ def test_the_benchmark_lists_the_cell_where_the_issue_says():
     # no process of an older cell loads a file PR 35 added (the Mellum2 cell,
     # of the same dialect, joins the readers of its scopes)
     for w in bench["workloads"]:
-        if w["name"] not in (CELL, MELLUM_CELL):
+        # (a later cell of the grouped-query layer joins the readers of its scopes)
+        if w["name"] not in (CELL, MELLUM_CELL, "keye_vl2_ep8_train_b1_t8192"):
             older = spec.load_cell(w["name"])
             assert older.config["runner"] != "laguna_moe_train"
             assert not {"swa_kernel_ms", "gqa_proj_ms", "mfu_pct.swa"} & set(older.per_layer)
@@ -1391,13 +1392,15 @@ MELLUM_NEW = ("mfu_pct.mellum", "swa_kernel_roofline_pct.mellum",
 
 def test_the_benchmark_lists_the_mellum2_cell_where_the_issue_says():
     bench = _json(os.path.join(ROOT, "BENCHMARK.json"))
-    assert [c["name"] for c in bench["configs"]][-1] == "mellum2_12b_d4"
-    config = bench["configs"][-1]
+    names = [c["name"] for c in bench["configs"]]
+    assert names.index("mellum2_12b_d4") == names.index("laguna_xs2_d5") + 1  # appended then
+    config = bench["configs"][names.index("mellum2_12b_d4")]
     assert config["file"] == "benchmarks/configs/mellum2_12b_d4.json"
     assert config["reduced"] == _json(MELLUM_REAL)["reduced"]
     assert config["source"] == _json(MELLUM_REAL)["source"]
-    cell = bench["workloads"][-1]
-    assert (cell["name"], cell["config"], cell["traffic"], cell["chips"]) == (
+    at = [w["name"] for w in bench["workloads"]].index(MELLUM_CELL)
+    cell = bench["workloads"][at]
+    assert at == 6 and (cell["name"], cell["config"], cell["traffic"], cell["chips"]) == (
         MELLUM_CELL, "mellum2_12b_d4", "closed_b1_t8192", 1)
     assert all(len(x["why"]) <= 200 for x in (config, cell))
     listed = {m["name"] for m in bench["per_layer"] if MELLUM_CELL in m.get("workloads", ())}
@@ -1412,16 +1415,18 @@ def test_the_benchmark_lists_the_mellum2_cell_where_the_issue_says():
     assert not listed & {"flash_attn_ms", "flash_attn_roofline_pct", "flash_attn_roofline_pct.moe",
                          "mfu_pct.swa", "swa_kernel_roofline_pct", "moe_gmm_roofline_pct"}
     for m in bench["per_layer"]:
-        if m["name"] in MELLUM_NEW:  # new metrics: this cell's alone, at the lists' end
-            assert m["workloads"] == [MELLUM_CELL] and m["moves"] == "train_tokens_per_s"
-    assert [m["name"] for m in bench["per_layer"]][-7:] == list(MELLUM_NEW)
+        if m["name"] in MELLUM_NEW:  # new then: this cell's first, appended in one run
+            assert m["workloads"][0] == MELLUM_CELL and m["moves"] == "train_tokens_per_s"
+    metrics = [m["name"] for m in bench["per_layer"]]
+    first = metrics.index(MELLUM_NEW[0])
+    assert metrics[first: first + 7] == list(MELLUM_NEW)
     loaded = spec.load_cell(MELLUM_CELL)
     assert loaded.end_to_end == ["train_tokens_per_s", "setup_s"]
     assert set(loaded.per_layer) == listed | {"compile_or_load_s"}
     for name in loaded.per_layer:  # every reader is a file beside the others
         assert hasattr(spec.load_module("layer_metrics", name), "compute")
-    # no process of an older cell loads a file this PR adds
-    for w in bench["workloads"][:-1]:
+    # no process of an older cell loads a file that PR added
+    for w in bench["workloads"][:at]:
         older = spec.load_cell(w["name"])
         assert older.config["runner"] != "mellum_moe_train"
         assert not set(MELLUM_NEW) & set(older.per_layer)

@@ -1,0 +1,405 @@
+"""Attention under a mask a learned indexer makes (``ops/sparse_attention.py``,
+``local_attention.heads_first_attention(mask=)``, multimodal RoPE in
+``models/transformer.py``) and the configuration-built decoder that runs it
+(``models/hybrid_decoder.py`` from the Qwen3-MoE keys with ``sa_config``:
+``KeyeVL2``'s language model) with its indexer's own loss in ``MoETrainer``,
+against formulas written out here, ``lax.top_k``, and the benchmark's plain
+reference ``benchmarks/reference/keye_moe_plain.py``, at tiny widths on the
+CPU, on seeded weights."""
+
+from __future__ import annotations
+
+import copy
+import json
+import os
+import sys
+import types
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax import lax
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BENCH = os.path.join(ROOT, "benchmarks")
+if BENCH not in sys.path:
+    sys.path.insert(0, BENCH)
+
+from harness import spec  # noqa: E402
+
+from akka_allreduce_tpu.ops import sparse_attention as sa  # noqa: E402
+
+ref = spec.load_module("reference", "keye_moe_plain")
+
+REAL = os.path.join(BENCH, "configs", "keye_vl2_30b_a3b_ep8.json")
+
+
+def _json(path):
+    with open(path, encoding="utf-8") as f:
+        return json.load(f)
+
+
+def _close(got, want, tol=2e-5):
+    scale = float(jnp.max(jnp.abs(want))) + 1e-30
+    assert float(jnp.max(jnp.abs(got - want))) <= tol * scale
+
+
+def _unequal_rows(t):
+    """Three rows of positions that differ, as an image's patches give them."""
+    i = jnp.arange(t)
+    return jnp.stack([i, i // 3, (2 * i) % 7]).astype(jnp.int32)
+
+
+# -- the selection -----------------------------------------------------------------
+
+
+def _top_k_mask(scores, topk, row0):
+    """The oracle: ``lax.top_k`` over the row with the keys after t at minus
+    infinity (the lower index first among equals), cut to the causal keys."""
+    r, c = scores.shape
+    causal = jnp.arange(c)[None, :] <= (row0 + jnp.arange(r))[:, None]
+    scores = jnp.where(scores == 0, 0.0, scores)  # -0.0 equals 0.0: the lower index first
+    _, best = lax.top_k(jnp.where(causal, scores, -jnp.inf), min(topk, c))
+    picked = jnp.zeros((r, c), bool).at[jnp.arange(r)[:, None], best].set(True)
+    return picked & causal
+
+
+@pytest.mark.parametrize("ties", ["none", "zeros", "signed_zeros", "few_values", "all_equal"])
+@pytest.mark.parametrize("rows,cols,row0,topk", [(24, 24, 0, 8), (16, 40, 24, 8), (8, 64, 56, 64)])
+def test_selection_is_top_k_with_ties_to_the_lower_index(ties, rows, cols, row0, topk):
+    scores = jax.random.normal(jax.random.PRNGKey(rows + cols), (rows, cols))
+    if ties == "zeros":  # what relu leaves: many exact zeros around the threshold
+        scores = jnp.where(scores < 0.6, 0.0, scores)
+    elif ties == "signed_zeros":
+        scores = jnp.where(scores < 0.6, jnp.where(scores < 0, -0.0, 0.0), scores)
+    elif ties == "few_values":
+        scores = jnp.round(scores * 2) / 2
+    elif ties == "all_equal":
+        scores = jnp.full_like(scores, -1.5)
+    got = sa.select_keys(scores, topk, row0)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(_top_k_mask(scores, topk, row0)))
+    t = row0 + np.arange(rows)
+    np.testing.assert_array_equal(np.asarray(got.sum(axis=1)), np.minimum(t + 1, topk))
+    assert not bool(jnp.any(got & (jnp.arange(cols)[None, :] > t[:, None])))
+
+
+def test_indexer_mask_by_blocks_is_the_selection_of_the_whole(monkeypatch):
+    """Runs of blocks through their loops (8 rows at a time, four runs) give
+    the mask one block of all rows gives; rows inside ``topk`` see every
+    causal key."""
+    t, j, d, topk = 64, 4, 8, 16
+    k = jax.random.split(jax.random.PRNGKey(1), 3)
+    q_i, k_i = jax.random.normal(k[0], (j, t, d)), jax.random.normal(k[1], (t, d))
+    w = jax.random.normal(k[2], (t, j))
+    whole = sa.indexer_mask(q_i, k_i, w, topk)  # 64 rows: one block of all
+    assert sa._stages(t, 8) == [(0, 16, 8), (16, 32, 8), (32, 48, 8), (48, 64, 8)]
+    assert sa._stages(640, 512) == [(0, 640, 640)] and sa._stages(32, 512) == [(0, 32, 32)]
+    monkeypatch.setattr(sa, "INDEX_ROWS", 8)
+    np.testing.assert_array_equal(
+        np.asarray(sa.indexer_mask(q_i, k_i, w, topk)), np.asarray(whole))
+    want = _top_k_mask(sa.index_scores(q_i, k_i, w), topk, 0)
+    np.testing.assert_array_equal(np.asarray(whole), np.asarray(want, np.int8))
+    assert whole.dtype == jnp.int8 and int(whole.sum()) == 16 * 17 // 2 + 48 * 16
+    written = jnp.einsum("tj,jts->ts", w, jax.nn.relu(jnp.einsum("jtd,sd->jts", q_i, k_i)))
+    _close(sa.index_scores(q_i, k_i, w), written)
+
+
+# -- attention under an array mask ------------------------------------------------
+
+
+def _qkv(b, t, h, h_kv, d, seed=0):
+    k = jax.random.split(jax.random.PRNGKey(seed), 3)
+    return (jax.random.normal(k[0], (b, h, t, d)) * d ** -0.5,
+            jax.random.normal(k[1], (b, h_kv, t, d)),
+            jax.random.normal(k[2], (b, h_kv, t, d)))
+
+
+def _random_mask(b, t, topk, seed=5):
+    scores = jax.random.normal(jax.random.PRNGKey(seed), (b, t, t))
+    return jnp.stack([sa.select_keys(s, topk, 0) for s in scores]).astype(jnp.int8)
+
+
+def _dense_under_mask(q, k, v, mask):
+    """Written out: softmax over the keys the mask keeps, heads-first."""
+    group = q.shape[1] // k.shape[1]
+    k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
+    scores = jnp.einsum("bhqd,bhkd->bhqk", q, k, precision="highest")
+    scores = jnp.where(mask[:, None] != 0, scores, -jnp.inf)
+    out = jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(scores, axis=-1), v, precision="highest")
+    return out, jax.nn.logsumexp(scores, axis=-1)
+
+
+@pytest.mark.parametrize("what", ["out_and_lse", "dq", "dk", "dv"])
+@pytest.mark.parametrize("heads,kv_heads", [(4, 2), (8, 1)])
+@pytest.mark.parametrize("mask_is", ["causal", "past_the_diagonal"])
+def test_masked_kernels_interpreted_match_the_portable_core(
+    what, heads, kv_heads, mask_is, monkeypatch
+):
+    """``flash_mha_sparse_fwd`` / ``_dq`` / ``_dkv`` in interpret mode against
+    ``blockwise_attention`` with the mask and against the softmax written
+    out. A mask with ones past the diagonal is cut by the causal rule, in the
+    kernels' straddling tiles as in the portable core."""
+    from akka_allreduce_tpu.ops.local_attention import blockwise_attention
+
+    monkeypatch.setattr(sa, "BLOCK_Q", 64)
+    monkeypatch.setattr(sa, "BLOCK_K", 128)
+    b, t, d = 2, 256, 32
+    q, k, v = _qkv(b, t, heads, kv_heads, d)
+    mask = within = _random_mask(b, t, 48)
+    if mask_is == "past_the_diagonal":
+        stray = jax.random.bernoulli(jax.random.PRNGKey(6), 0.3, mask.shape)
+        mask = mask | jnp.triu(stray, 1).astype(jnp.int8)
+        assert int(mask.sum()) > int(within.sum())
+    swap = lambda x: x.transpose(0, 2, 1, 3)  # noqa: E731
+
+    def portable(q, k, v):
+        out, lse = blockwise_attention(
+            swap(q), swap(k), swap(v), causal=True, sm_scale=1.0, mask=mask,
+            with_lse=True, block_k=64)
+        return swap(out), lse
+
+    def kernels(q, k, v):
+        out, lse = sa.sparse_attention(q, k, v, mask, True)
+        return out, lse.reshape(b, heads, t)
+
+    if what == "out_and_lse":
+        want = _dense_under_mask(q, k, v, within)
+        for got in (portable(q, k, v), kernels(q, k, v)):
+            _close(got[0], want[0])
+            _close(got[1], want[1])
+        return
+    weight = jax.random.normal(jax.random.PRNGKey(9), q.shape)
+    arg = "qkv".index(what[1])
+    grad = lambda f: jax.grad(  # noqa: E731
+        lambda *a: (f(*a)[0] * weight).sum(), argnums=arg)(q, k, v)
+    want = jax.grad(lambda *a: (_dense_under_mask(*a, within)[0] * weight).sum(), argnums=arg)(q, k, v)
+    _close(grad(kernels), want, 1e-4)
+    _close(grad(portable), want, 1e-4)
+
+
+@pytest.mark.parametrize("t", [64, 640])
+def test_heads_first_attention_takes_a_mask_off_the_chip(t):
+    """The blockwise core, in one block of keys up to 512 positions and in
+    several past them, against the softmax written out; the rows' log-sum-exp
+    comes back in the kernels' layout."""
+    from akka_allreduce_tpu.ops.local_attention import heads_first_attention
+
+    q, k, v = _qkv(1, t, 4, 2, 16)
+    mask = _random_mask(1, t, 24)
+    out, lse = heads_first_attention(q, k, v, causal=True, mask=mask)
+    want = _dense_under_mask(q, k, v, mask)
+    _close(out, want[0])
+    assert lse.shape == (1, 2, 2, t) and lse.dtype == jnp.float32
+    _close(lse.reshape(1, 4, t), want[1])
+
+
+def test_a_mask_is_for_causal_attention_without_a_window():
+    from akka_allreduce_tpu.ops.local_attention import heads_first_attention
+
+    q, k, v = _qkv(1, 32, 2, 1, 8)
+    mask = _random_mask(1, 32, 8)
+    with pytest.raises(ValueError, match="causal attention without a window"):
+        heads_first_attention(q, k, v, causal=False, mask=mask)
+    with pytest.raises(ValueError, match="causal attention without a window"):
+        heads_first_attention(q, k, v, causal=True, window=8, mask=mask)
+    # without a mask the call is today's: one array back
+    assert heads_first_attention(q, k, v, causal=True).shape == q.shape
+
+
+def test_the_kernels_take_the_cells_shape_and_the_gauges_say_what_they_run():
+    from akka_allreduce_tpu.obs import metrics
+    from akka_allreduce_tpu.ops.local_attention import _gauge_sparse
+
+    assert sa.takes_sparse(8192, 128, 128) and not sa.takes_sparse(8192 + 256, 128, 128)
+    assert not sa.takes_sparse(8192, 192, 128)
+    _gauge_sparse(8192)  # the kernels' wrapper: what their grid runs
+    sa._gauge_selected(8192, 2048)  # the selection: what it keeps of any scores
+    now = metrics.REGISTRY.snapshot()
+    assert now["attention.sparse.mask_pairs"] == 14_681_088  # ISSUE 41's count
+    # every tile that holds a causal pair, whole: the causal pairs and the
+    # tiles' overhang past the diagonal
+    assert now["attention.sparse.visited_pairs"] == sa.visited_pairs(8192)
+    causal = 8192 * 8193 // 2
+    assert causal < sa.visited_pairs(8192) <= causal + 8192 * sa.BLOCK_K
+
+
+def test_the_indexers_loss_and_its_gradient_made_in_the_forward_pass(monkeypatch):
+    """``indexer_kl`` against the loss written out, and its ``custom_vjp``
+    (gradients made block by block in the forward pass, scaled by the
+    cotangent) against autodiff of the written-out loss."""
+    t, j, d, h, h_kv, hd, topk = 32, 4, 8, 4, 2, 16, 8
+    ks = jax.random.split(jax.random.PRNGKey(2), 3)
+    q_i, k_i = jax.random.normal(ks[0], (j, t, d)), jax.random.normal(ks[1], (t, d))
+    w = jax.random.normal(ks[2], (t, j))
+    q, k, v = _qkv(1, t, h, h_kv, hd, seed=4)
+    mask = sa.indexer_mask(q_i, k_i, w, topk)
+    _, lse = _dense_under_mask(q, k, v, mask[None])
+    lse = lse.reshape(h_kv, h // h_kv, t)
+
+    def written(q_i, k_i, w):
+        scores = jnp.einsum("tj,jts->ts", w, jax.nn.relu(jnp.einsum("jtd,sd->jts", q_i, k_i)))
+        log_q = jax.nn.log_softmax(jnp.where(mask != 0, scores, -jnp.inf), axis=-1)
+        s = jnp.einsum("hqd,hkd->hqk", q[0], jnp.repeat(k[0], h // h_kv, axis=0))
+        p = jax.nn.softmax(jnp.where(mask != 0, s, -jnp.inf), axis=-1).mean(axis=0)
+        return jnp.sum(jnp.where(mask != 0, p * (jnp.log(jnp.where(p > 0, p, 1.0)) - log_q), 0.0))
+
+    for rows in (sa.INDEX_ROWS, 8):
+        monkeypatch.setattr(sa, "INDEX_ROWS", rows)
+        loss = lambda *a: 0.25 * sa.indexer_kl(*a, mask, q[0], k[0], lse)  # noqa: E731
+        got, grads = jax.value_and_grad(loss, argnums=(0, 1, 2))(q_i, k_i, w)
+        want, want_grads = jax.value_and_grad(
+            lambda *a: 0.25 * written(*a), argnums=(0, 1, 2))(q_i, k_i, w)
+        assert abs(float(got) - float(want)) < 1e-5 * abs(float(want)) and float(want) > 0
+        for a, b in zip(grads, want_grads):
+            _close(a, b, 1e-4)
+        _close(sa.indexer_kl(q_i, k_i, w, mask, q[0], k[0], lse), written(q_i, k_i, w))
+
+
+# -- multimodal RoPE -----------------------------------------------------------------
+
+
+def test_bf16_operands_move_the_index_keys_gradient_by_percents_and_its_norm_by_far_less():
+    """What ``correct_limits.grad_norm_gap`` of the cell's file is set by
+    (``benchmarks/tests/keye_index_key_tail.py``): the indexer's loss is a mean
+    over queries of a KL each, so its gradient to the key is decided by the
+    first few queries, and bf16 operands move it by percents as a vector on
+    every seed while its norm, which the check compares, moves far less."""
+    tests = os.path.join(BENCH, "tests")
+    if tests not in sys.path:
+        sys.path.insert(0, tests)
+    import keye_index_key_tail as tail
+
+    for seed in (0, 1):
+        norm_gap, vector_gap = (float(g) for g in tail.gaps(jax.random.PRNGKey(seed)))
+        assert 0.01 < vector_gap < 0.2, vector_gap
+        assert norm_gap < 0.2 * vector_gap, (norm_gap, vector_gap)
+
+
+@pytest.mark.parametrize("t,d,base", [(64, 16, 1e7), (8192, 128, 1e7), (4096, 64, 1e6)])
+def test_mrope_tables_on_equal_rows_are_bit_equal_to_the_one_row_tables(t, d, base):
+    from akka_allreduce_tpu.models.transformer import rope_tables
+
+    sections = (d // 8, d // 8 + d // 16, d // 2 - 2 * (d // 8) - d // 16)
+    rows = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), (3, t))
+    one = rope_tables(t, d, 0, base=base, scale=d ** -0.5)
+    three = rope_tables(t, d, 0, base=base, scale=d ** -0.5, positions=rows, sections=sections)
+    for a, b in zip(one, three):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_mrope_turns_each_section_by_its_own_row():
+    """``rope_heads_first`` with three unequal rows against the formula
+    written out (column i of the first half with i + D/2, angle ``pos[s(i), t]
+    theta^(-2i/D)``) and against the reference's own ``mrope``."""
+    from akka_allreduce_tpu.models.transformer import rope_heads_first, rope_tables
+
+    t, h, d, theta, sections = 32, 3, 16, 1e7, (2, 3, 3)
+    pos = _unequal_rows(t)
+    x = jax.random.normal(jax.random.PRNGKey(0), (1, h, t, d))
+    got = rope_heads_first(x, 0, base=theta, positions=pos, sections=sections)
+    row_of = np.repeat(np.arange(3), sections)
+    ang = np.asarray(pos, np.float64)[row_of].T * theta ** (-np.arange(0, d, 2) / d)
+    x1, x2 = np.asarray(x)[..., : d // 2], np.asarray(x)[..., d // 2:]
+    want = np.concatenate(
+        (x1 * np.cos(ang) - x2 * np.sin(ang), x1 * np.sin(ang) + x2 * np.cos(ang)), axis=-1)
+    _close(got, jnp.asarray(want, jnp.float32), 1e-5)
+    _close(got[0].transpose(1, 0, 2), ref.mrope(x[0].transpose(1, 0, 2), pos, theta, sections), 1e-5)
+    equal = rope_heads_first(x, 0, base=theta)
+    assert float(jnp.abs(got - equal).max()) > 1e-2  # the rows do differ
+    with pytest.raises(ValueError, match="positions"):
+        rope_tables(t, d, 0, positions=pos, sections=(2, 3, 4))
+
+
+# -- the benchmark's count and readers -------------------------------------------------
+
+
+def test_the_count_of_a_step_is_the_issues():
+    from harness import keye_flops
+
+    real = _json(REAL)
+    assert keye_flops.selected_pairs(real, 8192) == 14_681_088
+    flops = keye_flops.train_flops_per_step(real, 1, 8192, 5 * 8192)
+    tera = {k: round(v / 1e12, 2) for k, v in flops.items()}
+    assert tera == {"always": 6.99, "experts": 1.16, "attention": 3.61, "index_scores": 1.03,
+                    "target": 0.6, "total": 13.39}
+    assert keye_flops.attention_train_flops(real, 1, 8192) == 12 * 14_681_088 * 32 * 128 * 5
+    assert 14_681_088 / (8192 * 8193 // 2) == pytest.approx(0.437, abs=1e-3)
+
+
+class _Trace:
+    """Three steps; the ops named as a v5e trace names them."""
+
+    def __init__(self, ops):
+        self.ops = ops
+
+    def main_module(self):
+        return [(0.0, 0.1), (0.1, 0.1), (0.2, 0.1)]
+
+    def matching(self, name=None, kind=None):
+        import re
+
+        hit = [v for k, v in self.ops.items() if re.search(name, k)]
+        return sum(v[0] for v in hit), sum(v[1] for v in hit)
+
+
+def test_readers_of_the_new_metrics_on_a_made_up_record():
+    from harness import keye_flops
+
+    real, tr = _json(REAL), _json(os.path.join(BENCH, "traffic", "closed_b1_t8192.json"))
+    pre = "jit(step)/sparse_attention/"
+    scopes = {
+        "fusion.1": pre + "attn_indexer/indexer_proj/dot", "fusion.2": pre + "attn_indexer/indexer_scores/dot",
+        "fusion.3": pre + "attn_indexer/while/body/indexer_select/reduce",
+        "fusion.4": pre + "attn_indexer/indexer_target/exp", "fusion.5": pre + "attn_core/mul",
+        "flash_mha_sparse_fwd.1": pre + "attn_core/pallas", "flash_mha_sparse_dq.1": pre + "attn_core/pallas",
+        "flash_mha_sparse_dkv.1": pre + "attn_core/pallas", "while.1": pre + "attn_indexer/while",
+    }
+    ops = {n: (3, 0.003 * (i + 1), "while" if n.startswith("while") else "fusion")
+           for i, n in enumerate(scopes)}
+    ops.update({"gmm.1": (3, 0.030, "fusion"), "tgmm": (3, 0.012, "fusion")})
+    units = [{"t0": 0.1 * i, "t1": 0.1 * i + 0.1, "work": 8192, "ok": True,
+              "expert_rows": [[512.0] * 16] * 5, "buffer_rows": [10240.0] * 5} for i in range(3)]
+    units[0]["op_scopes"] = scopes
+    units[0]["counters"] = {"attention.sparse.mask_pairs": 14_681_088,
+                            "attention.sparse.visited_pairs": sa.visited_pairs(8192)}
+    record = {
+        "cell": types.SimpleNamespace(config=real, traffic=tr), "chips": 1,
+        "peak": {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9},
+        "window": {"units": units, "start": 0.0, "paused": 0.0},
+    }
+    trace = _Trace(ops)
+    read = lambda name: spec.load_module("layer_metrics", name).compute(record, trace)  # noqa: E731
+    assert read("indexer_ms") == pytest.approx(1e3 * (0.003 + 0.006 + 0.009 + 0.012) / 3)
+    assert read("indexer_select_ms") == pytest.approx(3.0)
+    assert read("indexer_target_ms") == pytest.approx(4.0)
+    kernel_ms = 1e3 * (0.018 + 0.021 + 0.024) / 3
+    assert read("attn_kernel_ms") == pytest.approx(kernel_ms)
+    assert read("gqa_around_kernel_ms") == pytest.approx(5.0)
+    assert read("attn_kernel_roofline_pct.keye") == pytest.approx(
+        100 * 12 * 14_681_088 * 32 * 128 * 5 / 197e12 / (1e-3 * kernel_ms))
+    assert read("sparse_tile_useful_pct") == pytest.approx(
+        100 * 14_681_088 / sa.visited_pairs(8192))
+    assert 40 < read("sparse_tile_useful_pct") < 43.7
+    assert read("mfu_pct.keye") == pytest.approx(100 * 13.39e12 * 10 / 197e12, rel=1e-3)
+    # five layers on the first rung, sixteen experts with 512 rows each
+    need = keye_flops.grouped_products(real, 16 * 512, 16)
+    assert need == {"flops": 18 * 8192 * 2048 * 768,
+                    "bytes": 9 * 2 * 8192 * (2048 + 768) + 24 * 16 * 2048 * 768}
+    # 512 rows an expert: the weights' bytes (read twice, written once in f32)
+    # outweigh the products by a little, so the layer is held to the HBM rate
+    assert 1.0 < need["bytes"] / 819e9 / (need["flops"] / 197e12) < 1.1
+    assert read("moe_gmm_ms") == pytest.approx(14.0)
+    assert read("moe_gmm_roofline_pct.keye") == pytest.approx(
+        100 * 5 * need["bytes"] / 819e9 / 0.014)
+    # an expert no row reached is never read
+    assert keye_flops.grouped_products(real, 16 * 512, 8)["bytes"] < need["bytes"]
+    # a program without the scopes, the gauges or the key set: nothing, and no raise
+    bare = copy.deepcopy(record)
+    for u in bare["window"]["units"]:
+        u.pop("op_scopes", None), u.pop("counters", None), u.pop("expert_rows", None)
+    for name in ("indexer_ms", "indexer_select_ms", "indexer_target_ms",
+                 "sparse_tile_useful_pct", "mfu_pct.keye", "moe_gmm_roofline_pct.keye"):
+        assert spec.load_module("layer_metrics", name).compute(bare, _Trace({})) is None, name
+    assert spec.load_module("layer_metrics", "attn_kernel_roofline_pct.keye").compute(
+        bare, _Trace({})) is None
