@@ -6,7 +6,16 @@ that an index score ranks highest, and attends to those alone.
 Four parts, each a function of its own:
 
 - :func:`index_scores`: ``I[t, s] = sum_j w[t, j] relu(q_I[t, j] . k_I[s])``,
-  float32, from the operands' dtype into the MXU.
+  float32, from the operands' dtype into the MXU. On the chip, on the shapes
+  :func:`takes_index_scores` takes, a ``custom_vjp`` over two Pallas TPU
+  kernels of the repo's own: ``index_scores_fwd`` runs a tile of (queries,
+  keys) through the 16 heads' products, ReLU and the weighted sum and writes
+  the float32 tile once; ``index_scores_bwd`` keeps a block's rows resident,
+  steps over tiles of keys, makes the pre-activations again and from them
+  ``dq_I``, ``dk_I`` and ``dw``. The per-head pre-activations live in VMEM
+  only; the key tiles wholly after a block's rows are skipped from the grid
+  position. Anywhere else two einsums, the portable form and the tests'
+  yardstick.
 - :func:`select_keys`: scores in, a mask out with exactly ``min(t + 1,
   topk)`` keys a row among the keys ``s <= t``, ties to the lower index. The
   mathematics needs the SET, not its order, so no row is sorted: the
@@ -46,6 +55,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from akka_allreduce_tpu.ops._platform import interpret_default
 from akka_allreduce_tpu.ops.ring_attention import _MASK_VALUE
 
 #: queries and keys a grid step of each kernel: a group's eight heads stack
@@ -56,17 +66,49 @@ BLOCK_K = 512
 #: the runs of such blocks that share their columns (:func:`_stages`)
 INDEX_ROWS = 512
 STAGES = 4
+#: query rows and keys a grid step of the index scores' forward kernel; the
+#: backward keeps a block's rows resident and steps over ``SCORE_K`` keys
+SCORE_Q = 512
+SCORE_K = 512
+#: elements of a block's index queries the backward kernel holds beside their
+#: gradient (16 heads x 512 rows x 64: what a 16 MB scope of VMEM has room for)
+_RESIDENT = 16 * 512 * 64
 _NT = (((1,), (1,)), ((), ()))  # a @ b.T
 _TN = (((0,), (0,)), ((), ()))  # a.T @ b
 _VMEM_LIMIT = 100 * 1024 * 1024  # of a v5e core's 128 MiB
 
 
+def _dot(a, b, dims=(((1,), (0,)), ((), ()))):
+    return lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
+
+
 # -- the indexer's scores and the selection ---------------------------------------
 
 
-def index_scores(q_i: jax.Array, k_i: jax.Array, w: jax.Array) -> jax.Array:
+def _on_chip(*arrays) -> bool:
+    """The platform question of :func:`index_scores`, as
+    ``local_attention._masked_heads_first`` asks it of its operands."""
+    return not interpret_default(*arrays)
+
+
+def _by_kernels(q_i, k_i, rows: int, cols: int) -> bool:
+    """Do ``rows`` of these index queries against ``cols`` of these keys go
+    to the kernels? By the platform and the shape, nothing else."""
+    return _on_chip(q_i, k_i) and takes_index_scores(rows, cols, q_i.shape[2], q_i.shape[0])
+
+
+def index_scores(q_i: jax.Array, k_i: jax.Array, w: jax.Array, row0=0) -> jax.Array:
     """``q_i`` (J, R, D), ``k_i`` (C, D), ``w`` (R, J) -> float32 (R, C):
-    ``sum_j w[r, j] relu(q_i[j, r] . k_i[c])``."""
+    ``sum_j w[r, j] relu(q_i[j, r] . k_i[c])``, the queries at positions
+    ``row0 .. row0 + R - 1``. The kernels (on the chip, on a shape
+    :func:`takes_index_scores` takes) skip the tiles of keys wholly after
+    their queries and write zeros there, entries no caller reads; no gradient
+    comes back from them."""
+    if _by_kernels(q_i, k_i, q_i.shape[1], k_i.shape[0]):
+        return _scores_by_kernels(
+            q_i, k_i, w.astype(jnp.float32), jnp.asarray(row0, jnp.int32),
+            interpret_default(q_i, k_i),
+        )
     pre = jnp.einsum("jrd,cd->jrc", q_i, k_i, preferred_element_type=jnp.float32)
     return jnp.einsum("jrc,rj->rc", jax.nn.relu(pre), w.astype(jnp.float32))
 
@@ -140,6 +182,17 @@ def _gauge_selected(t: int, topk: int) -> None:
     )
 
 
+@functools.lru_cache(maxsize=None)
+def _gauge_scored(t: int, topk: int) -> None:
+    """What the index scores' kernels run of a sequence, once a shape, to the
+    gauges ``attention.indexer.scored_pairs`` / ``.causal_pairs``
+    (OBSERVABILITY.md)."""
+    from akka_allreduce_tpu.obs import metrics as obs_metrics
+
+    obs_metrics.gauge("attention.indexer.scored_pairs").set(scored_pairs(t, topk))
+    obs_metrics.gauge("attention.indexer.causal_pairs").set(t * (t + 1) // 2)
+
+
 def indexer_mask(q_i, k_i, w, topk: int):
     """One sequence's mask from its indexer's operands (``q_i`` (J, T, D),
     ``k_i`` (T, D), ``w`` (T, J)): int8 (T, T), 1 where the query of the row
@@ -148,8 +201,11 @@ def indexer_mask(q_i, k_i, w, topk: int):
     every causal key and makes no score."""
     t = k_i.shape[0]
     _gauge_selected(t, topk)
+    stages = _stages(t, INDEX_ROWS)
+    if all(_by_kernels(q_i, k_i, n, r1) for _, r1, n in stages):
+        _gauge_scored(t, topk)
     out = []
-    for r0, r1, n in _stages(t, INDEX_ROWS):
+    for r0, r1, n in stages:
         if r1 <= topk:
             seen = (jnp.arange(r1)[None, :] <= jnp.arange(r0, r1)[:, None]).astype(jnp.int8)
         else:
@@ -158,7 +214,7 @@ def indexer_mask(q_i, k_i, w, topk: int):
             def block(start, n=n, keys=keys):
                 with jax.named_scope("indexer_scores"):
                     scores = index_scores(
-                        _rows_at(q_i, start, n, 1), keys, _rows_at(w, start, n, 0)
+                        _rows_at(q_i, start, n, 1), keys, _rows_at(w, start, n, 0), start
                     )
                 with jax.named_scope("indexer_select"):
                     return select_keys(scores, topk, start).astype(jnp.int8)
@@ -181,9 +237,10 @@ def _target_block(q, k, lse, seen):
     return jnp.where(seen, p, 0.0)
 
 
-def _kl_block(q_i, k_i, w, seen, target):
-    """``sum_r KL(target_r || softmax over the seen keys of I[r, .])``."""
-    scores = jnp.where(seen, index_scores(q_i, k_i, w), -jnp.inf)
+def _kl_block(q_i, k_i, w, seen, target, row0):
+    """``sum_r KL(target_r || softmax over the seen keys of I[r, .])``, the
+    block's first query at ``row0``."""
+    scores = jnp.where(seen, index_scores(q_i, k_i, w, row0), -jnp.inf)
     log_q = scores - jax.nn.logsumexp(scores, axis=1, keepdims=True)
     held = seen & (target > 0)
     log_p = jnp.log(jnp.where(held, target, 1.0))
@@ -209,7 +266,7 @@ def _kl_blocks(q_i, k_i, w, mask, q, k, lse, with_grads: bool):
                 target = _target_block(
                     _rows_at(q, start, n, 2), keys, _rows_at(lse, start, n, 2), seen
                 )
-            kl_of = functools.partial(_kl_block, seen=seen, target=target)
+            kl_of = functools.partial(_kl_block, seen=seen, target=target, row0=start)
             operands = (_rows_at(q_i, start, n, 1), keys_i, _rows_at(w, start, n, 0))
             with jax.named_scope("indexer_scores"):
                 if not with_grads:
@@ -254,6 +311,182 @@ def _indexer_kl_bwd(grads, g):
 indexer_kl.defvjp(_indexer_kl_fwd, _indexer_kl_bwd)
 
 
+# -- the indexer's scores: the kernels ----------------------------------------------
+
+
+def takes_index_scores(rows: int, cols: int, d: int, heads: int) -> bool:
+    """Do the index scores' kernels take a block of ``rows`` queries of
+    ``heads`` index heads against ``cols`` keys? Rows and columns the tiles
+    divide, and a block the backward kernel can keep resident."""
+    return rows % SCORE_Q == 0 and cols % SCORE_K == 0 and heads * rows * d <= _RESIDENT
+
+
+def _last_score_tile(row0, i, q_tile: int, k_tile: int):
+    """The last tile of ``k_tile`` keys that holds a key at or before a query
+    of tile i (``q_tile`` rows) of a block whose first query is at ``row0``."""
+    return (row0 + (i + 1) * q_tile - 1) // k_tile
+
+
+def scored_pairs(t: int, topk: int) -> int:
+    """(query, key) pairs the forward kernel's tiles run over a sequence's
+    mask pass (the runs past ``topk``: :func:`indexer_mask`) and loss pass
+    (every run: :func:`indexer_kl`), by the rule its grid skips by."""
+    return sum(
+        (1 + (r1 > topk)) * SCORE_Q * SCORE_K * (_last_score_tile(start, i, SCORE_Q, SCORE_K) + 1)
+        for r0, r1, n in _stages(t, INDEX_ROWS)
+        for start in range(r0, r1, n)
+        for i in range(n // SCORE_Q)
+    )
+
+
+def _scores_fwd_kernel(row0_ref, q_ref, k_ref, w_ref, o_ref):
+    i, j = pl.program_id(0), pl.program_id(1)
+    last = _last_score_tile(row0_ref[0], i, *o_ref.shape)
+
+    @pl.when(j <= last)
+    def _():
+        k, w = k_ref[...], w_ref[...]
+        acc = jnp.zeros(o_ref.shape, jnp.float32)
+        for h in range(q_ref.shape[0]):
+            acc = acc + w[:, h:h + 1] * jnp.maximum(_dot(q_ref[h], k, _NT), 0.0)
+        o_ref[...] = acc
+
+    @pl.when(j > last)  # wholly after the tile's queries: entries no caller reads
+    def _():
+        o_ref[...] = jnp.zeros(o_ref.shape, jnp.float32)
+
+
+def _scores_bwd_kernel(row0_ref, q_ref, qt_ref, k_ref, wt_ref, di_ref,
+                       dqt_ref, dkt_ref, dw_ref, dqt_scr, dw_scr):
+    """A tile of keys against the block's rows, head by head: the gate
+    ``m = dI (pre > 0)`` is the (K, N) operand of both gradient products,
+    which come out transposed, 64 rows each (``dq_I[j]^T = k_I^T m^T``,
+    ``dk_I^T += (w[:, j] q_I[j])^T m``): a product 64 wide runs the MXU half
+    empty, one 64 rows tall does not. The head's weight multiplies after the
+    product where it is a factor of the rows (``dq_I``) and the small operand
+    where it is not (``dk_I``)."""
+    heads, r, d = q_ref.shape
+    j = pl.program_id(0)
+
+    @pl.when(j == 0)
+    def _():
+        dqt_scr[...] = jnp.zeros(dqt_scr.shape, jnp.float32)
+        dw_scr[...] = jnp.zeros(dw_scr.shape, jnp.float32)
+
+    live = j * k_ref.shape[0] <= row0_ref[0] + r - 1  # the tile holds a key a row may see
+
+    @pl.when(live)
+    def _():
+        k, di, wt = k_ref[...], di_ref[...], wt_ref[...]
+        k_t = k.T
+        head_of = lax.broadcasted_iota(jnp.int32, dw_scr.shape, 1)
+        dk_t = jnp.zeros(dkt_ref.shape, jnp.float32)
+        dw = dw_scr[...]
+        for h in range(heads):
+            pre = _dot(q_ref[h], k, _NT)
+            m = jnp.where(pre > 0, di, 0.0)
+            gate, w_h = m.astype(k.dtype), wt[h:h + 1, :]
+            dqt_scr[h] += _dot(k_t, gate, _NT) * w_h
+            dk_t = dk_t + _dot((qt_ref[h] * w_h).astype(k.dtype), gate)
+            dw = jnp.where(head_of == h, dw + (m * pre).sum(axis=1, keepdims=True), dw)
+        dw_scr[...] = dw
+        dkt_ref[...] = dk_t.astype(dkt_ref.dtype)
+
+    @pl.when(jnp.logical_not(live))
+    def _():
+        dkt_ref[...] = jnp.zeros(dkt_ref.shape, dkt_ref.dtype)
+
+    @pl.when(j == pl.num_programs(0) - 1)
+    def _():
+        dqt_ref[...] = dqt_scr[...].astype(dqt_ref.dtype)
+        dw_ref[...] = dw_scr[...]
+
+
+# Both calls are jitted on their own: a step traces each of them dozens of
+# times (five layers, seven runs of blocks a layer, the primal, the forward
+# rule and the loops' own passes over their bodies), and a jitted function is
+# traced and lowered once a shape (four: the runs' keys), which is what a warm
+# start pays. The tiles are arguments so that the cache sees them.
+@functools.partial(jax.jit, static_argnums=(4, 5, 6))
+def _scores_forward(q_i, k_i, w, row0, interpret, q_tile, k_tile):
+    heads, r, d = q_i.shape
+    c = k_i.shape[0]
+    return pl.pallas_call(
+        _scores_fwd_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(r // q_tile, c // k_tile),
+            in_specs=[
+                pl.BlockSpec((heads, q_tile, d), lambda i, j, row0: (0, i, 0)),
+                # a skipped step fetches nothing: its tile is the last one run
+                pl.BlockSpec((k_tile, d), lambda i, j, row0: (
+                    jnp.minimum(j, _last_score_tile(row0[0], i, q_tile, k_tile)), 0)),
+                pl.BlockSpec((q_tile, heads), lambda i, j, row0: (i, 0)),
+            ],
+            out_specs=pl.BlockSpec((q_tile, k_tile), lambda i, j, row0: (i, j)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((r, c), jnp.float32),
+        name="index_scores_fwd", interpret=interpret,
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "parallel")),
+    )(row0.reshape(1), q_i, k_i, w)
+
+
+@functools.partial(jax.jit, static_argnums=(5, 6))
+def _scores_backward(q_i, k_i, w, d_scores, row0, interpret, k_tile):
+    heads, r, d = q_i.shape
+    c = k_i.shape[0]
+    whole = lambda *shape: pl.BlockSpec(shape, lambda j, row0: (0,) * len(shape))  # noqa: E731
+    tile = lambda j, row0: jnp.minimum(j, (row0[0] + r - 1) // k_tile)  # noqa: E731
+    dq_t, dk_t, dw = pl.pallas_call(
+        _scores_bwd_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(c // k_tile,),
+            in_specs=[
+                whole(heads, r, d), whole(heads, d, r),
+                pl.BlockSpec((k_tile, d), lambda j, row0: (tile(j, row0), 0)),
+                whole(heads, r),
+                pl.BlockSpec((r, k_tile), lambda j, row0: (0, tile(j, row0))),
+            ],
+            out_specs=[
+                whole(heads, d, r), pl.BlockSpec((d, k_tile), lambda j, row0: (0, j)),
+                whole(r, heads),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((heads, d, r), jnp.float32), pltpu.VMEM((r, heads), jnp.float32)
+            ],
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct((heads, d, r), q_i.dtype),
+            jax.ShapeDtypeStruct((d, c), k_i.dtype),
+            jax.ShapeDtypeStruct((r, heads), jnp.float32),
+        ],
+        name="index_scores_bwd", interpret=interpret,
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
+    )(row0.reshape(1), q_i, q_i.transpose(0, 2, 1), k_i, w.T, d_scores)
+    return dq_t.transpose(0, 2, 1), dk_t.T, dw
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _scores_by_kernels(q_i, k_i, w, row0, interpret):
+    """:func:`index_scores` on the kernels: float32 ``w``, ``row0`` an int32
+    scalar (a loop's counter: it reaches the kernels as a prefetched scalar).
+    The residuals are the operands; the backward makes the pre-activations
+    again, a tile at a time."""
+    return _scores_forward(q_i, k_i, w, row0, interpret, SCORE_Q, SCORE_K)
+
+
+def _scores_kernels_fwd(q_i, k_i, w, row0, interpret):
+    out = _scores_forward(q_i, k_i, w, row0, interpret, SCORE_Q, SCORE_K)
+    return out, (q_i, k_i, w, row0)
+
+
+def _scores_kernels_bwd(interpret, residuals, d_scores):
+    q_i, k_i, w, row0 = residuals
+    return (*_scores_backward(q_i, k_i, w, d_scores, row0, interpret, SCORE_K), None)
+
+
+_scores_by_kernels.defvjp(_scores_kernels_fwd, _scores_kernels_bwd)
+
+
 # -- attention under the mask: the kernels ---------------------------------------
 
 
@@ -281,10 +514,6 @@ def _last_key_tile(i):
 def _first_query_tile(j):
     """The first tile of queries that holds a query at or after a key of tile j."""
     return (j * BLOCK_K) // BLOCK_Q
-
-
-def _dot(a, b, dims=(((1,), (0,)), ((), ()))):
-    return lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
 
 
 def _visit(i, j, body):

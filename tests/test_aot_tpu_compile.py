@@ -401,6 +401,39 @@ def _laguna_moe_step(topo, monkeypatch):
     return compiled, len(calls) + 9 * 4
 
 
+def _index_scores_layer(topo, monkeypatch):
+    """One layer's indexer at the Keye cell's shape (16 index heads of 64, T
+    8,192, ``topk`` 2,048): the mask and the indexer's loss with its gradient.
+    The index scores run the repo's kernels inside the runs' loops, where the
+    compiler gives a kernel 16 MB of VMEM whatever it asks for (the backward
+    keeps a block's 512 rows resident inside that), and no (16, 512, keys)
+    float32 array is left in the program."""
+    from akka_allreduce_tpu.ops import sparse_attention as sa
+
+    t, heads, d = 8192, 16, 64
+    chip = SingleDeviceSharding(topo.devices[0])
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=chip)  # noqa: E731
+
+    def layer(q_i, k_i, w, q, k, lse):
+        mask = sa.indexer_mask(q_i, k_i, w, 2048)
+        loss, grads = jax.value_and_grad(sa.indexer_kl, argnums=(0, 1, 2))(
+            q_i, k_i, w, mask, q, k, lse)
+        return mask, loss, grads
+
+    compiled = jax.jit(layer).lower(
+        sds((heads, t, d), jnp.bfloat16), sds((t, d), jnp.bfloat16), sds((t, heads), jnp.float32),
+        sds((32, t, 128), jnp.bfloat16), sds((4, t, 128), jnp.bfloat16), sds((4, 8, t), jnp.float32),
+    ).compile()
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    # three runs of blocks make scores for the mask, four for the loss, each
+    # of those with its backward
+    assert sum("index_scores_fwd" in c for c in calls) == 3 + 4
+    assert sum("index_scores_bwd" in c for c in calls) == 4
+    assert not re.search(r"f32\[16,512,\d+\]", text)
+    return compiled, 3 + 4 + 4
+
+
 CASES = {
     "reduce_kernels_8x8M_f32": _reduce_kernels,
     "pallas_ring_4dev_64M_f32": _pallas_ring(None),
@@ -412,6 +445,7 @@ CASES = {
     "lfm2_moe_cell_step": _lfm2_moe_step,
     "joyai_mla_moe_cell_step": _joyai_mla_moe_step,
     "laguna_moe_cell_step": _laguna_moe_step,
+    "index_scores_kernels_t8192_j16_d64": _index_scores_layer,
     # the benchmark's own attention shapes, K/V compact into the kernel
     "splash_attention_b2_t4096_h24_kv2_d128": _kernel_attention(2, 4096, 24, 2, 128),
     "splash_attention_b1_t8192_h32_kv8_d64": _kernel_attention(1, 8192, 32, 8, 64),

@@ -84,11 +84,31 @@ def test_selection_is_top_k_with_ties_to_the_lower_index(ties, rows, cols, row0,
     assert not bool(jnp.any(got & (jnp.arange(cols)[None, :] > t[:, None])))
 
 
-def test_indexer_mask_by_blocks_is_the_selection_of_the_whole(monkeypatch):
+def _written_scores(q_i, k_i, w):
+    return jnp.einsum("tj,jts->ts", w.astype(jnp.float32), jax.nn.relu(
+        jnp.einsum("jtd,sd->jts", q_i, k_i, preferred_element_type=jnp.float32)))
+
+
+def _score_kernels_interpreted(monkeypatch, q_tile, k_tile):
+    """The index scores' kernels where the chip would run them, interpreted,
+    at tiles a tiny shape holds: the platform question answered yes."""
+    monkeypatch.setattr(sa, "_on_chip", lambda *arrays: True)
+    monkeypatch.setattr(sa, "SCORE_Q", q_tile)
+    monkeypatch.setattr(sa, "SCORE_K", k_tile)
+
+
+SCORES_BY = pytest.mark.parametrize("scores_by", ["einsums", "kernels"])
+
+
+@SCORES_BY
+def test_indexer_mask_by_blocks_is_the_selection_of_the_whole(scores_by, monkeypatch):
     """Runs of blocks through their loops (8 rows at a time, four runs) give
     the mask one block of all rows gives; rows inside ``topk`` see every
-    causal key."""
+    causal key. On the kernels the entries after a tile's queries are zeros
+    the selection never reads."""
     t, j, d, topk = 64, 4, 8, 16
+    if scores_by == "kernels":
+        _score_kernels_interpreted(monkeypatch, 8, 16)
     k = jax.random.split(jax.random.PRNGKey(1), 3)
     q_i, k_i = jax.random.normal(k[0], (j, t, d)), jax.random.normal(k[1], (t, d))
     w = jax.random.normal(k[2], (t, j))
@@ -101,8 +121,7 @@ def test_indexer_mask_by_blocks_is_the_selection_of_the_whole(monkeypatch):
     want = _top_k_mask(sa.index_scores(q_i, k_i, w), topk, 0)
     np.testing.assert_array_equal(np.asarray(whole), np.asarray(want, np.int8))
     assert whole.dtype == jnp.int8 and int(whole.sum()) == 16 * 17 // 2 + 48 * 16
-    written = jnp.einsum("tj,jts->ts", w, jax.nn.relu(jnp.einsum("jtd,sd->jts", q_i, k_i)))
-    _close(sa.index_scores(q_i, k_i, w), written)
+    _close(jnp.tril(sa.index_scores(q_i, k_i, w)), jnp.tril(_written_scores(q_i, k_i, w)))
 
 
 # -- attention under an array mask ------------------------------------------------
@@ -224,11 +243,15 @@ def test_the_kernels_take_the_cells_shape_and_the_gauges_say_what_they_run():
     assert causal < sa.visited_pairs(8192) <= causal + 8192 * sa.BLOCK_K
 
 
-def test_the_indexers_loss_and_its_gradient_made_in_the_forward_pass(monkeypatch):
+@SCORES_BY
+def test_the_indexers_loss_and_its_gradient_made_in_the_forward_pass(scores_by, monkeypatch):
     """``indexer_kl`` against the loss written out, and its ``custom_vjp``
     (gradients made block by block in the forward pass, scaled by the
-    cotangent) against autodiff of the written-out loss."""
+    cotangent) against autodiff of the written-out loss. On the kernels the
+    scores' own ``custom_vjp`` runs under ``jax.vjp`` inside the runs' scans."""
     t, j, d, h, h_kv, hd, topk = 32, 4, 8, 4, 2, 16, 8
+    if scores_by == "kernels":
+        _score_kernels_interpreted(monkeypatch, 8, 8)
     ks = jax.random.split(jax.random.PRNGKey(2), 3)
     q_i, k_i = jax.random.normal(ks[0], (j, t, d)), jax.random.normal(ks[1], (t, d))
     w = jax.random.normal(ks[2], (t, j))
@@ -254,6 +277,92 @@ def test_the_indexers_loss_and_its_gradient_made_in_the_forward_pass(monkeypatch
         for a, b in zip(grads, want_grads):
             _close(a, b, 1e-4)
         _close(sa.indexer_kl(q_i, k_i, w, mask, q[0], k[0], lse), written(q_i, k_i, w))
+
+
+# -- the index scores' kernels --------------------------------------------------------
+
+
+def _index_operands(dtype, rows, cols, heads=4, d=8, seed=3):
+    """A block's operands with what the kernels have to get right in them:
+    weights of both signs, and pre-activations at exactly 0 (a query and a
+    key of zeros)."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    q_i = jax.random.normal(ks[0], (heads, rows, d)).at[1, 5].set(0.0).astype(dtype)
+    k_i = jax.random.normal(ks[1], (cols, d)).at[7].set(0.0).astype(dtype)
+    w = jax.random.normal(ks[2], (rows, heads))
+    assert bool((w < 0).any()) and bool((w > 0).any())
+    return q_i, k_i, w
+
+
+# (first row, rows, keys): the sequence's start; a block astride the diagonal
+# whose keys end before the sequence does; a block's last rows
+BLOCKS = {"at_the_start": (0, 32, 96), "astride_with_keys_left": (32, 32, 64),
+          "last_rows": (64, 32, 96)}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("what", ["scores", "dq_i", "dk_i", "dw"])
+@pytest.mark.parametrize("block", sorted(BLOCKS))
+def test_score_kernels_interpreted_match_the_two_einsums(block, what, dtype, monkeypatch):
+    """``index_scores_fwd`` / ``index_scores_bwd`` in interpret mode, two
+    tiles of queries and four or six of keys, against the einsums that stay
+    in the module: the scores on every tile that holds a causal pair (zeros
+    on the others), and each gradient under a random float32 cotangent on the
+    causal pairs, as ``_kl_block`` hands it. Both forms accumulate in
+    float32, so from equal operands the scores and ``dw`` agree to a
+    re-ordered sum in either dtype; from bf16 operands the kernel's gate and
+    weighted query enter the MXU in bf16, as the masked kernels' ``ds`` does."""
+    row0, rows, cols = BLOCKS[block]
+    q_i, k_i, w = _index_operands(dtype, rows, cols)
+    einsums = lambda *a: sa.index_scores(*a, row0)  # noqa: E731
+    want, pull_einsums = jax.vjp(einsums, q_i, k_i, w)
+    _close(want, _written_scores(q_i, k_i, w))  # the yardstick is the formula
+    assert bool((want == 0).any())  # the zeroed query and key: pre-activations at 0
+    _score_kernels_interpreted(monkeypatch, 16, 16)
+    assert sa.takes_index_scores(rows, cols, 8, 4)
+    got, pull_kernels = jax.vjp(lambda *a: sa.index_scores(*a, jnp.int32(row0)), q_i, k_i, w)
+    run = np.repeat(np.repeat(  # the tiles the grid's rule runs
+        np.arange(cols // 16)[None, :] <= np.asarray(
+            [sa._last_score_tile(row0, i, 16, 16) for i in range(rows // 16)])[:, None],
+        16, axis=0), 16, axis=1)
+    causal = np.arange(cols)[None, :] <= (row0 + np.arange(rows))[:, None]
+    assert run[causal].all() and not run.all()
+    if what == "scores":
+        assert got.dtype == jnp.float32
+        _close(jnp.where(run, got, 0.0), jnp.where(run, want, 0.0))
+        assert not bool(jnp.any(jnp.where(run, 0.0, got)))
+        return
+    cotangent = jnp.where(causal, jax.random.normal(jax.random.PRNGKey(11), (rows, cols)), 0.0)
+    at = ("dq_i", "dk_i", "dw").index(what)
+    mine, theirs = pull_kernels(cotangent)[at], pull_einsums(cotangent)[at]
+    assert mine.dtype == theirs.dtype == (jnp.float32 if what == "dw" else dtype)
+    exact = dtype == jnp.float32 or what == "dw"
+    _close(mine.astype(jnp.float32), theirs.astype(jnp.float32), 2e-5 if exact else 2e-2)
+
+
+def test_the_score_kernels_take_the_cells_blocks_and_the_gauges_say_what_they_run():
+    from akka_allreduce_tpu.obs import metrics
+
+    for cols in (4096, 6144, 8192):  # 512 rows of 16 heads of 64 against a run's keys
+        assert sa.takes_index_scores(512, cols, 64, 16)
+    assert not sa.takes_index_scores(512, 4096 + 256, 64, 16)  # keys the tile does not divide
+    assert not sa.takes_index_scores(640, 8192, 64, 16)  # rows it does not
+    assert not sa.takes_index_scores(512, 8192, 128, 16)  # more than the backward holds
+    # the grid's rule: of a block at row0 the key tiles up to its last row's, whole;
+    # the mask pass makes no score for the rows inside topk
+    tiles = lambda blocks: sum(b + 1 for b in blocks)  # noqa: E731
+    assert sa.scored_pairs(8192, 2048) == 512 * 512 * (tiles(range(4, 16)) + tiles(range(16)))
+    sa._gauge_scored.__wrapped__(8192, 2048)
+    now = metrics.REGISTRY.snapshot()
+    causal = 8192 * 8193 // 2
+    assert now["attention.indexer.causal_pairs"] == causal
+    assert now["attention.indexer.scored_pairs"] == sa.scored_pairs(8192, 2048)
+    # two passes, the first without the 2,048 rows every causal key of which is kept
+    assert 1.0 < sa.scored_pairs(8192, 2048) / (2 * causal - 2048 * 2049 // 2) < 1.06
+    # a program on the einsum form writes neither
+    q_i, k_i, w = _index_operands(jnp.float32, 64, 64)
+    sa.indexer_mask(q_i, k_i, w, 16)
+    assert metrics.REGISTRY.snapshot()["attention.indexer.scored_pairs"] == sa.scored_pairs(8192, 2048)
 
 
 # -- multimodal RoPE -----------------------------------------------------------------
@@ -403,3 +512,36 @@ def test_readers_of_the_new_metrics_on_a_made_up_record():
         assert spec.load_module("layer_metrics", name).compute(bare, _Trace({})) is None, name
     assert spec.load_module("layer_metrics", "attn_kernel_roofline_pct.keye").compute(
         bare, _Trace({})) is None
+
+
+def test_reader_of_the_index_scores_on_a_made_up_record():
+    """``indexer_scores_ms`` counts what runs under ``indexer_scores``, XLA's
+    ops and the program's own kernels alike; ``indexer_ms`` counts the
+    kernels too, ``attn_kernel_ms`` does not."""
+    pre = "jit(step)/sparse_attention/attn_indexer/"
+    scopes = {
+        "fusion.1": pre + "indexer_proj/dot", "fusion.2": pre + "while/body/indexer_scores/reduce",
+        "index_scores_fwd.1": pre + "while/body/indexer_scores/pallas",
+        "index_scores_bwd.1": pre + "while/body/indexer_scores/pallas",
+        "fusion.3": pre + "while/body/indexer_select/reduce", "while.1": pre + "while",
+        "flash_mha_sparse_fwd.1": "jit(step)/sparse_attention/attn_core/pallas",
+    }
+    ops = {n: (3, 0.003 * (i + 1), "while" if n.startswith("while") else "fusion")
+           for i, n in enumerate(scopes)}
+    units = [{"t0": 0.1 * i, "t1": 0.1 * i + 0.1, "work": 8192, "ok": True} for i in range(3)]
+    units[0]["op_scopes"] = scopes
+    record = {"window": {"units": units, "start": 0.0, "paused": 0.0}}
+    trace = _Trace(ops)
+    read = lambda name: spec.load_module("layer_metrics", name).compute(record, trace)  # noqa: E731
+    assert read("indexer_scores_ms") == pytest.approx(1e3 * (0.006 + 0.009 + 0.012) / 3)
+    assert read("indexer_ms") == pytest.approx(1e3 * (0.003 + 0.006 + 0.009 + 0.012 + 0.015) / 3)
+    assert read("indexer_select_ms") == pytest.approx(5.0)
+    assert read("attn_kernel_ms") == pytest.approx(7.0)  # the masked kernel alone
+    # a program without the scope (or a runner without the map): nothing, and no raise
+    units[0]["op_scopes"] = {"fusion.1": "jit(step)/attention/attn_core/dot"}
+    assert read("indexer_scores_ms") is None
+    del units[0]["op_scopes"]
+    assert read("indexer_scores_ms") is None
+    bench = _json(os.path.join(ROOT, "BENCHMARK.json"))
+    entry = next(m for m in bench["per_layer"] if m["name"] == "indexer_scores_ms")
+    assert entry["layer"] == "the indexer" and entry["workloads"] == ["keye_vl2_ep8_train_b1_t8192"]

@@ -488,7 +488,7 @@ def test_the_seeded_weights_spread_the_embedding_on_its_own():
 
 KEYE_NEW = ("indexer_ms", "indexer_select_ms", "indexer_target_ms",
             "attn_kernel_roofline_pct.keye", "sparse_tile_useful_pct", "mfu_pct.keye",
-            "moe_gmm_roofline_pct.keye")
+            "moe_gmm_roofline_pct.keye", "indexer_scores_ms")  # the last since PR 42
 
 
 def test_the_benchmark_lists_the_cell_where_the_issue_says():
