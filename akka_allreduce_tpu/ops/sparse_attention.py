@@ -23,15 +23,25 @@ Four parts, each a function of its own:
   of a key that orders as the floats do (32 counting passes over the block),
   and the keys tied with it are ranked by position only where a row has more
   of them than it may keep.
-- :func:`sparse_attention`: three Pallas TPU kernels of the repo's own,
-  ``flash_mha_sparse_fwd`` / ``_dq`` / ``_dkv``, in ``ops/band_attention.py``'s
-  manner (a group's query heads stacked as rows of one product, f32 scores
-  and statistics, the operands' dtype into the MXU) with what a mask known
-  only at run time asks for beside it: a loop over K/V tiles with a running
-  maximum, the mask an int8 operand read a (queries, keys) tile at a time,
-  the tiles above the diagonal skipped from the grid position, the one
-  astride it cut by the causal rule from iotas. Returns the
-  rows' log-sum-exp beside the output: the indexer's loss reads it.
+- :func:`sparse_attention`: two Pallas TPU kernels of the repo's own,
+  ``flash_mha_sparse_fwd`` and ``flash_mha_sparse_bwd``, in
+  ``ops/band_attention.py``'s manner (a group's query heads stacked as rows
+  of one product, f32 scores and statistics, the operands' dtype into the
+  MXU) with what a mask known only at run time asks for beside it: a loop
+  over K/V tiles with a running maximum, the mask an int8 operand read a
+  (queries, keys) tile at a time, the tiles above the diagonal skipped from
+  the grid position, the causal rule taken from iotas beside the mask.
+  The forward makes a tile's scores in one product and then the softmax's
+  chain a head's :data:`SOFTMAX_ROWS` rows at a time, so that the chain
+  lives in registers between the scores' load and the weights' store (two
+  products a tile), and returns the rows' log-sum-exp beside the output: the
+  indexer's loss reads it. The backward is ONE kernel, query-major as the
+  forward: a visited tile's scores, probabilities and ``ds`` are made once
+  and feed all three gradient products (five products a tile), ``dq`` summed
+  over a row of key tiles, ``dk`` and ``dv`` of a K/V head's whole sequence
+  summed in a float32 (T, D) + (T, Dv) pair resident in VMEM that leaves the
+  chip's fast memory once, at the head's last step (K/V are compact, 4 heads
+  for 32: 8 MiB at T 8,192; :func:`takes_sparse` bounds T by it).
 - :func:`indexer_kl`: the indexer's own loss, ``sum_t KL(pbar_t || softmax
   over S_t of I[t, .])`` with ``pbar`` the head mean of the main attention's
   probabilities on the selected set, a block of query rows at a time so that
@@ -40,9 +50,9 @@ Four parts, each a function of its own:
   block's scores are alive then anyway), and kept as the residual: three
   small arrays where the scores of every block would have been.
 
-The kernels take T that their tiles divide and heads of up to 128
-(:func:`takes_sparse`); any other shape goes to the portable core
-(``local_attention._blockwise_olm`` with the mask).
+The kernels take T that their tiles divide and the backward's accumulators
+fit, and heads of up to 128 (:func:`takes_sparse`); any other shape goes to
+the portable core (``local_attention._blockwise_olm`` with the mask).
 """
 
 from __future__ import annotations
@@ -60,8 +70,10 @@ from akka_allreduce_tpu.ops.ring_attention import _MASK_VALUE
 
 #: queries and keys a grid step of each kernel: a group's eight heads stack
 #: ``BLOCK_Q`` rows each into one product against ``BLOCK_K`` keys
-BLOCK_Q = 256
+BLOCK_Q = 512
 BLOCK_K = 512
+#: rows of a head whose softmax the forward kernel makes at a time
+SOFTMAX_ROWS = 256
 #: query rows of index scores (and of the loss's target) alive at once, and
 #: the runs of such blocks that share their columns (:func:`_stages`)
 INDEX_ROWS = 512
@@ -76,6 +88,9 @@ _RESIDENT = 16 * 512 * 64
 _NT = (((1,), (1,)), ((), ()))  # a @ b.T
 _TN = (((0,), (0,)), ((), ()))  # a.T @ b
 _VMEM_LIMIT = 100 * 1024 * 1024  # of a v5e core's 128 MiB
+#: of that, what a grid step's tiles take: the masked kernels' blocks, buffered
+#: twice, and the (heads, queries, keys) f32 intermediates of the backward
+_TILE_ROOM = 40 * 1024 * 1024
 
 
 def _dot(a, b, dims=(((1,), (0,)), ((), ()))):
@@ -492,15 +507,26 @@ _scores_by_kernels.defvjp(_scores_kernels_fwd, _scores_kernels_bwd)
 
 def takes_sparse(t: int, d: int, dv: int) -> bool:
     """Do the kernels take the shape? T that both tiles divide, heads of up
-    to 128 (a group's stacked scores against a tile of keys fit VMEM)."""
-    return t % BLOCK_Q == 0 and t % BLOCK_K == 0 and max(d, dv) <= 128
+    to 128 (a group's stacked scores against a tile of keys fit VMEM), and a
+    sequence whose ``dk`` and ``dv`` the backward keeps resident beside its
+    tiles: a K/V head's float32 (T, D + Dv) sums and the blocks they leave
+    through, buffered twice, at up to four bytes an element, twelve bytes in
+    all, in the 60 MB that :data:`_VMEM_LIMIT` has past :data:`_TILE_ROOM`.
+    At heads of 128 that is T up to 16,384 (the cell's 8,192 takes 25 MB); a
+    longer sequence goes where every shape the kernels do not take goes, to
+    the portable core."""
+    resident = t * (d + dv) * (4 + 2 * 4)
+    return (
+        t % BLOCK_Q == 0 and t % BLOCK_K == 0 and max(d, dv) <= 128
+        and resident <= _VMEM_LIMIT - _TILE_ROOM
+    )
 
 
 def visited_pairs(t: int) -> int:
     """(query, key) pairs a head's tiles run, by the rule the kernels' grids
     skip by (:func:`_last_key_tile`): every tile that holds a causal pair,
     whole. A tile without a selected key is not skipped (on a learned mask
-    over 8,192 keys a 256 x 512 tile without one is not to be had)."""
+    over 8,192 keys a 512 x 512 tile without one is not to be had)."""
     return sum(
         BLOCK_Q * BLOCK_K * (_last_key_tile(i) + 1) for i in range(t // BLOCK_Q)
     )
@@ -511,41 +537,51 @@ def _last_key_tile(i):
     return ((i + 1) * BLOCK_Q - 1) // BLOCK_K
 
 
-def _first_query_tile(j):
-    """The first tile of queries that holds a query at or after a key of tile j."""
-    return (j * BLOCK_K) // BLOCK_Q
+def _visits(i, j):
+    """Does the tile (query tile ``i``, key tile ``j``) hold a causal pair?
+    The grid steps past a row's last such tile run nothing and, their blocks
+    clamped onto that tile's (:func:`_specs`), fetch nothing."""
+    return j <= _last_key_tile(i)
 
 
-def _visit(i, j, body):
-    """Run ``body`` at the grid steps whose tile (query tile ``i``, key tile
-    ``j``) holds a causal pair: ``body(None)`` on the tiles wholly below the
-    diagonal, compiled without the causal rule, and ``body((i, j))`` on the
-    one astride it, the last of the row (a query tile lies inside ONE key
-    tile, :data:`BLOCK_K` being a multiple of :data:`BLOCK_Q`)."""
-    assert BLOCK_K % BLOCK_Q == 0
-    last = _last_key_tile(i)
-    pl.when(j < last)(lambda: body(None))
-    pl.when(j == last)(lambda: body((i, j)))
-
-
-def _scores(q_ref, k_ref, mask_ref, astride):
-    """A group's stacked scores against a tile of keys, (G, rows, keys) f32,
-    the unseen ones at the mask value, and which are seen, (1, rows, keys):
-    where the mask says so and, on the tile ``astride`` the diagonal (its
-    grid position), the causal rule too."""
-    g, b, d = q_ref.shape
+def _seen(mask_ref, i, j):
+    """Which pairs of the tile at (query tile ``i``, key tile ``j``) are
+    seen, (rows, keys) bool, made once a tile for the group's heads: where
+    the mask says so AND the key is at or before the query. The causal rule
+    is taken from iotas on every visited tile, not only on the one astride
+    the diagonal: an int32 compare and an AND a tile, beside products that
+    set the pace, are under 2 % of either kernel's schedule and nothing on
+    the chip, and one compiled body a kernel in place of two halves the
+    kernels' code, which a warm start pays for (PERF.md section 6, PR 44)."""
     kept = mask_ref[...].astype(jnp.int32)
-    if astride is not None:
-        rows = astride[0] * BLOCK_Q + lax.broadcasted_iota(jnp.int32, kept.shape, 0)
-        keys = astride[1] * BLOCK_K + lax.broadcasted_iota(jnp.int32, kept.shape, 1)
-        kept = jnp.where(keys <= rows, kept, 0)
-    seen = (kept != 0)[None]
-    s = _dot(q_ref[...].reshape(g * b, d), k_ref[...], _NT).reshape(g, b, -1)
-    return jnp.where(seen, s, _MASK_VALUE), seen
+    rows = i * BLOCK_Q + lax.broadcasted_iota(jnp.int32, kept.shape, 0)
+    keys = j * BLOCK_K + lax.broadcasted_iota(jnp.int32, kept.shape, 1)
+    return (kept != 0) & (keys <= rows)
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr):
-    g, b, _ = q_ref.shape
+def _weights(s, m):
+    """``exp(s - m)``, a key's weight against the row's running maximum,
+    WITHOUT a select beside it. An unseen key's score is the mask value, so
+    its weight is exactly 0 once ``m`` is a seen key's score. Before a row
+    has seen a key, ``m`` is the mask value too and the weight of its unseen
+    keys is 1: garbage in ``l`` and ``acc`` that the row's first seen key
+    wipes, ``fade = exp(mask value - m)`` being exactly 0 then, and every
+    query sees a key (:func:`sparse_attention`)."""
+    return jnp.exp(s - m)
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
+                m_scr, l_scr, acc_scr, fade_scr, p_scr):
+    """A visited tile: the group's stacked scores in one product, then the
+    softmax's chain (mask, running maximum, weights, row sum, the cast) a
+    head's :data:`SOFTMAX_ROWS` rows at a time, each block's weights written
+    to ``p_scr`` in the operands' dtype as soon as they are made, and the
+    second product from ``p_scr`` whole. A block's chain lives in registers
+    between its scores' load and its weights' store; as whole-array
+    operations over (G, rows, keys) every step of it goes through VMEM, and
+    the one vector store a bundle then sets the kernel's pace."""
+    g, b, d = q_ref.shape
+    rows = min(b, SOFTMAX_ROWS)
     i, j = pl.program_id(2), pl.program_id(3)
 
     @pl.when(j == 0)
@@ -554,21 +590,24 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, m_scr, l_scr, acc
         l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
         acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
 
-    def visit(astride):
-        s, seen = _scores(q_ref, k_ref, mask_ref, astride)
-        m_old = m_scr[...]
-        m_new = jnp.maximum(m_old, s.max(axis=-1, keepdims=True))
-        # a row that has seen nothing yet keeps the mask value as its maximum:
-        # exp(s - m) would be 1 on its unseen keys
-        p = jnp.where(seen, jnp.exp(s - m_new), 0.0)
-        fade = jnp.exp(m_old - m_new)
-        l_scr[...] = fade * l_scr[...] + p.sum(axis=-1, keepdims=True)
-        v = v_ref[...]
-        pv = _dot(p.reshape(g * b, -1).astype(v.dtype), v).reshape(g, b, -1)
-        acc_scr[...] = fade * acc_scr[...] + pv
-        m_scr[...] = m_new
-
-    _visit(i, j, visit)
+    @pl.when(_visits(i, j))
+    def _():
+        seen = _seen(mask_ref, i, j)
+        s = _dot(q_ref[...].reshape(g * b, d), k_ref[...], _NT)
+        for h in range(g):
+            for r in range(0, b, rows):
+                part, stacked = slice(r, r + rows), slice(h * b + r, h * b + r + rows)
+                s_b = jnp.where(seen[part], s[stacked], _MASK_VALUE)
+                m_old = m_scr[h, part]
+                m_new = jnp.maximum(m_old, s_b.max(axis=-1, keepdims=True))
+                p = _weights(s_b, m_new)
+                fade = jnp.exp(m_old - m_new)
+                l_scr[h, part] = fade * l_scr[h, part] + p.sum(axis=-1, keepdims=True)
+                m_scr[h, part] = m_new
+                fade_scr[h, part] = fade
+                p_scr[stacked, :] = p.astype(p_scr.dtype)
+        pv = _dot(p_scr[...], v_ref[...]).reshape(acc_scr.shape)
+        acc_scr[...] = fade_scr[...] * acc_scr[...] + pv
 
     @pl.when(j == pl.num_programs(3) - 1)
     def _():
@@ -577,91 +616,78 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, m_scr, l_scr, acc
         lse_ref[...] = (m_scr[...] + jnp.log(l))[..., 0]
 
 
-def _probs(q_ref, k_ref, mask_ref, lse_ref, astride):
-    s, seen = _scores(q_ref, k_ref, mask_ref, astride)
-    return jnp.where(seen, jnp.exp(s - lse_ref[...][..., None]), 0.0)
-
-
-def _dq_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref, dq_ref, acc_scr):
+def _bwd_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
+                dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr):
+    """A visited tile's scores, probabilities and ``ds`` made ONCE, and the
+    three gradient products from them. ``dq`` of the query tile is summed in
+    ``dq_scr`` over its row of key tiles; ``dk`` and ``dv`` of the K/V head's
+    WHOLE sequence in ``dk_scr`` / ``dv_scr``, float32 (T, D), a key tile's
+    rows taking the query tiles' contributions in the order of the grid."""
     g, b, d = q_ref.shape
     i, j = pl.program_id(2), pl.program_id(3)
 
-    @pl.when(j == 0)
-    def _():
-        acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
-
-    def visit(astride):
-        k = k_ref[...]
-        p = _probs(q_ref, k_ref, mask_ref, lse_ref, astride)
-        dp = _dot(do_ref[...].reshape(g * b, -1), v_ref[...], _NT).reshape(p.shape)
-        ds = p * (dp - delta_ref[...][..., None])
-        acc_scr[...] += _dot(ds.reshape(g * b, -1).astype(k.dtype), k).reshape(g, b, d)
-
-    _visit(i, j, visit)
-
-    @pl.when(j == pl.num_programs(3) - 1)
-    def _():
-        dq_ref[...] = acc_scr[...].astype(dq_ref.dtype)
-
-
-def _dkv_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
-                dk_ref, dv_ref, dk_scr, dv_scr):
-    g, b, d = q_ref.shape
-    j, i = pl.program_id(2), pl.program_id(3)
-
-    @pl.when(i == 0)
+    @pl.when((i == 0) & (j == 0))
     def _():
         dk_scr[...] = jnp.zeros(dk_scr.shape, jnp.float32)
         dv_scr[...] = jnp.zeros(dv_scr.shape, jnp.float32)
 
-    def visit(astride):
-        # rows are the group's stacked queries: contracting them sums the
-        # group's heads into the one K/V head
+    @pl.when(j == 0)
+    def _():
+        dq_scr[...] = jnp.zeros(dq_scr.shape, jnp.float32)
+
+    @pl.when(_visits(i, j))
+    def _():
+        k = k_ref[...]
         q, do = q_ref[...].reshape(g * b, d), do_ref[...].reshape(g * b, -1)
-        p = _probs(q_ref, k_ref, mask_ref, lse_ref, astride)
+        # the unseen scores are at the mask value and every row's log-sum-exp
+        # is finite (every query sees a key): their probability is exactly 0
+        s = _dot(q, k, _NT).reshape(g, b, -1)
+        s = jnp.where(_seen(mask_ref, i, j)[None], s, _MASK_VALUE)
+        p = jnp.exp(s - lse_ref[...][..., None])
         dp = _dot(do, v_ref[...], _NT).reshape(p.shape)
         ds = p * (dp - delta_ref[...][..., None])
-        dv_scr[...] += _dot(p.reshape(g * b, -1).astype(do.dtype), do, _TN)
-        dk_scr[...] += _dot(ds.reshape(g * b, -1).astype(q.dtype), q, _TN)
+        p, ds = p.reshape(g * b, -1), ds.reshape(g * b, -1)
+        dq_scr[...] += _dot(ds.astype(k.dtype), k).reshape(g, b, d)
+        # rows are the group's stacked queries: contracting them sums the
+        # group's heads into the one K/V head
+        keys = pl.ds(pl.multiple_of(j * BLOCK_K, BLOCK_K), BLOCK_K)
+        dv_scr[keys, :] += _dot(p.astype(do.dtype), do, _TN)
+        dk_scr[keys, :] += _dot(ds.astype(q.dtype), q, _TN)
 
-    _visit(i, j, visit)  # the query tiles before key tile j's first lie above the diagonal
+    @pl.when(j == pl.num_programs(3) - 1)
+    def _():
+        dq_ref[...] = dq_scr[...].astype(dq_ref.dtype)
 
-    @pl.when(i == pl.num_programs(3) - 1)
+    @pl.when((i == pl.num_programs(2) - 1) & (j == pl.num_programs(3) - 1))
     def _():
         dk_ref[...] = dk_scr[...].astype(dk_ref.dtype)
         dv_ref[...] = dv_scr[...].astype(dv_ref.dtype)
 
 
-def _call(kernel, name, grid, in_specs, out_specs, out_shape, scratch, interpret):
+def _call(kernel, name, grid, in_specs, out_specs, out_shape, scratch, interpret, query_tiles):
     return pl.pallas_call(
         kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
         out_shape=out_shape, scratch_shapes=scratch, name=name, interpret=interpret,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
+            dimension_semantics=("parallel", "parallel", query_tiles, "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT,
         ),
     )
 
 
-# Index maps. Query-major kernels (forward, dq) run (n, h, i, j), the key
-# tiles above the diagonal clamped onto the last one below it, so that the
-# skipped steps fetch nothing; the key-major one (dkv) runs (n, h, j, i) with
-# the query tiles before the first useful one clamped onto it.
-def _specs(g: int, key_major: bool):
-    if key_major:
-        at = lambda j, i: (jnp.maximum(i, _first_query_tile(j)), j)  # noqa: E731
-    else:
-        at = lambda i, j: (i, jnp.minimum(j, _last_key_tile(i)))  # noqa: E731
+# Index maps. Both kernels run (n, h, i, j), query-major, the key tiles above
+# the diagonal clamped onto the last one below it, so that the skipped steps
+# fetch nothing.
+def _specs(g: int):
+    key_tile = lambda i, j: jnp.minimum(j, _last_key_tile(i))  # noqa: E731
     queries = lambda w: pl.BlockSpec(  # noqa: E731
-        (None, g, BLOCK_Q, w), lambda n, h, a, b: (n, h, at(a, b)[0], 0)
+        (None, g, BLOCK_Q, w), lambda n, h, i, j: (n, h, i, 0)
     )
     keys = lambda w: pl.BlockSpec(  # noqa: E731
-        (None, None, BLOCK_K, w), lambda n, h, a, b: (n, h, at(a, b)[1], 0)
+        (None, None, BLOCK_K, w), lambda n, h, i, j: (n, h, key_tile(i, j), 0)
     )
-    mask = pl.BlockSpec((None, BLOCK_Q, BLOCK_K), lambda n, h, a, b: (n, *at(a, b)))
-    rows = pl.BlockSpec(
-        (None, None, g, BLOCK_Q), lambda n, h, a, b: (n, h, 0, at(a, b)[0])
-    )
+    mask = pl.BlockSpec((None, BLOCK_Q, BLOCK_K), lambda n, h, i, j: (n, i, key_tile(i, j)))
+    rows = pl.BlockSpec((None, None, g, BLOCK_Q), lambda n, h, i, j: (n, h, 0, i))
     return queries, keys, mask, rows
 
 
@@ -669,42 +695,37 @@ def _forward(q, k, v, mask, interpret):
     n, h, t, d = q.shape
     h_kv, dv = k.shape[1], v.shape[-1]
     g = h // h_kv
-    queries, keys, tile, rows = _specs(g, False)
+    queries, keys, tile, rows = _specs(g)
     stat = pltpu.VMEM((g, BLOCK_Q, 1), jnp.float32)
     return _call(
         _fwd_kernel, "flash_mha_sparse_fwd", (n, h_kv, t // BLOCK_Q, t // BLOCK_K),
         [queries(d), keys(d), keys(dv), tile], [queries(dv), rows],
         [jax.ShapeDtypeStruct((n, h, t, dv), q.dtype),
          jax.ShapeDtypeStruct((n, h_kv, g, t), jnp.float32)],
-        [stat, stat, pltpu.VMEM((g, BLOCK_Q, dv), jnp.float32)], interpret,
+        [stat, stat, pltpu.VMEM((g, BLOCK_Q, dv), jnp.float32), stat,
+         pltpu.VMEM((g * BLOCK_Q, BLOCK_K), v.dtype)], interpret, "parallel",
     )(q, k, v, mask)
 
 
-def _dq(q, k, v, mask, do, lse, delta, interpret):
+def _backward(q, k, v, mask, do, lse, delta, interpret):
+    """``(dq, dk, dv)`` from one kernel: the query tiles of a K/V head run in
+    order (``"arbitrary"``), because the head's ``dk`` and ``dv`` are summed
+    across them in VMEM and leave it once, at the head's last step."""
     n, h, t, d = q.shape
     h_kv, dv = k.shape[1], v.shape[-1]
     g = h // h_kv
-    queries, keys, tile, rows = _specs(g, False)
+    queries, keys, tile, rows = _specs(g)
+    whole = lambda w: pl.BlockSpec(  # noqa: E731
+        (None, None, t, w), lambda n, h, i, j: (n, h, 0, 0)
+    )
     return _call(
-        _dq_kernel, "flash_mha_sparse_dq", (n, h_kv, t // BLOCK_Q, t // BLOCK_K),
+        _bwd_kernel, "flash_mha_sparse_bwd", (n, h_kv, t // BLOCK_Q, t // BLOCK_K),
         [queries(d), keys(d), keys(dv), tile, queries(dv), rows, rows],
-        queries(d), jax.ShapeDtypeStruct(q.shape, q.dtype),
-        [pltpu.VMEM((g, BLOCK_Q, d), jnp.float32)], interpret,
-    )(q, k, v, mask, do, lse, delta)
-
-
-def _dkv(q, k, v, mask, do, lse, delta, interpret):
-    n, h, t, d = q.shape
-    h_kv, dv = k.shape[1], v.shape[-1]
-    g = h // h_kv
-    queries, keys, tile, rows = _specs(g, True)
-    return _call(
-        _dkv_kernel, "flash_mha_sparse_dkv", (n, h_kv, t // BLOCK_K, t // BLOCK_Q),
-        [queries(d), keys(d), keys(dv), tile, queries(dv), rows, rows],
-        [keys(d), keys(dv)],
-        [jax.ShapeDtypeStruct(k.shape, k.dtype), jax.ShapeDtypeStruct(v.shape, v.dtype)],
-        [pltpu.VMEM((BLOCK_K, d), jnp.float32), pltpu.VMEM((BLOCK_K, dv), jnp.float32)],
-        interpret,
+        [queries(d), whole(d), whole(dv)],
+        [jax.ShapeDtypeStruct(q.shape, q.dtype), jax.ShapeDtypeStruct(k.shape, k.dtype),
+         jax.ShapeDtypeStruct(v.shape, v.dtype)],
+        [pltpu.VMEM((g, BLOCK_Q, d), jnp.float32), pltpu.VMEM((t, d), jnp.float32),
+         pltpu.VMEM((t, dv), jnp.float32)], interpret, "arbitrary",
     )(q, k, v, mask, do, lse, delta)
 
 
@@ -713,10 +734,17 @@ def sparse_attention(q, k, v, mask, interpret: bool = False):
     """Attention of ``q`` (B, H, T, D) WITH the score scale in it against
     compact ``k`` (B, H_kv, T, D) and ``v`` (B, H_kv, T, Dv) under ``mask``,
     int8 (B, T, T): query t sees key s iff ``mask[b, t, s] != 0`` and ``s <=
-    t`` (a tile above the diagonal is not read, the one that straddles it is
-    cut by it), and every query sees a key.
+    t`` (a tile above the diagonal is not read, the others are cut by the
+    rule beside the mask), and every query sees a key (the forward leans on it:
+    :func:`_weights`; so does the backward, whose probabilities are taken
+    against a finite log-sum-exp).
     Returns ``(o (B, H, T, Dv), lse float32 (B, H_kv, G, T))``; the gradient
-    is the output's alone (``lse`` is a statistic here, not a path)."""
+    is the output's alone (``lse`` is a statistic here, not a path). Two
+    kernels: ``flash_mha_sparse_fwd`` (two products a visited tile) and ONE
+    backward, ``flash_mha_sparse_bwd`` (five: the tile's scores, weights and
+    ``ds`` made once for ``dq``, ``dk`` and ``dv``), which keeps a K/V head's
+    float32 ``dk`` / ``dv`` of the whole sequence resident in VMEM, the bound
+    on T that :func:`takes_sparse` states."""
     return _forward(q, k, v, mask, interpret)
 
 
@@ -729,9 +757,7 @@ def _sparse_bwd(interpret, residuals, cotangents):
     q, k, v, mask, o, lse = residuals
     do = cotangents[0]
     delta = (o.astype(jnp.float32) * do.astype(jnp.float32)).sum(axis=-1).reshape(lse.shape)
-    dq = _dq(q, k, v, mask, do, lse, delta, interpret)
-    dk, dv = _dkv(q, k, v, mask, do, lse, delta, interpret)
-    return dq, dk, dv, None
+    return (*_backward(q, k, v, mask, do, lse, delta, interpret), None)
 
 
 sparse_attention.defvjp(_sparse_fwd, _sparse_bwd)

@@ -434,6 +434,48 @@ def _index_scores_layer(topo, monkeypatch):
     return compiled, 3 + 4 + 4
 
 
+def _masked_attention_layer(topo, monkeypatch):
+    """One layer's attention under a learned mask at the Keye cell's shape
+    (32 query heads on 4 K/V heads of 128, T 8,192, bf16), forward and
+    backward: one ``flash_mha_sparse_fwd`` and ONE ``flash_mha_sparse_bwd``,
+    which keeps a K/V head's float32 ``dk`` and ``dv`` of the whole sequence
+    in VMEM (two (8192, 128) accumulators under the kernels' 100 MB), and no
+    (heads, T, T) array anywhere in the program."""
+    from akka_allreduce_tpu.ops import sparse_attention as sa
+    from akka_allreduce_tpu.ops.local_attention import heads_first_attention
+
+    t, h, h_kv, d = 8192, 32, 4, 128
+    chip = SingleDeviceSharding(topo.devices[0])
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=chip)  # noqa: E731
+
+    def loss(q, k, v, mask):
+        out, lse = heads_first_attention(q, k, v, causal=True, mask=mask)
+        return out.astype(jnp.float32).sum() + jax.lax.stop_gradient(lse).sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        sds((1, h, t, d), jnp.bfloat16), sds((1, h_kv, t, d), jnp.bfloat16),
+        sds((1, h_kv, t, d), jnp.bfloat16), sds((1, t, t), jnp.int8),
+    ).compile()
+    text = compiled.as_text()
+    calls = [line.split(" = ", 1)[0] for line in text.splitlines() if "tpu_custom_call" in line]
+    assert sum("flash_mha_sparse_fwd" in c for c in calls) == 1, calls
+    assert sum("flash_mha_sparse_bwd" in c for c in calls) == 1, calls
+    assert "flash_mha_sparse_dq" not in text and "flash_mha_sparse_dkv" not in text
+    assert not re.search(rf"\[(?:1,)?(?:{h}|{h_kv}|{h_kv},{h // h_kv}),{t},{t}\]", text)
+    assert [g.shape for g in compiled.out_info] == [(1, h, t, d), (1, h_kv, t, d), (1, h_kv, t, d)]
+    # the longest sequence the shapes' rule takes at this head, in float32:
+    # the backward with its accumulators of twice the length still compiles
+    long = 2 * t
+    assert sa.takes_sparse(long, d, d) and not sa.takes_sparse(2 * long, d, d)
+    rows = sds((1, h_kv, h // h_kv, long), jnp.float32)
+    jax.jit(lambda *a: sa._backward(*a, False)).lower(
+        sds((1, h, long, d), jnp.float32), sds((1, h_kv, long, d), jnp.float32),
+        sds((1, h_kv, long, d), jnp.float32), sds((1, long, long), jnp.int8),
+        sds((1, h, long, d), jnp.float32), rows, rows,
+    ).compile()
+    return compiled, 2
+
+
 CASES = {
     "reduce_kernels_8x8M_f32": _reduce_kernels,
     "pallas_ring_4dev_64M_f32": _pallas_ring(None),
@@ -446,6 +488,7 @@ CASES = {
     "joyai_mla_moe_cell_step": _joyai_mla_moe_step,
     "laguna_moe_cell_step": _laguna_moe_step,
     "index_scores_kernels_t8192_j16_d64": _index_scores_layer,
+    "masked_attention_kernels_t8192_h32_kv4": _masked_attention_layer,
     # the benchmark's own attention shapes, K/V compact into the kernel
     "splash_attention_b2_t4096_h24_kv2_d128": _kernel_attention(2, 4096, 24, 2, 128),
     "splash_attention_b1_t8192_h32_kv8_d64": _kernel_attention(1, 8192, 32, 8, 64),

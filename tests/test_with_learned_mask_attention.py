@@ -10,6 +10,7 @@ CPU, on seeded weights."""
 from __future__ import annotations
 
 import copy
+import functools
 import json
 import os
 import sys
@@ -149,28 +150,31 @@ def _dense_under_mask(q, k, v, mask):
     return out, jax.nn.logsumexp(scores, axis=-1)
 
 
-@pytest.mark.parametrize("what", ["out_and_lse", "dq", "dk", "dv"])
-@pytest.mark.parametrize("heads,kv_heads", [(4, 2), (8, 1)])
-@pytest.mark.parametrize("mask_is", ["causal", "past_the_diagonal"])
-def test_masked_kernels_interpreted_match_the_portable_core(
-    what, heads, kv_heads, mask_is, monkeypatch
-):
-    """``flash_mha_sparse_fwd`` / ``_dq`` / ``_dkv`` in interpret mode against
-    ``blockwise_attention`` with the mask and against the softmax written
-    out. A mask with ones past the diagonal is cut by the causal rule, in the
-    kernels' straddling tiles as in the portable core."""
-    from akka_allreduce_tpu.ops.local_attention import blockwise_attention
-
+def _small_tiles(monkeypatch):
     monkeypatch.setattr(sa, "BLOCK_Q", 64)
     monkeypatch.setattr(sa, "BLOCK_K", 128)
-    b, t, d = 2, 256, 32
+    monkeypatch.setattr(sa, "SOFTMAX_ROWS", 32)
+
+
+@functools.lru_cache(maxsize=None)
+def _kernels_and_the_core(heads, kv_heads, mask_is, b, t, topk, with_core=True):
+    """The output with the rows' log-sum-exp and the three operands'
+    gradients, ``{what: (the kernels', the portable core's, the softmax's
+    written out)}``, made once a shape for the cases that each hold one of
+    them: the masked kernels interpreted at (64, 128) tiles, the forward's
+    softmax 32 rows of a head at a time; ``blockwise_attention`` with the
+    mask (where asked for, else the written-out side again)."""
+    from akka_allreduce_tpu.ops.local_attention import blockwise_attention
+
+    d = 32
     q, k, v = _qkv(b, t, heads, kv_heads, d)
-    mask = within = _random_mask(b, t, 48)
+    mask = within = _random_mask(b, t, topk)
     if mask_is == "past_the_diagonal":
         stray = jax.random.bernoulli(jax.random.PRNGKey(6), 0.3, mask.shape)
         mask = mask | jnp.triu(stray, 1).astype(jnp.int8)
         assert int(mask.sum()) > int(within.sum())
     swap = lambda x: x.transpose(0, 2, 1, 3)  # noqa: E731
+    weight = jax.random.normal(jax.random.PRNGKey(9), q.shape)
 
     def portable(q, k, v):
         out, lse = blockwise_attention(
@@ -182,19 +186,157 @@ def test_masked_kernels_interpreted_match_the_portable_core(
         out, lse = sa.sparse_attention(q, k, v, mask, True)
         return out, lse.reshape(b, heads, t)
 
+    def all_of(f):
+        def weighted(*operands):
+            out = f(*operands)
+            return (out[0] * weight).sum(), out
+
+        (_, out), grads = jax.value_and_grad(weighted, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+        return dict(zip(("out_and_lse", "dq", "dk", "dv"), (out, *grads)))
+
+    with pytest.MonkeyPatch.context() as patch:
+        _small_tiles(patch)
+        want = all_of(lambda *a: _dense_under_mask(*a, within))
+        sides = [all_of(kernels), all_of(portable) if with_core else want, want]
+    return {what: tuple(side[what] for side in sides) for what in sides[0]}
+
+
+def _kernels_against_the_core(what, *shape):
+    got, portable, want = _kernels_and_the_core(*shape)[what]
     if what == "out_and_lse":
-        want = _dense_under_mask(q, k, v, within)
-        for got in (portable(q, k, v), kernels(q, k, v)):
-            _close(got[0], want[0])
-            _close(got[1], want[1])
+        for side in (got, portable):
+            _close(side[0], want[0])
+            _close(side[1], want[1])
         return
-    weight = jax.random.normal(jax.random.PRNGKey(9), q.shape)
-    arg = "qkv".index(what[1])
-    grad = lambda f: jax.grad(  # noqa: E731
-        lambda *a: (f(*a)[0] * weight).sum(), argnums=arg)(q, k, v)
-    want = jax.grad(lambda *a: (_dense_under_mask(*a, within)[0] * weight).sum(), argnums=arg)(q, k, v)
-    _close(grad(kernels), want, 1e-4)
-    _close(grad(portable), want, 1e-4)
+    _close(got, want, 1e-4)
+    _close(portable, want, 1e-4)
+
+
+MASK_IS = pytest.mark.parametrize("mask_is", ["causal", "past_the_diagonal"])
+# one K/V head under all the heads, and two: the backward's resident pair is
+# zeroed at a head's first step and written out at its last
+HEADS = pytest.mark.parametrize("heads,kv_heads", [(4, 2), (8, 1), (8, 2)])
+
+
+@pytest.mark.parametrize("what", ["out_and_lse", "dq", "dk", "dv"])
+@HEADS
+@MASK_IS
+def test_masked_kernels_interpreted_match_the_portable_core(what, heads, kv_heads, mask_is):
+    """``flash_mha_sparse_fwd`` / ``_bwd`` in interpret mode against
+    ``blockwise_attention`` with the mask and against the softmax written
+    out. A mask with ones past the diagonal is cut by the causal rule, in the
+    kernels' straddling tiles as in the portable core."""
+    _kernels_against_the_core(what, heads, kv_heads, mask_is, 2, 256, 48)
+
+
+@pytest.mark.parametrize("what", ["dq", "dk", "dv"])
+@HEADS
+@MASK_IS
+def test_the_one_backward_kernel_sums_both_ways_across_tiles(
+    what, heads, kv_heads, mask_is, monkeypatch
+):
+    """T of four key tiles and eight query tiles: ``flash_mha_sparse_bwd``
+    sums a query tile's ``dq`` over up to four key tiles, and in its resident
+    pair of a K/V head a key tile's ``dk`` and ``dv`` over up to eight query
+    tiles (the first key tile takes every query tile's, the last the last
+    two's), the group's heads into the one head they share."""
+    _small_tiles(monkeypatch)
+    assert sa._last_key_tile(7) == 3 and sa._last_key_tile(1) == 0  # the eighth row of tiles has four
+    _kernels_against_the_core(what, heads, kv_heads, mask_is, 1, 512, 96, False)
+
+
+@pytest.mark.parametrize("keys,heads,kv_heads", [
+    ("a_band_behind_the_query", 4, 2), ("a_band_behind_the_query", 8, 1),
+    ("a_band_behind_the_query", 8, 2), ("the_queries_own_tile_alone", 8, 2),
+])
+def test_a_rows_first_seen_key_wipes_what_its_unseen_tiles_left(keys, heads, kv_heads, monkeypatch):
+    """The forward takes ``exp(s - m)`` without a select beside it
+    (``_weights``). Rows whose kept keys all lie past their first key tile
+    run that tile, and every one before the first with a kept key, at the mask
+    value as their maximum: weights of 1 on unseen keys, sums of garbage in
+    ``l`` and ``acc``. The first kept key's ``fade = exp(mask value - m)`` is
+    exactly 0, so the output and the log-sum-exp are BIT-equal to the kernel
+    with the select kept, and close to the softmax written out."""
+    _small_tiles(monkeypatch)
+    b, t, d = 1, 512, 32
+    q, k, v = _qkv(b, t, heads, kv_heads, d, seed=3)
+    rows, cols = jnp.arange(t)[:, None], jnp.arange(t)[None, :]
+    if keys == "a_band_behind_the_query":  # 40 keys: past row 168 none in key tile 0
+        mask = (cols <= rows) & (cols > rows - 40)
+    else:  # only keys of the tile astride the diagonal, the row's last
+        mask = (cols <= rows) & (cols >= rows // 128 * 128) & ((rows - cols) % 3 == 0)
+    mask = mask[None].astype(jnp.int8)
+    first_kept = jnp.argmax(mask[0] != 0, axis=1)
+    assert int(jnp.sum(first_kept >= 128)) >= t - 168  # rows with no kept key in their first tile
+    assert int(jnp.sum(first_kept >= 384)) >= 80  # rows with three such tiles before their first key
+    got = sa.sparse_attention(q, k, v, mask, True)
+    with monkeypatch.context() as m:
+        m.setattr(sa, "_weights", lambda s, at: jnp.where(
+            s > sa._MASK_VALUE, jnp.exp(s - at), 0.0))  # the select kept
+        kept = sa.sparse_attention(q, k, v, mask, True)
+    np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(kept[0]))
+    np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(kept[1]))
+    want = _dense_under_mask(q, k, v, mask)
+    _close(got[0], want[0])
+    _close(got[1].reshape(b, heads, t), want[1])
+
+
+def test_a_small_step_lowers_to_the_two_kernels():
+    """Forward and backward of a layer's attention under a mask, lowered for
+    the chip: one ``flash_mha_sparse_fwd``, ONE ``flash_mha_sparse_bwd``, and
+    neither of the two kernels it took the place of."""
+    import re
+
+    t, h, h_kv, d = 2 * sa.BLOCK_K, 8, 2, 128
+    assert sa.takes_sparse(t, d, d)
+
+    def loss(q, k, v, mask):
+        out, lse = sa.sparse_attention(q, k, v, mask, False)
+        return out.astype(jnp.float32).sum() + lax.stop_gradient(lse).sum()
+
+    sds = jax.ShapeDtypeStruct
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).trace(
+        sds((1, h, t, d), jnp.bfloat16), sds((1, h_kv, t, d), jnp.bfloat16),
+        sds((1, h_kv, t, d), jnp.bfloat16), sds((1, t, t), jnp.int8),
+    ).lower(lowering_platforms=("tpu",)).as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert sorted(set(re.findall(r"flash_mha_sparse_\w+", text))) == [
+        "flash_mha_sparse_bwd", "flash_mha_sparse_fwd"]
+    assert "_dq" not in text and "_dkv" not in text
+
+
+def test_a_sequence_whose_sums_do_not_fit_goes_to_the_portable_core(monkeypatch):
+    """The backward keeps a K/V head's float32 ``dk`` and ``dv`` of the whole
+    sequence in VMEM: ``takes_sparse`` refuses a T past what is left beside
+    the tiles, and ``heads_first_attention(mask=)`` then runs the portable
+    core, on the chip too (the platform question answered yes here)."""
+    from akka_allreduce_tpu.ops import _platform
+    from akka_allreduce_tpu.ops.local_attention import heads_first_attention
+
+    room = sa._VMEM_LIMIT - sa._TILE_ROOM
+    assert sa.takes_sparse(16384, 128, 128) and 16384 * 256 * 12 <= room
+    assert not sa.takes_sparse(32768, 128, 128) and 32768 * 256 * 12 > room
+    assert sa.takes_sparse(32768, 64, 64)  # the bound is on T x (D + Dv)
+
+    def no_kernels(*a, **kw):
+        raise AssertionError("the kernels were asked for a shape they do not take")
+
+    monkeypatch.setattr(_platform, "interpret_default", lambda *arrays: False)
+    monkeypatch.setattr(sa, "sparse_attention", no_kernels)
+    _small_tiles(monkeypatch)
+    monkeypatch.setattr(sa, "_TILE_ROOM", sa._VMEM_LIMIT - 128 * 32 * 12)
+    t = 256
+    assert sa.takes_sparse(128, 16, 16) and not sa.takes_sparse(t, 16, 16)
+    q, k, v = _qkv(1, t, 4, 2, 16)
+    mask = _random_mask(1, t, 24)
+    grad = lambda f: jax.grad(lambda *a: (f(*a)[0] ** 2).sum(), argnums=(0, 1, 2))(q, k, v)  # noqa: E731
+    out, lse = heads_first_attention(q, k, v, causal=True, mask=mask)
+    want = _dense_under_mask(q, k, v, mask)
+    _close(out, want[0])
+    _close(lse.reshape(1, 4, t), want[1])
+    for got, ref_ in zip(grad(lambda *a: heads_first_attention(*a, causal=True, mask=mask)),
+                         grad(lambda *a: _dense_under_mask(*a, mask))):
+        _close(got, ref_, 1e-4)
 
 
 @pytest.mark.parametrize("t", [64, 640])
@@ -461,8 +603,8 @@ def test_readers_of_the_new_metrics_on_a_made_up_record():
         "fusion.1": pre + "attn_indexer/indexer_proj/dot", "fusion.2": pre + "attn_indexer/indexer_scores/dot",
         "fusion.3": pre + "attn_indexer/while/body/indexer_select/reduce",
         "fusion.4": pre + "attn_indexer/indexer_target/exp", "fusion.5": pre + "attn_core/mul",
-        "flash_mha_sparse_fwd.1": pre + "attn_core/pallas", "flash_mha_sparse_dq.1": pre + "attn_core/pallas",
-        "flash_mha_sparse_dkv.1": pre + "attn_core/pallas", "while.1": pre + "attn_indexer/while",
+        "flash_mha_sparse_fwd.1": pre + "attn_core/pallas", "flash_mha_sparse_bwd.1": pre + "attn_core/pallas",
+        "while.1": pre + "attn_indexer/while",
     }
     ops = {n: (3, 0.003 * (i + 1), "while" if n.startswith("while") else "fusion")
            for i, n in enumerate(scopes)}
@@ -482,7 +624,7 @@ def test_readers_of_the_new_metrics_on_a_made_up_record():
     assert read("indexer_ms") == pytest.approx(1e3 * (0.003 + 0.006 + 0.009 + 0.012) / 3)
     assert read("indexer_select_ms") == pytest.approx(3.0)
     assert read("indexer_target_ms") == pytest.approx(4.0)
-    kernel_ms = 1e3 * (0.018 + 0.021 + 0.024) / 3
+    kernel_ms = 1e3 * (0.018 + 0.021) / 3
     assert read("attn_kernel_ms") == pytest.approx(kernel_ms)
     assert read("gqa_around_kernel_ms") == pytest.approx(5.0)
     assert read("attn_kernel_roofline_pct.keye") == pytest.approx(
