@@ -8,7 +8,7 @@ PYTHON ?= python3
 # arlint scan surface: the package, the entry shims at the repo root, and the
 # tests' subprocess worker helpers (async/thread code runs there too). Narrow
 # it per-path with the [tool.arlint] exclude list, never by trimming this.
-LINT_PATHS = akka_allreduce_tpu/ bench.py chip_smoke.py $(wildcard tests/*_worker.py)
+LINT_PATHS = akka_allreduce_tpu/ chip_smoke.py $(wildcard tests/*_worker.py)
 
 # arlint: async-safety / buffer-aliasing / wire-contract / thread-race /
 # determinism analyzer (ANALYSIS.md). Exit 1 on any unsuppressed finding —

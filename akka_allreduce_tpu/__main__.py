@@ -4,7 +4,6 @@
     python -m akka_allreduce_tpu local-demo   --nodes 4 --size 1000000
     python -m akka_allreduce_tpu cluster-master --port 7070 --nodes 2 --rounds 20
     python -m akka_allreduce_tpu cluster-node --seed 127.0.0.1:7070
-    python -m akka_allreduce_tpu bench        --floats 67108864 --schedule psum
     python -m akka_allreduce_tpu train-mlp    --steps 100 --batch 64
     python -m akka_allreduce_tpu train-resnet --steps 5 --bucket 262144
     python -m akka_allreduce_tpu train-lm     --steps 30 --seq-len 256 --impl ring
@@ -308,39 +307,6 @@ def _cmd_local_demo(argv: list[str]) -> int:
     return 0
 
 
-def _cmd_bench(argv: list[str]) -> int:
-    p = argparse.ArgumentParser("bench", description="threshold-allreduce bandwidth")
-    p.add_argument("--floats", type=int, default=64 * 1024 * 1024)
-    p.add_argument("--iters", type=int, default=10)
-    p.add_argument("--schedule", choices=("psum", "butterfly", "ring"), default="psum")
-    p.add_argument("--bucket", type=int, default=None)
-    p.add_argument(
-        "--compress",
-        choices=("bf16", "int8"),
-        default=None,
-        help="wire compression: bf16 halves collective bytes "
-        "(psum/butterfly/ring), int8 quarters them (ring only)",
-    )
-    _add_mesh_flags(p)
-    args = p.parse_args(argv)
-
-    import json
-
-    from akka_allreduce_tpu.comm.bandwidth import measure_allreduce
-
-    mesh = _make_mesh(args)
-    r = measure_allreduce(
-        mesh,
-        args.floats,
-        iters=args.iters,
-        schedule=args.schedule,
-        bucket_size=args.bucket,
-        compress=args.compress,
-    )
-    print(json.dumps(r.to_dict()))
-    return 0
-
-
 def _basic_train_flags(p: argparse.ArgumentParser) -> None:
     """The shared core every DP training CLI carries — train-zero1 uses
     exactly this subset, so its defaults can never drift from train-mlp's
@@ -446,7 +412,7 @@ def _mfu_fields(flops_per_step, sec_per_step, n_devices: int = 1) -> dict:
     """tflops/mfu JSONL+print fields (empty off-TPU or without a FLOP model).
 
     MFU convention: GLOBAL model FLOPs (no remat recompute) over the mesh's
-    aggregate dense bf16 peak — utils/benchmarking.py docstring
+    aggregate dense bf16 peak — utils/benchmarking.py's conventions
     (VERDICT r2 #1).
     """
     from akka_allreduce_tpu.utils.benchmarking import device_peak_flops, mfu
@@ -531,8 +497,7 @@ def _run_training_chain(trainer, ds, args, *, label: str, flops_per_step=None) -
             contributors=m.contributors,
         )
     losses = [m.loss for m in history]
-    # amortized time still includes compile, so this MFU is a LOWER bound;
-    # bench-mfu is the slope-timed (compile-excluded) measurement
+    # amortized time still includes compile, so this MFU is a LOWER bound
     perf = _mfu_fields(
         flops_per_step, total / max(len(losses), 1), trainer.n_devices
     )
@@ -605,7 +570,7 @@ def _run_training(trainer, ds, args, *, label: str, flops_per_step=None) -> int:
         ckpt.save(trainer, force=True, block=True)
         ckpt.close()
     # host-loop step time includes per-step host<->device I/O, so this MFU
-    # is a floor; bench-mfu / --device-data measure the on-device figure
+    # is a floor; --device-data measures the on-device figure
     perf = _mfu_fields(
         flops_per_step, total / max(len(losses), 1), trainer.n_devices
     )
@@ -629,22 +594,6 @@ def _run_training(trainer, ds, args, *, label: str, flops_per_step=None) -> int:
         f"{total:.2f}s ({total / max(len(losses), 1) * 1e3:.1f} ms/step)"
         f"{_mfu_note(perf)}; {trend}"
     )
-    return 0
-
-
-def _cmd_bench_suite(argv: list[str]) -> int:
-    p = argparse.ArgumentParser(
-        "bench-suite",
-        description="run the full BASELINE config matrix (configs 1-5), one "
-        "JSON record each (BASELINE.md)",
-    )
-    p.add_argument("--out", default=None, help="append records to this JSONL")
-    p.add_argument("--quick", action="store_true", help="1/8-size payloads")
-    args = p.parse_args(argv)
-
-    from akka_allreduce_tpu.bench_suite import run_suite
-
-    run_suite(quick=args.quick, out=args.out)
     return 0
 
 
@@ -839,12 +788,6 @@ def _cmd_train_fsdp(argv: list[str]) -> int:
     )
 
 
-def _cmd_bench_mfu(argv: list[str]) -> int:
-    from akka_allreduce_tpu.bench_mfu import main as mfu_main
-
-    return mfu_main(argv)
-
-
 def _cmd_train_mlp(argv: list[str]) -> int:
     p = argparse.ArgumentParser("train-mlp", description="MLP/MNIST DP-SGD (config 3)")
     _train_flags(p)
@@ -921,8 +864,7 @@ def _cmd_train_resnet(argv: list[str]) -> int:
         (args.image_size, args.image_size, 3), args.classes, seed=0
     )
     # conv FLOPs from the analytic architecture mirror (the 6N rule
-    # undercounts convs), x3 for fwd + bwd — the SAME convention bench-mfu
-    # uses, so the two tools always agree on ResNet MFU
+    # undercounts convs), x3 for fwd + bwd
     from akka_allreduce_tpu.models.resnet import resnet_fwd_flops
 
     fwd = resnet_fwd_flops(trainer.model, args.image_size, args.batch)
@@ -2410,7 +2352,7 @@ def _cmd_lm_generate(argv: list[str]) -> int:
     prompt = jnp.asarray(x[:, : args.prompt_len])
 
     # decode throughput: slope between a short and the full generation so
-    # prefill + dispatch overhead cancels (bench.py's discipline)
+    # prefill + dispatch overhead cancels
     import statistics
 
     lo = max(1, args.gen // 4)
@@ -2454,181 +2396,6 @@ def _cmd_lm_generate(argv: list[str]) -> int:
         f"{args.kv_heads or args.heads},{args.d_model // args.heads})"
         f"{qnote})"
     )
-    return 0
-
-
-def _cmd_bench_checkpoint(argv: list[str]) -> int:
-    """Measure checkpoint stall: sync save wall time (the step loop is
-    frozen for all of it) vs async save (steps keep ticking while the
-    on-device copy drains to host and Orbax writes off-thread)."""
-    p = argparse.ArgumentParser(
-        "bench-checkpoint",
-        description="step-loop stall of sync vs async checkpointing on a "
-        "transformer LM (VERDICT r3 #2: checkpoint cost is part of the "
-        "recovery story)",
-    )
-    p.add_argument("--d-model", type=int, default=2048)
-    p.add_argument("--layers", type=int, default=8)
-    p.add_argument("--heads", type=int, default=None, help="default d/128")
-    p.add_argument("--seq-len", type=int, default=2048)
-    p.add_argument("--batch", type=int, default=2)
-    p.add_argument("--vocab", type=int, default=256)
-    p.add_argument("--bf16", action="store_true")
-    p.add_argument(
-        "--trainer", choices=("lm", "fsdp", "zero1", "pipeline"),
-        default="lm",
-        help="trainer family under test: the sharded-state families "
-        "(fsdp/zero1/pipeline) exercise the shard-local async capture "
-        "path (VERDICT r4 #1)",
-    )
-    p.add_argument(
-        "--store", choices=("orbax", "delta"), default="orbax",
-        help="delta: content-addressed per-leaf store (async hashing)",
-    )
-    p.add_argument(
-        "--remat", choices=("full", "params"), default=None,
-        help="fsdp only: rematerialization mode (the flagship size OOMs "
-        "one chip without it — same flag as bench-mfu)",
-    )
-    p.add_argument("--baseline-steps", type=int, default=5)
-    p.add_argument("--max-steps-during", type=int, default=200)
-    p.add_argument("--dir", default=None, help="default: a temp dir")
-    p.add_argument("--skip-sync", action="store_true",
-                   help="skip the (slow) synchronous-save comparison")
-    args = p.parse_args(argv)
-
-    import json
-    import statistics
-    import tempfile
-
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from akka_allreduce_tpu.models import data
-    from akka_allreduce_tpu.parallel import data_seq_mesh, line_mesh
-    from akka_allreduce_tpu.train import (
-        AsyncDeltaCheckpointer,
-        AsyncTrainerCheckpointer,
-        DeltaCheckpointer,
-        FSDPLMTrainer,
-        LongContextTrainer,
-        PipelineLMTrainer,
-        TrainerCheckpointer,
-        Zero1DPTrainer,
-    )
-
-    heads = args.heads or max(1, args.d_model // 128)
-    lm_kw = dict(
-        vocab=args.vocab,
-        d_model=args.d_model,
-        n_heads=heads,
-        n_layers=args.layers,
-        seq_len=args.seq_len,
-        compute_dtype=jnp.bfloat16 if args.bf16 else jnp.float32,
-    )
-    n_dev = len(jax.devices())
-    if args.trainer == "lm":
-        trainer = LongContextTrainer(
-            data_seq_mesh(1, 1), learning_rate=1e-3, **lm_kw
-        )
-    elif args.trainer == "fsdp":
-        trainer = FSDPLMTrainer(
-            line_mesh(n_dev), remat=args.remat or False, **lm_kw
-        )
-    elif args.trainer == "pipeline":
-        pp = n_dev  # all devices as stages (1 on the real chip)
-        pp_kw = dict(lm_kw)
-        pp_kw.pop("n_layers")
-        trainer = PipelineLMTrainer(
-            jax.make_mesh((1, pp), ("data", "pipe")),
-            layers_per_stage=-(-args.layers // pp),
-            microbatches=2,
-            learning_rate=1e-3,
-            **pp_kw,
-        )
-    else:  # zero1: MLP classification family, width scaled by --d-model
-        import optax
-
-        from akka_allreduce_tpu.models import MLP
-
-        trainer = Zero1DPTrainer(
-            MLP(hidden=(args.d_model,) * args.layers, classes=10),
-            line_mesh(n_dev),
-            example_input=np.zeros((1, 28, 28, 1), np.float32),
-            optimizer=optax.adam(1e-3),
-        )
-    state_gb = trainer.param_count * 4 * 3 / 1e9  # f32 params + adam mu/nu
-    # round the batch up to what the family's data placement divides by
-    # (fsdp/zero1 spread rows over all devices; pipeline needs microbatches)
-    div = {"fsdp": n_dev, "zero1": n_dev, "pipeline": 2}.get(args.trainer, 1)
-    batch = -(-args.batch // div) * div
-    if args.trainer == "zero1":
-        ds = data.mnist_like()
-        batches = ds.batches(batch, 10_000)
-    else:
-        ds = data.lm_copy_task(args.seq_len, vocab=args.vocab)
-        batches = ds.batches(batch, 10_000)
-
-    def step():
-        t0 = time.perf_counter()
-        trainer.train_step(*next(batches))  # loss float = device sync
-        return time.perf_counter() - t0
-
-    step()  # compile
-    base = [step() for _ in range(args.baseline_steps)]
-    base_ms = statistics.median(base) * 1e3
-
-    # always a FRESH subdir: re-running against an existing directory would
-    # hit the step-dedup early return and measure no save at all
-    d = tempfile.mkdtemp(prefix="ckpt_bench_", dir=args.dir)
-    sync_cls, async_cls = (
-        (DeltaCheckpointer, AsyncDeltaCheckpointer)
-        if args.store == "delta"
-        else (TrainerCheckpointer, AsyncTrainerCheckpointer)
-    )
-    sync_s = None
-    if not args.skip_sync:
-        with sync_cls(f"{d}/sync") as ck:
-            t0 = time.perf_counter()
-            ck.save(trainer)
-            sync_s = time.perf_counter() - t0
-
-    delta_stats = None
-    with async_cls(f"{d}/async") as ck:
-        t0 = time.perf_counter()
-        ck.save(trainer)
-        capture_s = time.perf_counter() - t0  # the only stall the loop sees
-        during = []
-        while ck.busy() and len(during) < args.max_steps_during:
-            during.append(step())
-        stepped_s = time.perf_counter() - t0
-        ck.wait_until_finished()
-        # true background-save duration — past the step cap the loop just
-        # waits, so this can exceed stepped_s
-        save_wall_s = time.perf_counter() - t0
-        saved_step = ck.latest_step()
-        delta_stats = getattr(ck, "last_stats", None)
-    during_ms = statistics.median(during) * 1e3 if during else None
-    rec = {
-        "metric": "checkpoint_stall",
-        "trainer": args.trainer,
-        "store": args.store,
-        "delta_stats": delta_stats,
-        "params_m": round(trainer.param_count / 1e6, 1),
-        "state_gb": round(state_gb, 2),
-        "baseline_ms_per_step": round(base_ms, 1),
-        "async_capture_stall_s": round(capture_s, 3),
-        "async_save_wall_s": round(save_wall_s, 1),
-        "steps_during_async_save": len(during),
-        "ms_per_step_during_save": (
-            round(during_ms, 1) if during_ms is not None else None
-        ),
-        "sync_save_stall_s": round(sync_s, 1) if sync_s is not None else None,
-        "saved_step": saved_step,
-        "platform": jax.devices()[0].platform,
-    }
-    print(json.dumps(rec))
     return 0
 
 
@@ -5664,10 +5431,6 @@ COMMANDS = {
     "cluster-standby": _cmd_cluster_standby,
     "train-cluster-master": _cmd_train_cluster_master,
     "train-cluster-node": _cmd_train_cluster_node,
-    "bench": _cmd_bench,
-    "bench-suite": _cmd_bench_suite,
-    "bench-mfu": _cmd_bench_mfu,
-    "bench-checkpoint": _cmd_bench_checkpoint,
     "soak": _cmd_soak,
     "train-mlp": _cmd_train_mlp,
     "train-resnet": _cmd_train_resnet,
@@ -5706,13 +5469,12 @@ def main(argv: list[str] | None = None) -> int:
 
 # Commands whose PROCESS keeps compiled programs between runs (and across
 # an elastic re-mesh back to a mesh size already compiled): the training
-# and bench CLIs. The cluster roles and the drills' children get no
+# CLIs. The cluster roles and the drills' children get no
 # persistent cache — ROADMAP Design 1 ties a hang/abort to it.
 _COMPILE_CACHED = frozenset(
     {
         "train-mlp", "train-resnet", "train-zero1", "train-fsdp",
         "train-lm", "train-moe", "train-pp", "elastic-demo", "lm-generate",
-        "bench", "bench-mfu", "bench-suite",
     }
 )
 

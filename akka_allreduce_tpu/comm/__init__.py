@@ -15,8 +15,3 @@ from akka_allreduce_tpu.comm.allreduce import (  # noqa: F401
     masked_psum,
     threshold_allreduce,
 )
-from akka_allreduce_tpu.comm.bandwidth import (  # noqa: F401
-    BandwidthReport,
-    bus_bandwidth_gbps,
-    measure_allreduce,
-)

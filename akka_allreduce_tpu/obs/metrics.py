@@ -15,7 +15,7 @@ loop and the worker's per-chunk handlers):
   on hot paths should hold the object.
 - **snapshot-to-dict**: ``Registry.snapshot()`` returns one flat
   JSON-ready dict, so any JSONL sink (``MetricsLogger.log_snapshot``, the
-  flight recorder, bench_suite records) gets the whole registry for free.
+  flight recorder) gets the whole registry for free.
 
 Naming convention (OBSERVABILITY.md): dotted ``<layer>.<noun>[.<detail>]``
 — e.g. ``transport.dropped.no_route``, ``worker.rounds_completed``,

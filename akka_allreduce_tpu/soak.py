@@ -149,7 +149,7 @@ def run_soak(
         }
     else:
         # one real chip: a zero-device control node still exercises the
-        # full membership/re-mesh machinery (bench-suite config 5's shape)
+        # full membership/re-mesh machinery
         assignment = {0: list(devices), 1: []}
         nodes = 2
     lost = nodes - 1

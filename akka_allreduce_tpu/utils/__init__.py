@@ -1,9 +1,6 @@
 """Logging, metrics, and timing utilities."""
 
-from akka_allreduce_tpu.utils.metrics import (  # noqa: F401
-    MetricsLogger,
-    RoundMetrics,
-)
+from akka_allreduce_tpu.utils.metrics import MetricsLogger  # noqa: F401
 from akka_allreduce_tpu.utils.compile_cache import (  # noqa: F401
     enable_compile_cache,
 )

@@ -1,25 +1,10 @@
-"""FLOP accounting / MFU, and slope timing for the bench harnesses.
-
-The timing discipline shared by ``bench.py`` and ``bench_suite.py`` (kept
-until the benchmark issue replaces it, ROADMAP Design 4):
-
-- per-iteration time is the SLOPE between a short and a long traced trip
-  count, so the constant dispatch + fetch overhead cancels in the difference;
-- the trip-count spread is scaled so the on-device signal dominates jitter;
-- lo/hi samples are interleaved (congestion drifts on the seconds scale);
-- the reported value is the MEDIAN of per-pair slopes: jitter contaminates
-  both ends of each difference roughly symmetrically, so the median is a
-  consistent estimate, where best-of-N (round 1's estimator) kept the single
-  most optimistic outlier and swung ~30% run to run.
+"""FLOP accounting / MFU for the train CLIs' summary print and ``soak.py``:
+``PEAK_BF16_FLOPS``, ``device_peak_flops``, ``dense_train_flops``,
+``transformer_train_flops``, ``mfu``, ``moe_active_params``.
 """
 
 from __future__ import annotations
 
-from dataclasses import dataclass
-from typing import Callable
-
-# -- FLOP accounting / MFU ----------------------------------------------------
-#
 # VERDICT r2 #1: every workload reports model-FLOPs utilization, not just
 # ms/step. Conventions (PaLM appendix B / Chinchilla):
 #
@@ -135,8 +120,7 @@ def moe_active_params(
     runs ``topk`` of the ``n_experts`` expert MLPs, so the expert leaves
     (path contains ``moe_``) scale by topk/n_experts; everything else counts
     fully. Feed the result to :func:`transformer_train_flops` as
-    ``n_params`` (shared by train-moe and bench-mfu so the two tools can
-    never disagree on the accounting)."""
+    ``n_params``."""
     import jax
     import numpy as np
 
@@ -149,77 +133,3 @@ def moe_active_params(
         if any("moe_" in str(getattr(k, "key", "")) for k in path)
     )
     return total - expert + expert * topk / n_experts
-
-
-@dataclass(frozen=True)
-class SlopeEstimate:
-    """Per-iteration seconds with a robustness diagnostic."""
-
-    seconds_per_iter: float  # median of per-pair slopes
-    spread_pct: float  # 100 * IQR / median over the slope samples
-    n_samples: int
-
-    def noisy(self, max_spread_pct: float = 15.0) -> bool:
-        return not (self.spread_pct <= max_spread_pct)
-
-
-def median_slope(
-    timed: Callable[[int], float],
-    trips_lo: int,
-    trips_hi: int,
-    *,
-    outer: int = 8,
-    warmup: bool = True,
-    target_signal_s: float | None = None,
-    max_trips: int = 100_000,
-) -> SlopeEstimate:
-    """Median per-iteration time from interleaved (lo, hi) timing pairs.
-
-    ``timed(trips)`` runs the workload ``trips`` iterations and returns
-    wall seconds including any constant dispatch overhead. The trip
-    count must be a *traced* argument of the underlying jit, so changing
-    ``trips_hi`` never recompiles.
-
-    ``target_signal_s`` rescales ``trips_hi`` from one rough warmup slope so
-    the on-device signal reaches that many seconds regardless of the actual
-    throughput — a static trip count tuned for HBM speed drowns in dispatch
-    jitter when the workload turns out to run VMEM-resident ~8x faster.
-    """
-    import numpy as np
-
-    if trips_hi <= trips_lo:
-        raise ValueError(f"need trips_hi > trips_lo, got {trips_lo}/{trips_hi}")
-    t_hi_rough = None
-    if warmup:
-        timed(trips_lo)  # pays the one compile (trip count is traced)
-        t_hi_rough = timed(trips_hi)  # post-compile: reused for the rescale
-    if target_signal_s is not None:
-        # Grow trips_hi until the (hi - lo) on-device signal is clearly
-        # positive and ~target_signal_s seconds. Each step multiplies
-        # trips_hi by at most 16, so one jitter-delayed rough sample can
-        # inflate the budget by one bounded notch, never to max_trips
-        # outright; a NON-positive rough slope means the signal is still
-        # drowned in jitter and must escalate, not give up.
-        for _ in range(4):
-            if t_hi_rough is None:
-                t_hi_rough = timed(trips_hi)
-            rough = (t_hi_rough - timed(trips_lo)) / (trips_hi - trips_lo)
-            t_hi_rough = None
-            if rough > 0:
-                want = trips_lo + int(target_signal_s / rough)
-                if want <= trips_hi or trips_hi >= max_trips:
-                    break
-                trips_hi = min(want, 16 * trips_hi, max_trips)
-            elif trips_hi >= max_trips:
-                break
-            else:
-                trips_hi = min(16 * trips_hi, max_trips)
-    slopes = []
-    for _ in range(outer):
-        t_lo = timed(trips_lo)
-        t_hi = timed(trips_hi)
-        slopes.append((t_hi - t_lo) / (trips_hi - trips_lo))
-    med = float(np.median(slopes))
-    q1, q3 = np.percentile(slopes, [25, 75])
-    spread = 100.0 * float(q3 - q1) / med if med > 0 else float("inf")
-    return SlopeEstimate(med, round(spread, 1), outer)

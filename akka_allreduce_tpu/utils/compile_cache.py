@@ -14,7 +14,7 @@ multi-second trainer steps, which clear them, and a cache-everything
 override is what ROADMAP Design 1's abort was tied to.
 
 Called by the PROCESS entry points only (``python -m akka_allreduce_tpu``
-for the training/bench commands, ``bench.py``, ``chip_smoke.py``) before
+for the training commands, ``chip_smoke.py``) before
 their first compile — never at package import and never by the tests'
 conftest: tier-1 runs without a persistent cache.
 """

@@ -1,43 +1,16 @@
-"""Structured per-round metrics (SURVEY.md §6 "Metrics / logging").
+"""The JSONL sink of structured records (SURVEY.md §6 "Metrics / logging").
 
-The reference logs periodic throughput lines from workers; here every round
-emits a structured record — round latency, achieved GB/s, contributor count —
-to JSONL. This stream IS the benchmark output for the BASELINE configs.
+The reference logs periodic throughput lines from workers; here soak, the
+train CLIs, the cluster roles and the drills write events and registry
+snapshots as one JSON object a line.
 """
 
 from __future__ import annotations
 
-import dataclasses
 import io
 import json
 import time
 from typing import Any, TextIO
-
-
-@dataclasses.dataclass
-class RoundMetrics:
-    round_num: int
-    latency_s: float
-    data_bytes: int
-    n_devices: int
-    contributors: float  # mean contributor count across chunks
-    schedule: str = "psum"
-    extra: dict[str, Any] = dataclasses.field(default_factory=dict)
-
-    @property
-    def bus_gbps(self) -> float:
-        """Bus bandwidth: 2*(n-1)/n * bytes / t (BASELINE.md measurement rules)."""
-        if self.latency_s <= 0 or self.n_devices <= 0:
-            return 0.0
-        scale = 2.0 * (self.n_devices - 1) / self.n_devices
-        return scale * self.data_bytes / self.latency_s / 1e9
-
-    def to_json(self) -> str:
-        d = dataclasses.asdict(self)
-        d.pop("extra")
-        d.update(self.extra)
-        d["bus_gbps"] = self.bus_gbps
-        return json.dumps(d)
 
 
 class MetricsLogger:
@@ -53,11 +26,6 @@ class MetricsLogger:
             self._own = True
         else:
             self._stream = sink
-        self.records: list[RoundMetrics] = []
-
-    def log_round(self, m: RoundMetrics) -> None:
-        self.records.append(m)
-        self._stream.write(m.to_json() + "\n")
 
     def log_event(self, **fields: Any) -> None:
         fields.setdefault("t", time.time())
@@ -65,9 +33,8 @@ class MetricsLogger:
 
     def log_snapshot(self, registry, **extra: Any) -> None:
         """One ``metrics_snapshot`` record carrying a whole
-        ``obs.metrics.Registry`` — how existing JSONL consumers
-        (bench_suite, soak, the training CLIs) get the registry stream
-        without learning a new sink."""
+        ``obs.metrics.Registry`` — how existing JSONL consumers (soak, the
+        training CLIs) get the registry stream without learning a new sink."""
         self.log_event(
             kind="metrics_snapshot", metrics=registry.snapshot(), **extra
         )

@@ -62,7 +62,7 @@ OUT_DIR = os.path.join(HERE, "chiprun_out", "chip_smoke")
 SEED = 0
 HBM_BYTES = 16e9  # one v5e chip
 
-# the flagship the repo claims (bench_mfu.py docstring): ~404M parameters
+# the flagship the repo claims: ~404M parameters
 FLAGSHIP = dict(
     d_model=2048, heads=16, layers=8, seq_len=2048, batch=8, vocab=256
 )
@@ -383,7 +383,7 @@ def phase_threshold_reduce(
         return elastic_average_step(x, v, alpha), avg, count
 
     @jax.jit
-    def reference(x, v):  # bench.py's BENCH_XLA body: unfused jax.numpy
+    def reference(x, v):  # unfused jax.numpy
         c = jnp.maximum(v.sum(), 1.0)
         avg = (x * v[:, None]).sum(0) / c
         return (1.0 - alpha) * x + alpha * avg[None], avg, v.sum()

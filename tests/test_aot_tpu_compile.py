@@ -149,7 +149,7 @@ def _grouped_psum(topo, monkeypatch):
     (d1024, 8 experts, 4 layers): when it staged them through one flat
     buffer, libtpu 0.0.34 laid that buffer out as f32[N/8, 8] after the
     (d_model, 8) router leaf — 16x lane padding, over 16 GB, refused
-    (``bench-mfu --workload moe`` on the chip, PR 21)."""
+    (on the chip, PR 21)."""
     from akka_allreduce_tpu.comm.allreduce import grouped_tree_psum
 
     mesh = jax.make_mesh((4,), ("data",), devices=topo.devices)

@@ -1466,9 +1466,9 @@ def test_cli_json_conflicts_with_other_format(tmp_path):
 def test_cli_widened_surface_matches_make_lint():
     """The exact widened `make lint` surface (package + entry shims + test
     worker helpers) exits 0 on the shipped tree."""
-    lint_paths = ["akka_allreduce_tpu/", "bench.py", "chip_smoke.py"] + sorted(
+    lint_paths = ["akka_allreduce_tpu/", "chip_smoke.py"] + sorted(
         str(p.relative_to(REPO_ROOT)) for p in (REPO_ROOT / "tests").glob("*_worker.py")
     )
-    assert lint_paths[3:], "worker helpers must exist (surface satellite)"
+    assert lint_paths[2:], "worker helpers must exist (surface satellite)"
     r = _run_cli(*lint_paths)
     assert r.returncode == 0, r.stdout + r.stderr

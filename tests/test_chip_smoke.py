@@ -12,6 +12,7 @@ import subprocess
 import sys
 import tempfile
 
+import numpy as np
 import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -95,7 +96,7 @@ def test_compile_cache_rule(env_dir, monkeypatch):
     "argv,placed",
     [
         (["train-lm"], True),
-        (["bench-mfu"], True),
+        (["train-moe"], True),
         (["elastic-demo"], True),
         (["cluster-node"], False),  # cluster roles and drill children:
         (["chaos-train-node"], False),  # no persistent cache (Design 1)
@@ -155,6 +156,55 @@ def test_peak_flops_has_no_silent_none_on_a_tpu(device, want):
     assert device_peak_flops(device) == want
     got = mfu(1e12, 1.0, device_peak_flops(device))
     assert (got is None) if want is None else (got == 1e12 / want)
+
+
+# 16 tokens; QK^T and AV are 2 x 2 B T^2 d = 2,048 a layer forward, 6,144
+# over three layers, half of it under a causal mask
+_LM = dict(n_params=1000, batch=2, seq=8, d_model=4, n_layers=3)
+_MOE_TREE = {
+    "embed": np.zeros((10, 4)),  # 40
+    "layer_0": {
+        "router": np.zeros((4, 4)),  # 16
+        "moe_experts": {
+            "w_in": np.zeros((4, 4, 8)),  # 128
+            "w_out": np.zeros((4, 8, 4)),  # 128
+        },
+    },
+}
+
+
+@pytest.mark.parametrize(
+    "fn,kwargs,by_hand",
+    [
+        # 6 x 1,000 x 16, and three forwards' worth of 3,072
+        pytest.param(
+            "transformer_train_flops", _LM, 96_000 + 9_216, id="lm_causal"
+        ),
+        pytest.param(
+            "transformer_train_flops", dict(_LM, causal=False),
+            96_000 + 18_432, id="lm_not_causal",
+        ),
+        # a fourth forward of both terms: 8 x 16,000 + 4 x 3,072
+        pytest.param(
+            "transformer_train_flops", dict(_LM, remat=True),
+            128_000 + 12_288, id="lm_causal_remat",
+        ),
+        pytest.param(
+            "dense_train_flops", dict(n_params=1000, tokens=16), 96_000,
+            id="dense",
+        ),
+        # 40 + 16 whole, the 256 under ``moe_`` at 2 of 4
+        pytest.param(
+            "moe_active_params", dict(params=_MOE_TREE, topk=2, n_experts=4),
+            56 + 128, id="moe_active_top2_of_4",
+        ),
+    ],
+)
+def test_flop_counts_against_counts_by_hand(fn, kwargs, by_hand):
+    """The counts behind the train CLIs' MFU print and ``soak.py``."""
+    from akka_allreduce_tpu.utils import benchmarking
+
+    assert getattr(benchmarking, fn)(**kwargs) == by_hand
 
 
 def test_second_node_process_on_one_chip_is_refused(monkeypatch):

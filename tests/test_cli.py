@@ -3,7 +3,9 @@ scripts, exercised in-process on the virtual CPU mesh)."""
 
 import json
 
-from akka_allreduce_tpu.__main__ import main
+import pytest
+
+from akka_allreduce_tpu.__main__ import _COMPILE_CACHED, COMMANDS, main
 
 
 class TestCLI:
@@ -12,11 +14,16 @@ class TestCLI:
         assert "commands:" in capsys.readouterr().out
         assert main(["no-such-cmd"]) == 2
 
-    def test_bench(self, capsys):
-        assert main(["bench", "--floats", "4096", "--iters", "2"]) == 0
-        report = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-        assert report["n_devices"] == 8
-        assert report["bus_gbps_best"] > 0
+    @pytest.mark.parametrize(
+        "name", ["bench", "bench-suite", "bench-mfu", "bench-checkpoint"]
+    )
+    def test_retired_bench_commands_are_refused(self, name, capsys):
+        """A rate is measured by ``benchmarks/run.py`` and by nothing else:
+        the package's own harnesses went in PR 45, with their commands."""
+        assert main([name]) == 2
+        assert "expected one of" in capsys.readouterr().out
+        assert name not in COMMANDS
+        assert _COMPILE_CACHED <= set(COMMANDS)
 
     def test_local_demo(self, capsys):
         assert (

@@ -8,12 +8,8 @@ import jax
 import numpy as np
 import pytest
 
-from akka_allreduce_tpu.comm import (
-    measure_allreduce,
-    threshold_allreduce,
-)
+from akka_allreduce_tpu.comm import threshold_allreduce
 from akka_allreduce_tpu.parallel import grid_factors, grid_mesh, line_mesh
-from akka_allreduce_tpu.utils import MetricsLogger
 
 
 @pytest.fixture(scope="module")
@@ -291,32 +287,6 @@ class TestHostEntryMasksInPlace:
                 np.asarray(out).astype("float32"), want.astype("float32"),
                 equal_nan=True,
             )
-
-
-class TestBandwidthHarness:
-    def test_measure_reports_and_logs(self, line8):
-        logger = MetricsLogger()
-        rep = measure_allreduce(
-            line8, 4096, iters=3, warmup=1, logger=logger
-        )
-        assert rep.n_devices == 8
-        assert rep.bus_gbps_best > 0
-        lines = logger.dump().strip().splitlines()
-        assert len(lines) == 3
-        import json
-
-        rec = json.loads(lines[0])
-        assert rec["n_devices"] == 8 and rec["bus_gbps"] > 0
-
-    @pytest.mark.parametrize(
-        "schedule,compress", [("psum", "bf16"), ("ring", "int8")]
-    )
-    def test_measure_with_compression(self, line8, schedule, compress):
-        rep = measure_allreduce(
-            line8, 4096, iters=2, warmup=1,
-            schedule=schedule, compress=compress,
-        )
-        assert rep.bus_gbps_best > 0
 
 
 class TestMeshHelpers:

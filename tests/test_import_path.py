@@ -9,7 +9,7 @@ a process. Every case is a fresh interpreter (``run_fresh``, conftest.py).
 import pytest
 
 ENTRIES = {
-    # the five training cells, every train-* command, bench-suite, soak
+    # the training cells, every train-* command, soak
     "train": "import akka_allreduce_tpu.train",
     # a configuration-built decoder before its trainer
     "hybrid_decoder": "import akka_allreduce_tpu.models.hybrid_decoder",
