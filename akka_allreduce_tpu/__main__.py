@@ -2088,6 +2088,11 @@ def _train_moe_from_config(args) -> int:
             f"{hist[-1].indexer_loss:.4f} ({int(hist[-1].selected_pairs.sum())} "
             f"pairs kept of {len(model.layer_types) * args.batch * args.seq_len * (args.seq_len + 1) // 2})"
         )
+    if hist[-1].state_rms is not None:  # linear-attention layers
+        mtp += (
+            f", log decay {hist[-1].log_decay_mean:.4f}, "
+            f"state rms {hist[0].state_rms:.4f} -> {hist[-1].state_rms:.4f}"
+        )
     print(
         f"moe: {args.steps} steps on {trainer.n_devices} devices in "
         f"{dt:.2f}s ({dt / args.steps * 1e3:.1f} ms/step); "

@@ -5,18 +5,22 @@ DeepSeek-V3 dialect, ``joyai_llm_flash``), and decoders that mix windowed and
 full attention by the layer's kind, at one head count or at one per layer
 (the ``laguna`` dialect, which ``mellum`` writes too), and decoders whose
 attention runs under a mask a learned indexer makes from the data (the
-Qwen3-MoE key set with ``sa_config``, ``KeyeVL2``'s language model).
+Qwen3-MoE key set with ``sa_config``, ``KeyeVL2``'s language model), and
+decoders whose layers are linear attention with a matrix state (the gated
+delta rule) three to one with gated full attention (``qwen3_next``).
 
 Layer ``i``:  ``h = x + Op_i(RMSNorm(x))``,  ``y = h + FF_i(RMSNorm(h))``.
 ``Op_i`` is a gated short convolution where ``layer_types[i] == "conv"``,
 grouped-query attention (:class:`GroupedQueryAttention`: rotate-half RoPE,
 per-head RMSNorm on q and k or none, a per-head output gate or none) where
 ``"full_attention"``, the same under a causal window where
-``"sliding_attention"``, and latent attention (:class:`LatentAttention`) where
-``"latent_attention"``; ``FF_i`` is a gated SiLU MLP for the first
+``"sliding_attention"``, latent attention (:class:`LatentAttention`) where
+``"latent_attention"``, and a Gated DeltaNet mixer (:class:`GatedDeltaNet`)
+where ``"linear_attention"``; ``FF_i`` is a gated SiLU MLP for the first
 ``num_dense_layers`` layers and a dropless expert layer after them, routed
 by sigmoid or softmax scores, with a shared expert beside the routed ones
-where ``shared_width`` says so. A final RMSNorm, then an untied head. No
+where ``shared_width`` says so (under its own sigmoid gate where
+``shared_gate``). A final RMSNorm, then an untied head. No
 bias anywhere.
 
 With ``mtp_depth == 1`` one more layer predicts the token after the next
@@ -68,6 +72,19 @@ class IndexerRule(NamedTuple):
     heads: int
     head_dim: int
     topk: int
+
+
+class LinearAttentionRule(NamedTuple):
+    """The sizes of a Gated DeltaNet mixer: ``key_heads`` heads of
+    ``key_dim`` for q and k, each serving ``value_heads / key_heads``
+    consecutive value heads of ``value_dim``, behind a causal depthwise
+    convolution of ``conv_taps`` taps."""
+
+    key_heads: int
+    value_heads: int
+    key_dim: int
+    value_dim: int
+    conv_taps: int
 
 
 def _dense(features: int, dtype, name: str) -> nn.Dense:
@@ -142,9 +159,11 @@ class GroupedQueryAttention(nn.Module):
     """``n_heads`` queries on ``n_kv_heads`` keys and values, rotate-half
     RoPE, causal. As ``lfm2_moe`` has it: per-head RMSNorm on q and k, one
     ``rope_theta`` over the whole head. The further fields are the ``laguna``
-    dialect's: no such norm; ``gated``, a per-head ``sigmoid(x W_g)`` (in
+    dialect's: no such norm; ``gated`` true, a per-head ``sigmoid(x W_g)`` (in
     float32, read from the layer's normed input) on the kernel's output
-    before ``W_o``; ``window``, query ``i`` sees keys ``i - window + 1 .. i``;
+    before ``W_o``, and ``gated`` ``"element"`` (``qwen3_next``) one gate an
+    element of that output, read from a ``W_q`` of twice the width: a head's
+    ``head_dim`` query columns, then its ``head_dim`` gate columns; ``window``, query ``i`` sees keys ``i - window + 1 .. i``;
     ``rotary_dim`` / ``yarn`` / ``attention_factor`` as
     :func:`models.transformer.rope` takes them; ``scope_name``, the named scope
     around the layer.
@@ -184,7 +203,7 @@ class GroupedQueryAttention(nn.Module):
     norm_eps: float
     compute_dtype: jnp.dtype
     qk_norm: bool = True
-    gated: bool = False
+    gated: bool | str = False  # no gate | one a head | "element": one an element
     window: int | None = None
     rotary_dim: int | None = None
     yarn: tuple[float, int, float, float] | None = None
@@ -233,12 +252,17 @@ class GroupedQueryAttention(nn.Module):
                 **mrope,
             )
 
+        if self.gated not in (False, True, "element"):
+            raise ValueError(f"gated = {self.gated!r}: False, True (a head) or 'element'")
+        by_element = self.gated == "element"
         with jax.named_scope(self.scope_name):
             with jax.named_scope("attn_qkv"):
-                q = _HeadsIn(self.n_heads, hd, dt, name="q")(x)
+                q = _HeadsIn(self.n_heads, hd * (1 + by_element), dt, name="q")(x)
                 k = _HeadsIn(self.n_kv_heads, hd, dt, name="k")(x)
                 v = _HeadsIn(self.n_kv_heads, hd, dt, name="v")(x)
-                if self.gated:
+                if by_element:
+                    q, gate = q[..., :hd], q[..., hd:]
+                elif self.gated:
                     gate = _HeadsIn(self.n_heads, None, dt, name="gate")(x)
             if self.indexer is not None:
                 return self._under_learned_mask(x, q, k, v, turned, positions)
@@ -247,7 +271,7 @@ class GroupedQueryAttention(nn.Module):
                 out = heads_first_attention(q, k, v, causal=True, window=self.window)
                 if self.gated:
                     gate = jax.nn.sigmoid(gate.astype(jnp.float32)).astype(dt)
-                    out = out * gate[..., None]
+                    out = out * (gate if by_element else gate[..., None])
             with jax.named_scope("attn_out"):
                 return _HeadsOut(d, dt, name="out")(out)
 
@@ -390,6 +414,102 @@ class LatentAttention(nn.Module):
             return _HeadsOut(d, dt, name="out")(out)
 
 
+def _decay_rate_init(key, shape, dtype=jnp.float32):
+    """``A_log`` as the Gated DeltaNet reference layer seeds it: ``log A``,
+    ``A ~ U(0, 16)`` held away from 0."""
+    return jnp.log(jnp.maximum(jax.random.uniform(key, shape, dtype, 0.0, 16.0), 1e-3))
+
+
+def _step_bias_init(key, shape, dtype=jnp.float32):
+    """``dt_bias`` as that layer seeds it: the inverse softplus of a step
+    log-uniform in [1e-3, 1e-1], so that with ``A`` above a token's log decay
+    starts in about (-1.6, 0) and the state carries memory across chunks."""
+    step = jnp.exp(jax.random.uniform(key, shape, dtype, math.log(1e-3), math.log(1e-1)))
+    return step + jnp.log(-jnp.expm1(-step))
+
+
+def _filter_init(key, shape, dtype=jnp.float32):
+    """A depthwise ``Conv1d``'s default: uniform within ``taps^-0.5``."""
+    bound = shape[-1] ** -0.5
+    return jax.random.uniform(key, shape, dtype, -bound, bound)
+
+
+class GatedDeltaNet(nn.Module):
+    """The linear-attention mixer of ``qwen3_next`` (Gated DeltaNet, arXiv
+    2412.06464), ``rule`` a :class:`LinearAttentionRule`: ``[q | k | v | z] =
+    x W_qkvz``, ``[b | a] = x W_ba`` (one of each a value head); ``[q | k |
+    v] <- silu(conv([q | k | v]))``, causal and depthwise; ``q <- l2norm(q)
+    key_dim^-0.5``, ``k <- l2norm(k)`` (epsilon 1e-6 under the root); in
+    float32 ``beta = sigmoid(b)``, ``g = -exp(A_log) softplus(a + dt_bias)``;
+    the gated delta rule (``ops/delta_rule.py``) from a zero state; ``y =
+    RMSNorm(o) (*) silu(z)`` over each head's ``value_dim`` columns, the norm
+    first; ``W_o``. Returns ``(out, the mean of g, the root-mean-square of
+    the final state)``. Scopes: ``linear_attention`` around ``gdn_in``,
+    ``gdn_conv``, ``gdn_core`` (the two l2-norms, ``beta``, ``g`` and the
+    rule, nothing else) and ``gdn_out``."""
+
+    rule: LinearAttentionRule
+    norm_eps: float
+    compute_dtype: jnp.dtype
+
+    @nn.compact
+    def __call__(self, x):
+        from akka_allreduce_tpu.ops.delta_rule import gated_delta_rule
+        from akka_allreduce_tpu.ops.short_conv import silu_short_conv
+
+        rule, dt, f32 = self.rule, self.compute_dtype, jnp.float32
+        b, t, d = x.shape
+        hk, hv, dk, dv = rule.key_heads, rule.value_heads, rule.key_dim, rule.value_dim
+        keys, values = hk * dk, hv * dv
+
+        def heads_first(y, heads):
+            return y.reshape(b, t, heads, -1).transpose(0, 2, 1, 3)
+
+        def l2norm(y, scale):
+            y = y.astype(f32)
+            y = y * lax.rsqrt(jnp.sum(jnp.square(y), axis=-1, keepdims=True) + 1e-6)
+            return (y * scale).astype(dt)
+
+        with jax.named_scope("linear_attention"):
+            with jax.named_scope("gdn_in"):
+                qkvz = _dense(2 * keys + 2 * values, dt, "qkvz")(x)
+                ba = _dense(2 * hv, dt, "ba")(x)
+            taps = self.param("conv", _filter_init, (2 * keys + values, rule.conv_taps))
+            a_log = self.param("A_log", _decay_rate_init, (hv,))
+            dt_bias = self.param("dt_bias", _step_bias_init, (hv,))
+            scale = self.param("norm", nn.initializers.ones, (dv,))
+
+            # Between the two projections everything is made again on the
+            # backward pass from ``qkvz`` and ``ba``: what the chunked rule
+            # keeps for its own backward (each chunk's state, the solved
+            # systems) is over a GB a layer at 8,192 positions, and the
+            # convolution's output, the heads-first copies and the rule's
+            # output are 0.4 GB more; the two projections' outputs are 0.2
+            @jax.checkpoint
+            def mixed(qkvz, ba, taps, a_log, dt_bias, scale):
+                with jax.named_scope("gdn_conv"):
+                    qkv = silu_short_conv(qkvz[..., : 2 * keys + values], taps)
+                with jax.named_scope("gdn_core"):
+                    q = l2norm(heads_first(qkv[..., :keys], hk), dk ** -0.5)
+                    k = l2norm(heads_first(qkv[..., keys: 2 * keys], hk), 1.0)
+                    v = heads_first(qkv[..., 2 * keys:], hv)
+                    ba = ba.astype(f32).transpose(0, 2, 1)  # (B, 2 H_v, T)
+                    beta = jax.nn.sigmoid(ba[:, :hv])
+                    g = -jnp.exp(a_log)[:, None] * jax.nn.softplus(
+                        ba[:, hv:] + dt_bias[:, None]
+                    )
+                    o, state = gated_delta_rule(q, k, v, g, beta)
+                with jax.named_scope("gdn_out"):
+                    z = heads_first(qkvz[..., 2 * keys + values:], hv)
+                    y = _rms(o, scale, self.norm_eps) * jax.nn.silu(z)
+                return y, jnp.mean(g), jnp.sqrt(jnp.mean(jnp.square(state)))
+
+            y, log_decay, state_rms = mixed(qkvz, ba, taps, a_log, dt_bias, scale)
+            with jax.named_scope("gdn_out"):
+                out = _HeadsOut(d, dt, name="out")(y)
+        return out, log_decay, state_rms
+
+
 class GatedMLP(nn.Module):
     width: int
     compute_dtype: jnp.dtype
@@ -406,7 +526,9 @@ class HeldExperts(nn.Module):
     """The expert layer of one device: returns ``(y, rows, dropped, taken)``
     with ``rows`` the (held_count,) rows each held expert received. With
     ``shared_width`` a shared expert, a gated MLP every token passes through
-    and every device computes alike, is added unweighted."""
+    and every device computes alike, is added: unweighted, or with
+    ``shared_gate`` times ``sigmoid(x w_sg)``, one number a token, computed
+    alike on every device as the shared expert is."""
 
     num_experts: int
     experts_per_token: int
@@ -419,6 +541,7 @@ class HeldExperts(nn.Module):
     compute_dtype: jnp.dtype
     shared_width: int = 0
     score: str = "sigmoid"  # the router's score function, or "softmax"
+    shared_gate: bool = False
 
     @nn.compact
     def __call__(self, x):
@@ -445,7 +568,16 @@ class HeldExperts(nn.Module):
         y = y.reshape(x.shape)
         if self.shared_width:
             with jax.named_scope("shared_expert"):
-                y = y + GatedMLP(self.shared_width, self.compute_dtype, name="shared")(x)
+                shared = GatedMLP(self.shared_width, self.compute_dtype, name="shared")(x)
+                if self.shared_gate:
+                    w_sg = self.param("shared_gate", init, (d, 1))
+                    gate = jnp.einsum(
+                        "...d,de->...e", x.astype(self.compute_dtype),
+                        w_sg.astype(self.compute_dtype),
+                        preferred_element_type=jnp.float32,
+                    )
+                    shared = shared * jax.nn.sigmoid(gate).astype(shared.dtype)
+                y = y + shared
         return y, route.group_sizes[:h], dropped, route.buffer_rows
 
 
@@ -464,7 +596,10 @@ class HybridDecoderLM(nn.Module):
     total) and the (query, key) pairs each layer's mask keeps,
     (layers,) float32. ``positions`` (3, T): the temporal, height and width
     rows of multimodal RoPE where the model has ``mrope_sections``; left
-    out, text (three equal rows 0 .. T - 1)."""
+    out, text (three equal rows 0 .. T - 1). With ``linear_attention`` two
+    scalars come last of all: the mean log decay ``g`` over tokens, heads
+    and linear layers, and the largest root-mean-square of such a layer's
+    final state."""
 
     vocab: int
     d_model: int
@@ -501,11 +636,18 @@ class HybridDecoderLM(nn.Module):
     # per kind of attention layer, where given; such a layer has no per-head
     # norm and is scoped by its kind
     rope_by_kind: tuple[RotaryRule, ...] = ()
-    attn_gate: bool = False  # a per-head sigmoid gate on attention's output
+    # a sigmoid gate on attention's output: False, true (one a head) or
+    # "element" (:class:`GroupedQueryAttention`'s ``gated``)
+    attn_gate: bool | str = False
     router_score: str = "sigmoid"
     # attention under a learned mask (``sa_config``) and multimodal RoPE
     indexer: IndexerRule | None = None
     mrope_sections: tuple[int, ...] | None = None
+    # ``qwen3_next``: the "linear_attention" layers' sizes, the columns of a
+    # full-attention head that rotate (None: all), a gate on the shared expert
+    linear_attention: LinearAttentionRule | None = None
+    rotary_dim: int | None = None
+    shared_gate: bool = False
 
     @classmethod
     def from_config(cls, cfg: dict, **overrides) -> "HybridDecoderLM":
@@ -517,7 +659,9 @@ class HybridDecoderLM(nn.Module):
         where it has ``sa_config``, or ``decoder_sparse_step`` with
         ``mlp_only_layers`` and ``moe_intermediate_size`` (``KeyeVL2``'s
         language model: :func:`_from_qwen3_moe_keys` says what it builds and
-        what it refuses by name); else ``lfm2_moe``'s. The key that counts the experts (``num_experts`` /
+        what it refuses by name), unless it has ``linear_num_value_heads``
+        (``qwen3_next``, whose file has those three keys too and is asked for
+        first: :func:`_from_qwen3_next`); else ``lfm2_moe``'s. The key that counts the experts (``num_experts`` /
         ``n_routed_experts``) counts the experts HELD when
         ``router_num_experts`` states the model's own count beside it (a
         chip's share, ``held_experts`` its ids); otherwise all experts are
@@ -526,6 +670,8 @@ class HybridDecoderLM(nn.Module):
             read = _from_deepseek_v3_keys
         elif _rope_by_layer_kind(cfg):
             read = _from_laguna
+        elif "linear_num_value_heads" in cfg:
+            read = _from_qwen3_next
         elif "sa_config" in cfg or all(
             key in cfg
             for key in ("decoder_sparse_step", "mlp_only_layers", "moe_intermediate_size")
@@ -571,6 +717,11 @@ class HybridDecoderLM(nn.Module):
                 self.rope_theta, self.norm_eps, dt, name=pre + "attn",
                 scope_name="sparse_attention" if self.indexer else "attention",
                 mrope_sections=self.mrope_sections, indexer=self.indexer,
+                gated=self.attn_gate, rotary_dim=self.rotary_dim,
+            )
+        if kind == "linear_attention":
+            return GatedDeltaNet(
+                self.linear_attention, self.norm_eps, dt, name=pre + "linear"
             )
         if kind == "latent_attention":
             return LatentAttention(
@@ -588,14 +739,20 @@ class HybridDecoderLM(nn.Module):
             epsilon=self.norm_eps, dtype=dt, name=name
         )
         rows, dropped, buffers, index_kl, index_pairs = [], [], [], [], []
+        decays, state_rms = [], []
         if positions is not None and not self.mrope_sections:
             raise ValueError("positions are taken by a model with mrope_sections")
 
         def layer(x, pre: str, index: int, dense: bool):
-            op = self._operator(self.layer_types[index], pre, index)
+            kind = self.layer_types[index]
+            op = self._operator(kind, pre, index)
             extra = (positions,) if self.indexer or self.mrope_sections else ()
             y = op(norm(pre + "op_norm")(x), *extra)
-            if self.indexer:
+            if kind == "linear_attention":
+                y, decay, rms = y
+                decays.append(decay)
+                state_rms.append(rms)
+            elif self.indexer:
                 y, kl, pairs = y
                 index_kl.append(kl)
                 index_pairs.append(pairs)
@@ -607,7 +764,8 @@ class HybridDecoderLM(nn.Module):
                 self.num_experts, self.experts_per_token,
                 self.moe_intermediate_size, self.held_first, self.held_count,
                 self.use_select_bias, self.renormalise, self.routed_scale,
-                dt, self.shared_width, self.router_score, name=pre + "moe",
+                dt, self.shared_width, self.router_score, self.shared_gate,
+                name=pre + "moe",
             )(h)
             rows.append(r)
             dropped.append(dr)
@@ -655,6 +813,8 @@ class HybridDecoderLM(nn.Module):
             else jnp.zeros((0,), jnp.float32),
             *mtp_logits,
             *((sum(index_kl), jnp.stack(index_pairs)) if self.indexer else ()),
+            *((jnp.mean(jnp.stack(decays)), jnp.max(jnp.stack(state_rms)))
+              if decays else ()),
         )
 
 
@@ -848,14 +1008,21 @@ def _from_qwen3_moe_keys(cfg: dict) -> dict:
     use, a ``rope_scaling`` type other than ``default``, interleaved
     sections (``mrope_interleaved``), a dense layer among the expert layers
     (``decoder_sparse_step`` != 1, a non-empty ``mlp_only_layers``), more
-    than one index key head, biases, tied embeddings, recomputation."""
+    than one index key head, biases, tied embeddings, recomputation, a shared
+    expert (``shared_expert_intermediate_size``), a ``partial_rotary_factor``
+    other than 1 and any ``linear_*`` / ``full_attention_interval`` key, so
+    that no file is built as plain attention without what those keys ask."""
     refused = {
         "use_sliding_window": False, "decoder_sparse_step": 1, "mlp_only_layers": [],
         "attention_bias": False, "tie_word_embeddings": False, "hidden_act": "silu",
+        "shared_expert_intermediate_size": 0, "partial_rotary_factor": 1,
     }
     for key, built in refused.items():
         if cfg.get(key, built) != built:
             raise ValueError(f"{key} = {cfg[key]!r} is not built (only {built!r})")
+    for key in cfg:  # ``qwen3_next``'s, which :func:`_from_qwen3_next` reads
+        if key.startswith("linear_") or key == "full_attention_interval":
+            raise ValueError(f"{key} is not built by the Qwen3-MoE reader")
     scaling = cfg.get("rope_scaling") or {}
     for key in ("rope_type", "type"):
         if scaling.get(key, "default") != "default":
@@ -899,4 +1066,70 @@ def _from_qwen3_moe_keys(cfg: dict) -> dict:
         use_select_bias=False, router_score="softmax",
         renormalise=bool(cfg["norm_topk_prob"]), routed_scale=1.0,
         indexer=indexer, mrope_sections=sections or None,
+    )
+
+
+def _from_qwen3_next(cfg: dict) -> dict:
+    """``qwen3_next``'s keys (entered by ``linear_num_value_heads``): layer i
+    is ``full_attention`` where ``(i + 1) % full_attention_interval == 0`` and
+    ``linear_attention`` elsewhere (or as ``layer_types`` says where the file
+    has it); the linear layers a Gated DeltaNet mixer at the ``linear_*``
+    sizes; the full layers grouped-query attention with a learned RMSNorm on
+    each head of q and k, rotate-half RoPE on the first
+    ``partial_rotary_factor`` of a head and a sigmoid gate an element from a
+    doubled ``W_q``; every layer an expert layer with softmax scores
+    renormalised over the picks where ``norm_topk_prob``, no selection bias,
+    no scale, and a shared expert of ``shared_expert_intermediate_size``
+    under its own sigmoid gate. Refused by name, because not built: a dense
+    layer among the expert layers (``decoder_sparse_step`` != 1, a non-empty
+    ``mlp_only_layers``), a sliding window in use, a ``rope_scaling``,
+    biases, tied embeddings, an activation other than ``silu``, value heads
+    that are no multiple of the key heads, recomputation."""
+    refused = {
+        "use_sliding_window": False, "decoder_sparse_step": 1, "mlp_only_layers": [],
+        "rope_scaling": None, "attention_bias": False, "tie_word_embeddings": False,
+        "hidden_act": "silu",
+    }
+    for key, built in refused.items():
+        if cfg.get(key, built) != built:
+            raise ValueError(f"{key} = {cfg[key]!r} is not built (only {built!r})")
+    program = cfg.get("program", {})
+    if program.get("remat"):
+        raise ValueError(f"program.remat {program['remat']!r}: recomputation is not built")
+    rule = LinearAttentionRule(
+        int(cfg["linear_num_key_heads"]), int(cfg["linear_num_value_heads"]),
+        int(cfg["linear_key_head_dim"]), int(cfg["linear_value_head_dim"]),
+        int(cfg["linear_conv_kernel_dim"]),
+    )
+    if rule.value_heads % rule.key_heads:
+        raise ValueError(
+            f"linear_num_value_heads = {rule.value_heads} is not built (only a "
+            f"multiple of linear_num_key_heads = {rule.key_heads})"
+        )
+    layers, every = int(cfg["num_hidden_layers"]), int(cfg["full_attention_interval"])
+    kinds = tuple(cfg.get("layer_types") or (
+        "full_attention" if (i + 1) % every == 0 else "linear_attention"
+        for i in range(layers)
+    ))
+    for kind in set(kinds) - {"full_attention", "linear_attention"}:
+        raise ValueError(f"layer type {kind!r} is not built")
+    total, first, count = _held_share(cfg, "num_experts")
+    head_dim = int(cfg["head_dim"])
+    return dict(
+        vocab=int(cfg["vocab_size"]), d_model=int(cfg["hidden_size"]),
+        layer_types=kinds, num_dense_layers=0,
+        n_heads=int(cfg["num_attention_heads"]),
+        n_kv_heads=int(cfg["num_key_value_heads"]), head_dim=head_dim,
+        intermediate_size=int(cfg["intermediate_size"]),
+        moe_intermediate_size=int(cfg["moe_intermediate_size"]),
+        num_experts=total,
+        experts_per_token=int(cfg["num_experts_per_tok"]),
+        held_first=first, held_count=count,
+        norm_eps=float(cfg["rms_norm_eps"]), rope_theta=float(cfg["rope_theta"]),
+        use_select_bias=False, router_score="softmax",
+        renormalise=bool(cfg["norm_topk_prob"]), routed_scale=1.0,
+        shared_width=int(cfg["shared_expert_intermediate_size"]), shared_gate=True,
+        attn_gate="element",
+        rotary_dim=int(head_dim * float(cfg.get("partial_rotary_factor", 1))),
+        linear_attention=rule,
     )

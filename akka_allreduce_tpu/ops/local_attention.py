@@ -261,7 +261,10 @@ def _splash_blocks(
     sum. Neither head 64 nor 192 against 128 moved the choice. Past 128,
     float32 inputs at 1024 overrun VMEM in the described-v5e compile (bf16
     ones fit, and ran), so those keep 512, which :func:`flash_shapes_ok`
-    guarantees divides T. ``window``: a causal band :func:`_takes_band` left
+    guarantees divides T. q, k AND v at 256 (T 8192, 16 heads on 2, bf16;
+    CHANGES.md, PR 46): 1024 tiles 4.38 ms forward and 13.91 forward +
+    backward a layer, 512 tiles 4.76 and 15.88, both compiled for the
+    described v5e first: 1024 again. ``window``: a causal band :func:`_takes_band` left
     to the library (the band kernel's tile does not divide it, or it is past
     1,024)."""
     from jax.experimental.pallas.ops.tpu.splash_attention import BlockSizes

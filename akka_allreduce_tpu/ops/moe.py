@@ -597,7 +597,10 @@ def _rung_forward(x, weights, w1, w3, w2, route, *, rows, keep, impl):
     with jax.named_scope("moe_combine"):
         y = _rows_to_sources(ys * ws.astype(ys.dtype), route.inverse, x.shape[0])
     if rows != keep:
-        gate = up = jnp.zeros((keep, gate.shape[1]), gate.dtype)
+        zeros = jnp.zeros((keep, gate.shape[1]), gate.dtype)
+        # under shard_map's check every rung's result has to vary alike
+        varying = tuple(sorted(jax.typeof(gate).vma))
+        gate = up = lax.pcast(zeros, varying, to="varying") if varying else zeros
     return y, (gate, up)
 
 

@@ -12,6 +12,11 @@ The backward is written out (``jax.custom_vjp``) so that only the projection
 ``bcz`` is kept for it: ``s`` and ``c`` are made again from it, two (B, T, d)
 tensors a layer that autodiff would have saved. The filter's gradient is
 accumulated in float32.
+
+The same taps serve a second caller, the convolution in front of a
+linear-attention mixer (:func:`silu_short_conv`): ``out = silu(c)`` with ``c``
+the causal filter of the input itself, no gate; its backward too keeps only
+the input and makes ``c`` again.
 """
 
 from __future__ import annotations
@@ -51,12 +56,9 @@ def _fwd(bcz, w):
     return gated_short_conv(bcz, w), (bcz, w)
 
 
-def _bwd(res, dy):
-    bcz, w = res
+def _taps_backward(dc: jax.Array, s: jax.Array, w: jax.Array):
+    """``(ds, dw)`` from the cotangent ``dc`` of ``_causal_taps(s, w)``."""
     taps = w.shape[1]
-    b, c, z = jnp.split(bcz, 3, axis=-1)
-    s = b * z
-    dc = dy * c  # cotangent of the convolution's output
     wc = w.astype(s.dtype)
     # the transpose of a causal filter reads the future
     ds = sum(wc[:, j] * _shift_left(dc, taps - 1 - j) for j in range(taps))
@@ -71,6 +73,14 @@ def _bwd(res, dy):
         ],
         axis=1,
     ).astype(w.dtype)
+    return ds, dw
+
+
+def _bwd(res, dy):
+    bcz, w = res
+    b, c, z = jnp.split(bcz, 3, axis=-1)
+    s = b * z
+    ds, dw = _taps_backward(dy * c, s, w)  # the cotangent of the filter's output
     dbcz = jnp.concatenate(
         (ds * z, dy * _causal_taps(s, w), ds * b), axis=-1
     )
@@ -78,3 +88,23 @@ def _bwd(res, dy):
 
 
 gated_short_conv.defvjp(_fwd, _bwd)
+
+
+@jax.custom_vjp
+def silu_short_conv(x: jax.Array, w: jax.Array) -> jax.Array:
+    """``silu`` of the causal depthwise filter of ``x`` (batch, T, d) by ``w``
+    (d, L), zeros to the left, in ``x``'s dtype."""
+    return jax.nn.silu(_causal_taps(x, w))
+
+
+def _silu_fwd(x, w):
+    return silu_short_conv(x, w), (x, w)
+
+
+def _silu_bwd(res, dy):
+    x, w = res
+    _, through_silu = jax.vjp(jax.nn.silu, _causal_taps(x, w))
+    return _taps_backward(through_silu(dy)[0], x, w)
+
+
+silu_short_conv.defvjp(_silu_fwd, _silu_bwd)

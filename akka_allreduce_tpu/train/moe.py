@@ -41,6 +41,12 @@ _PAST_FIRST_RUNG = obs_metrics.counter("trainer.moe.layers_past_first_rung")
 # kept, summed over the layers and the steps
 _INDEXER_LOSS = obs_metrics.gauge("trainer.indexer.loss")
 _SELECTED_PAIRS = obs_metrics.counter("trainer.indexer.selected_pairs")
+# for a model with linear-attention layers (``model.linear_attention``), of
+# the last step: the mean log decay over tokens, heads and layers (how long
+# the memory is), and the largest root-mean-square of a layer's final state
+# (a state dying or blowing up shows here before the loss)
+_LOG_DECAY = obs_metrics.gauge("trainer.linear_attention.log_decay_mean")
+_STATE_RMS = obs_metrics.gauge("trainer.linear_attention.state_rms")
 
 
 @dataclasses.dataclass
@@ -73,6 +79,12 @@ class MoEStepMetrics:
     # (query, key) pairs each layer's mask kept, (layers,), summed over the
     # replicas
     selected_pairs: np.ndarray | None = None
+    # of a model with linear-attention layers: the mean log decay of the
+    # gated delta rule over tokens, heads and layers, and the largest
+    # root-mean-square of a layer's final state (means over the replicas).
+    # None from a model without such layers
+    log_decay_mean: float | None = None
+    state_rms: float | None = None
 
 
 class MoETrainer(ShardedLMTrainer):
@@ -104,7 +116,10 @@ class MoETrainer(ShardedLMTrainer):
         model with an ``indexer`` returns its indexer's loss and the pairs
         its masks kept last of all: the loss enters the total as it is
         (its gradient reaches the indexer's leaves alone) and is reported as
-        ``indexer_loss``, the pairs as ``selected_pairs``.
+        ``indexer_loss``, the pairs as ``selected_pairs``. A model with
+        ``linear_attention`` layers returns their mean log decay and their
+        largest final state's root-mean-square last of all: reported as
+        ``log_decay_mean`` and ``state_rms``, in no loss.
       params: with ``model``, its variables (seeded weights handed in);
         left out, ``model.init`` runs jitted from ``seed``.
     """
@@ -213,6 +228,7 @@ class MoETrainer(ShardedLMTrainer):
         tokens0 = jnp.zeros((1, seq_len // self.sp), jnp.int32)
         mtp = bool(getattr(model, "mtp_depth", 0))
         indexer = getattr(model, "indexer", None) is not None
+        linear = getattr(model, "linear_attention", None) is not None
         if model is not None:
             self.params = (
                 params if params is not None
@@ -254,6 +270,8 @@ class MoETrainer(ShardedLMTrainer):
         if indexer:
             self._mean_names = (*self._mean_names, "indexer_loss")
             self._sum_names = (*self._sum_names, "selected_pairs")
+        if linear:
+            self._mean_names = (*self._mean_names, "log_decay_mean", "state_rms")
         model_apply = self.model.apply
         aux_coef = self.aux_coef
         token_ce = optax.softmax_cross_entropy_with_integer_labels
@@ -266,6 +284,8 @@ class MoETrainer(ShardedLMTrainer):
             # their global sum / denom is the masked token-weighted mean
             total = ce + aux_coef * aux * tokens_local
             means = (ce, aux * tokens_local, dropped * tokens_local)
+            if linear:  # last of all of the model's outputs
+                state_rms, log_decay = rows.pop(), rows.pop()
             if indexer:  # last of the model's outputs; the pairs stay a sum
                 pairs, index_kl = rows.pop(), rows.pop() * tokens_local
             if mtp:
@@ -283,6 +303,8 @@ class MoETrainer(ShardedLMTrainer):
                 total = total + index_kl
                 means += (index_kl,)
                 rows.append(pairs)
+            if linear:
+                means += (log_decay * tokens_local, state_rms * tokens_local)
             return total, (means, tuple(rows))
 
         self._build_step(
@@ -306,7 +328,8 @@ class MoETrainer(ShardedLMTrainer):
         rows of the row buffers moved for them, and the expert layers whose
         buffer was larger than the model's ``first_rung`` at a replica's
         tokens (summed over the replicas, so a layer counts where any replica
-        left the rung)."""
+        left the rung); where it has an indexer or linear-attention layers,
+        their gauges."""
         out = super().train_step(tokens, labels, valid)
         first_rung = getattr(self.model, "first_rung", None)
         if first_rung is not None and out.buffer_rows is not None:
@@ -317,6 +340,9 @@ class MoETrainer(ShardedLMTrainer):
         if out.selected_pairs is not None:
             _INDEXER_LOSS.set(out.indexer_loss)
             _SELECTED_PAIRS.inc(float(out.selected_pairs.sum()))
+        if out.state_rms is not None:
+            _LOG_DECAY.set(out.log_decay_mean)
+            _STATE_RMS.set(out.state_rms)
         return out
 
     def train_chain(

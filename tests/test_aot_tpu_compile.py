@@ -20,6 +20,7 @@ from __future__ import annotations
 import json
 import os
 import re
+import sys
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs to /tmp
 
@@ -142,6 +143,52 @@ def _kernel_attention(b, t, h, h_kv, d):
         return compiled, 2  # forward, and the fused backward
 
     return build
+
+
+def _kernel_attention_at_tiles(tile, *shape):
+    """:func:`_kernel_attention` with the splash kernel's q and K/V blocks
+    forced to ``tile`` (the forward's compute block stays 512): what a sweep
+    on the chip holds against the tiles ``_splash_blocks`` takes."""
+
+    def build(topo, monkeypatch):
+        from jax.experimental.pallas.ops.tpu.splash_attention import BlockSizes
+
+        import akka_allreduce_tpu.ops.local_attention  # noqa: F401  (the module, not the function)
+
+        module = sys.modules["akka_allreduce_tpu.ops.local_attention"]
+        monkeypatch.setattr(module, "_splash_blocks", lambda *a: BlockSizes(
+            block_q=tile, block_kv=tile, block_kv_compute=512, block_q_dkv=tile,
+            block_kv_dkv=tile, block_kv_dkv_compute=tile, use_fused_bwd_kernel=True))
+        return _kernel_attention(*shape)(topo, monkeypatch)
+
+    return build
+
+
+def _gated_delta_rule_layer(topo, monkeypatch):
+    """One linear layer's gated delta rule at the Qwen3-Next cell's shape (16
+    key heads and 32 value heads of 128, T 8,192), forward and backward: ONE
+    loop over the 128 chunks each way, a float32 state, and no loop over the
+    positions."""
+    from akka_allreduce_tpu.ops.delta_rule import gated_delta_rule
+
+    chip = SingleDeviceSharding(topo.devices[0])
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=chip)  # noqa: E731
+
+    def loss(q, k, v, g, beta):
+        out, state = gated_delta_rule(q, k, v, g, beta)
+        return out.astype(jnp.float32).sum() + state.sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        sds((1, 16, 8192, 128), jnp.bfloat16), sds((1, 16, 8192, 128), jnp.bfloat16),
+        sds((1, 32, 8192, 128), jnp.bfloat16), sds((1, 32, 8192), jnp.float32),
+        sds((1, 32, 8192), jnp.float32),
+    ).compile()
+    text = compiled.as_text()
+    loops = [line for line in text.splitlines() if " while(" in line]
+    assert len(loops) == 2  # the chunks forward, the chunks backward
+    assert "[128,1,32,64,128]" in text  # what a loop is handed: 128 chunks of 64
+    assert "f32[1,32,128,128]" in text
+    return compiled, 0  # plain XLA
 
 
 def _grouped_psum(topo, monkeypatch):
@@ -492,6 +539,12 @@ CASES = {
     # the benchmark's own attention shapes, K/V compact into the kernel
     "splash_attention_b2_t4096_h24_kv2_d128": _kernel_attention(2, 4096, 24, 2, 128),
     "splash_attention_b1_t8192_h32_kv8_d64": _kernel_attention(1, 8192, 32, 8, 64),
+    # q, k AND v at head 256 (the Qwen3-Next cell's full layer): the 1024
+    # tiles ``_splash_blocks`` takes for bf16, and the 512 they were swept against
+    "splash_attention_b1_t8192_h16_kv2_d256": _kernel_attention(1, 8192, 16, 2, 256),
+    "splash_attention_b1_t8192_h16_kv2_d256_tiles512": _kernel_attention_at_tiles(
+        512, 1, 8192, 16, 2, 256),
+    "gated_delta_rule_t8192_h32_d128": _gated_delta_rule_layer,
     "sc2_b2_cell_step": _lm_step(_b2_cell_sizes, (685.9e6, 686.1e6)),
 }
 

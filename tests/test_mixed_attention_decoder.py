@@ -1379,7 +1379,8 @@ def test_the_benchmark_lists_the_cell_where_the_issue_says():
     # of the same dialect, joins the readers of its scopes)
     for w in bench["workloads"]:
         # (a later cell of the grouped-query layer joins the readers of its scopes)
-        if w["name"] not in (CELL, MELLUM_CELL, "keye_vl2_ep8_train_b1_t8192"):
+        if w["name"] not in (CELL, MELLUM_CELL, "keye_vl2_ep8_train_b1_t8192",
+                             "qwen3_next_ep16_train_b1_t8192"):
             older = spec.load_cell(w["name"])
             assert older.config["runner"] != "laguna_moe_train"
             assert not {"swa_kernel_ms", "gqa_proj_ms", "mfu_pct.swa"} & set(older.per_layer)

@@ -520,8 +520,8 @@ def test_the_benchmark_lists_the_cell_where_the_issue_says():
                          "moe_gmm_roofline_pct.mellum", "flash_attn_ms", "mtp_share_pct"}
     metrics = [m["name"] for m in bench["per_layer"]]
     first = metrics.index(KEYE_NEW[0])  # new metrics: this cell's first, appended in one run
-    assert metrics[first:] == list(KEYE_NEW)
-    for m in bench["per_layer"][first:]:
+    assert metrics[first: first + len(KEYE_NEW)] == list(KEYE_NEW)  # later cells' follow
+    for m in bench["per_layer"][first: first + len(KEYE_NEW)]:
         assert m["workloads"][0] == CELL and m["moves"] == "train_tokens_per_s"
     e2e = {m["name"]: m for m in bench["end_to_end"]}
     assert CELL in e2e["train_tokens_per_s"]["workloads"] and e2e["train_tokens_per_s"]["bound"] == 0.022
