@@ -481,10 +481,13 @@ class GatedDeltaNet(nn.Module):
 
             # Between the two projections everything is made again on the
             # backward pass from ``qkvz`` and ``ba``: what the chunked rule
-            # keeps for its own backward (each chunk's state, the solved
-            # systems) is over a GB a layer at 8,192 positions, and the
-            # convolution's output, the heads-first copies and the rule's
-            # output are 0.4 GB more; the two projections' outputs are 0.2
+            # keeps for its own backward is over a GB a layer at 8,192
+            # positions in the XLA form (each chunk's state and what the
+            # scan's body made of it) and 0.34 GB under the kernels (each
+            # chunk's incoming states and its ``T``, written by the pass made
+            # again and read once by the backward kernel); the convolution's
+            # output, the heads-first copies and the rule's output are 0.4 GB
+            # more; the two projections' outputs are 0.2
             @jax.checkpoint
             def mixed(qkvz, ba, taps, a_log, dt_bias, scale):
                 with jax.named_scope("gdn_conv"):

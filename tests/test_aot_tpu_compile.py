@@ -166,29 +166,53 @@ def _kernel_attention_at_tiles(tile, *shape):
 
 def _gated_delta_rule_layer(topo, monkeypatch):
     """One linear layer's gated delta rule at the Qwen3-Next cell's shape (16
-    key heads and 32 value heads of 128, T 8,192), forward and backward: ONE
-    loop over the 128 chunks each way, a float32 state, and no loop over the
-    positions."""
-    from akka_allreduce_tpu.ops.delta_rule import gated_delta_rule
+    key heads and 32 value heads of 128, T 8,192) as the mixer calls it: under
+    its two scopes and a ``jax.checkpoint``, forward and backward. On the chip
+    that is the rule's Pallas kernels (the pass made again with its
+    residuals and the backward: a gradient alone needs no primal), both under
+    both scopes the benchmark's
+    readers match, a float32 state, no loop over the chunks, and less kept
+    than the XLA form keeps at the same place."""
+    from akka_allreduce_tpu.ops import delta_rule
 
     chip = SingleDeviceSharding(topo.devices[0])
     sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=chip)  # noqa: E731
 
-    def loss(q, k, v, g, beta):
-        out, state = gated_delta_rule(q, k, v, g, beta)
-        return out.astype(jnp.float32).sum() + state.sum()
+    def compiled():
+        @jax.checkpoint
+        def rule(*operands):
+            with jax.named_scope("linear_attention"), jax.named_scope("gdn_core"):
+                return delta_rule.gated_delta_rule(*operands)
 
-    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
-        sds((1, 16, 8192, 128), jnp.bfloat16), sds((1, 16, 8192, 128), jnp.bfloat16),
-        sds((1, 32, 8192, 128), jnp.bfloat16), sds((1, 32, 8192), jnp.float32),
-        sds((1, 32, 8192), jnp.float32),
-    ).compile()
-    text = compiled.as_text()
-    loops = [line for line in text.splitlines() if " while(" in line]
-    assert len(loops) == 2  # the chunks forward, the chunks backward
-    assert "[128,1,32,64,128]" in text  # what a loop is handed: 128 chunks of 64
+        def loss(*operands):
+            out, state = rule(*operands)
+            return out.astype(jnp.float32).sum() + state.sum()
+
+        return jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+            sds((1, 16, 8192, 128), jnp.bfloat16), sds((1, 16, 8192, 128), jnp.bfloat16),
+            sds((1, 32, 8192, 128), jnp.bfloat16), sds((1, 32, 8192), jnp.float32),
+            sds((1, 32, 8192), jnp.float32),
+        ).compile()
+
+    assert delta_rule.takes_delta_rule(8192, 128, 128, 16, 32, jnp.bfloat16)
+    by_kernels = compiled()
+    text = by_kernels.as_text()
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    names = sorted(re.search(r"%(gated_delta_rule_\w+?)[.\d]* = ", line).group(1) for line in calls)
+    assert names == ["gated_delta_rule_bwd", "gated_delta_rule_fwd"]
+    for line in calls:
+        scope = re.search(r'op_name="([^"]*)"', line).group(1)
+        assert re.search(r"(?:^|/)gdn_core(?:/|$)", scope) and "linear_attention" in scope, scope
+    assert " while(" not in text and "[128,1,32,64,128]" not in text
     assert "f32[1,32,128,128]" in text
-    return compiled, 0  # plain XLA
+    with monkeypatch.context() as m:  # the portable form at the same place
+        m.setattr(delta_rule, "_on_chip", lambda *arrays: False)
+        xla_form = compiled()
+    assert "tpu_custom_call" not in xla_form.as_text()
+    assert "[128,1,32,64,128]" in xla_form.as_text()  # ONE loop over 128 chunks of 64
+    kept = lambda c: c.memory_analysis().temp_size_in_bytes  # noqa: E731
+    assert kept(by_kernels) < kept(xla_form)
+    return by_kernels, 2
 
 
 def _grouped_psum(topo, monkeypatch):

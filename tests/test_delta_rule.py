@@ -1,6 +1,7 @@
 """The gated delta rule in chunked form (``ops/delta_rule.py``) against the
 token-by-token recurrence it stands for, forward, final state and every
 gradient, at tiny widths on the CPU; the triangular solve it rests on; the
+rule's Pallas kernels, interpreted, against that XLA form at heads of 128; the
 convolution in front of it (``ops/short_conv.silu_short_conv``). The decoder
 built on them: ``tests/test_linear_attention_decoder.py``."""
 
@@ -13,6 +14,8 @@ import pytest
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
+from akka_allreduce_tpu.obs import metrics
+from akka_allreduce_tpu.ops import delta_rule
 from akka_allreduce_tpu.ops.delta_rule import gated_delta_rule, inverse_unit_lower
 from akka_allreduce_tpu.ops.short_conv import silu_short_conv
 
@@ -186,6 +189,149 @@ def test_shapes_that_disagree_are_refused():
         gated_delta_rule(q, k, v, g, beta[:, :2])
     with pytest.raises(ValueError):
         gated_delta_rule(q, k, v[:, :3], g[:, :3], beta[:, :3])  # 3 heads on 2
+
+
+# -- the rule's kernels, interpreted ---------------------------------------------------
+
+KERNEL_T = 256  # four chunks of 64: two grid steps of two chunks, the state carried across both
+GAUGE, UNWRITTEN = "linear_attention.rule.kernel_chunks", -1
+
+
+def kernel_operands(seed=0, decay="mixed", write="mixed", heads=(1, 2), t=KERNEL_T, d=128,
+                    dtype=jnp.bfloat16):
+    """As :func:`operands` at heads the kernels take: two value heads a key
+    head, 128 columns, bf16."""
+    hk, hv = heads
+    key = jax.random.split(jax.random.PRNGKey(seed), 5)
+    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)  # noqa: E731
+    q = unit(jax.random.normal(key[0], (1, hk, t, d))) * d ** -0.5
+    k = unit(jax.random.normal(key[1], (1, hk, t, d)))
+    v = jax.random.normal(key[2], (1, hv, t, d))
+    spread = jax.random.uniform(key[3], (1, hv, t))
+    g = {"mixed": -0.8 * spread, "alpha_near_0": -5.0 - spread,
+         "alpha_near_1": -1e-3 * spread}[decay]
+    beta = {"mixed": jax.random.uniform(key[4], (1, hv, t)), "beta_0": jnp.zeros((1, hv, t)),
+            "beta_1": jnp.ones((1, hv, t))}[write]
+    return q.astype(dtype), k.astype(dtype), v.astype(dtype), g, beta
+
+
+@pytest.fixture
+def kernels_interpreted(monkeypatch):
+    """The kernels where the chip would run them, interpreted, two chunks a
+    grid step; the gauge at a value no call writes, its writer's memory of
+    the shapes it has seen cleared."""
+    monkeypatch.setattr(delta_rule, "_on_chip", lambda *arrays: True)
+    monkeypatch.setattr(delta_rule, "BLOCK_CHUNKS", 2)
+    delta_rule._gauge_kernel_chunks.cache_clear()
+    metrics.gauge(GAUGE).set(UNWRITTEN)
+    yield
+    delta_rule._gauge_kernel_chunks.cache_clear()
+
+
+def on_the_xla_form(*args):
+    real, delta_rule._on_chip = delta_rule._on_chip, lambda *arrays: False
+    try:
+        return gated_delta_rule(*args)
+    finally:
+        delta_rule._on_chip = real
+
+
+@pytest.mark.parametrize("heads,decay,write", [
+    ((1, 2), "mixed", "mixed"), ((2, 4), "mixed", "mixed"),
+    ((1, 2), "alpha_near_0", "mixed"), ((1, 2), "alpha_near_1", "mixed"),
+    ((1, 2), "mixed", "beta_0"), ((1, 2), "mixed", "beta_1"),
+])
+def test_kernels_interpreted_match_the_xla_form(heads, decay, write, kernels_interpreted):
+    """``gated_delta_rule_fwd`` / ``_bwd`` in interpret mode against the XLA
+    form on the same bf16 operands: ``o`` and the final state (the kernels
+    make the same products in the same precisions), and all five gradients of
+    a loss that also reads the final state, so that the backward's ``dS``
+    starts from a cotangent. The gradients are held to the XLA form's on
+    float32 copies of the operands, which the kernels' (float32 between their
+    products) are about as near as the XLA form's own in bf16 (within a bf16 rounding of
+    the largest entry)."""
+    args = kernel_operands(heads[0] + len(decay), decay, write, heads)
+    # a function of its own: a trace jit has kept would not write the gauge again
+    out, state = jax.jit(lambda *a: gated_delta_rule(*a))(*args)
+    want, want_state = jax.jit(on_the_xla_form)(*args)
+    assert out.dtype == jnp.bfloat16 and state.dtype == jnp.float32
+    assert metrics.REGISTRY.snapshot()[GAUGE] == heads[1] * KERNEL_T // 64
+    close(out.astype(jnp.float32), want.astype(jnp.float32), 1e-2)
+    close(state, want_state, 1e-4)
+    grad = lambda fn: jax.jit(jax.grad(weighed(  # noqa: E731
+        lambda *a: tuple(x.astype(jnp.float32) for x in fn(*a))), argnums=tuple(range(5))))
+    got, in_bf16 = grad(gated_delta_rule)(*args), grad(on_the_xla_form)(*args)
+    exact = grad(on_the_xla_form)(*(x.astype(jnp.float32) for x in args))
+    for a, b, c, name in zip(got, in_bf16, exact, "q k v g beta".split()):
+        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+        if write == "beta_0":  # nothing is written: only beta has a gradient
+            assert name == "beta" or float(jnp.abs(a).max()) == 0.0, name
+        scale = float(jnp.abs(c).max())
+        assert scale > 0 or write == "beta_0", name
+        err = lambda x: float(jnp.abs(x - c).max())  # noqa: E731
+        assert err(a) <= max(2.0 * err(b), 1e-2 * scale) + 1e-7, (name, err(a), err(b), scale)
+
+
+def test_kernels_keep_float32_where_a_chunks_keys_are_alike(kernels_interpreted):
+    """The regime of :func:`test_alike_keys_in_a_chunk_keep_float32`: the
+    kernels' solve is the halving too, so they are as near the recurrence
+    as the XLA form is; and the packed solve alone on ``I + A`` all ones below the
+    diagonal (both heads' halves) gives the bidiagonal of 1 and -1."""
+    q, k, v, _, _ = kernel_operands(9, t=128)
+    k = jnp.broadcast_to(k[:, :, :1], k.shape)
+    flat = jnp.zeros((1, 2, 128))
+    args = (q, k, v, flat, flat + 1.0)
+    exact = RECURRENCE(*(x.astype(jnp.float32) for x in args))
+    got = jax.jit(lambda *a: gated_delta_rule(*a))(*args)
+    xla = jax.jit(on_the_xla_form)(*args)
+    for a, b, c in zip(got, xla, exact):  # bf16 products around a float32 solve, both
+        err = lambda x: float(jnp.abs(x.astype(jnp.float32) - c).max())  # noqa: E731
+        close(a.astype(jnp.float32), c, 5e-2)
+        assert err(a) <= 1.5 * err(b)
+    ones = jnp.tile(jnp.tril(jnp.ones((64, 64)), -1), (1, 2))
+    solved, = delta_rule._solve([ones])
+    close(solved, jnp.tile(jnp.eye(64) - jnp.eye(64, k=-1), (1, 2)), 1e-5)
+    a = jnp.tril(jax.random.normal(jax.random.PRNGKey(3), (2, 64, 64)) * 0.3, -1)
+    solved, = delta_rule._solve([jnp.concatenate((a[0], a[1]), axis=1)])
+    close(solved, jnp.concatenate(tuple(inverse_unit_lower(a)), axis=1), 1e-5)
+
+
+@pytest.mark.parametrize("why,t,d,dtype", [
+    ("a ragged T", 200, 128, jnp.bfloat16), ("heads of 64", KERNEL_T, 64, jnp.bfloat16),
+    ("float32 operands", KERNEL_T, 128, jnp.float32),
+])
+def test_a_shape_the_kernels_refuse_takes_the_xla_form(why, t, d, dtype, kernels_interpreted,
+                                                       monkeypatch):
+    def no_kernels(*args):
+        raise AssertionError(why + " reached the kernels")
+
+    monkeypatch.setattr(delta_rule, "_rule_by_kernels", no_kernels)
+    args = kernel_operands(4, t=t, d=d, dtype=dtype)
+    out, state = gated_delta_rule(*args)
+    want, want_state = RECURRENCE(*(x.astype(jnp.float32) for x in args))
+    close(out.astype(jnp.float32), want, 5e-2)
+    close(state, want_state, 5e-2)
+    assert metrics.REGISTRY.snapshot()[GAUGE] == UNWRITTEN
+
+
+def test_the_kernels_take_the_cells_shape_and_nothing_off_the_chip(
+        kernels_interpreted, monkeypatch):
+    takes = delta_rule.takes_delta_rule
+    monkeypatch.setattr(delta_rule, "BLOCK_CHUNKS", 16)  # as the module has it
+    assert takes(8192, 128, 128, 16, 32, jnp.bfloat16)
+    assert not takes(8192 + 64, 128, 128, 16, 32, jnp.bfloat16)  # a chunk past a grid step
+    assert not takes(8192, 128, 128, 16, 16, jnp.bfloat16)  # one value head a key head
+    assert not takes(8192, 128, 256, 16, 32, jnp.bfloat16)
+    assert not takes(8192, 128, 128, 16, 32, jnp.float32)
+    delta_rule._gauge_kernel_chunks(1, 32, 8192)
+    assert metrics.REGISTRY.snapshot()[GAUGE] == 4096
+    # off the chip the platform alone decides: the XLA form, the gauge untouched
+    monkeypatch.undo()
+    metrics.gauge(GAUGE).set(UNWRITTEN)
+    q, k, v, g, beta = (jax.ShapeDtypeStruct(x.shape, x.dtype) for x in kernel_operands())
+    text = jax.jit(gated_delta_rule).lower(q, k, v, g, beta).as_text()
+    assert "gated_delta_rule_fwd" not in text and "while" in text
+    assert metrics.REGISTRY.snapshot()[GAUGE] == UNWRITTEN
 
 
 # -- the convolution in front of it -------------------------------------------------
